@@ -23,6 +23,7 @@ import paddle_tpu as pt
 from paddle_tpu.models import GPTForCausalLM
 from paddle_tpu.models.generation import generate
 from paddle_tpu.models.gpt import GPTConfig
+from paddle_tpu.utils.runtime_env import use_compile_cache
 
 
 def main():
@@ -33,6 +34,7 @@ def main():
     ap.add_argument("--top-p", type=float, default=0.0,
                     help="0 = greedy; >0 = nucleus sampling")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = GPTConfig(vocab_size=50304, hidden_size=768, num_layers=12,
                     num_heads=12, max_position_embeddings=1024,

@@ -22,6 +22,7 @@ from paddle_tpu.jit import TrainStep
 from paddle_tpu.models import GPTForCausalLM, GPTPretrainingCriterion
 from paddle_tpu.models.gpt import GPTConfig
 from paddle_tpu.optimizer import AdamW
+from paddle_tpu.utils.runtime_env import use_compile_cache
 
 
 def main():
@@ -30,6 +31,7 @@ def main():
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=512)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = GPTConfig(vocab_size=50304, hidden_size=768, num_layers=12,
                     num_heads=12, max_position_embeddings=1024,

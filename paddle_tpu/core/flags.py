@@ -77,8 +77,8 @@ define_flag("FLAGS_check_nan_inf", False,
 define_flag("FLAGS_benchmark", False, "benchmark mode: sync after each op")
 define_flag("FLAGS_fast_bn_stats", False,
             "one-pass batch-norm statistics (running-mean pivot): one "
-            "HBM read instead of 2-3 per BN during training (+11% on "
-            "ResNet-50, see BENCH_EXTRA.md). Bit-exact for normalized "
+            "HBM read instead of 2-3 per BN during training. Bit-exact "
+            "for normalized "
             "activations; loses f32 precision only if a channel's "
             "|mean| exceeds ~1e3 x its std while the running mean is "
             "still far from the data (cold start). Default off = "

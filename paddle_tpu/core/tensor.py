@@ -254,7 +254,7 @@ class Tensor:
         """to(dtype) / to(device) / to(device, dtype)."""
         dst_dtype = None
         dst_place = None
-        from .device import Place
+        from .device import Place, parse_device
         for a in list(args) + list(kwargs.values()):
             if isinstance(a, (dtypes.DType,)) or (
                     isinstance(a, str) and a in dtypes._BY_NAME):
@@ -262,9 +262,7 @@ class Tensor:
             elif isinstance(a, Place):
                 dst_place = a
             elif isinstance(a, str):
-                from .device import set_device, get_place as _gp
-                cur = _gp()
-                dst_place = Place(*_parse_dev(a))
+                dst_place = parse_device(a)
         arr = self._data
         if dst_dtype is not None:
             from ..ops import cast
@@ -338,14 +336,6 @@ def _rebuild_tensor(arr, stop_gradient, name):
     """Unpickle target of Tensor.__reduce__ (numpy -> device array)."""
     return Tensor._wrap(jnp.asarray(arr), stop_gradient=stop_gradient,
                         name=name)
-
-
-def _parse_dev(s):
-    s = s.lower()
-    if ":" in s:
-        k, i = s.split(":")
-        return (("cpu" if k == "cpu" else "tpu"), int(i))
-    return (("cpu" if s == "cpu" else "tpu"), 0)
 
 
 def _is_tracer(x):

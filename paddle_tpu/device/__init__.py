@@ -126,8 +126,7 @@ def register_pjrt_plugin(platform_name, library_path, options=None,
     library_path: path to the vendor's PJRT plugin shared object.
     """
     from jax._src import xla_bridge
-    if getattr(xla_bridge, "backends_are_initialized",
-               lambda: False)():
+    if xla_bridge.backends_are_initialized():
         import warnings
         warnings.warn(
             "register_pjrt_plugin called after jax backends initialized: "

@@ -279,8 +279,7 @@ class AutoTuner:
 class HardwareSpec:
     """Per-chip peak numbers. Defaults: TPU v5e."""
     flops_bf16: float = 197e12      # MXU peak, bf16
-    achieved_mfu: float = 0.45      # realistic fraction of peak (measured
-    # on this framework's own benches — BENCH_EXTRA.md)
+    achieved_mfu: float = 0.45      # assumed realistic fraction of peak
     hbm_bytes_per_s: float = 819e9
     ici_bytes_per_s: float = 100e9  # per-direction, per-link (v5e 2D torus)
     dcn_bytes_per_s: float = 12.5e9

@@ -68,9 +68,8 @@ def _ring_local(q, k, v, *, axis, sm_scale, causal, chunk):
     l0 = jnp.zeros((b, h, s_loc), jnp.float32)
     # the zero carries are device-invariant at init but device-varying
     # after the first update; align their provenance for scan
-    _vary = (functools.partial(jax.lax.pcast, to="varying")
-             if hasattr(jax.lax, "pcast") else jax.lax.pvary)
-    acc0, m0, l0 = (_vary(t, (axis,)) for t in (acc0, m0, l0))
+    acc0, m0, l0 = (jax.lax.pcast(t, (axis,), to="varying")
+                    for t in (acc0, m0, l0))
     perm = [(i, (i + 1) % chunk) for i in range(chunk)]
 
     def body(carry, step):

@@ -1,6 +1,6 @@
 """Attention-bias classes for memory_efficient_attention (ref:
-python/paddle/incubate/nn/attn_bias.py — the xformers-style bias
-taxonomy). Each class can MATERIALIZE itself as an additive float mask;
+python/paddle/incubate/nn/attn_bias.py — the xformers-style family of
+bias classes). Each class can MATERIALIZE itself as an additive float mask;
 memory_efficient_attention also pattern-matches the causal/block
 classes to stay on the masked-flash path without materializing."""
 from __future__ import annotations

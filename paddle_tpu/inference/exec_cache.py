@@ -20,7 +20,7 @@ write can never be loaded.
 
 Store layout (flat directory)::
 
-    <root>/<key>.exec   pickled {payload, in_tree, out_tree}
+    <root>/<key>.exec   pickled {payload, in_tree, out_tree, device_ids}
     <root>/<key>.json   manifest: schema, family, byte count,
                         payload sha256, device fingerprint, timestamps
 
@@ -46,7 +46,7 @@ __all__ = [
     "code_fingerprint", "SCHEMA_VERSION", "ENV_DIR", "default_dir",
 ]
 
-SCHEMA_VERSION = 1
+SCHEMA_VERSION = 2
 #: environment variable naming the default store directory; when unset
 #: the engine runs without a persistent cache.
 ENV_DIR = "PADDLE_TPU_EXEC_CACHE"
@@ -212,9 +212,13 @@ class ExecCache:
         try:
             from jax.experimental import serialize_executable as se
             payload, in_tree, out_tree = se.serialize(compiled)
+            # the devices it runs on: the loader would otherwise assume
+            # every device of the backend
+            device_ids = [d.id for d in
+                          compiled.runtime_executable().local_devices()]
             blob = pickle.dumps(
                 {"payload": payload, "in_tree": in_tree,
-                 "out_tree": out_tree},
+                 "out_tree": out_tree, "device_ids": device_ids},
                 protocol=pickle.HIGHEST_PROTOCOL)
             manifest = {
                 "schema": SCHEMA_VERSION,
@@ -308,9 +312,12 @@ class ExecCache:
                 return None
             with open(self._payload_path(key), "rb") as f:
                 rec = pickle.loads(f.read())
+            import jax
             from jax.experimental import serialize_executable as se
+            by_id = {d.id: d for d in jax.devices()}
             compiled = se.deserialize_and_load(
-                rec["payload"], rec["in_tree"], rec["out_tree"])
+                rec["payload"], rec["in_tree"], rec["out_tree"],
+                execution_devices=[by_id[i] for i in rec["device_ids"]])
         except Exception:
             self._bump("corrupt")
             self._bump("misses")

@@ -54,7 +54,6 @@ from __future__ import annotations
 import collections
 import dataclasses
 import time
-import warnings
 from typing import Dict, List, Optional
 
 import jax
@@ -1274,18 +1273,17 @@ class LLMEngine:
         vdq = None if vq is None else 1.0 / vq
         T_pool = self.cache.allocator.num_blocks * bs
         # implementation pick is an executable-shape property: resolved
-        # ONCE here (the Pallas availability probe runs a device call —
-        # never inside the trace), then baked into the program
+        # ONCE here, then baked into the program
         path, why = ragged_attention_path(
             tb, T_pool if with_pool else 0, nH, kvH, H_D, bs, with_pool)
-        self._ragged_paths[fkey] = (path, why)
         if path == "jnp" and jax.default_backend() == "tpu":
             # the reference path materializes [H, T, T] scores — fine
-            # for CPU tests/oracles, a serving cliff on TPU
-            warnings.warn(
-                f"ragged executable {fkey} fell back to the jnp "
-                f"reference on a TPU backend: {why}", RuntimeWarning,
-                stacklevel=2)
+            # for CPU tests/oracles, not a serving path. The engine
+            # made this launch shape itself, so this is its bug.
+            raise RuntimeError(
+                f"ragged executable {fkey} would run the jnp reference "
+                f"on a TPU backend: {why}")
+        self._ragged_paths[fkey] = (path, why)
 
         def ragged(params, kcs, vcs, ids, rows, pos, kvs, off, wf, sel,
                    key):
@@ -2061,10 +2059,8 @@ class LLMEngine:
                 self._pool_bytes * (nb - free) // max(nb, 1))
             now = time.perf_counter()
             if now - self._hbm_sampled_at >= 1.0:
-                live = getattr(jax, "live_arrays", None)
-                if live is not None:
-                    m["hbm_live"].set(
-                        sum(getattr(a, "nbytes", 0) for a in live()))
+                m["hbm_live"].set(
+                    sum(a.nbytes for a in jax.live_arrays()))
                 self._hbm_sampled_at = now
         if _fl._ARMED:
             cfg = _fl.config()

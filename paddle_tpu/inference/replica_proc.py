@@ -25,6 +25,7 @@ importable callables, never closures.
 """
 from __future__ import annotations
 
+import contextlib
 import multiprocessing
 import os
 import queue as _queue
@@ -378,6 +379,30 @@ class ReplicaProcessClient:
 # ---------------------------------------------------------------------------
 # spawning
 # ---------------------------------------------------------------------------
+def _worker_device_env():
+    """The block a worker is spawned in, decided by what THIS process
+    holds. An accelerator belongs to one process: a parent whose JAX
+    already runs on one cannot have replica processes on it, and
+    putting them on the CPU instead would hide that — so it refuses.
+    A parent on the CPU backend hands JAX_PLATFORMS=cpu to the worker's
+    environment (its own platform may have been chosen in code, which
+    a child would not inherit). A parent that has not touched JAX
+    leaves the worker to open whatever its environment names."""
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return contextlib.nullcontext()
+    import jax
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"this process already holds the {backend} backend; a "
+            "replica process could not open the same chip. Run the "
+            "replicas in-process (ReplicaSet), or spawn them from a "
+            "parent that never touches JAX.")
+    from ..utils.runtime_env import cpu_only_child_env
+    return cpu_only_child_env()
+
+
 def start_replica_process(model_builder, model_kwargs=None,
                           engine_kwargs=None, *, tp: Optional[int] = None,
                           shard_param=None,
@@ -393,8 +418,9 @@ def start_replica_process(model_builder, model_kwargs=None,
     transport contract. `model_builder` and `shard_param` must be
     module-level importable callables (the spawn context and the RPC
     layer both pickle by reference). The worker inherits the parent's
-    environment — set XLA_FLAGS/JAX_PLATFORMS before calling when the
-    replica needs a forced device population. `role`: the fleet
+    environment (set XLA_FLAGS before calling when the replica needs a
+    forced device population); `_worker_device_env` decides its
+    platform. `role`: the fleet
     process_role the worker identifies as (default "engine"; a
     disaggregated pool uses "engine_prefill" / "engine_decode")."""
     ctx = ctx or multiprocessing.get_context("spawn")
@@ -405,7 +431,8 @@ def start_replica_process(model_builder, model_kwargs=None,
               shard_param, exec_cache_dir, bind, process_name,
               aggregator_endpoint, ready_q, role),
         daemon=True)
-    proc.start()
+    with _worker_device_env():
+        proc.start()
     deadline = time.monotonic() + start_timeout_s
     while True:
         try:

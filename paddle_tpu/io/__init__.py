@@ -437,6 +437,7 @@ class DataLoader:
         import warnings
         from . import _process_worker as PW
         from ..resilience import faults
+        from ..utils.runtime_env import cpu_only_child_env
 
         idx_batches = list(self.batch_sampler)
         if not idx_batches:
@@ -452,20 +453,17 @@ class DataLoader:
         # cached): a dataset mutated between epochs (curriculum state,
         # swapped transform) must reach the workers, exactly as it does
         # in the num_workers=0 and thread tiers. One dumps() per epoch,
-        # shared by all workers and respawns — the child unpickles it
-        # only after its env guard (see _process_worker).
+        # shared by all workers and respawns.
         import pickle
         payload_bytes = pickle.dumps(
             (self.dataset, custom, self.worker_init_fn))
         # io.* faults cross the spawn boundary via snapshot/install
         specs = faults.snapshot()
 
-        # children force JAX_PLATFORMS=cpu as worker_main's FIRST
-        # action, BEFORE the dataset bytes are unpickled — so a spawned
-        # worker can never contend for the parent's TPU. (The parent's
-        # env is deliberately NOT mutated here: a temporary
-        # process-wide JAX_PLATFORMS=cpu would race any concurrent
-        # first-time jax init in the parent and silently pin it to CPU.)
+        # workers are compute-only and must never open the parent's
+        # chip: they are spawned with JAX_PLATFORMS=cpu in their
+        # environment, which holds from the interpreter's first
+        # instruction (before spawn_main unpickles anything).
         # workers inherit the parent's observability flags at spawn
         # time and ship their metric snapshots + trace events back
         # with the "done" farewell
@@ -477,7 +475,8 @@ class DataLoader:
                 args=(w, W, payload_bytes, idx_batches, queues[w], stop,
                       resume_from, specs, attempt, obs_on),
                 daemon=True)
-            p.start()
+            with cpu_only_child_env():
+                p.start()
             return p
 
         procs = [spawn(w) for w in range(W)]

@@ -5,14 +5,13 @@ pipelines).
 
 Design: spawned processes (never fork — the parent owns a live TPU
 client; fork would duplicate its state) + SharedMemory array transport.
-Workers are compute-only: the dataset/collate/init objects cross the
-spawn boundary as an opaque pickle BYTES blob, so `worker_main` can
-force JAX_PLATFORMS=cpu before those bytes are unpickled — no import-
-or unpickle-time computation in the dataset's module chain can
-initialize a backend and contend for the parent's TPU. (Shipping the
-objects as plain Process args would not guarantee that: with the spawn
-start method the child unpickles its args in `spawn_main`, BEFORE the
-target function runs.) The default collate produces NUMPY batches —
+Workers are compute-only and must never open the parent's chip: the
+DataLoader spawns them with JAX_PLATFORMS=cpu in their environment
+(`utils.runtime_env.cpu_only_child_env`), so it holds before
+`spawn_main` imports or unpickles anything. The dataset/collate/init
+objects cross the spawn boundary as one pickle BYTES blob, made once
+per epoch and shared by every worker and respawn. The default collate
+produces NUMPY batches —
 Tensors are materialised by the parent. Large arrays travel via
 multiprocessing.shared_memory (one copy into the segment, one copy out
 in the parent — no pickle of the payload bytes); small leaves ride the
@@ -33,7 +32,6 @@ unregistered from the resource tracker so ownership can pass to the
 consumer)."""
 from __future__ import annotations
 
-import os
 import traceback
 
 import numpy as np
@@ -187,7 +185,7 @@ def worker_main(wid, num_workers, payload_bytes, idx_batches, out_queue,
     same protocol as the in-process thread tier).
 
     payload_bytes: pickle of (dataset, collate_fn_or_None,
-    worker_init_fn_or_None) — deserialized HERE, after the env guard.
+    worker_init_fn_or_None).
     resume_from: first batch index the parent still needs; a worker
     respawned to replace a dead one skips its stripe's earlier batches.
     fault_specs: a faults.snapshot() from the parent, re-armed in this
@@ -208,9 +206,6 @@ def worker_main(wid, num_workers, payload_bytes, idx_batches, out_queue,
     import pickle
     import queue as _q
     import time as _time
-    # a spawned child must never touch the parent's TPU: the env guard
-    # runs BEFORE any user code (dataset unpickle / init fn) executes
-    os.environ["JAX_PLATFORMS"] = "cpu"
     try:
         dataset, collate_fn, worker_init_fn = pickle.loads(payload_bytes)
         from ..resilience import faults

@@ -1,8 +1,9 @@
 """ctypes bindings for the native data-loader core (queue.cc).
 
-Compiled on first use with g++ (cached next to the source); every
-entry point degrades gracefully to pure-Python when no toolchain is
-present, so the framework never hard-depends on the native path."""
+Compiled on first use with g++ through `utils.cpp_extension` — the
+library is named by the hash of its source, so a stale build is never
+loaded; every entry point degrades to pure-Python when no toolchain is
+present, so the data loader never hard-depends on the native path."""
 from __future__ import annotations
 
 import ctypes
@@ -13,9 +14,8 @@ from typing import Optional
 
 import numpy as np
 
-_HERE = os.path.dirname(os.path.abspath(__file__))
-_SRC = os.path.join(_HERE, "queue.cc")
-_SO = os.path.join(_HERE, "libptio.so")
+_SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                    "queue.cc")
 _lib = None
 _lock = threading.Lock()
 
@@ -24,22 +24,11 @@ NATIVE_COLLATE_MIN_BYTES = 1 << 16  # below this np.stack wins
 
 
 def _build() -> Optional[str]:
+    from ...utils.cpp_extension import _compile
     try:
-        if os.path.exists(_SO) and (
-                not os.path.exists(_SRC)
-                or os.path.getmtime(_SO) >= os.path.getmtime(_SRC)):
-            return _SO  # prebuilt (possibly source-less install)
-    except OSError:
-        pass
-    try:
-        subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", "-pthread", "-std=c++17",
-             "-o", _SO + ".tmp", _SRC],
-            check=True, capture_output=True, timeout=120)
-        os.replace(_SO + ".tmp", _SO)
-        return _SO
-    except Exception:
-        return None
+        return _compile("ptio", [_SRC], ["-pthread"], None, False)
+    except (RuntimeError, OSError, subprocess.CalledProcessError):
+        return None     # no toolchain: the pure-Python paths serve
 
 
 def load():

@@ -14,6 +14,7 @@ whole-graph CINN compile analog).
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -381,8 +382,6 @@ class TrainStep:
             shardings = [
                 NamedSharding(mesh, shard_param(n, tuple(p.shape)))
                 for n, p in zip(pnames, self.params)]
-            self.params = [jax.device_put(p, s)
-                           for p, s in zip(self.params, shardings)]
             repl = NamedSharding(mesh, PartitionSpec())
 
             def _shard_state(v, psh):
@@ -390,12 +389,36 @@ class TrainStep:
                 return jax.device_put(
                     v, psh if getattr(v, "shape", ()) != () else repl)
 
-            self.opt_states = [
-                {k: _shard_state(v, s) for k, v in st.items()}
-                for st, s in zip(self.opt_states, shardings)]
-            self.buffers = [jax.device_put(b, repl) for b in self.buffers]
+            # one parameter at a time, and the model and the optimizer
+            # are re-pointed at the committed arrays as they are made
+            # (as without a mesh, they see what the step sees): the
+            # unsharded originals all sit on the default device, and at
+            # real sizes it cannot hold them next to its shard
+            for i, (p, s) in enumerate(zip(ptensors, shardings)):
+                p._data = self.params[i] = jax.device_put(
+                    self.params[i], s)
+                if self.opt_states[i]:
+                    optimizer._accumulators[id(p)] = self.opt_states[i] = {
+                        k: _shard_state(v, s)
+                        for k, v in self.opt_states[i].items()}
+            for i, b in enumerate(btensors):
+                b._data = self.buffers[i] = jax.device_put(
+                    self.buffers[i], repl)
             if shard_data is not None:
                 self._data_sharding = NamedSharding(mesh, shard_data)
+        # what the Pallas kernels need to split themselves over the mesh
+        # (the compiler cannot partition them): the mesh and the axes
+        # the batch dimension is sharded over
+        self._kernel_plan = contextlib.nullcontext
+        if self.mesh is not None:
+            from ..kernels.pallas.flash_attention import mesh_plan
+            lead = (self._data_sharding.spec[0]
+                    if self._data_sharding is not None
+                    and len(self._data_sharding.spec) else None)
+            batch_axes = (() if lead is None else
+                          lead if isinstance(lead, tuple) else (lead,))
+            self._kernel_plan = functools.partial(
+                mesh_plan, self.mesh, batch_axes)
         self._donate = donate
         # numerics plane: trainable-param names + optimizer group
         # labels for the packed stats bundle (computed once — the
@@ -516,7 +539,8 @@ class TrainStep:
         lr_val = self.optimizer.get_lr()
         lr = jnp.asarray(lr_val, jnp.float32)
         from ..utils.watchdog import watchdog
-        with watchdog(what=f"TrainStep step {step_id}") as wd:
+        with watchdog(what=f"TrainStep step {step_id}") as wd, \
+                self._kernel_plan():
             out = self._step_fn(
                 self.params, self.opt_states, self.buffers, seed, lr,
                 args, kwargs)

@@ -37,7 +37,10 @@ the [b,s,h,d] <-> [b,s,h*d] reshape is free (no axis reordering).
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -191,20 +194,6 @@ def _seg_operands(segment_ids, b, sq, sk):
     return q_seg, kv_seg
 
 
-def _validated_bw_window():
-    """The device's validated-bandwidth window from
-    observability.perf.VALIDATED_BW_WINDOW (BENCH_EXTRA r5 methodology:
-    sweeps timed outside it pick noise winners). None = no validated
-    window known for this device — the sweep runs unvalidated, which
-    is the honest option when there is nothing to validate against."""
-    import jax as _jax
-    from ...observability import perf as _perf
-    try:
-        return _perf.lookup(_jax.devices()[0], _perf.VALIDATED_BW_WINDOW)
-    except Exception:
-        return None
-
-
 def _autotuned_blocks(kind, q, k, H, Hk, causal, has_seg, defaults,
                       run_shape, normalize):
     """Per-(shape-class, device-generation) {block_q, block_k} search
@@ -212,11 +201,7 @@ def _autotuned_blocks(kind, q, k, H, Hk, causal, has_seg, defaults,
     a candidate set (hand-tuned defaults included, so tuned >= default
     up to noise) on synthetic data and persists the winner; later calls
     and later PROCESSES pay one dict lookup. Tracer-safe: measurement
-    uses fresh concrete arrays, never the traced operands. The sweep is
-    constrained to the validated-bandwidth window (ISSUE 10: the shipped
-    seq-2048 fwd config was tuned in an unvalidated window — tune()
-    discards sweeps whose effective-BW probes fall outside
-    perf.VALIDATED_BW_WINDOW instead of persisting noise)."""
+    uses fresh concrete arrays, never the traced operands."""
     from . import autotune
     import jax as _jax
     if not autotune.enabled():
@@ -229,8 +214,10 @@ def _autotuned_blocks(kind, q, k, H, Hk, causal, has_seg, defaults,
     # batch size is deliberately NOT in the key: blocks are per-tile
     # choices and b only multiplies the grid — keying on it would stall
     # a variable-batch serving workload with a fresh search per b
-    key = (kind, sq, sk, H, Hk, HD // H, str(q.dtype), int(causal),
-           int(has_seg))
+    # (the backward gets H/Hk back from custom_vjp residuals as typed
+    # scalars: plain ints keep one spelling of the key)
+    key = (kind, sq, sk, int(H), int(Hk), HD // int(H), str(q.dtype),
+           int(causal), int(has_seg))
     hit = autotune.lookup(key)
     if hit is not None:
         return hit
@@ -257,11 +244,13 @@ def _autotuned_blocks(kind, q, k, H, Hk, causal, has_seg, defaults,
     # closure per invocation would recompile every sample — measured
     # 500 s of tuning vs ~90 s with cached runners)
     runners: dict = {}
-    return autotune.tune(
-        key, norm,
-        lambda c: autotune._time_call(
-            runners.setdefault(c, run_shape(*c))),
-        bw_window=_validated_bw_window())
+
+    def _timed(c):
+        if c not in runners:
+            runners[c] = run_shape(*c)
+        return autotune._time_call(runners[c])
+
+    return autotune.tune(key, norm, _timed)
 
 
 def _flash_fwd_fused(q, k, v, H, causal, block_q=256, block_k=1024,
@@ -642,23 +631,11 @@ def _xla_attention(q, k, v, attn_mask, causal, sm_scale, segment_ids=None):
     return jnp.swapaxes(o, 1, 2).astype(q.dtype)
 
 
-_pallas_ok = None
-
-
 def _pallas_available():
-    global _pallas_ok
-    if _pallas_ok is None:
-        try:
-            if jax.default_backend() != "tpu":
-                _pallas_ok = False
-            else:
-                x = jnp.zeros((1, 128, 128), jnp.float32)
-                _flash_fwd_fused(x, x, x, 1, False, block_q=128,
-                                 block_k=128)
-                _pallas_ok = True
-        except Exception:
-            _pallas_ok = False
-    return _pallas_ok
+    """The Pallas kernels are the path on a TPU backend and only there.
+    Nothing is tried and nothing is caught: a kernel the chip's
+    compiler refuses raises from the call that launched it."""
+    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
@@ -746,6 +723,49 @@ def attention_path(q_shape, k_shape, masked=False):
     return ("pallas", "")
 
 
+# (mesh, batch_axes) while a program that GSPMD will partition over
+# `mesh` is being traced; None otherwise
+_MESH_PLAN: contextvars.ContextVar = contextvars.ContextVar(
+    "flash_mesh_plan", default=None)
+
+
+@contextlib.contextmanager
+def mesh_plan(mesh, batch_axes=()):
+    """Tell the kernels traced inside this block that the program will
+    be partitioned over `mesh`, with the batch dimension of its data
+    split over `batch_axes`. The compiler cannot partition a Mosaic
+    kernel by itself, so under a plan `flash_attention` splits its call
+    with `shard_map`: batch over `batch_axes`, heads over the mesh's
+    other axes (the Megatron layout) where the per-device head count
+    still fits the kernel, whole on every device of an axis where it
+    does not."""
+    token = _MESH_PLAN.set((mesh, tuple(batch_axes)))
+    try:
+        yield
+    finally:
+        _MESH_PLAN.reset(token)
+
+
+def _planned_specs(plan, q_shape, k_shape):
+    """PartitionSpecs (q/k/v/out [b, s, h, d], segment ids [b, s]) that
+    split the kernel over the plan's mesh."""
+    from jax.sharding import PartitionSpec as P
+    mesh, batch_axes = plan
+    b, sq, h, d = q_shape
+    hk = k_shape[2]
+    batch_axes = tuple(a for a in batch_axes if mesh.shape[a] > 1)
+    if b % math.prod(mesh.shape[a] for a in batch_axes):
+        batch_axes = ()
+    head_axes = tuple(a for a in mesh.axis_names
+                      if a not in batch_axes and mesh.shape[a] > 1)
+    n = math.prod(mesh.shape[a] for a in head_axes)
+    if h % n or hk % n or not _shapes_ok(
+            (b, sq, h // n, d), (b, k_shape[1], hk // n, d)):
+        head_axes = ()
+    return (P(batch_axes or None, None, head_axes or None, None),
+            P(batch_axes or None, None))
+
+
 def flash_attention(q, k, v, attn_mask=None, causal=False,
                     softmax_scale=None, segment_ids=None):
     """[b, s, h, d] in and out; k/v may have fewer heads (GQA/MQA).
@@ -765,5 +785,15 @@ def flash_attention(q, k, v, attn_mask=None, causal=False,
     if segment_ids is not None:
         segment_ids = (jnp.asarray(segment_ids[0], jnp.int32),
                        jnp.asarray(segment_ids[1], jnp.int32))
+    plan = _MESH_PLAN.get()
+    if plan is not None and use_pallas:
+        spec, seg_spec = _planned_specs(plan, q.shape, k.shape)
+        return jax.shard_map(
+            lambda q, k, v, seg: _flash_core(q, k, v, seg, causal,
+                                             sm_scale, True),
+            mesh=plan[0],
+            in_specs=(spec, spec, spec,
+                      None if segment_ids is None else (seg_spec,) * 2),
+            out_specs=spec, check_vma=False)(q, k, v, segment_ids)
     return _flash_core(q, k, v, segment_ids, causal, sm_scale,
                        bool(use_pallas))

@@ -13,6 +13,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .flash_attention import _pallas_available
+
 
 def _ln_kernel(x_ref, w_ref, b_ref, o_ref, *, eps, has_w, has_b):
     x = x_ref[:].astype(jnp.float32)
@@ -45,24 +47,6 @@ def _rows_block(n_rows, hidden, dtype):
     while n_rows % rows:
         rows -= 1
     return rows
-
-
-_pallas_ok = None
-
-
-def _pallas_available():
-    global _pallas_ok
-    if _pallas_ok is None:
-        try:
-            if jax.default_backend() != "tpu":
-                _pallas_ok = False
-            else:
-                x = jnp.zeros((8, 128), jnp.float32)
-                _ln_pallas(x, None, None, 1e-5)
-                _pallas_ok = True
-        except Exception:
-            _pallas_ok = False
-    return _pallas_ok
 
 
 def _ln_pallas(x2d, w, b, eps, interpret=False):

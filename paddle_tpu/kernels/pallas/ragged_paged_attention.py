@@ -63,15 +63,12 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .flash_attention import _pallas_available
+
 _NEG_INF = -1e30
 _LANES = 128
 _SUBL = 8
 _VMEM_LIMIT = 64 * 1024 * 1024
-
-# jax renamed TPUCompilerParams -> CompilerParams; support both so the
-# kernel loads on the CPU test image's older jax and on TPU images
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams", None)
 
 
 # ---------------------------------------------------------------------------
@@ -166,7 +163,8 @@ def _ragged_reference(q, k_new, v_new, kpool, vpool, rows, pos,
 def _ragged_kernel(voff_ref, qrow_ref, qpos_ref, krow_ref, kpos_ref,
                    dq_ref, q_ref, kp_ref, vp_ref, kn_ref, vn_ref,
                    o_ref, acc_ref, m_ref, l_ref,
-                   *, H, Hk, D, bq, bkp, bkn, nkp, nkn, bs, int8_pool):
+                   *, H, Hk, D, bq, bkp, bkn, nkp, nkn, bs, tpg,
+                   int8_pool):
     """One (q-tile, kv-tile) program of the online-softmax sweep. The
     kv axis is [pool tiles..., packed tiles...]: programs j < nkp read
     the paged pool (validity from the per-token page-offset operand),
@@ -214,22 +212,34 @@ def _ragged_kernel(voff_ref, qrow_ref, qpos_ref, krow_ref, kpos_ref,
     if nkp:     # statically absent when the launch reads no pool
         @pl.when(j < nkp)
         def _pool_phase():
-            # ownership mask rebuilt in-kernel: pool tile j covers
-            # pages [j*bkp//bs, ...), each page contributing bs token
-            # columns valid while slot < per-(q-token, page) count
+            # ownership mask rebuilt in-kernel. voff_ref is the lane
+            # group (selected by the BlockSpec from j) that holds this
+            # tile's per-(q-token, page) valid-slot counts at lanes
+            # [o, o + bkp//bs). A one-hot [LANES, bkp] page->column
+            # expansion on the MXU spreads each count over its page's
+            # bs columns — Mosaic has no dynamic lane slice, and the
+            # counts (<= bs <= 256) and the 0/1 selector are exact at
+            # any matmul precision.
             kf = kp_ref[:]
             vf = vp_ref[:]
             if int8_pool:
                 kf = kf.astype(jnp.float32)
                 vf = vf.astype(jnp.float32)
-            slot = jax.lax.broadcasted_iota(jnp.int32, (bq, bs), 1)
-            oks = []
-            for t in range(bkp // bs):
-                page = j * (bkp // bs) + t
-                vc = jax.lax.dynamic_slice(voff_ref[:], (0, page),
-                                           (bq, 1))    # [bq, 1]
-                oks.append(slot < vc)
-            ok = jnp.concatenate(oks, axis=1)          # [bq, bkp]
+            o = (j % tpg) * (bkp // bs)
+            page = jax.lax.broadcasted_iota(
+                jnp.int32, (_LANES, bkp), 0) - o
+            col = jax.lax.broadcasted_iota(jnp.int32, (_LANES, bkp), 1)
+            sel = (col >= page * bs) & (col < (page + 1) * bs)
+            vc = jax.lax.dot_general(
+                voff_ref[:], sel.astype(jnp.float32),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)     # [bq, bkp]
+            base = jnp.sum(
+                jnp.where(sel, (page * bs).astype(jnp.float32), 0.0),
+                axis=0, keepdims=True)                  # [1, bkp]
+            slot = jax.lax.broadcasted_iota(
+                jnp.int32, (1, bkp), 1).astype(jnp.float32) - base
+            ok = slot < vc                              # [bq, bkp]
             _online(kf, vf, ok, int8_pool)
 
     @pl.when(j >= nkp)
@@ -304,10 +314,8 @@ def _autotuned_ragged_blocks(T, T_pool, H, Hk, D, dtype, int8_pool, bs,
             runners[c] = run_shape(*c)
         return runners[c]
 
-    from .flash_attention import _validated_bw_window
     return autotune.tune(
-        key, keep, lambda c: autotune._time_call(_runner(c)),
-        bw_window=_validated_bw_window())
+        key, keep, lambda c: autotune._time_call(_runner(c)))
 
 
 def _ragged_pallas(q, k_new, v_new, kpool, vpool, rows, pos, kv_start,
@@ -315,12 +323,14 @@ def _ragged_pallas(q, k_new, v_new, kpool, vpool, rows, pos, kv_start,
                    with_pool=True, interpret=False, block_q=256,
                    block_k=512, autotune_ok=True):
     """Pallas path. Operand prep (all cheap [T]-sized int work in XLA):
-      voff [T, NB_pad]: per packed token, per page: how many leading
-        slots of that page are valid context for the token's row
-        (min(kv_start[row] - page_start, bs), clipped to [0, bs]);
+      voff [T, lane groups * LANES] f32: per packed token, per page:
+        how many leading slots of that page are valid context for the
+        token's row (min(kv_start[row] - page_start, bs), clipped to
+        [0, bs]), laid out so each pool tile's pages sit inside one
+        LANES-wide group the BlockSpec can select;
       row/pos replicated id tiles for the packed phase;
-      dq [2, Hk] -> [SUBL, LANES] f32: per-kv-head k/v dequant scales
-        (ones when the pool is fp)."""
+      dq [2, Hk] f32 in SMEM: per-kv-head k/v dequant scales (ones
+        when the pool is fp)."""
     T, H, D = q.shape
     Hk = k_new.shape[1]
     B, NB = off.shape
@@ -328,6 +338,10 @@ def _ragged_pallas(q, k_new, v_new, kpool, vpool, rows, pos, kv_start,
     int8_pool = bool(with_pool) and kpool.dtype == jnp.int8
     if with_pool:
         T_pool = kpool.shape[0]
+        if NB * bs != T_pool:
+            raise ValueError(
+                f"off maps {NB} blocks of {bs} but the pool holds "
+                f"{T_pool} tokens")
     else:
         # tiny dummy pool keeps one kernel shape: nkp=0 drops the phase
         T_pool = 0
@@ -339,8 +353,9 @@ def _ragged_pallas(q, k_new, v_new, kpool, vpool, rows, pos, kv_start,
         clamps — the dedup key for the autotune candidate set."""
         ebq = _pick_div(T, bq, min(T, _SUBL)) or T
         ekn = (_pick_div(T, bk, _LANES) or T) if T >= _LANES else T
-        ekp = (_pick_div(T_pool, max(bk, bs), bs) or T_pool) \
-            if T_pool else 0
+        # a pool tile's pages must fit one lane group of voff
+        ekp = (_pick_div(T_pool, min(max(bk, bs), _LANES * bs), bs)
+               or T_pool) if T_pool else 0
         return (ebq, ekn, ekp)
 
     if autotune_ok and not interpret and (block_q, block_k) == (256, 512):
@@ -372,7 +387,11 @@ def _ragged_pallas(q, k_new, v_new, kpool, vpool, rows, pos, kv_start,
     bq, bkn, bkp = _eff(block_q, block_k)
     nkp = (T_pool // bkp) if T_pool else 0
     nkn = T // bkn
-    NB_pad = -(-max(NB, 1) // _LANES) * _LANES
+    # pool tile j's ppt pages live at lanes [(j % tpg) * ppt, +ppt) of
+    # voff's lane group j // tpg
+    ppt = (bkp // bs) if nkp else 1
+    tpg = _LANES // ppt
+    ngrp = -(-max(nkp, 1) // tpg)
 
     qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
     q2 = qs.reshape(T, H * D)
@@ -389,8 +408,15 @@ def _ragged_pallas(q, k_new, v_new, kpool, vpool, rows, pos, kv_start,
         jnp.where(page_start >= 0,
                   kv_start[rc][:, None] - page_start, 0),
         0, bs)
-    vcount = jnp.where(live[:, None], vcount, 0).astype(jnp.int32)
-    voff = jnp.zeros((T, NB_pad), jnp.int32).at[:, :NB].set(vcount)
+    vcount = jnp.where(live[:, None], vcount, 0).astype(jnp.float32)
+    if nkp:
+        vcount = jnp.pad(vcount.reshape(T, nkp, ppt),
+                         ((0, 0), (0, ngrp * tpg - nkp), (0, 0)))
+        voff = jnp.pad(vcount.reshape(T, ngrp, tpg * ppt),
+                       ((0, 0), (0, 0), (0, _LANES - tpg * ppt)))
+        voff = voff.reshape(T, ngrp * _LANES)
+    else:
+        voff = jnp.zeros((T, _LANES), jnp.float32)
 
     qrow = jnp.broadcast_to(rows[:, None], (T, _LANES))
     qpos = jnp.broadcast_to(pos[:, None], (T, _LANES))
@@ -401,10 +427,12 @@ def _ragged_pallas(q, k_new, v_new, kpool, vpool, rows, pos, kv_start,
         dq = dq.at[0].set(kdq.astype(jnp.float32))
     if vdq is not None:
         dq = dq.at[1].set(vdq.astype(jnp.float32))
-    dq2 = jnp.zeros((_SUBL, _LANES), jnp.float32).at[:2, :Hk].set(dq)
 
     def _pool_idx(i, j):
         return (jnp.minimum(j, max(nkp - 1, 0)), 0)
+
+    def _voff_idx(i, j):
+        return (i, jnp.minimum(j, max(nkp - 1, 0)) // tpg)
 
     def _pack_idx(i, j):
         return (jnp.clip(j - nkp, 0, nkn - 1), 0)
@@ -413,18 +441,18 @@ def _ragged_pallas(q, k_new, v_new, kpool, vpool, rows, pos, kv_start,
     kernel = functools.partial(
         _ragged_kernel, H=H, Hk=Hk, D=D, bq=bq,
         bkp=bkp if nkp else bs, bkn=bkn, nkp=nkp, nkn=nkn, bs=bs,
-        int8_pool=int8_pool)
+        tpg=tpg, int8_pool=int8_pool)
     def _pack_idx_ids(i, j):
         # kv-side id tiles are [_SUBL, T]: block column j - nkp
         return (0, jnp.clip(j - nkp, 0, nkn - 1))
 
     in_specs = [
-        pl.BlockSpec((bq, NB_pad), lambda i, j: (i, 0)),      # voff
+        pl.BlockSpec((bq, _LANES), _voff_idx),                # voff
         pl.BlockSpec((bq, _LANES), lambda i, j: (i, 0)),      # qrow
         pl.BlockSpec((bq, _LANES), lambda i, j: (i, 0)),      # qpos
         pl.BlockSpec((_SUBL, bkn), _pack_idx_ids),            # krow
         pl.BlockSpec((_SUBL, bkn), _pack_idx_ids),            # kpos
-        pl.BlockSpec((_SUBL, _LANES), lambda i, j: (0, 0)),   # dq
+        pl.BlockSpec(memory_space=pltpu.SMEM),                # dq
         pl.BlockSpec((bq, H * D), lambda i, j: (i, 0)),       # q
         pl.BlockSpec((bkp if nkp else _SUBL, Hk * D),
                      _pool_idx),                              # kpool
@@ -433,11 +461,6 @@ def _ragged_pallas(q, k_new, v_new, kpool, vpool, rows, pos, kv_start,
         pl.BlockSpec((bkn, Hk * D), _pack_idx),               # k_new
         pl.BlockSpec((bkn, Hk * D), _pack_idx),               # v_new
     ]
-    compiler_params = None
-    if _CompilerParams is not None and not interpret:
-        compiler_params = _CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=_VMEM_LIMIT)
     out = pl.pallas_call(
         kernel,
         grid=grid,
@@ -449,42 +472,17 @@ def _ragged_pallas(q, k_new, v_new, kpool, vpool, rows, pos, kv_start,
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
         ],
-        **({"compiler_params": compiler_params}
-           if compiler_params is not None else {}),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-    )(voff, qrow, qpos, krow, kpos, dq2, q2, kp2, vp2, kn2, vn2)
+    )(voff, qrow, qpos, krow, kpos, dq, q2, kp2, vp2, kn2, vn2)
     return out.reshape(T, H, D)
 
 
 # ---------------------------------------------------------------------------
 # dispatch
 # ---------------------------------------------------------------------------
-_pallas_ok = None
-
-
-def _pallas_available():
-    global _pallas_ok
-    if _pallas_ok is None:
-        try:
-            if jax.default_backend() != "tpu":
-                _pallas_ok = False
-            else:
-                T, H, D = 8, 1, 128
-                z = jnp.zeros((T, H, D), jnp.float32)
-                _ragged_pallas(
-                    z, z, z, jnp.zeros((128, H, D), jnp.float32),
-                    jnp.zeros((128, H, D), jnp.float32),
-                    jnp.zeros((T,), jnp.int32),
-                    jnp.arange(T, dtype=jnp.int32),
-                    jnp.zeros((1,), jnp.int32),
-                    jnp.zeros((1, 2), jnp.int32), 64, 1.0,
-                    autotune_ok=False)
-                _pallas_ok = True
-        except Exception:
-            _pallas_ok = False
-    return _pallas_ok
-
-
 def _shape_reject_reason(T, T_pool, H, Hk, D, block_size, with_pool):
     """None if the Pallas kernel applies, else a human-readable reason."""
     if T < _SUBL or T % _SUBL:
@@ -504,6 +502,10 @@ def _shape_reject_reason(T, T_pool, H, Hk, D, block_size, with_pool):
     if with_pool:
         if block_size % _SUBL:
             return f"block_size {block_size} must be a multiple of {_SUBL}"
+        if block_size > 256:
+            # per-page slot counts ride a matmul that may round its
+            # operands to bfloat16: integers stay exact up to 256
+            return f"block_size {block_size} must be <= 256"
         if T_pool % block_size:
             return "pool length must be a multiple of block_size"
     return None

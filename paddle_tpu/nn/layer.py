@@ -325,11 +325,9 @@ class Layer:
             self._transform(cast_float)
             self._dtype = dtypes.to_dtype(dtype).name
         if device is not None:
-            from ..core.device import Place
-            place = device if isinstance(device, Place) else None
-            if place is None:
-                from ..core.tensor import _parse_dev
-                place = Place(*_parse_dev(str(device)))
+            from ..core.device import Place, parse_device
+            place = (device if isinstance(device, Place)
+                     else parse_device(str(device)))
             self._transform(lambda a: jax.device_put(a, place.jax_device()))
         return self
 

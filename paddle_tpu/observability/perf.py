@@ -29,12 +29,8 @@ sub-surfaces, all near-zero when observability is disabled:
   bound=hbm|flops}`. Peaks come from the per-chip spec tables below
   (shared with bench.py); an UNKNOWN device (the CPU test box) gets NO
   roofline series — an honest absence beats a made-up denominator.
-  Spec peaks are the denominator by convention; BENCH_EXTRA r5
-  measured the shared chip's EFFECTIVE bandwidth at 233-314 GB/s vs
-  the 819 GB/s v5e spec in degraded windows (`VALIDATED_BW_WINDOW`),
-  so a utilization read taken in such a window understates the kernel
-  — `set_device_peaks()` lets a session that has measured its own
-  window pin the denominator it validated.
+  Spec peaks are the denominator by convention; `set_device_peaks()`
+  lets a test or a session pin another one.
 
 * **Dispatch-gap profiler.** The eager autograd engine
   (`autograd.tape.run_backward`) reports the host-side gap between
@@ -68,67 +64,56 @@ __all__ = [
     "note_graph_cache", "family_records",
     "reset_window", "device_peaks", "set_device_peaks", "lookup",
     "interconnect_peaks", "set_interconnect_peaks",
-    "PEAK_BF16_FLOPS", "HBM_BYTES_PER_SEC", "VALIDATED_BW_WINDOW",
+    "PEAK_BF16_FLOPS", "HBM_BYTES_PER_SEC",
     "ICI_BYTES_PER_SEC", "DCN_BYTES_PER_SEC",
     "DISPATCH_GAP_BUCKETS",
 ]
 
 # ---------------------------------------------------------------------------
-# device peaks (single source of truth — bench.py wraps these with its
-# historical v5e defaults; the roofline gauges use them STRICTLY: an
-# unmatched device kind publishes no series)
+# device peaks, per jax device, keyed by the `device_kind` string the
+# installed runtime reports (jax 0.9.0 / libtpu 0.0.34; the strings
+# were read from `jax.experimental.topologies.get_topology_desc`).
+# Only generations where one jax device is one chip are listed: a
+# per-chip figure under a per-core device would be a wrong denominator.
+# A device kind that is not a key has NO peaks: the roofline gauges
+# publish nothing for it and bench.py refuses to compute a utilization.
+#
+# Sources. "TPU v5 lite" (v5e): Google Cloud documentation, "TPU v5e" —
+# 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s chip-to-chip
+# interconnect. "TPU v5" (v5p) and "TPU v6 lite" (v6e): the same
+# documentation's pages for those versions as copied into this table
+# by earlier PRs; not re-checked against a chip, none was available.
+# DCN figures are a host NIC's ~25 GB/s split over the host's chips —
+# an estimate, not a published peak.
 # ---------------------------------------------------------------------------
 PEAK_BF16_FLOPS = {
-    # per-chip peak bf16 FLOP/s
-    "v5e": 197e12, "v5litepod": 197e12, "v5p": 459e12, "v4": 275e12,
-    "v3": 123e12, "v6e": 918e12,
+    "TPU v5 lite": 197e12, "TPU v5": 459e12, "TPU v6 lite": 918e12,
 }
 
 HBM_BYTES_PER_SEC = {
-    # per-chip HBM bandwidth (spec)
-    "v5e": 819e9, "v5litepod": 819e9, "v5p": 2765e9, "v4": 1228e9,
-    "v3": 900e9, "v6e": 1640e9,
+    "TPU v5 lite": 819e9, "TPU v5": 2765e9, "TPU v6 lite": 1640e9,
 }
 
-# measured EFFECTIVE bandwidth window on the shared v5e (BENCH_EXTRA
-# round-5 methodology findings): the spec denominator overstates what a
-# degraded window can deliver — surfaced by tools/perf_ledger.py next
-# to utilization numbers so low reads get interpreted honestly
-VALIDATED_BW_WINDOW = {
-    "v5e": (233e9, 314e9), "v5litepod": (233e9, 314e9),
-}
-
-# per-chip aggregate ONE-WAY interconnect bandwidth (spec): ICI is the
-# sum over the chip's inter-chip links (v5e: 4 links x 45 GB/s, v4/v5p:
-# 6 links), DCN the chip's share of the host NIC (hosts split ~25 GB/s
-# over their chips). The collective observability layer
-# (observability.comms) reads these the way the roofline gauges read
-# the HBM table: STRICTLY — an unknown device publishes no
+# per-chip aggregate ONE-WAY interconnect bandwidth. The collective
+# observability layer (observability.comms) reads these the way the
+# roofline gauges read the HBM table: an unknown device publishes no
 # link-utilization series, and algorithmic bandwidth stands alone as
-# an absolute gauge. Spec caveat mirrors VALIDATED_BW_WINDOW: these
-# are link peaks, not what a congested fabric delivers.
+# an absolute gauge. These are link peaks, not what a congested fabric
+# delivers.
 ICI_BYTES_PER_SEC = {
-    "v5e": 1.8e11, "v5litepod": 1.8e11,   # 4 x 45 GB/s
-    "v5p": 5.4e11,                        # 6 x 90 GB/s
-    "v4": 2.7e11,                         # 6 x 45 GB/s
-    "v3": 1.4e11,
-    "v6e": 3.6e11,                        # 4 x 90 GB/s
+    "TPU v5 lite": 2.0e11, "TPU v5": 5.4e11, "TPU v6 lite": 3.6e11,
 }
 
 DCN_BYTES_PER_SEC = {
-    "v5e": 3.1e9, "v5litepod": 3.1e9, "v6e": 3.1e9, "v3": 3.1e9,
-    "v4": 6.2e9, "v5p": 6.2e9,
+    "TPU v5 lite": 3.1e9, "TPU v5": 6.2e9, "TPU v6 lite": 3.1e9,
 }
 
 
-def lookup(device, table: dict, default=None):
-    """Substring match of the device kind against a peak table (the
-    bench.py `_device_lookup` convention, shared)."""
-    kind = getattr(device, "device_kind", "").lower().replace(" ", "")
-    for key, val in table.items():
-        if key in kind:
-            return val
-    return default
+def lookup(device, table: dict):
+    """The table's entry for the device's `device_kind`, or None when
+    the kind is not a key. Exact match: "TPU v5" must not answer for
+    "TPU v5 lite"."""
+    return table.get(getattr(device, "device_kind", None))
 
 
 # operator/test override: (peak_flops, peak_bytes_per_sec) or None
@@ -139,9 +124,8 @@ def set_device_peaks(flops: Optional[float] = None,
                      bytes_per_sec: Optional[float] = None) -> None:
     """Pin the roofline denominators explicitly — for tests on the CPU
     box (which otherwise publishes no roofline series) and for sessions
-    that measured their own validated-bandwidth window (BENCH_EXTRA:
-    the shared chip's effective BW runs well under spec in degraded
-    windows). Call with no arguments to clear the override."""
+    that measured their own peaks. Call with no arguments to clear the
+    override."""
     global _PEAK_OVERRIDE
     if flops is None and bytes_per_sec is None:
         _PEAK_OVERRIDE = None

@@ -535,8 +535,8 @@ def batch_norm(x, running_mean, running_var, weight=None, bias=None,
             # running mean as pivot p. Both sums reduce the SAME
             # centered input, so XLA multi-output fusion computes them
             # in ONE read of the activation (jnp.mean+jnp.var re-read
-            # it: measured 27.5 -> 20.6 GB/step on ResNet-50,
-            # BENCH_EXTRA.md; a Welford lax.reduce is stable but
+            # it: 27.5 -> 20.6 GB/step on ResNet-50 in a deleted
+            # pre-round record; a Welford lax.reduce is stable but
             # defeats the fusion). Precision caveat on the flag help.
             n = 1.0
             for a in axes:

@@ -31,20 +31,23 @@ import ctypes
 import hashlib
 import os
 import subprocess
-import tempfile
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
 from ..core.tensor import Tensor
+from .runtime_env import CHECKOUT
 
 __all__ = ["load", "CppExtension", "CUDAExtension", "get_build_directory"]
 
 
 def get_build_directory():
+    """`PT_EXTENSION_DIR`, else `<checkout>/.pt_extensions` (gitignored):
+    a fixed place next to the sources, so that what is built belongs to
+    this checkout and nothing is left in the system temp directory."""
     d = os.environ.get("PT_EXTENSION_DIR") or os.path.join(
-        tempfile.gettempdir(), "paddle_tpu_extensions")
+        CHECKOUT, ".pt_extensions")
     os.makedirs(d, exist_ok=True)
     return d
 
@@ -59,13 +62,23 @@ def _compile(name, sources, extra_cflags, build_directory, verbose,
     tag.update(" ".join(list(extra_cflags or []) + list(ldflags)).encode())
     lib_path = os.path.join(build_dir, f"{name}_{tag.hexdigest()[:12]}.so")
     if not os.path.exists(lib_path):
+        # built beside its final name and renamed into place: another
+        # process compiling the same sources never loads a partial file
+        tmp_path = f"{lib_path}.{os.getpid()}.tmp"
         # -l libraries must FOLLOW the objects that reference them
         cmd = (["g++", "-O3", "-shared", "-fPIC", "-std=c++17"]
                + list(extra_cflags or []) + list(sources)
-               + list(ldflags) + ["-o", lib_path])
+               + list(ldflags) + ["-o", tmp_path])
         if verbose:
             print("cpp_extension:", " ".join(cmd))
-        subprocess.run(cmd, check=True, capture_output=not verbose)
+        try:
+            subprocess.run(cmd, check=True, capture_output=not verbose)
+        except FileNotFoundError as e:
+            raise RuntimeError(
+                f"g++ not found: {name} is compiled from "
+                f"{', '.join(sources)} on first use and there is no "
+                "other implementation") from e
+        os.replace(tmp_path, lib_path)
     return lib_path
 
 

@@ -336,3 +336,76 @@ def test_bwd_tpu_bf16_multi_kblock_partials():
     dq_x = jax.grad(f_x)(q, k, v)
     rel = float(jnp.abs(dq_p - dq_x).max() / (jnp.abs(dq_x).max() + 1e-9))
     assert rel < 2e-2, rel
+
+
+# ---------------------------------------------------------------------------
+# no probe, no fallback; and the kernel under a mesh
+# ---------------------------------------------------------------------------
+def test_tpu_backend_kernel_failure_raises_not_falls_back(monkeypatch):
+    """On a tpu backend the Pallas kernel IS the path: when it cannot
+    compile (here: a Mosaic kernel handed to the CPU compiler) the error
+    reaches the caller. It used to be caught by a probe that latched the
+    XLA composite in, with nothing but a missing speed-up to show it."""
+    q, k, v = _make()
+    assert not fa._pallas_available()           # off tpu: no, untried
+    assert fa.attention_path(q.shape, k.shape)[0] == "xla"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_AUTOTUNE", "0")
+    assert fa.attention_path(q.shape, k.shape) == ("pallas", "")
+    with pytest.raises(Exception) as exc:
+        jax.block_until_ready(fa.flash_attention(q, k, v, causal=True))
+    assert "interpret" in str(exc.value).lower() \
+        or "pallas" in str(exc.value).lower()
+    # shape-based routing is a decision from something the code can
+    # see, and stays: an odd length still takes the composite
+    odd = _make(s=100)
+    out = fa.flash_attention(*odd, causal=True)
+    assert out.shape == odd[0].shape
+
+
+def _interpreted_kernels(monkeypatch):
+    """Steer the dispatch onto the Pallas kernels on the CPU test box:
+    the backend check answers "tpu" and the two kernels run in
+    interpret mode."""
+    import functools
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(fa, "_flash_fwd_fused", functools.partial(
+        fa._flash_fwd_fused, interpret=True))
+    monkeypatch.setattr(fa, "_flash_bwd_fused", functools.partial(
+        fa._flash_bwd_fused, interpret=True))
+
+
+@pytest.mark.parametrize("batch_axes,h,want", [
+    (("dp",), 4, (("dp",), None, ("mp",), None)),   # Megatron layout
+    ((), 8, (None, None, ("dp", "mp"), None)),      # heads over all 4
+    (("dp",), 2, (("dp",), None, None, None)),      # 1 head x 64: whole
+])
+def test_mesh_plan_splits_kernel_with_shard_map(monkeypatch, batch_axes, h,
+                                                want):
+    """Under `mesh_plan` the kernel is split over the mesh (batch over
+    the data axes, heads over the others where the per-device heads
+    still fit the kernel) and gives the unsplit kernel's outputs and
+    gradients."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    _interpreted_kernels(monkeypatch)
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("dp", "mp"))
+    q, k, v = _make(b=2, s=128, h=h, d=64, seed=3)
+    spec, seg_spec = fa._planned_specs((mesh, batch_axes), q.shape, k.shape)
+    assert spec == P(*want)
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=True)
+        return (out * out).sum(), out
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2), has_aux=True))
+    ref_g, ref_out = grad(q, k, v)
+    sh = NamedSharding(mesh, P("dp"))
+    with fa.mesh_plan(mesh, batch_axes):
+        got_g, got_out = jax.jit(
+            jax.grad(loss, argnums=(0, 1, 2), has_aux=True),
+            in_shardings=(sh, sh, sh))(q, k, v)
+    np.testing.assert_allclose(np.asarray(got_out), np.asarray(ref_out),
+                               rtol=2e-5, atol=2e-5)
+    for a, b in zip(got_g, ref_g):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4)

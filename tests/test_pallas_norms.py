@@ -47,3 +47,23 @@ def test_bf16_rows_blocking():
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(ref, np.float32),
                                rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("norm", ["layer_norm", "rms_norm"])
+def test_tpu_backend_kernel_failure_raises_not_falls_back(monkeypatch, norm):
+    """Off tpu the XLA norm serves, untried. On a tpu backend the fused
+    kernel is the path for shapes it takes, and a kernel that cannot
+    compile (here: Mosaic handed to the CPU compiler) raises instead of
+    latching the XLA norm in; shapes it does not take still route to
+    XLA — that is a decision from something the code can see."""
+    import jax
+    fn = getattr(norms, norm)
+    x = _x()
+    ref = np.asarray(fn(x))                     # cpu: XLA
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(Exception):
+        jax.block_until_ready(fn(x))
+    odd = fn(x[:, :100])                        # 100 lanes: not the kernel's
+    assert odd.shape == (64, 100)
+    monkeypatch.undo()
+    np.testing.assert_array_equal(np.asarray(fn(x)), ref)

@@ -143,6 +143,52 @@ class TestRoofline:
         assert rec["achieved_bytes_per_s"] == pytest.approx(1e8)
         assert rec["utilization_hbm"] is None
 
+    def test_peaks_are_keyed_by_the_device_kind_jax_reports(self):
+        """The installed runtime calls a v5e chip "TPU v5 lite" (the
+        described topology says so). The table answers for exactly the
+        kinds it lists: an unknown kind has no peak, and "TPU v5" (v5p)
+        does not answer for "TPU v5 lite"."""
+        import types
+        from jax.experimental import topologies
+        v5e = types.SimpleNamespace(device_kind="TPU v5 lite")
+        assert perf.lookup(v5e, perf.PEAK_BF16_FLOPS) == 197e12
+        assert perf.lookup(v5e, perf.HBM_BYTES_PER_SEC) == 819e9
+        assert perf.device_peaks(v5e) == (197e12, 819e9)
+        assert perf.interconnect_peaks(v5e)["ici"] == 2.0e11
+        assert perf.lookup(types.SimpleNamespace(device_kind="TPU v5"),
+                           perf.PEAK_BF16_FLOPS) == 459e12
+        for kind in ("TPU v9 imaginary", "cpu", "tpu v5 lite", None):
+            dev = types.SimpleNamespace(device_kind=kind)
+            assert perf.lookup(dev, perf.PEAK_BF16_FLOPS) is None
+            assert perf.device_peaks(dev) is None
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"cannot describe a v5e topology: {e}")
+        assert perf.device_peaks(topo.devices[0]) == (197e12, 819e9)
+
+    def test_bench_refuses_a_device_without_peaks(self, monkeypatch):
+        """bench.py used to assume v5e peaks for a device it did not
+        know; now that is an error, and only a run asked for by name as
+        a CPU smoke gets a not-a-number instead."""
+        import math
+        import types
+        import bench
+        unknown = types.SimpleNamespace(device_kind="TPU v9 imaginary")
+        with pytest.raises(RuntimeError, match="PEAK_BF16_FLOPS"):
+            bench.peak_flops(unknown)
+        with pytest.raises(RuntimeError, match="HBM_BYTES_PER_SEC"):
+            bench.hbm_bw(unknown)
+        v5e = types.SimpleNamespace(device_kind="TPU v5 lite")
+        assert bench.peak_flops(v5e) == 197e12
+        assert bench.hbm_bw(v5e) == 819e9
+        monkeypatch.setattr(bench, "_CPU_SMOKE", True)
+        assert math.isnan(bench.peak_flops(unknown))
+        assert bench._finite_or_none(
+            {"mfu": float("nan"), "n": [1, float("inf")], "s": "x"}) == \
+            {"mfu": None, "n": [1, None], "s": "x"}
+
     def test_pinned_peaks_give_exact_utilization(self):
         obs.enable()
         perf.set_device_peaks(1e12, 1e11)

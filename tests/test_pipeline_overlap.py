@@ -20,8 +20,6 @@ below:
      matches the analytic 1F1B bound (p-1)/(m+p-1) exactly and beats
      FThenB — i.e. given concurrency the hardware provides, the emitted
      order achieves textbook pipelining.
-
-Recorded in BENCH_EXTRA.md.
 """
 import time
 
@@ -177,8 +175,7 @@ def test_executor_timeline_never_starves_the_device():
 
     (Direct queue-ahead is NOT observable on this box: the CPU client
     inline-executes each computation on its single worker, measured as
-    0/12 units still running when forward_part returns; documented in
-    BENCH_EXTRA.md.)"""
+    0/12 units still running when forward_part returns.)"""
     m, dim = 6, 192
     pipe = _build(dim, m)
     LOG.clear()

@@ -5,7 +5,7 @@ fallback for unpicklable datasets.
 
 Note: this sandbox exposes ONE cpu core, so these tests verify the
 mechanism (spawn, ordering, shm round-trip, error/worker-info
-plumbing), not a parallel speedup — documented in BENCH_EXTRA.md."""
+plumbing), not a parallel speedup."""
 import numpy as np
 import pytest
 

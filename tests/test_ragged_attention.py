@@ -69,7 +69,7 @@ def _naive(q, k_new, v_new, kpool, vpool, rows, pos, kv_start, off,
 
 
 def _mixed_case(T=64, B=4, NB=8, bs=8, H=4, Hk=2, D=64, int8=False,
-                seed=0):
+                seed=0, scatter_pages=False):
     """One packed launch with every row kind the engine ships:
     row 0 fresh prefill (no pool reads), row 1 single decode token,
     row 2 a verify window, row 3 a prefix-resume suffix; tail dead."""
@@ -106,9 +106,11 @@ def _mixed_case(T=64, B=4, NB=8, bs=8, H=4, Hk=2, D=64, int8=False,
     pack(2, 10, 5)               # verify window over 10 cached
     pack(3, 16, 7)               # prefix-resume over 16 cached
     # physical pages: row 1 -> pages 0..2, row 2 -> 3..4, row 3 -> 5..6
-    off[1, [0, 1, 2]] = np.arange(3) * bs
-    off[2, [3, 4]] = np.arange(2) * bs
-    off[3, [5, 6]] = np.arange(2) * bs
+    # (scatter_pages: the same seven drawn from anywhere in the pool)
+    phys = rng.permutation(NB)[:7] if scatter_pages else np.arange(7)
+    off[1, phys[0:3]] = np.arange(3) * bs
+    off[2, phys[3:5]] = np.arange(2) * bs
+    off[3, phys[5:7]] = np.arange(2) * bs
     return dict(q=q, k_new=k_new, v_new=v_new, kpool=kpool,
                 vpool=vpool, rows=rows, pos=pos, kv_start=kv_start,
                 off=off, bs=bs, scale=1.0 / np.sqrt(D), kdq=kdq,
@@ -178,6 +180,26 @@ def test_pallas_interpret_matches_reference(int8):
     np.testing.assert_array_equal(got[dead], 0.0)
 
 
+@pytest.mark.parametrize("NB,block_k", [
+    (9, 24),       # 3 pages per pool tile: not a power of two
+    (40, 16),      # 20 pool tiles inside one lane group of voff
+    (256, 512),    # 64 pages per tile: two tiles per lane group, 2 groups
+])
+def test_pallas_interpret_pool_tiles_and_lane_groups(NB, block_k):
+    """The in-kernel ownership mask across several pool tiles, with the
+    owned pages scattered over the pool so every tile/lane-group offset
+    of the page-count operand is exercised."""
+    c = _mixed_case(NB=NB, Hk=2, D=128, seed=13, scatter_pages=True)
+    args = [jnp.asarray(c[k]) for k in (
+        "q", "k_new", "v_new", "kpool", "vpool", "rows", "pos",
+        "kv_start", "off")]
+    got = np.asarray(ra._ragged_pallas(
+        *args, c["bs"], c["scale"], interpret=True, block_k=block_k,
+        autotune_ok=False))
+    ref = _run_ref(c, path="jnp")
+    np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-4)
+
+
 def test_pallas_interpret_no_pool():
     c = _mixed_case(Hk=2, D=128, seed=7)
     got = _run_ref(c, path="pallas_interpret", with_pool=False)
@@ -225,3 +247,33 @@ def test_pallas_compiled_matches_reference_tpu():
     got = _run_ref(c, path="pallas")
     ref = _run_ref(c, path="jnp")
     np.testing.assert_allclose(got, ref, rtol=5e-3, atol=5e-3)
+
+
+def test_engine_raises_when_its_launch_lands_on_the_reference_on_tpu(
+        monkeypatch):
+    """Off tpu the dispatcher answers "jnp" without trying anything and
+    the engine records why. On a tpu backend the reference is not a
+    serving path: a launch shape the engine made itself and the kernel
+    cannot take used to be a RuntimeWarning, and is now an error."""
+    from paddle_tpu.inference import LLMEngine
+    from paddle_tpu.models import GPTForCausalLM
+    from paddle_tpu.models.gpt import GPTConfig
+    cfg = GPTConfig(vocab_size=128, hidden_size=64, num_layers=1,
+                    num_heads=4, max_position_embeddings=64,
+                    hidden_dropout_prob=0.0, attention_dropout_prob=0.0)
+    eng = LLMEngine(GPTForCausalLM(cfg), max_batch=2, block_size=8,
+                    num_blocks=8, prompt_quantum=16)
+    _fn, path = eng._ragged_fn(16, False, False)
+    assert path == "jnp"
+    assert "backend" in eng._ragged_paths[("ragged", 16, False, False)][1]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # 4 heads x 16: the fused head axis is 64 lanes, the kernel wants 128
+    with pytest.raises(RuntimeError, match="lane-aligned"):
+        eng._ragged_fn(32, False, False)
+    assert ("ragged", 32, False, False) not in eng._fns
+    # and with a shape the kernel takes, the kernel is the path: nothing
+    # is tried first, nothing falls back
+    assert ra.ragged_attention_path(64, 64, 4, 2, 128, 8) == ("pallas", "")
+    c = _mixed_case(Hk=2, D=128)
+    with pytest.raises(Exception):
+        jax.block_until_ready(_run_ref(c, path=None))

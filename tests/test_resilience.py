@@ -18,6 +18,10 @@ contracts under test:
 import os
 import warnings
 
+# what a spawned DataLoader worker's interpreter was started with
+# (EnvGuardDs reads it in the worker)
+_ENV_AT_IMPORT = os.environ.get("JAX_PLATFORMS")
+
 import numpy as np
 import pytest
 
@@ -381,13 +385,15 @@ class ShmDs(Dataset):
 
 
 class EnvGuardDs(ShmDs):
-    """Asserts the spawn-env contract: JAX_PLATFORMS=cpu must already
-    be set when the dataset is UNPICKLED in the worker (i.e. the env
-    guard runs before any user code), not just when __getitem__ runs."""
+    """Asserts the spawn-env contract: JAX_PLATFORMS=cpu is in the
+    worker's environment from the interpreter's start — before this
+    test module is imported there (_ENV_AT_IMPORT), so also when the
+    dataset is UNPICKLED, not just when __getitem__ runs."""
 
     def __setstate__(self, state):
-        assert os.environ.get("JAX_PLATFORMS") == "cpu", \
-            "dataset unpickled before the worker's env guard"
+        assert _ENV_AT_IMPORT == "cpu", \
+            "worker interpreter started without JAX_PLATFORMS=cpu"
+        assert os.environ.get("JAX_PLATFORMS") == "cpu"
         self.__dict__.update(state)
 
 
@@ -496,11 +502,13 @@ class TestSelfHealingDataLoader:
 
     def test_env_guard_precedes_unpickle(self, monkeypatch):
         # parent without JAX_PLATFORMS: the child can only pass
-        # EnvGuardDs.__setstate__ if worker_main's guard ran first
+        # EnvGuardDs.__setstate__ if it was SPAWNED with the variable
         monkeypatch.delenv("JAX_PLATFORMS", raising=False)
         out = _collect(DataLoader(EnvGuardDs(n=8), batch_size=4,
                                   num_workers=2))
         assert len(out) == 2
+        # and the parent's own environment is as it was
+        assert "JAX_PLATFORMS" not in os.environ
 
     def test_tensor_collate_stays_on_process_tier(self):
         """Tensor-returning collate_fns used to demote to the thread

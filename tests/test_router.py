@@ -688,14 +688,19 @@ class TestKnownFailures:
 
     def test_known_failures_tolerated_new_flagged(self, tmp_path):
         kf = self._tool()
-        known = kf.load_manifest()["failures"][0]
+        # the checked-in list is empty on the installed jax: the
+        # mechanism is exercised against a manifest of the test's own
+        known = "tests/test_env.py::test_needs_other_box"
+        manifest = tmp_path / "manifest.json"
+        manifest.write_text(json.dumps(
+            {"failures": [known], "flaky": []}))
         log = tmp_path / "t1.log"
         log.write_text(
             f"FAILED {known} - AttributeError: shard_map\n"
             "FAILED tests/test_new.py::test_regression - boom\n"
             f"FAILED {known} - AttributeError: shard_map\n"
             "2 failed, 1 passed in 2.0s\n")
-        report = kf.check_log(str(log))
+        report = kf.check_log(str(log), str(manifest))
         assert report.new == ["tests/test_new.py::test_regression"]
         assert not report.ok
         assert known in report.known_seen
@@ -713,5 +718,8 @@ class TestKnownFailures:
         environment-failure list the repo docs cite — pin its shape
         so a drive-by edit can't silently blank the gate."""
         m = self._tool().load_manifest()
-        assert len(m["failures"]) == 27
-        assert all("::" in n for n in m["failures"] + m["flaky"])
+        # every environment failure the seed listed passes on the
+        # installed jax 0.9.0: a new entry needs its reason in _comment
+        assert m["failures"] == []
+        assert len(m["flaky"]) == 3
+        assert all("::" in n for n in m["flaky"])

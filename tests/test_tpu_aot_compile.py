@@ -1,0 +1,130 @@
+"""The main path's Pallas kernels, compiled for a TPU v5e that is
+described and not attached (`jax.experimental.topologies`), at the
+GPT-3 1.3B shapes `chip_smoke.py` runs.
+
+Interpret mode cannot show what the chip's compiler refuses: the ragged
+kernel's pool phase passed every interpret test and did not lower
+(`dynamic_slice`), and a Mosaic kernel under a mesh is refused unless it
+is split with `shard_map`. These compiles guard every later PR at no
+chip time. A compile that passes is a compile, not a run: results and
+times come from `chip_smoke.py` on the chip.
+
+The tuning sweep is off (it would run kernels) and so is the persistent
+compilation cache (an entry written for a described device cannot be
+read back here and warns)."""
+from importlib import import_module
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental import topologies
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+fa = import_module("paddle_tpu.kernels.pallas.flash_attention")
+norms = import_module("paddle_tpu.kernels.pallas.norms")
+rpa = import_module("paddle_tpu.kernels.pallas.ragged_paged_attention")
+
+H, D, HIDDEN = 16, 128, 2048            # GPT-3 1.3B
+BLOCK, NUM_BLOCKS, MAX_BATCH = 64, 256, 8   # chip_smoke.ENGINE
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return topo.devices
+
+
+@pytest.fixture(autouse=True)
+def _no_sweep_no_cache(monkeypatch):
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_AUTOTUNE", "0")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _compiled_text(fn, *specs):
+    text = jax.jit(fn).lower(*specs).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+@pytest.mark.parametrize("tokens,with_pool,int8", [
+    (256, True, False),     # a prompt-quantum multiple, bf16 pool
+    (64, True, True),       # a pow2 bucket below the quantum, int8 pool
+    (256, False, False),    # fresh prefill: nothing reads the pool
+])
+def test_ragged_paged_attention_compiles(v5e, tokens, with_pool, int8):
+    one = SingleDeviceSharding(v5e[0])
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    pool = S((NUM_BLOCKS * BLOCK, H, D), jnp.int8 if int8 else jnp.bfloat16)
+    tok = S((tokens, H, D), jnp.bfloat16)
+    ids = S((tokens,), jnp.int32)
+    dq = S((H,), jnp.float32)
+
+    def launch(q, k, v, kp, vp, rows, pos, kvs, off, kdq, vdq):
+        return rpa._ragged_pallas(
+            q, k, v, kp, vp, rows, pos, kvs, off, BLOCK, D ** -0.5,
+            kdq=kdq if int8 else None, vdq=vdq if int8 else None,
+            with_pool=with_pool)
+
+    _compiled_text(launch, tok, tok, tok, pool, pool, ids, ids,
+                   S((MAX_BATCH,), jnp.int32),
+                   S((MAX_BATCH, NUM_BLOCKS), jnp.int32), dq, dq)
+
+
+def _flash_loss(q, k, v):
+    out = fa._flash_core(q, k, v, None, True, D ** -0.5, True)
+    return out.astype(jnp.float32).sum()
+
+
+def test_flash_attention_fwd_bwd_compiles(v5e):
+    x = jax.ShapeDtypeStruct((4, 2048, H, D), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(v5e[0]))
+    text = _compiled_text(jax.grad(_flash_loss, argnums=(0, 1, 2)), x, x, x)
+    assert text.count("tpu_custom_call") >= 2       # forward and backward
+
+
+def test_flash_attention_splits_itself_over_a_mesh(v5e):
+    """Mosaic kernels cannot be partitioned by the compiler: under
+    `mesh_plan` the call is split with shard_map — batch over "dp",
+    heads over "mp" — and compiles for the 2x2 mesh."""
+    mesh = Mesh(np.array(v5e).reshape(2, 2), ("dp", "mp"))
+    x = jax.ShapeDtypeStruct(
+        (4, 2048, H, D), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P("dp", None, "mp", None)))
+
+    def attend(q, k, v):
+        with fa.mesh_plan(mesh, ("dp",)):
+            return fa.flash_attention(q, k, v, causal=True)
+
+    spec, _ = fa._planned_specs((mesh, ("dp",)), x.shape, x.shape)
+    assert spec == P(("dp",), None, ("mp",), None)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        _compiled_text(attend, x, x, x)
+
+
+@pytest.mark.parametrize("kernel", ["layer_norm", "rms_norm"])
+def test_fused_norms_compile(v5e, kernel):
+    one = SingleDeviceSharding(v5e[0])
+    x = jax.ShapeDtypeStruct((8192, HIDDEN), jnp.bfloat16, sharding=one)
+    w = jax.ShapeDtypeStruct((HIDDEN,), jnp.float32, sharding=one)
+    if kernel == "layer_norm":
+        _compiled_text(lambda x, w, b: norms._ln_pallas(x, w, b, 1e-5),
+                       x, w, w)
+    else:
+        _compiled_text(lambda x, w: norms._rms_pallas(x, w, 1e-6), x, w)

@@ -5,7 +5,7 @@ fused loop.
 
 The "full" variant scatters into the pool AND reads it back through the
 whole-pool attention in the same scan body — the aliasing pattern that
-costs XLA a full pool copy per step (BENCH_EXTRA r5: ~617 MB/step at
+costs XLA a full pool copy per step (a deleted pre-round record read ~617 MB/step at
 1.3B). The "staged" variant is the engine's current body: k/v writes
 land in a small [L, B, chunk] side buffer, the pool stays read-only in
 the scan, and one flat token-major scatter per cache merges the chunk

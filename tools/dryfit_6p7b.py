@@ -11,7 +11,7 @@ one-chip box.
       proves the sharded state + step compile end-to-end (slow: minutes
       of CPU time; run deliberately)
 
-Each prints one JSON line; results recorded in BENCH_EXTRA.md.
+Each prints one JSON line.
 """
 from __future__ import annotations
 

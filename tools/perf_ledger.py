@@ -7,7 +7,7 @@ The round-over-round gate (`bench.py --gate`) answers "did throughput
 regress"; this tool answers "WHICH executable family regressed": it
 diffs the latest record per config against the ledger history,
 comparing each family's achieved bytes/s (the HBM-bound side — every
-hot path in this repo is bandwidth-dominated, see BENCH_EXTRA).
+hot path in this repo is bandwidth-dominated).
 
     python tools/perf_ledger.py                  # trajectory table
     python tools/perf_ledger.py --check          # diff latest vs history
@@ -19,8 +19,8 @@ hot path in this repo is bandwidth-dominated, see BENCH_EXTRA).
     family — the attribution the gate cannot give;
   * prior records from the SAME revision only report the ratio (two
     runs of one revision differ by box noise, not by code — the
-    interleaved-window gate is the honest same-code comparator, cf.
-    the BENCH_EXTRA methodology findings), so a ledger written
+    interleaved-window gate is the honest same-code comparator), so
+    a ledger written
     entirely by the current revision is self-consistent and passes;
   * a family present in every prior record of a config but MISSING
     from the latest fails (an instrumented path silently stopped

@@ -151,10 +151,9 @@ def main():
     kcs, vcs = eng.cache.key_caches, eng.cache.value_caches
     kcs, vcs, toks = fn(params, kcs, vcs, cur, lens, tblj, offj,
                         jax.random.PRNGKey(0))
-    np.asarray(toks)        # real sync; donated caches differ per call
+    np.asarray(toks)        # sync: the tokens to the host
     t0 = time.perf_counter()
     for i in range(4):
-        # vary cur so the dedup cache can't short-circuit the call
         kcs, vcs, toks = fn(params, kcs, vcs, cur + i, lens, tblj,
                             offj, jax.random.PRNGKey(i))
         np.asarray(toks)
