@@ -63,7 +63,13 @@ def recompute(function, *args, use_reentrant=True, preserve_rng_state=True,
     callable directly."""
     if isinstance(function, Layer):
         layer = function
-        fn = function.forward
+        # forward is called past Layer.__call__: the block's
+        # jax.named_scope is entered here instead
+        scope = layer.scope_name()
+
+        def fn(*inputs, **kw):
+            with jax.named_scope(scope):
+                return layer.forward(*inputs, **kw)
     else:
         layer = getattr(function, "__self__", None)
         layer = layer if isinstance(layer, Layer) else None

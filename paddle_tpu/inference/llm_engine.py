@@ -1388,29 +1388,30 @@ class LLMEngine:
             tb = self._token_bucket(B * (self._spec_k + 1))
         else:
             tb = self._token_bucket(T_raw)
-        ids = np.zeros((tb,), np.int32)
-        rows = np.full((tb,), -1, np.int32)
-        pos = np.zeros((tb,), np.int32)
-        kvs = np.zeros((B,), np.int32)
-        off = np.full((B, NB), -1, np.int32)
-        wf = np.full((tb,), T_pool, np.int32)
-        sel = np.zeros((B,), np.int32)
-        spans = {}
-        c = 0
-        for s, toks, st, _ap in entries:
-            m = len(toks)
-            b = s.slot
-            ids[c:c + m] = toks
-            rows[c:c + m] = b
-            gpos = st + np.arange(m, dtype=np.int32)
-            pos[c:c + m] = gpos
-            kvs[b] = st
-            pages = np.asarray(self.cache.pages(s.rid), np.int32)
-            off[b, pages] = np.arange(len(pages), dtype=np.int32) * bs
-            wf[c:c + m] = pages[gpos // bs] * bs + gpos % bs
-            sel[b] = c + m - 1
-            spans[b] = (c, m)
-            c += m
+        with _ot.span("engine.pack", tokens=T_raw, bucket=tb):
+            ids = np.zeros((tb,), np.int32)
+            rows = np.full((tb,), -1, np.int32)
+            pos = np.zeros((tb,), np.int32)
+            kvs = np.zeros((B,), np.int32)
+            off = np.full((B, NB), -1, np.int32)
+            wf = np.full((tb,), T_pool, np.int32)
+            sel = np.zeros((B,), np.int32)
+            spans = {}
+            c = 0
+            for s, toks, st, _ap in entries:
+                m = len(toks)
+                b = s.slot
+                ids[c:c + m] = toks
+                rows[c:c + m] = b
+                gpos = st + np.arange(m, dtype=np.int32)
+                pos[c:c + m] = gpos
+                kvs[b] = st
+                pages = np.asarray(self.cache.pages(s.rid), np.int32)
+                off[b, pages] = np.arange(len(pages), dtype=np.int32) * bs
+                wf[c:c + m] = pages[gpos // bs] * bs + gpos % bs
+                sel[b] = c + m - 1
+                spans[b] = (c, m)
+                c += m
         fn, impl = self._ragged_fn(tb, with_pool, all_pos)
         compiling = fn.pending          # first call pays the compile
         kcs, vcs = self.cache.key_caches, self.cache.value_caches
@@ -1524,48 +1525,50 @@ class LLMEngine:
                         st_v = jax.lax.dynamic_update_slice(
                             st_v, vw[None, :, None], (li, 0, i, 0, 0))
                         # scores: frozen pool part + staged part
-                        q4 = (q.astype(jnp.float32) * scale).reshape(
-                            B, kvH, rep, H_D)
-                        if cdtype == jnp.int8:
-                            qop = q4
-                            kp = kcs[li].astype(jnp.float32)
-                            ks = st_k[li].astype(jnp.float32)
-                        else:
-                            qop = q4.astype(cdtype)
-                            kp = kcs[li]
-                            ks = st_k[li]
-                        sp = jnp.einsum(
-                            "bkrd,tkd->bkrt", qop, kp,
-                            preferred_element_type=jnp.float32)
-                        ss = jnp.einsum(
-                            "bkrd,bjkd->bkrj", qop, ks,
-                            preferred_element_type=jnp.float32)
-                        if kdq is not None:
-                            sp = sp * kdq[li][None, :, None, None]
-                            ss = ss * kdq[li][None, :, None, None]
-                        sp = jnp.where(pool_ok[:, None, None, :], sp,
-                                       -jnp.inf)
-                        ss = jnp.where((jpos <= i)[None, None, None, :],
-                                       ss, -jnp.inf)
-                        s = jnp.concatenate([sp, ss], axis=-1)
-                        p = jax.nn.softmax(s, axis=-1)
-                        pp, ps = p[..., :T_pool], p[..., T_pool:]
-                        if cdtype == jnp.int8:
-                            vp = vcs[li].astype(jnp.float32)
-                            vs = st_v[li].astype(jnp.float32)
-                            ppo, pso = pp, ps
-                        else:
-                            vp, vs = vcs[li], st_v[li]
-                            ppo, pso = pp.astype(cdtype), ps.astype(
-                                cdtype)
-                        o = jnp.einsum(
-                            "bkrt,tkd->bkrd", ppo, vp,
-                            preferred_element_type=jnp.float32)
-                        o = o + jnp.einsum(
-                            "bkrj,bjkd->bkrd", pso, vs,
-                            preferred_element_type=jnp.float32)
-                        if vdq is not None:
-                            o = o * vdq[li][None, :, None, None]
+                        with jax.named_scope("decode_attention"):
+                            q4 = (q.astype(jnp.float32) * scale).reshape(
+                                B, kvH, rep, H_D)
+                            if cdtype == jnp.int8:
+                                qop = q4
+                                kp = kcs[li].astype(jnp.float32)
+                                ks = st_k[li].astype(jnp.float32)
+                            else:
+                                qop = q4.astype(cdtype)
+                                kp = kcs[li]
+                                ks = st_k[li]
+                            sp = jnp.einsum(
+                                "bkrd,tkd->bkrt", qop, kp,
+                                preferred_element_type=jnp.float32)
+                            ss = jnp.einsum(
+                                "bkrd,bjkd->bkrj", qop, ks,
+                                preferred_element_type=jnp.float32)
+                            if kdq is not None:
+                                sp = sp * kdq[li][None, :, None, None]
+                                ss = ss * kdq[li][None, :, None, None]
+                            sp = jnp.where(pool_ok[:, None, None, :], sp,
+                                           -jnp.inf)
+                            ss = jnp.where(
+                                (jpos <= i)[None, None, None, :], ss,
+                                -jnp.inf)
+                            s = jnp.concatenate([sp, ss], axis=-1)
+                            p = jax.nn.softmax(s, axis=-1)
+                            pp, ps = p[..., :T_pool], p[..., T_pool:]
+                            if cdtype == jnp.int8:
+                                vp = vcs[li].astype(jnp.float32)
+                                vs = st_v[li].astype(jnp.float32)
+                                ppo, pso = pp, ps
+                            else:
+                                vp, vs = vcs[li], st_v[li]
+                                ppo, pso = pp.astype(cdtype), ps.astype(
+                                    cdtype)
+                            o = jnp.einsum(
+                                "bkrt,tkd->bkrd", ppo, vp,
+                                preferred_element_type=jnp.float32)
+                            o = o + jnp.einsum(
+                                "bkrj,bjkd->bkrd", pso, vs,
+                                preferred_element_type=jnp.float32)
+                            if vdq is not None:
+                                o = o * vdq[li][None, :, None, None]
                         o = o.reshape(B, nH * H_D)
                         x = fam.attn_out(layer, x, o.astype(
                             x._data.dtype)[:, None, :])
@@ -1644,20 +1647,21 @@ class LLMEngine:
         # through to the trash page via the table padding). Leasing is
         # delta-based off the cache's leased length, so a retry after a
         # failed executable call never double-leases.
-        for s in list(active):
-            if self.slots[s.slot] is not s:     # got preempted meanwhile
-                continue
-            faults.fault_point("engine.decode.seq", rid=s.rid)
-            want = min(s.length + chunk, max(s.token_budget, s.length))
-            by = want - self.cache.length(s.rid)
-            if by > 0 and not self._grow(s, by):
-                raise MemoryError(
-                    "paged pool too small for even one sequence's "
-                    "decode chunk — enlarge num_blocks")
-            # COW guard: the chunk's write range must not touch pages
-            # other sequences still reference (no-op by construction
-            # under page-aligned prefix matching)
-            self.cache.ensure_writable(s.rid, s.length)
+        with _ot.span("engine.schedule", of="decode_leases"):
+            for s in list(active):
+                if self.slots[s.slot] is not s:     # got preempted meanwhile
+                    continue
+                faults.fault_point("engine.decode.seq", rid=s.rid)
+                want = min(s.length + chunk, max(s.token_budget, s.length))
+                by = want - self.cache.length(s.rid)
+                if by > 0 and not self._grow(s, by):
+                    raise MemoryError(
+                        "paged pool too small for even one sequence's "
+                        "decode chunk — enlarge num_blocks")
+                # COW guard: the chunk's write range must not touch pages
+                # other sequences still reference (no-op by construction
+                # under page-aligned prefix matching)
+                self.cache.ensure_writable(s.rid, s.length)
         active = [s for s in self.slots
                   if s is not None and (only is None or s is only)]
         if not active:
@@ -1666,26 +1670,27 @@ class LLMEngine:
         B = self.max_batch
         NB = self.cache.allocator.num_blocks
         active_slots = {s.slot for s in active}
-        cur = np.zeros((B,), np.int32)
-        lens = np.zeros((B,), np.int32)
-        # write table (page index -> physical block; full static width)
-        tbl = np.full((B, self.npb_full), self._trash_page, np.int32)
-        # ownership map (physical block -> start position in row b, or
-        # -1) for the whole-pool attention; inactive rows own only the
-        # trash page so their softmax has one (ignored) valid position
-        off = np.full((B, NB), -1, np.int32)
-        off[:, self._trash_page] = 0
-        for b in range(B):
-            s = self.slots[b]
-            if s is None or b not in active_slots:
-                continue
-            cur[b] = self._last_token(s)
-            lens[b] = s.length
-            pages = self.cache.pages(s.rid)
-            tbl[b, :len(pages)] = pages
-            off[b, self._trash_page] = -1
-            off[b, pages] = np.arange(len(pages), dtype=np.int32) \
-                * self.block_size
+        with _ot.span("engine.pack", rows=len(active)):
+            cur = np.zeros((B,), np.int32)
+            lens = np.zeros((B,), np.int32)
+            # write table (page index -> physical block; full static width)
+            tbl = np.full((B, self.npb_full), self._trash_page, np.int32)
+            # ownership map (physical block -> start position in row b, or
+            # -1) for the whole-pool attention; inactive rows own only the
+            # trash page so their softmax has one (ignored) valid position
+            off = np.full((B, NB), -1, np.int32)
+            off[:, self._trash_page] = 0
+            for b in range(B):
+                s = self.slots[b]
+                if s is None or b not in active_slots:
+                    continue
+                cur[b] = self._last_token(s)
+                lens[b] = s.length
+                pages = self.cache.pages(s.rid)
+                tbl[b, :len(pages)] = pages
+                off[b, self._trash_page] = -1
+                off[b, pages] = np.arange(len(pages), dtype=np.int32) \
+                    * self.block_size
         fn = self._decode_fn(chunk)
         compiling = fn.pending          # first call pays the compile
         kcs, vcs = self.cache.key_caches, self.cache.value_caches
@@ -2087,38 +2092,41 @@ class LLMEngine:
             finished.extend(self._failed)
             self._failed.clear()
         faults.fault_point("engine.step")
-        self._expire_deadlines(finished)
-        fresh = self._admit()
+        with _ot.span("engine.schedule"):
+            self._expire_deadlines(finished)
+            fresh = self._admit()
         if fresh:
-            for seq, first in self._safe_prefills(fresh, finished):
-                seq.out.append(first)
-                self.stats["decode_tokens"] += 1
-                if seq.t_first is None:     # resumed seqs keep theirs
-                    seq.t_first = time.perf_counter()
-                    if _om._ENABLED:
-                        m = _metrics()
-                        ttft = seq.t_first - seq.t_enq
-                        m["ttft"].observe(ttft)
-                        # latency-budget attribution: the accumulated
-                        # components, plus a residual so the five
-                        # observations sum to the TTFT observation
-                        # exactly — "other" is scheduler overhead plus
-                        # anything a failed-over life burned on a
-                        # replica this engine never saw
-                        known = (seq.bud_queue + seq.bud_prefill
-                                 + seq.bud_miss + seq.bud_compile)
-                        bh = m["ttft_budget"]
-                        bh.labels(component="queue_wait").observe(
-                            seq.bud_queue)
-                        bh.labels(component="prefill_compute").observe(
-                            seq.bud_prefill)
-                        bh.labels(component="affinity_miss").observe(
-                            seq.bud_miss)
-                        bh.labels(component="compile_stall").observe(
-                            seq.bud_compile)
-                        bh.labels(component="other").observe(
-                            max(ttft - known, 0.0))
-                self._maybe_finish(seq, finished)
+            pairs = self._safe_prefills(fresh, finished)
+            with _ot.span("engine.commit", of="prefill"):
+                for seq, first in pairs:
+                    seq.out.append(first)
+                    self.stats["decode_tokens"] += 1
+                    if seq.t_first is None:     # resumed seqs keep theirs
+                        seq.t_first = time.perf_counter()
+                        if _om._ENABLED:
+                            m = _metrics()
+                            ttft = seq.t_first - seq.t_enq
+                            m["ttft"].observe(ttft)
+                            # latency-budget attribution: the accumulated
+                            # components, plus a residual so the five
+                            # observations sum to the TTFT observation
+                            # exactly — "other" is scheduler overhead plus
+                            # anything a failed-over life burned on a
+                            # replica this engine never saw
+                            known = (seq.bud_queue + seq.bud_prefill
+                                     + seq.bud_miss + seq.bud_compile)
+                            bh = m["ttft_budget"]
+                            bh.labels(component="queue_wait").observe(
+                                seq.bud_queue)
+                            bh.labels(component="prefill_compute").observe(
+                                seq.bud_prefill)
+                            bh.labels(component="affinity_miss").observe(
+                                seq.bud_miss)
+                            bh.labels(component="compile_stall").observe(
+                                seq.bud_compile)
+                            bh.labels(component="other").observe(
+                                max(ttft - known, 0.0))
+                    self._maybe_finish(seq, finished)
         if self._proposer is not None and self._run_spec_step(finished):
             # speculative step committed tokens, rolled back the KV
             # lease, and retired finished sequences itself (its device
@@ -2159,30 +2167,31 @@ class LLMEngine:
                 self._fail_seq(
                     s, f"decode raised {type(e).__name__}: {e}",
                     "error", finished)
-        for slot, toks in chunk_out.items():
-            seq = self.slots[slot]
-            if seq is None:
-                continue
-            for t in toks:
-                if len(seq.out) >= seq.max_new:
-                    break
-                seq.out.append(int(t))
-                self.stats["decode_tokens"] += 1
-                if (self.eos_token_id is not None
-                        and int(t) == self.eos_token_id):
-                    break
-            if self.cache.enable_prefix_caching:
-                # register newly FILLED full blocks before the sequence
-                # can retire (so its pages park hash-indexed): valid KV
-                # covers prompt + appended tokens, capped at what the
-                # chunk actually wrote. Skip the token-array rebuild
-                # entirely when no block boundary was crossed.
-                ntok = min(seq.length, len(seq.prompt) + len(seq.out))
-                if self.cache.cached_prefix_len(seq.rid) \
-                        + self.block_size <= ntok:
-                    self.cache.commit_prefix(
-                        seq.rid, self._merged_tokens(seq), upto=ntok)
-            self._maybe_finish(seq, finished)
+        with _ot.span("engine.commit", of="decode"):
+            for slot, toks in chunk_out.items():
+                seq = self.slots[slot]
+                if seq is None:
+                    continue
+                for t in toks:
+                    if len(seq.out) >= seq.max_new:
+                        break
+                    seq.out.append(int(t))
+                    self.stats["decode_tokens"] += 1
+                    if (self.eos_token_id is not None
+                            and int(t) == self.eos_token_id):
+                        break
+                if self.cache.enable_prefix_caching:
+                    # register newly FILLED full blocks before the sequence
+                    # can retire (so its pages park hash-indexed): valid KV
+                    # covers prompt + appended tokens, capped at what the
+                    # chunk actually wrote. Skip the token-array rebuild
+                    # entirely when no block boundary was crossed.
+                    ntok = min(seq.length, len(seq.prompt) + len(seq.out))
+                    if self.cache.cached_prefix_len(seq.rid) \
+                            + self.block_size <= ntok:
+                        self.cache.commit_prefix(
+                            seq.rid, self._merged_tokens(seq), upto=ntok)
+                self._maybe_finish(seq, finished)
         return finished
 
     def _maybe_finish(self, seq: _Seq, finished: List[GenerationResult]):

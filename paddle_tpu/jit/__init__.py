@@ -24,11 +24,12 @@ import jax.numpy as jnp
 
 from ..core.tensor import Tensor
 from ..core.generator import rng_scope, next_key
-from ..nn.layer import Layer
+from ..nn.layer import Layer, _TRACING
 from ..observability import comms as _cm
 from ..observability import metrics as _om
 from ..observability import numerics as _num
 from ..observability import perf as _pf
+from ..observability import tracing as _ot
 from ..ops.registry import OpDef
 from ..ops import registry as _op_registry
 from ..autograd import tape
@@ -61,7 +62,10 @@ def _collect_params(layer: Layer):
 
 class _functional_params:
     """Temporarily swap layer parameter/buffer storage with given arrays so
-    the module forward runs functionally (torch functional_call idiom)."""
+    the module forward runs functionally (torch functional_call idiom).
+    Every traced path enters it, so it is also the mark that a program
+    is being traced: while it is held, layers run under their
+    `jax.named_scope` (nn/layer.py)."""
 
     def __init__(self, tensors: List[Tensor], arrays):
         self.tensors = tensors
@@ -71,9 +75,11 @@ class _functional_params:
         self.saved = [t._data for t in self.tensors]
         for t, a in zip(self.tensors, self.arrays):
             t._data = a
+        _TRACING.depth += 1
         return self
 
     def __exit__(self, *exc):
+        _TRACING.depth -= 1
         for t, s in zip(self.tensors, self.saved):
             t._data = s
         return False
@@ -476,8 +482,9 @@ class TrainStep:
             loss, grads = jax.value_and_grad(compute_loss)(
                 train_params, frozen_params, buffers, seed, args, kw)
             train_states = [s for s, t in zip(opt_states, trainable) if t]
-            new_train, new_states = optimizer.functional_update(
-                train_params, grads, train_states, lr)
+            with jax.named_scope("optimizer"):
+                new_train, new_states = optimizer.functional_update(
+                    train_params, grads, train_states, lr)
             new_params, new_opt_states = [], []
             ti = 0
             for p, s, t in zip(params, opt_states, trainable):
@@ -504,13 +511,24 @@ class TrainStep:
             jax.jit(step, donate_argnums=donate_argnums), "train_step")
 
     def __call__(self, *args, **kwargs):
-        args = [a if isinstance(a, Tensor) else Tensor(a) for a in args]
-        args = [a._data for a in args]
-        kwargs = {k: (v._data if isinstance(v, Tensor) else v)
-                  for k, v in kwargs.items()}
-        if self._data_sharding is not None:
-            args = [jax.device_put(a, self._data_sharding) for a in args]
         step_id = self._step_count
+        # the root span of the step's host work; its children share
+        # `step`. Also profiler annotations while a session records
+        # (observability/tracing.py), one `train_step` per run of the
+        # step's program on the device
+        with _ot.span("train_step", step=step_id):
+            return self._call(step_id, args, kwargs)
+
+    def _call(self, step_id, args, kwargs):
+        with _ot.span("train_step.feed", step=step_id):
+            args = [a if isinstance(a, Tensor) else Tensor(a)
+                    for a in args]
+            args = [a._data for a in args]
+            kwargs = {k: (v._data if isinstance(v, Tensor) else v)
+                      for k, v in kwargs.items()}
+            if self._data_sharding is not None:
+                args = [jax.device_put(a, self._data_sharding)
+                        for a in args]
         seed = jax.random.fold_in(self._rng, step_id)
         self._step_count += 1
         if _om._ENABLED:
@@ -541,9 +559,10 @@ class TrainStep:
         from ..utils.watchdog import watchdog
         with watchdog(what=f"TrainStep step {step_id}") as wd, \
                 self._kernel_plan():
-            out = self._step_fn(
-                self.params, self.opt_states, self.buffers, seed, lr,
-                args, kwargs)
+            with _ot.span("train_step.dispatch", step=step_id):
+                out = self._step_fn(
+                    self.params, self.opt_states, self.buffers, seed, lr,
+                    args, kwargs)
             if self._numerics_on:
                 loss, self.params, self.opt_states, packed = out
             else:

@@ -476,6 +476,7 @@ def _ragged_pallas(q, k_new, v_new, kpool, vpool, rows, pos, kv_start,
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
+        name="ragged_attention",    # also the innermost jax.named_scope
     )(voff, qrow, qpos, krow, kpos, dq, q2, kp2, vp2, kn2, vn2)
     return out.reshape(T, H, D)
 

@@ -14,7 +14,7 @@ import math
 from dataclasses import dataclass, field
 
 from .. import ops
-from ..nn.layer import Layer
+from ..nn.layer import Layer, traced_scope
 from ..nn.layers.common import Linear, Embedding, Dropout
 from ..nn.layers.norm import LayerNorm
 from ..nn.layers.container import LayerList
@@ -244,8 +244,11 @@ class GPTForCausalLM(Layer):
         """Project hidden states to vocab logits (tied or untied head) —
         shared by forward() and the decode path (models/generation.py)."""
         if self.lm_head is None:
-            w = self.gpt.embeddings.word_embeddings.weight
-            return ops.matmul(hidden, w, transpose_y=True)
+            # the tied head is no Layer: it names itself in a traced
+            # program as the untied one is named by its parent
+            with traced_scope("lm_head"):
+                w = self.gpt.embeddings.word_embeddings.weight
+                return ops.matmul(hidden, w, transpose_y=True)
         return self.lm_head(hidden)
 
     def forward(self, input_ids, position_ids=None, caches=None):

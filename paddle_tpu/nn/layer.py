@@ -6,7 +6,9 @@ with buffers, train/eval mode, forward pre/post hooks, to()/astype.
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
+import threading
 from collections import OrderedDict
 from typing import Callable, Iterator, Optional, Tuple
 
@@ -45,6 +47,56 @@ jax.tree_util.register_pytree_node(
 )
 
 _hook_id = itertools.count()
+
+class _Tracing(threading.local):
+    """`depth` > 0 while this thread traces a program
+    (`jit._functional_params`, which TrainStep, to_static, recompute,
+    generate and the engine's program builders all enter, counts it up
+    and down). Layer.__call__ then runs forward under
+    `jax.named_scope(<the name its parent holds it by>)`, so every
+    operation's `op_name` carries a path like `gpt/layers/3/attn` that
+    the profiler's trace hands back (benchmarks/harness/
+    trace_scopes.py). Per thread: an engine prewarm tracing on one
+    thread leaves eager calls on the others as they are. Never on the
+    eager path: one attribute read there."""
+    depth = 0
+
+
+_TRACING = _Tracing()
+_NO_SCOPE = contextlib.nullcontext()
+
+
+def traced_scope(name: str):
+    """`jax.named_scope(name)` while a program is being traced, else a
+    shared no-op: for what is not a Layer (a tied head, a method)."""
+    return jax.named_scope(name) if _TRACING.depth else _NO_SCOPE
+
+
+def _is_holder(layer: "Layer") -> bool:
+    # a LayerList, a LayerDict: no forward of its own, so never called
+    return type(layer).forward is Layer.forward
+
+
+def _hold(parent: "Layer", name: str, child: "Layer") -> None:
+    """Leave on `child` the name `parent` holds it by; a layer held
+    twice keeps its first. A holder is never called, so the layers it
+    holds carry its name before theirs (`layers/3`), whether they join
+    it before or after it is attached itself."""
+    if "_scope_name" in child.__dict__:
+        return
+    if _is_holder(parent) and "_scope_name" in parent.__dict__:
+        name = f"{parent.__dict__['_scope_name']}/{name}"
+    _set_scope_name(child, None, name)
+
+
+def _set_scope_name(layer: "Layer", old, new: str) -> None:
+    layer.__dict__["_scope_name"] = new
+    if _is_holder(layer):
+        for key, sub in layer._sub_layers.items():
+            was = f"{old}/{key}" if old else key
+            if isinstance(sub, Layer) and \
+                    sub.__dict__.get("_scope_name") == was:
+                _set_scope_name(sub, was, f"{new}/{key}")
 
 
 class HookRemoveHelper:
@@ -90,6 +142,7 @@ class Layer:
                     d.pop(name, None)
             layers[name] = value
             self.__dict__.pop(name, None)
+            _hold(self, name, value)
         else:
             if params is not None and name in params:
                 if value is None:
@@ -131,6 +184,8 @@ class Layer:
 
     def add_sublayer(self, name, sublayer):
         self._sub_layers[str(name)] = sublayer
+        if isinstance(sublayer, Layer):
+            _hold(self, str(name), sublayer)
         return sublayer
 
     def register_buffer(self, name, tensor, persistable=True):
@@ -294,7 +349,11 @@ class Layer:
             res = hook(self, inputs)
             if res is not None:
                 inputs = res if isinstance(res, tuple) else (res,)
-        out = self.forward(*inputs, **kwargs)
+        if _TRACING.depth:
+            with jax.named_scope(self.scope_name()):
+                out = self.forward(*inputs, **kwargs)
+        else:
+            out = self.forward(*inputs, **kwargs)
         for hook in list(self._forward_post_hooks.values()):
             res = hook(self, inputs, out)
             if res is not None:
@@ -345,6 +404,11 @@ class Layer:
 
     def full_name(self):
         return self._name_scope
+
+    def scope_name(self):
+        """The `jax.named_scope` this layer's forward is traced under:
+        the name its parent holds it by, or its class's for a root."""
+        return self.__dict__.get("_scope_name") or self._name_scope
 
     def extra_repr(self):
         return ""

@@ -68,7 +68,7 @@ class LayerList(Layer):
                                     if idx < 0 else idx)]
 
     def __setitem__(self, idx, layer):
-        self._sub_layers[str(idx)] = layer
+        self.add_sublayer(str(idx), layer)
 
     def __len__(self):
         return len(self._sub_layers)

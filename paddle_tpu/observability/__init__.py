@@ -23,8 +23,18 @@ numbers from. Built-in instrumentation (recorded only while enabled):
   checkpoints skipped/quarantined by `resume_latest`.
 * `optimizer` fused step — executable-cache hits / compiles (misses) /
   eager fallbacks, plus compile wall time.
-* `profiler.RecordEvent` — routed through the same trace ring buffer,
-  so both exporters see one event stream.
+* `profiler.RecordEvent` — opens the same `span()`, so both exporters
+  see one event stream.
+* `jit.TrainStep` — `train_step` / `train_step.feed` /
+  `train_step.dispatch` spans; every `CompileTimed` first call —
+  `compile.lower` / `compile.backend` / `compile.first_run` spans and
+  `perf.compile_record(family)`, written metrics on or off.
+
+Every span is also a profiler annotation (`tracing.py`) while a profiler
+session records (tracing enabled or not): the program's spans then lie
+in the `.xplane.pb` on the device's clock, beside device operations
+that carry the layers' `jax.named_scope` paths
+(`python3 benchmarks/tools/scope_table.py <file.xplane.pb>`).
 
 Sub-surfaces: `observability.slo` (declarative latency objectives
 evaluated from the registry), `observability.flight` (anomaly flight

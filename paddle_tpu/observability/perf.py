@@ -57,9 +57,11 @@ import time
 from typing import Dict, Optional, Tuple
 
 from . import metrics as _m
+from . import tracing as _t
 
 __all__ = [
     "CostModel", "read_cost_model", "CompileTimed", "record_compile",
+    "compile_record",
     "observe_roofline", "note_dispatch_gap", "note_dispatch_batch",
     "note_graph_cache", "family_records",
     "reset_window", "device_peaks", "set_device_peaks", "lookup",
@@ -319,6 +321,32 @@ def _metrics():
 # ---------------------------------------------------------------------------
 _FAMILY_COST: Dict[str, CostModel] = {}     # last compile's expectation
 _FAMILY_RUNS: Dict[str, dict] = {}          # this window's executions
+# where each family's set-up seconds went, for the process's life
+# (CompileTimed writes it once per first call, metrics on or off)
+_FAMILY_COMPILE: Dict[str, dict] = {}
+
+
+def compile_record(family: str) -> Optional[dict]:
+    """Where the family's first calls spent their time in this process,
+    or None before its first: `compiles` (first calls so far) and, summed
+    over them, `lower_s` (tracing and lowering the program), `backend_s`
+    (XLA's compile, or the persistent cache's load when it hits; with an
+    executable store, the store's load) and `first_run_s` (the first
+    execution, not waited for), with the last one's `outcome`
+    (compile | disk_hit). Written whether or not metrics are enabled:
+    a one-shot at compile time costs the hot path nothing."""
+    rec = _FAMILY_COMPILE.get(family)
+    return dict(rec) if rec is not None else None
+
+
+def _note_compile(family: str, parts: dict, outcome: str) -> None:
+    rec = _FAMILY_COMPILE.setdefault(family, {
+        "compiles": 0, "lower_s": 0.0, "backend_s": 0.0,
+        "first_run_s": 0.0})
+    rec["compiles"] += 1
+    for part, seconds in parts.items():
+        rec[part + "_s"] += seconds
+    rec["outcome"] = outcome
 
 
 def _family_slot(family: str) -> dict:
@@ -462,6 +490,12 @@ class CompileTimed:
     executable; `expected` carries the CostModel for roofline
     accounting at the call sites.
 
+    The first call's three phases are spans (`compile.lower`,
+    `compile.backend`, `compile.first_run`, each with `family=`) and
+    their seconds go to `compile_record(family)`, metrics on or off:
+    what a run's set-up spent lowering and compiling (or loading) the
+    family's programs.
+
     Degradation contract: if AOT lowering/compiling raises (an exotic
     backend, a sharding the AOT path rejects) the shim falls back to
     plain jit dispatch — compile count/time still recorded, no cost
@@ -537,10 +571,24 @@ class CompileTimed:
         outcome = "compile"
         out = None
         ran = False
-        compiled = self._load_from_store()
+        parts = {"lower": 0.0, "backend": 0.0, "first_run": 0.0}
+
+        def timed(part, fn, *a):
+            # one phase of the first call: a `compile.<part>` span, its
+            # seconds kept for the family's compile_record
+            with _t.span("compile." + part, family=self.family):
+                t = time.perf_counter()
+                try:
+                    return fn(*a)
+                finally:
+                    parts[part] += time.perf_counter() - t
+
+        compiled = None
+        if self.store is not None:
+            compiled = timed("backend", self._load_from_store)
         if compiled is not None:
             try:
-                out = compiled(*args)
+                out = timed("first_run", compiled, *args)
                 ran = True
                 outcome = "disk_hit"
             except TypeError:
@@ -550,19 +598,22 @@ class CompileTimed:
                 compiled = None
         if compiled is None:
             try:
-                compiled = self.jit_fn.lower(*args).compile()
+                lowered = timed("lower", self.jit_fn.lower, *args)
+                # a cache load when jax's persistent cache hits
+                compiled = timed("backend", lowered.compile)
             except Exception:
                 compiled = None     # fall back to plain jit dispatch
             else:
                 self._save_to_store(compiled)
         if not ran:
-            out = (compiled if compiled is not None
-                   else self.jit_fn)(*args)
+            out = timed("first_run", compiled if compiled is not None
+                        else self.jit_fn, *args)
         # cleared only on success: a first call that raises (watchdog,
         # injected fault) leaves the compile un-recorded, and the
         # retry — which pays the compile again or hits jax's cache —
         # records it instead of losing the count
         self.pending = False
+        _note_compile(self.family, parts, outcome)
         if compiled is not None:
             self.fn = compiled
             self.expected = record_compile(self.family, compiled)

@@ -2,10 +2,19 @@
 buffer, exported as Chrome-trace JSON (chrome://tracing / Perfetto) or
 JSONL.
 
-One event stream: `profiler.RecordEvent` routes its host spans through
-the same ring buffer, so `profiler.export_chrome_tracing` and the
-exporters here produce one consistent file whichever API recorded the
-span.
+One event stream: `profiler.RecordEvent` opens its host spans with
+`span()`, so `profiler.export_chrome_tracing` and the exporters here
+produce one consistent file whichever API recorded the span.
+
+A span is also a profiler annotation. While a `jax.profiler` session is
+recording (`jax.profiler.start_trace`, `paddle_tpu.profiler.Profiler`,
+the benchmark's `--trace 1`), every `span()` enters a
+`jax.profiler.TraceAnnotation` of the same name and attributes, whether
+or not the ring is enabled: the program's spans then lie in the
+`.xplane.pb`'s host plane on the clock of the device's operations, and
+`python3 benchmarks/tools/scope_table.py <file.xplane.pb>` reads them
+beside the device time of each `jax.named_scope` component. Outside a
+session the annotation costs one flag read and nothing is allocated.
 
 Events are stored directly in chrome-trace "complete event" shape —
 {"name", "ph": "X", "pid", "tid", "ts", "dur", "args"} with ts/dur in
@@ -32,8 +41,9 @@ worker rings to the parent; perf_counter is CLOCK_MONOTONIC on Linux,
 so child timestamps order correctly against the parent's).
 
 Cost model: `span()` returns a shared no-op singleton when tracing is
-disabled (zero allocation on the hot path); when enabled, one small
-object + one dict per finished span, into a deque bounded at
+disabled and no profiler session records (zero allocation on the hot
+path); under a session, one annotation object; with the ring enabled,
+one small object + one dict per finished span, into a deque bounded at
 `capacity()` events (oldest dropped)."""
 from __future__ import annotations
 
@@ -45,11 +55,13 @@ import threading
 import time
 from typing import List, Optional
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 __all__ = [
     "span", "add_event", "events", "clear", "enable", "disable",
     "enabled", "set_capacity", "capacity", "export_chrome_trace",
     "export_jsonl", "current_trace", "trace_context", "new_trace_id",
-    "new_span_id", "ingest", "appended_total", "events_with_total",
+    "new_span_id", "ingest", "events_with_total",
 ]
 
 _ENABLED = False
@@ -98,17 +110,8 @@ def capacity() -> int:
     return _RING.maxlen
 
 
-def appended_total() -> int:
-    """Events ever appended (add_event + ingest), monotonic across
-    clear()/set_capacity(). `appended_total() - events-you-have-seen`
-    is the incremental-consumer read; the excess over `len(events())`
-    is what the ring dropped before anyone copied it out. For a copy
-    that is CONSISTENT with the total, use events_with_total()."""
-    return _APPENDED
-
-
 def events_with_total():
-    """(ring copy oldest-first, appended_total) captured atomically:
+    """(ring copy oldest-first, events ever appended) captured atomically:
     ring[i] is globally the (total - len(ring) + i)-th event ever
     appended, so an incremental consumer holding a shipped high-water
     mark can slice exactly the unshipped tail and count rotations as
@@ -248,10 +251,25 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+# whether a jax.profiler session is recording: a flag read
+_profiling = _TraceAnnotation.is_enabled
+
+
+class _Annotation(_TraceAnnotation):
+    """A span while a profiler session records and the ring is off: the
+    profiler's annotation, with the span's surface and no ring state."""
+    __slots__ = ()
+    trace_id = None
+    span_id = None
+    parent_id = None
+
+    def end(self):
+        self.__exit__(None, None, None)
+
 
 class _Span:
     __slots__ = ("name", "args", "_t0", "trace_id", "span_id",
-                 "parent_id", "_token")
+                 "parent_id", "_token", "_ann")
 
     def __init__(self, name, args, trace_id=None):
         self.name = name
@@ -261,8 +279,12 @@ class _Span:
         self.span_id = None
         self.parent_id = None
         self._token = None
+        self._ann = None
 
     def __enter__(self):
+        if _profiling():
+            self._ann = _TraceAnnotation(self.name, **(self.args or {}))
+            self._ann.__enter__()
         cur = _CTX.get()
         if self.trace_id is None:
             self.trace_id = cur[0] if cur else new_trace_id()
@@ -285,6 +307,9 @@ class _Span:
                 _CTX.set(None)
             self._token = None
         t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         add_event(self.name, t0 / 1000.0, (t1 - t0) / 1000.0,
                   args=self.args,
                   trace=(self.trace_id, self.span_id, self.parent_id))
@@ -302,15 +327,18 @@ def span(name: str, request_id=None, trace_id: Optional[str] = None,
             ...
 
     Records one complete event on exit when tracing is enabled; returns
-    a shared no-op context when disabled. The event carries trace
+    a shared no-op context when disabled. Either way, while a
+    `jax.profiler` session records, the span is also entered as a
+    `TraceAnnotation(name, **attrs)` and so lies in the profiler's
+    trace on the device's clock. The event carries trace
     context IDs: a span opened inside another span becomes its child
     (same trace_id, parent_id = enclosing span_id); at top level a
     fresh trace starts. request_id= stamps request attribution into the
     event args; trace_id= adopts an existing trace explicitly."""
-    if not _ENABLED:
-        return _NULL_SPAN
     if request_id is not None:
         attrs["request_id"] = request_id
+    if not _ENABLED:
+        return _Annotation(name, **attrs) if _profiling() else _NULL_SPAN
     return _Span(name, attrs or None, trace_id=trace_id)
 
 

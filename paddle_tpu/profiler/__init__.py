@@ -1,13 +1,17 @@
 """Profiler (ref: python/paddle/profiler/profiler.py:346 + C++ host/device
 tracers §5.1).
 
-Host spans: RecordEvent context managers into the observability trace
-ring (`paddle_tpu.observability.tracing`) — ONE event stream shared
-with `observability.span`, so `export_chrome_tracing` here and the
-observability exporters produce consistent files whichever API recorded
-the span. Device timeline: jax.profiler (XLA/PJRT trace) captured
-alongside when a dir is given — TPU kernels, transfers, and host
-callbacks land in the same tensorboard-loadable trace."""
+Host spans: a RecordEvent IS an `observability.tracing.span` — ONE event
+stream, so `export_chrome_tracing` here and the observability exporters
+produce consistent files whichever API recorded the span. Device
+timeline: `Profiler` (unless `timer_only`) also starts `jax.profiler`
+(XLA/PJRT trace), and every span open while it records is entered as a
+profiler annotation too: the `.xplane.pb` under `PADDLE_TPU_TRACE_DIR`
+then holds the program's spans, TPU kernels, transfers and host
+callbacks on one clock. Read it with
+`python3 benchmarks/tools/scope_table.py <file.xplane.pb>` (device time
+by `jax.named_scope` component, the spans' self times) or in
+tensorboard/Perfetto."""
 from __future__ import annotations
 
 import json
@@ -65,23 +69,24 @@ class RecordEvent:
     event_tracing.h:43)
 
     Idempotent: a second end() (or __exit__ after an explicit end()) is
-    a no-op — the span is consumed by the first end. Events land in the
-    shared observability trace ring whenever tracing is enabled (by a
-    running Profiler or by observability.enable())."""
+    a no-op — the span is consumed by the first end. It opens an
+    `observability.tracing.span`: the event lands in the shared trace
+    ring when tracing is enabled at begin() (by a running Profiler or by
+    observability.enable()) and in the profiler's trace while a
+    `jax.profiler` session records."""
 
     def __init__(self, name: str, event_type=None):
         self.name = name
-        self._t0 = None
+        self._span = None
 
     def begin(self):
-        self._t0 = time.perf_counter_ns()
+        self._span = _tracing.span(self.name)
+        self._span.__enter__()
 
     def end(self):
-        t0, self._t0 = self._t0, None       # consume: double end no-ops
-        if t0 is None or not _tracing.enabled():
-            return
-        t1 = time.perf_counter_ns()
-        _tracing.add_event(self.name, t0 / 1000.0, (t1 - t0) / 1000.0)
+        sp, self._span = self._span, None   # consume: double end no-ops
+        if sp is not None:
+            sp.end()
 
     def __enter__(self):
         self.begin()
