@@ -356,6 +356,77 @@ def _tensor_unflatten(aux, children):
 jax.tree_util.register_pytree_node(Tensor, _tensor_flatten, _tensor_unflatten)
 
 
+class DeferredTensor(Tensor):
+    """A Tensor that stands for an array nobody has read yet.
+
+    `compute()` runs at the first read of `_data`, which is how every op
+    and every interop method reaches the array, so any reader gets what
+    an ordinary Tensor would have held. A consumer that knows a cheaper
+    way to its own result asks for `producer` (whatever the maker left
+    there to say what the array is the product of) while `computed` is
+    still False, and never pays for the array (GPT's head and loss:
+    models/gpt.py). Shape and dtype are known without the array. Made
+    only inside traced programs, where no tape records."""
+    __slots__ = ("_compute", "_array", "_aval", "producer")
+
+    def __init__(self, compute, shape, dtype, producer=None):
+        self._compute = compute
+        self._array = None
+        self._aval = jax.ShapeDtypeStruct(tuple(shape), dtype)
+        self.producer = producer
+        self.stop_gradient = True   # as any op's output under no_grad
+        self.persistable = False
+        self.name = f"generated_tensor_{next(_name_counter)}"
+        self._grad = None
+        self._grad_node = None
+        self._out_idx = 0
+        self._hooks = {}
+        self._hook_counter = itertools.count()
+        self._retain_grad = False
+        self._dist_attr = None
+
+    @property
+    def computed(self) -> bool:
+        return self._array is not None
+
+    @property
+    def _data(self):
+        if self._array is None:
+            self._array = self._compute()
+            self._compute = None
+        return self._array
+
+    @_data.setter
+    def _data(self, arr):
+        self._array = arr
+        self._compute = None
+
+    @property
+    def shape(self):
+        return list(self._aval.shape)
+
+    @property
+    def ndim(self):
+        return len(self._aval.shape)
+
+    dim = ndim
+
+    @property
+    def size(self):
+        return int(np.prod(self._aval.shape)) if self._aval.shape else 1
+
+    @property
+    def dtype(self) -> dtypes.DType:
+        return dtypes.from_np(self._aval.dtype)
+
+    def __deepcopy__(self, memo):
+        return self.detach().__deepcopy__(memo)
+
+
+jax.tree_util.register_pytree_node(
+    DeferredTensor, _tensor_flatten, _tensor_unflatten)
+
+
 def to_tensor(data, dtype=None, place=None, stop_gradient=True) -> Tensor:
     """paddle.to_tensor analog (ref: python/paddle/tensor/creation.py)."""
     return Tensor(data, dtype=dtype, place=place, stop_gradient=stop_gradient)

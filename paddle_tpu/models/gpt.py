@@ -12,9 +12,16 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
+from typing import NamedTuple
 
 from .. import ops
-from ..nn.layer import Layer, traced_scope
+from ..amp import auto_cast
+from ..amp.state import maybe_cast_inputs
+from ..autograd import tape
+from ..core.tensor import DeferredTensor, Tensor
+from ..nn.layer import Layer, traced_scope, _TRACING
+from ..observability import perf
+from ..ops import nn_ops
 from ..nn.layers.common import Linear, Embedding, Dropout
 from ..nn.layers.norm import LayerNorm
 from ..nn.layers.container import LayerList
@@ -220,8 +227,24 @@ class GPTModel(Layer):
         return (x, new_caches) if caches is not None else x
 
 
+class _Head(NamedTuple):
+    """What deferred logits are the product of: `hidden` and `weight` as
+    amp cast them for the projection when the model ran, `weight`
+    [vocab, hidden] if `transpose_y` (the tied embedding) else
+    [hidden, vocab] (an untied Linear's)."""
+    hidden: Tensor
+    weight: Tensor
+    transpose_y: bool
+
+
 class GPTForCausalLM(Layer):
-    """GPT with a (tied) LM head producing [b, s, vocab] logits."""
+    """GPT with a (tied) LM head producing [b, s, vocab] logits.
+
+    In a traced training forward (a `TrainStep`, no caches, one device)
+    the logits are a `DeferredTensor`: `GPTPretrainingCriterion` computes
+    the loss from what they are the product of, in chunks of tokens
+    (`ops.linear_cross_entropy`), and the [tokens, vocab] array never
+    exists; any other reader gets the whole product as ever."""
 
     def generate(self, input_ids, **kwargs):
         """Static-shape KV-cache decoding (see models/generation.py)."""
@@ -257,20 +280,79 @@ class GPTForCausalLM(Layer):
             hidden, new_caches = out
         else:
             hidden = out
-        logits = self.lm_logits(hidden)
-        return (logits, new_caches) if caches is not None else logits
+        if caches is not None:
+            return self.lm_logits(hidden), new_caches
+        logits = self._deferred_logits(hidden)
+        return self.lm_logits(hidden) if logits is None else logits
+
+    def _deferred_logits(self, hidden):
+        """The logits as a promise, where the program is such that the
+        criterion can do without them: traced for training with jax's
+        own autodiff (no tape), on one device (under a mesh the tied
+        embedding is sharded and the whole product is the path that is
+        tested there), and nothing hooked onto an untied head. Else
+        None."""
+        head = self.lm_head
+        if not (_TRACING.depth and self.training
+                and not tape.is_grad_enabled()
+                and (head is None or not (head._forward_pre_hooks
+                                          or head._forward_post_hooks))):
+            return None
+        from ..kernels.pallas.flash_attention import _MESH_PLAN
+        if _MESH_PLAN.get() is not None:    # TrainStep's, under a mesh
+            perf.trace_note("head_loss", "whole")
+            return None
+        w = (self.gpt.embeddings.word_embeddings.weight if head is None
+             else head.weight)
+        # the casts `matmul` (and `linear`: both on amp's white list)
+        # would have made now; whoever computes from these later may
+        # stand outside `auto_cast`
+        cast = maybe_cast_inputs(ops.matmul.op_def, {"x": hidden, "y": w})
+        made = _Head(cast["x"], cast["y"], head is None)
+
+        def whole():
+            perf.trace_note("head_loss", "whole")
+            with auto_cast(enable=False), traced_scope("lm_head"):
+                return ops.matmul(made.hidden, made.weight,
+                                  transpose_y=made.transpose_y)._data
+
+        vocab = w.shape[0] if head is None else w.shape[1]
+        return DeferredTensor(whole, hidden.shape[:-1] + [vocab],
+                              made.hidden._data.dtype, producer=made)
 
 
 class GPTPretrainingCriterion(Layer):
     """Next-token cross-entropy (labels = input shifted by the caller)."""
 
     def forward(self, logits, labels, loss_mask=None):
+        if (isinstance(logits, DeferredTensor) and not logits.computed
+                and isinstance(logits.producer, _Head)):
+            return self._from_head(logits.producer, labels, loss_mask)
         loss = ops.cross_entropy(logits, labels, reduction="none")
         if loss_mask is not None:
             loss_mask = ops.reshape(loss_mask, loss.shape)
             return ops.sum(loss * loss_mask) / ops.maximum(
                 ops.sum(loss_mask), 1e-6)
         return ops.mean(loss)
+
+    def _from_head(self, head, labels, loss_mask):
+        """The same mean from the head's operands, the logits never
+        whole (ops.linear_cross_entropy). Operations keep the `lm_head`
+        scope beside this criterion's."""
+        h = head.hidden.shape[-1]
+        hidden = ops.reshape(head.hidden, (-1, h))
+        n = hidden.shape[0]
+        if loss_mask is None:
+            weight = None                       # 1/n each: the mean
+        else:
+            mask = ops.cast(ops.reshape(loss_mask, (n,)), "float32")
+            weight = mask / ops.maximum(ops.sum(mask), 1e-6)
+        chunks = nn_ops._lce_plan(n, nn_ops.LCE_CHUNK)[0]
+        perf.trace_note("head_loss", f"fused, chunks {chunks}")
+        with auto_cast(enable=False), traced_scope("lm_head"):
+            return ops.linear_cross_entropy(
+                hidden, head.weight, ops.reshape(labels, (n,)), weight,
+                transpose_y=head.transpose_y)
 
 
 def num_params(config: GPTConfig) -> int:

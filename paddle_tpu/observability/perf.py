@@ -53,6 +53,7 @@ window.
 from __future__ import annotations
 
 import dataclasses
+import threading
 import time
 from typing import Dict, Optional, Tuple
 
@@ -61,7 +62,7 @@ from . import tracing as _t
 
 __all__ = [
     "CostModel", "read_cost_model", "CompileTimed", "record_compile",
-    "compile_record",
+    "compile_record", "trace_note",
     "observe_roofline", "note_dispatch_gap", "note_dispatch_batch",
     "note_graph_cache", "family_records",
     "reset_window", "device_peaks", "set_device_peaks", "lookup",
@@ -333,13 +334,15 @@ def compile_record(family: str) -> Optional[dict]:
     (XLA's compile, or the persistent cache's load when it hits; with an
     executable store, the store's load) and `first_run_s` (the first
     execution, not waited for), with the last one's `outcome`
-    (compile | disk_hit). Written whether or not metrics are enabled:
+    (compile | disk_hit) and whatever the traced code noted of itself
+    (`trace_note`). Written whether or not metrics are enabled:
     a one-shot at compile time costs the hot path nothing."""
     rec = _FAMILY_COMPILE.get(family)
     return dict(rec) if rec is not None else None
 
 
-def _note_compile(family: str, parts: dict, outcome: str) -> None:
+def _note_compile(family: str, parts: dict, outcome: str,
+                  notes: Optional[dict] = None) -> None:
     rec = _FAMILY_COMPILE.setdefault(family, {
         "compiles": 0, "lower_s": 0.0, "backend_s": 0.0,
         "first_run_s": 0.0})
@@ -347,6 +350,30 @@ def _note_compile(family: str, parts: dict, outcome: str) -> None:
     for part, seconds in parts.items():
         rec[part + "_s"] += seconds
     rec["outcome"] = outcome
+    rec.update(notes or {})
+
+
+class _TraceNotes(threading.local):
+    notes = None    # a dict while a CompileTimed's first call runs here
+
+
+_TRACE_NOTES = _TraceNotes()
+
+
+def trace_note(key: str, value: str) -> None:
+    """From code that runs while a program is traced: which of its paths
+    it took (`head_loss`: `fused, chunks 2` | `whole`, models/gpt.py).
+    The note lands in `compile_record(family)` of the `CompileTimed`
+    whose first call is tracing on this thread, under `key`; paths taken
+    side by side in one program are joined by `; `. Outside such a call
+    it is dropped: a one-shot at trace time, nothing on the hot path."""
+    notes = _TRACE_NOTES.notes
+    if notes is not None:
+        seen = notes.get(key)
+        if seen is None:
+            notes[key] = value
+        elif value not in seen.split("; "):
+            notes[key] = f"{seen}; {value}"
 
 
 def _family_slot(family: str) -> dict:
@@ -572,15 +599,19 @@ class CompileTimed:
         out = None
         ran = False
         parts = {"lower": 0.0, "backend": 0.0, "first_run": 0.0}
+        notes = {}
 
         def timed(part, fn, *a):
             # one phase of the first call: a `compile.<part>` span, its
-            # seconds kept for the family's compile_record
+            # seconds kept for the family's compile_record beside what
+            # the traced code noted of itself (`trace_note`)
             with _t.span("compile." + part, family=self.family):
                 t = time.perf_counter()
+                outer, _TRACE_NOTES.notes = _TRACE_NOTES.notes, notes
                 try:
                     return fn(*a)
                 finally:
+                    _TRACE_NOTES.notes = outer
                     parts[part] += time.perf_counter() - t
 
         compiled = None
@@ -613,7 +644,7 @@ class CompileTimed:
         # retry — which pays the compile again or hits jax's cache —
         # records it instead of losing the count
         self.pending = False
-        _note_compile(self.family, parts, outcome)
+        _note_compile(self.family, parts, outcome, notes)
         if compiled is not None:
             self.fn = compiled
             self.expected = record_compile(self.family, compiled)

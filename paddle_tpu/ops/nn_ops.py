@@ -6,6 +6,8 @@ XLA fuses them into surrounding ops (Pallas fused variants live in
 paddle_tpu/kernels/pallas and are swapped in by incubate.nn.functional)."""
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -719,6 +721,159 @@ def cross_entropy(input, label, weight=None, ignore_index=-100,
     if reduction == "sum":
         return jnp.sum(loss)
     return loss
+
+
+# Tokens in one chunk of `linear_cross_entropy`. Each chunk reads and
+# writes the float32 dW accumulator: 8*h*v bytes against the 2*c*h*v
+# operations of the matmul it rides on, c/4 operations a byte, and a
+# v5e's ridge is 240 (197 TFLOP/s over 819 GB/s). At 4096 the
+# accumulator costs a quarter of one of the chunk's three matmuls, and
+# the chunk's bf16 logits are 412 MB at a 50304-wide vocabulary,
+# whatever the hidden size.
+LCE_CHUNK = 4096
+
+
+def _lce_plan(n, chunk):
+    """(chunks, tokens in each): the fewest chunks of at most `chunk`
+    tokens. A count they do not divide is padded with tokens of weight
+    zero (they add nothing to the loss or to a gradient), each chunk a
+    multiple of 128 rows so the tiles stay whole."""
+    k = max(1, -(-n // chunk))
+    c = -(-n // k)
+    if k > 1 and c % 128:
+        c = min(chunk, c + 128 - c % 128)
+    return k, c
+
+
+def _lce_dot(a, b, contract, out_dtype=None):
+    """matmul's arithmetic (linalg.matmul): float32 operands at HIGHEST,
+    low-precision ones at the MXU's speed into a float32 accumulator,
+    rounded to the operands' dtype unless the caller keeps float32."""
+    low = a.dtype in (jnp.bfloat16, jnp.float16)
+    out = jax.lax.dot_general(
+        a, b, (contract, ((), ())),
+        precision=None if low else jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32 if low else None)
+    return out.astype(out_dtype or a.dtype)
+
+
+def _lce_chunk(hidden, weight, safe, coef, transpose_y, with_grads):
+    """One chunk. `coef` is each token's weight in the loss, zero where
+    its label is ignored. The logits are rounded as `matmul` rounds
+    them; the log-sum-exp and the softmax are float32."""
+    wdim = 1 if transpose_y else 0          # weight's hidden axis
+    logits = _lce_dot(hidden, weight, ((1,), (wdim,)))         # [c, v]
+    z = logits.astype(jnp.float32)
+    lse = jax.scipy.special.logsumexp(z, axis=-1)
+    picked = jnp.take_along_axis(logits, safe[:, None], axis=-1)[:, 0]
+    ce = lse - picked.astype(jnp.float32)
+    if not with_grads:
+        return ce, None, None
+    hit = jax.lax.broadcasted_iota(jnp.int32, z.shape, 1) == safe[:, None]
+    dlogits = ((jnp.exp(z - lse[:, None]) - hit.astype(jnp.float32))
+               * coef[:, None]).astype(logits.dtype)
+    dh = _lce_dot(dlogits, weight, ((1,), (1 - wdim,)), jnp.float32)
+    if transpose_y:
+        dw = _lce_dot(dlogits, hidden, ((0,), (0,)), jnp.float32)  # [v, h]
+    else:
+        dw = _lce_dot(hidden, dlogits, ((0,), (0,)), jnp.float32)  # [h, v]
+    return ce, dh, dw
+
+
+def _lce_run(hidden, weight, label, token_weight, transpose_y,
+             ignore_index, chunk, with_grads):
+    n, h = hidden.shape
+    k, c = _lce_plan(n, chunk)
+    lbl = label.astype(jnp.int32)
+    valid = lbl != ignore_index
+    safe = jnp.where(valid, lbl, 0)
+    coef = jnp.where(valid, token_weight.astype(jnp.float32), 0.0)
+    pad = k * c - n
+    if pad:
+        hidden = jnp.pad(hidden, ((0, pad), (0, 0)))
+        safe = jnp.pad(safe, (0, pad))
+        coef = jnp.pad(coef, (0, pad))
+    xs = (hidden.reshape(k, c, h), safe.reshape(k, c), coef.reshape(k, c))
+
+    def body(dw_acc, x):
+        ce, dh, dw = _lce_chunk(x[0], weight, x[1], x[2], transpose_y,
+                                with_grads)
+        if with_grads:
+            dw_acc = dw_acc + dw
+        return dw_acc, (ce, dh)
+
+    # unrolled: as a `while` the GPT-3 1.3B step does not fit a v5e
+    # (the float32 dW would have to live across the whole backward; in
+    # line, XLA may compute a chunk's share of it late from logits made
+    # again, as it does with whole logits under the same pressure), and
+    # where nothing presses it was no faster (PERF.md, PR 26)
+    dw0 = jnp.zeros(weight.shape, jnp.float32) if with_grads else None
+    dw, (ce, dh) = jax.lax.scan(body, dw0, xs, unroll=True)
+    ce = jnp.where(valid, ce.reshape(k * c)[:n], 0.0)
+    loss = jnp.sum(ce * token_weight.astype(jnp.float32))
+    if not with_grads:
+        return loss, None
+    return loss, (dh.reshape(k * c, h)[:n], dw, ce)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _lce(hidden, weight, label, token_weight, transpose_y, ignore_index,
+         chunk):
+    return _lce_run(hidden, weight, label, token_weight, transpose_y,
+                    ignore_index, chunk, False)[0]
+
+
+def _lce_fwd(hidden, weight, label, token_weight, transpose_y,
+             ignore_index, chunk):
+    loss, (dh, dw, ce) = _lce_run(hidden, weight, label, token_weight,
+                                  transpose_y, ignore_index, chunk, True)
+    # what the backward hands on, already in its final form but for the
+    # scalar that arrives there; dtypes are kept as zero-size arrays
+    like = (jnp.zeros((0,), hidden.dtype), jnp.zeros((0,), weight.dtype),
+            jnp.zeros((0,), token_weight.dtype))
+    return loss, (dh, dw, ce, like)
+
+
+def _lce_bwd(transpose_y, ignore_index, chunk, res, g):
+    dh, dw, ce, like = res
+    g = g.astype(jnp.float32)
+    return ((dh * g).astype(like[0].dtype), (dw * g).astype(like[1].dtype),
+            None, (ce * g).astype(like[2].dtype))
+
+
+_lce.defvjp(_lce_fwd, _lce_bwd)
+
+
+@register_op("linear_cross_entropy", amp_policy="keep")
+def linear_cross_entropy(hidden, weight, label, token_weight=None,
+                         transpose_y=True, ignore_index=-100,
+                         chunk=LCE_CHUNK):
+    """sum_i token_weight[i] * cross_entropy(hidden[i] @ W, label[i]):
+    the vocabulary projection and the loss in one op, so that no
+    [tokens, vocab] array exists. `hidden` [n, h]; `weight` [v, h] (a
+    tied embedding, transpose_y=True) or [h, v] (a Linear's); `label`
+    [n]; `token_weight` [n] float32 (default 1/n each: the mean over all
+    tokens), which is how a mask and its mean come in. A label equal to
+    `ignore_index` costs nothing.
+
+    The tokens go through in chunks under one `jax.custom_vjp` (an
+    unrolled `lax.scan`). Differentiated, the forward computes each chunk's
+    logits once and in the same pass its `softmax - onehot`, its
+    `d hidden` and its share of `dW` (float32, summed across chunks);
+    the backward only scales them by the scalar it receives, which is
+    exact because cross-entropy's gradient depends on what follows it
+    by that scalar alone. Three matmuls of the head's size where
+    `cross_entropy(matmul(hidden, W))` differentiates into three and
+    keeps the logits alive (or computes them again) for each reader.
+    Rounding is `matmul`'s and `cross_entropy`'s: logits in the
+    operands' dtype from a float32 accumulator, log-sum-exp and softmax
+    in float32, the logits' gradient rounded once to their dtype.
+    Operands are used as given (amp "keep": the caller casts)."""
+    if token_weight is None:
+        token_weight = jnp.full((hidden.shape[0],), 1.0 / hidden.shape[0],
+                                jnp.float32)
+    return _lce(hidden, weight, label, token_weight, bool(transpose_y),
+                int(ignore_index), int(chunk))
 
 
 @register_op("softmax_with_cross_entropy", amp_policy="black")

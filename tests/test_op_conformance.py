@@ -446,6 +446,9 @@ class TestRegistryCoverage:
         # covered by tests/test_serving.py TestIncubateFunctionalBatch
         "fused_matmul_bias", "fused_dot_product_attention",
         "fused_ec_moe", "fused_gate_attention",
+        # covered by tests/test_fused_head_loss.py (against cross_entropy
+        # of the whole product, values and both gradients)
+        "linear_cross_entropy",
     }
 
     def test_coverage_accounting(self):

@@ -12,6 +12,7 @@ times come from `chip_smoke.py` on the chip.
 The tuning sweep is off (it would run kernels) and so is the persistent
 compilation cache (an entry written for a described device cannot be
 read back here and warns)."""
+import re
 from importlib import import_module
 
 import jax
@@ -128,3 +129,122 @@ def test_fused_norms_compile(v5e, kernel):
                        x, w, w)
     else:
         _compiled_text(lambda x, w: norms._rms_pallas(x, w, 1e-6), x, w)
+
+
+# ---------------------------------------------------------------------------
+# the training step's head and loss: no [tokens, vocab] array in the program
+# ---------------------------------------------------------------------------
+VOCAB, TOKENS = 50304, (16, 1024)       # gpt2-small.train-1k's step
+
+
+@pytest.fixture(scope="module")
+def head_steps(v5e):
+    """GPT-2 small's width, vocabulary and batch at two layers, the
+    step written as benchmarks/drivers/train_window.py writes it, and
+    the same step with its loss function reading the logits first (the
+    whole product). Compiled for one described v5e: (text, temporaries'
+    bytes) of each."""
+    import paddle_tpu as pt
+    from paddle_tpu import amp
+    from paddle_tpu.jit import TrainStep
+    from paddle_tpu.models import GPTForCausalLM, GPTPretrainingCriterion
+    from paddle_tpu.models.gpt import GPTConfig
+    from paddle_tpu.optimizer import AdamW
+    one = SingleDeviceSharding(v5e[0])
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
+
+    out = {}
+    crit = GPTPretrainingCriterion()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("PADDLE_TPU_PALLAS_AUTOTUNE", "0")
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        was_on = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        for path in ("fused", "whole"):
+            pt.seed(0)
+            model = GPTForCausalLM(GPTConfig(
+                vocab_size=VOCAB, hidden_size=768, num_layers=2,
+                num_heads=12, max_position_embeddings=1024,
+                hidden_dropout_prob=0.0, attention_dropout_prob=0.0,
+                use_flash_attention=True))
+            model.train()
+
+            def loss_fn(m, ids, labels, path=path):
+                with amp.auto_cast(enable=True, level="O1",
+                                   dtype="bfloat16"):
+                    logits = m(ids)
+                if path == "whole":
+                    logits._data
+                return crit(logits, labels)
+
+            step = TrainStep(model, AdamW(
+                learning_rate=1e-4, parameters=model.parameters(),
+                moment_dtype="bfloat16"), loss_fn)
+            ids = jax.ShapeDtypeStruct(TOKENS, jnp.int32, sharding=one)
+            compiled = step._step_fn.jit_fn.lower(
+                [spec(p) for p in step.params],
+                [{k: spec(v) for k, v in st.items()}
+                 for st in step.opt_states],
+                [spec(b) for b in step.buffers],
+                spec(jax.random.PRNGKey(0)), spec(jnp.float32(1e-4)),
+                [ids, ids], {}).compile()
+            text = compiled.as_text()
+            assert "tpu_custom_call" in text
+            out[path] = (text, compiled.memory_analysis().temp_size_in_bytes)
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        compilation_cache.reset_cache()
+    return out
+
+
+def _vocab_matmuls(text):
+    """The program's matmuls (`convolution` is what a dot is by then)
+    that have the vocabulary among their operands' or their result's
+    dimensions: each one's result shape."""
+    shape_of, found = {}, []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%\S+) = (\w+\[[\d,]*\])", line)
+        if not m:
+            continue
+        shape_of[m.group(1)] = m.group(2)
+        call = re.search(r" convolution\(([^)]*)\)", line)
+        if call:
+            shapes = [m.group(2)] + [shape_of.get(a.strip(), "")
+                                     for a in call.group(1).split(",")]
+            if any(str(VOCAB) in re.findall(r"\d+", sh) for sh in shapes):
+                found.append(m.group(2))
+    return found
+
+
+def test_the_fused_step_holds_no_tokens_by_vocab_array(head_steps):
+    fused, _ = head_steps["fused"]
+    whole, _ = head_steps["whole"]
+    n = TOKENS[0] * TOKENS[1]
+    for shape in (f"[{n},{VOCAB}]", f"[{TOKENS[0]},{TOKENS[1]},{VOCAB}]"):
+        assert shape not in fused, shape
+    # the whole path is what the test would see if it saw nothing
+    assert f"[{TOKENS[0]},{TOKENS[1]},{VOCAB}]" in whole
+
+
+def test_the_fused_step_computes_each_chunks_logits_once(head_steps):
+    """Three matmuls of the head's size a chunk, the chunk bodies in
+    line (16384 tokens are four chunks of 4096): the chunk's logits, its
+    d hidden, its share of dW; the logits of no chunk a second time.
+    (Where memory presses, XLA may make them again: PERF.md, PR 26.)"""
+    fused, _ = head_steps["fused"]
+    shapes = _vocab_matmuls(fused)
+    assert len(shapes) == 3 * 4, shapes
+    assert sum(s.endswith(f"[4096,{VOCAB}]") for s in shapes) == 4, shapes
+    # where the whole path has its three, on all 16384 tokens
+    whole, _ = head_steps["whole"]
+    assert len(_vocab_matmuls(whole)) == 3
+
+
+def test_the_fused_step_needs_less_memory_than_whole_logits(head_steps):
+    """Bytes from the compiler's own count of the program's temporaries;
+    the whole bf16 logits alone are 1.65 GB, a chunk's 0.41."""
+    _, fused = head_steps["fused"]
+    _, whole = head_steps["whole"]
+    assert fused < whole - 0.25e9, (fused, whole)

@@ -177,15 +177,89 @@ ROOT = "gptforcausallm"
     f"/gpt/checkpoint/rematted_computation/layers/0/mlp/",
     f"jit(step)/jvp({ROOT})/gpt/embeddings/word_embeddings/",
     f"jit(step)/jvp({ROOT})/gpt/final_norm/",
-    f"jit(step)/jvp({ROOT})/lm_head/dot_general",
-    f"jit(step)/transpose(jvp({ROOT}))/lm_head/dot_general",
-    "jit(step)/jvp(gptpretrainingcriterion)/",
-    "jit(step)/transpose(jvp(gptpretrainingcriterion))/",
+    # the head and the loss in chunks (ops.linear_cross_entropy): every
+    # matmul of the head runs in the forward's loop, under the
+    # criterion's scope and the head's; the backward only scales
+    "jit(step)/jvp(gptpretrainingcriterion)/lm_head/while/body/"
+    "closed_call/dot_general",
+    "jit(step)/jvp(gptpretrainingcriterion)/lm_head/while/body/"
+    "closed_call/exp",
+    "jit(step)/transpose(jvp(gptpretrainingcriterion))/lm_head/",
     "jit(step)/optimizer/",
 ])
 def test_a_traced_steps_op_names_carry_the_programs_paths(step_op_names,
                                                           fragment):
     assert any(fragment in n for n in step_op_names), fragment
+
+
+def test_no_head_operation_of_the_fused_step_lies_outside_its_scopes(
+        step_op_names):
+    """`head_loss_ms.train` finds the head by `lm_head` or `criterion`
+    in the path: every dot_general outside the blocks has one."""
+    dots = [n for n in step_op_names
+            if n.endswith("dot_general") and "/layers/" not in n]
+    assert dots and all("lm_head" in n and "criterion" in n for n in dots)
+
+
+@pytest.fixture(scope="module")
+def whole_step_op_names():
+    """The same step, its loss function reading the logits itself."""
+    pt.seed(0)
+    model = GPTForCausalLM(gpt_tiny())
+    model.train()
+    opt = AdamW(learning_rate=1e-3, parameters=model.parameters())
+
+    def loss_fn(m, ids, labels):
+        with amp.auto_cast(enable=True, level="O1", dtype="bfloat16"):
+            logits = m(ids)
+        return pt.ops.mean(pt.ops.cross_entropy(logits, labels,
+                                                reduction="none"))
+
+    step = TrainStep(model, opt, loss_fn)
+    ids = np.zeros((2, 32), np.int32)
+    perf._FAMILY_COMPILE.pop("train_step", None)
+    step(ids, ids)
+    lowered = step._step_fn.jit_fn.lower(
+        step.params, step.opt_states, step.buffers,
+        jax.random.PRNGKey(0), jnp.float32(1e-3), [ids, ids], {})
+    return (perf.compile_record("train_step"),
+            set(re.findall(r'op_name="([^"]*)"',
+                           lowered.compile().as_text())))
+
+
+@pytest.mark.parametrize("fragment", [
+    # the whole product is computed where it is first read, under the
+    # head's name
+    "jit(step)/jvp(lm_head)/dot_general",
+    "jit(step)/transpose(jvp(lm_head))/dot_general",
+])
+def test_whole_logits_keep_the_heads_name(whole_step_op_names, fragment):
+    record, names = whole_step_op_names
+    assert record["head_loss"] == "whole"
+    assert any(fragment in n for n in names), fragment
+    assert not any("lm_head/while" in n for n in names)
+
+
+def test_the_compile_record_says_which_path_the_head_took():
+    """`tiny_step`'s loss function is the benchmark driver's: logits
+    under `auto_cast`, the criterion outside."""
+    pt.seed(0)
+    model = GPTForCausalLM(gpt_tiny())
+    model.train()
+    crit = GPTPretrainingCriterion()
+
+    def loss_fn(m, ids, labels):
+        with amp.auto_cast(enable=True, level="O1", dtype="bfloat16"):
+            logits = m(ids)
+        return crit(logits, labels)
+
+    step = TrainStep(model, AdamW(learning_rate=1e-3,
+                                  parameters=model.parameters()), loss_fn)
+    perf._FAMILY_COMPILE.pop("train_step", None)
+    ids = np.zeros((2, 32), np.int32)
+    step(ids, ids)
+    assert perf.compile_record("train_step")["head_loss"] == \
+        "fused, chunks 1"
 
 
 def test_the_flash_kernels_name_themselves():
