@@ -1,4 +1,4 @@
-"""Flagship model families (GPT / LLaMA / BERT).
+"""Flagship model families (GPT / LLaMA / Jamba / BERT).
 
 The reference keeps language models out-of-tree (PaddleNLP) but its
 north-star benchmarks are GPT-3/LLaMA hybrid-parallel training
@@ -13,6 +13,10 @@ from .gpt import (  # noqa: F401
 from .llama import (  # noqa: F401
     LlamaConfig, LlamaModel, LlamaForCausalLM, llama_tiny, llama2_7b,
     llama2_13b,
+)
+from .jamba import (  # noqa: F401
+    JambaConfig, JambaModel, JambaForCausalLM, JambaMambaMixer,
+    JambaAttention, JambaMLP, JambaDecoderLayer, jamba_tiny,
 )
 from .bert import (  # noqa: F401
     BertConfig, BertModel, BertForMaskedLM, bert_tiny, bert_base,

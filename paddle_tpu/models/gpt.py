@@ -12,16 +12,15 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import NamedTuple
 
 from .. import ops
 from ..amp import auto_cast
-from ..amp.state import maybe_cast_inputs
-from ..autograd import tape
-from ..core.tensor import DeferredTensor, Tensor
-from ..nn.layer import Layer, traced_scope, _TRACING
+from ..core.tensor import DeferredTensor
+from ..nn.layer import Layer, traced_scope
 from ..observability import perf
 from ..ops import nn_ops
+from . import lm_head as _lm_head
+from .lm_head import _Head
 from ..nn.layers.common import Linear, Embedding, Dropout
 from ..nn.layers.norm import LayerNorm
 from ..nn.layers.container import LayerList
@@ -227,16 +226,6 @@ class GPTModel(Layer):
         return (x, new_caches) if caches is not None else x
 
 
-class _Head(NamedTuple):
-    """What deferred logits are the product of: `hidden` and `weight` as
-    amp cast them for the projection when the model ran, `weight`
-    [vocab, hidden] if `transpose_y` (the tied embedding) else
-    [hidden, vocab] (an untied Linear's)."""
-    hidden: Tensor
-    weight: Tensor
-    transpose_y: bool
-
-
 class GPTForCausalLM(Layer):
     """GPT with a (tied) LM head producing [b, s, vocab] logits.
 
@@ -263,62 +252,21 @@ class GPTForCausalLM(Layer):
                                       std=config.initializer_range),
                                   bias_attr=False)
 
+    def _tied_weight(self):
+        return self.gpt.embeddings.word_embeddings.weight
+
     def lm_logits(self, hidden):
         """Project hidden states to vocab logits (tied or untied head) —
         shared by forward() and the decode path (models/generation.py)."""
-        if self.lm_head is None:
-            # the tied head is no Layer: it names itself in a traced
-            # program as the untied one is named by its parent
-            with traced_scope("lm_head"):
-                w = self.gpt.embeddings.word_embeddings.weight
-                return ops.matmul(hidden, w, transpose_y=True)
-        return self.lm_head(hidden)
+        return _lm_head.lm_logits(hidden, self._tied_weight(), self.lm_head)
 
     def forward(self, input_ids, position_ids=None, caches=None):
         out = self.gpt(input_ids, position_ids, caches)
         if caches is not None:
             hidden, new_caches = out
-        else:
-            hidden = out
-        if caches is not None:
             return self.lm_logits(hidden), new_caches
-        logits = self._deferred_logits(hidden)
-        return self.lm_logits(hidden) if logits is None else logits
-
-    def _deferred_logits(self, hidden):
-        """The logits as a promise, where the program is such that the
-        criterion can do without them: traced for training with jax's
-        own autodiff (no tape), on one device (under a mesh the tied
-        embedding is sharded and the whole product is the path that is
-        tested there), and nothing hooked onto an untied head. Else
-        None."""
-        head = self.lm_head
-        if not (_TRACING.depth and self.training
-                and not tape.is_grad_enabled()
-                and (head is None or not (head._forward_pre_hooks
-                                          or head._forward_post_hooks))):
-            return None
-        from ..kernels.pallas.flash_attention import _MESH_PLAN
-        if _MESH_PLAN.get() is not None:    # TrainStep's, under a mesh
-            perf.trace_note("head_loss", "whole")
-            return None
-        w = (self.gpt.embeddings.word_embeddings.weight if head is None
-             else head.weight)
-        # the casts `matmul` (and `linear`: both on amp's white list)
-        # would have made now; whoever computes from these later may
-        # stand outside `auto_cast`
-        cast = maybe_cast_inputs(ops.matmul.op_def, {"x": hidden, "y": w})
-        made = _Head(cast["x"], cast["y"], head is None)
-
-        def whole():
-            perf.trace_note("head_loss", "whole")
-            with auto_cast(enable=False), traced_scope("lm_head"):
-                return ops.matmul(made.hidden, made.weight,
-                                  transpose_y=made.transpose_y)._data
-
-        vocab = w.shape[0] if head is None else w.shape[1]
-        return DeferredTensor(whole, hidden.shape[:-1] + [vocab],
-                              made.hidden._data.dtype, producer=made)
+        return _lm_head.causal_lm_logits(self.training, out,
+                                        self._tied_weight(), self.lm_head)
 
 
 class GPTPretrainingCriterion(Layer):

@@ -449,6 +449,10 @@ class TestRegistryCoverage:
         # covered by tests/test_fused_head_loss.py (against cross_entropy
         # of the whole product, values and both gradients)
         "linear_cross_entropy",
+        # covered by tests/test_selective_scan.py (against the float32
+        # recurrence one step at a time, values and every gradient;
+        # against a grouped convolution)
+        "selective_scan", "causal_conv1d",
     }
 
     def test_coverage_accounting(self):

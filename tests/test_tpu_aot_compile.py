@@ -248,3 +248,110 @@ def test_the_fused_step_needs_less_memory_than_whole_logits(head_steps):
     _, fused = head_steps["fused"]
     _, whole = head_steps["whole"]
     assert fused < whole - 0.25e9, (fused, whole)
+
+
+# ---------------------------------------------------------------------------
+# Jamba: the selective-scan kernels and a step with both kinds of layer
+# ---------------------------------------------------------------------------
+ssm = import_module("paddle_tpu.kernels.pallas.selective_scan")
+JAMBA_SEQ, JAMBA_INNER, JAMBA_STATE = 4096, 5120, 16   # jamba2-3b-l14
+
+
+@pytest.mark.parametrize("x_dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_selective_scan_fwd_bwd_compiles(v5e, x_dtype):
+    """Both kernels at AI21-Jamba2-3B's mixer: one row of 4096 steps,
+    5120 channels, 16 states (float32 under amp; bfloat16 outside)."""
+    one = SingleDeviceSharding(v5e[0])
+
+    def S(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    seq, e, n = JAMBA_SEQ, JAMBA_INNER, JAMBA_STATE
+    text = _compiled_text(
+        jax.grad(lambda *a: jnp.sum(ssm._scan(*a, "pallas").astype(
+            jnp.float32)), argnums=tuple(range(6))),
+        S((1, seq, e), x_dtype), S((1, seq, e)), S((e, n)),
+        S((1, seq, n)), S((1, seq, n)), S((e,)))
+    assert text.count("tpu_custom_call") == 2
+    assert "ssm_scan_fwd" in text and "ssm_scan_bwd" in text
+    # nothing of the state sequence's size: seq x channels x states
+    assert f"{seq},{e},{n}" not in text and f"{seq},{n},{e}" not in text
+
+
+@pytest.fixture(scope="module")
+def jamba_step(v5e):
+    """One Mamba and one attention layer at AI21-Jamba2-3B's widths
+    (an eighth of its vocabulary: the head is not the point), 1 x 4096
+    tokens, the step written as benchmarks/drivers/jamba_train_window.py
+    writes it, compiled for one described v5e: (text, compile record)."""
+    import paddle_tpu as pt
+    from paddle_tpu import amp
+    from paddle_tpu.jit import TrainStep
+    from paddle_tpu.models import GPTPretrainingCriterion, JambaForCausalLM
+    from paddle_tpu.models.jamba import JambaConfig
+    from paddle_tpu.observability import perf
+    from paddle_tpu.optimizer import AdamW
+    one = SingleDeviceSharding(v5e[0])
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
+
+    crit = GPTPretrainingCriterion()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("PADDLE_TPU_PALLAS_AUTOTUNE", "0")
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        was_on = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        pt.seed(0)
+        model = JambaForCausalLM(JambaConfig(
+            vocab_size=8192, num_hidden_layers=2, attn_layer_period=2,
+            attn_layer_offset=1, use_flash_attention=True, recompute=True))
+        model.train()
+
+        def loss_fn(m, ids, labels):
+            with amp.auto_cast(enable=True, level="O1", dtype="bfloat16"):
+                logits = m(ids)
+            return crit(logits, labels)
+
+        step = TrainStep(model, AdamW(
+            learning_rate=1e-4, parameters=model.parameters(),
+            moment_dtype="bfloat16"), loss_fn)
+        ids = jax.ShapeDtypeStruct((1, JAMBA_SEQ), jnp.int32, sharding=one)
+        notes = {}
+        outer, perf._TRACE_NOTES.notes = perf._TRACE_NOTES.notes, notes
+        try:
+            compiled = step._step_fn.jit_fn.lower(
+                [spec(p) for p in step.params],
+                [{k: spec(v) for k, v in st.items()}
+                 for st in step.opt_states],
+                [spec(b) for b in step.buffers],
+                spec(jax.random.PRNGKey(0)), spec(jnp.float32(1e-4)),
+                [ids, ids], {}).compile()
+        finally:
+            perf._TRACE_NOTES.notes = outer
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        compilation_cache.reset_cache()
+    return compiled.as_text(), notes
+
+
+@pytest.mark.parametrize("kernel,calls", [
+    ("ssm_scan_fwd", 2),            # the forward, and run again
+    ("ssm_scan_bwd", 1),
+    ("flash_fwd", 2),
+    ("flash_bwd_transpose", 1)])
+def test_the_jamba_step_holds_its_mosaic_kernels(jamba_step, kernel, calls):
+    text, _notes = jamba_step
+    found = re.findall(rf"%{kernel}[.\d]* = .*custom-call\(", text)
+    assert len(found) == calls, (kernel, len(found))
+    assert text.count("tpu_custom_call") == 6
+
+
+def test_the_jamba_step_says_which_paths_it_took(jamba_step):
+    """Multi-query attention (20 query heads on 1 key/value head, no
+    positions) takes the flash kernel's grouped-head path; the scan its
+    kernels at hand-set blocks; the head is deferred."""
+    _text, notes = jamba_step
+    assert notes == {"ssm_scan": "pallas, chunk 64, tile 512",
+                     "attention": "pallas", "head_loss": "fused, chunks 1"}
