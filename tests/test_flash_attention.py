@@ -295,6 +295,232 @@ def test_flash_attention_dispatch_cpu_fallback():
                                rtol=1e-5, atol=1e-5)
 
 
+# ---------------------------------------------------------------------------
+# the causal walk: key sub-blocks inside the resident block, up to the
+# diagonal
+# ---------------------------------------------------------------------------
+def _walk_case(b, sq, sk, h, hk, d, seed=0):
+    rng = np.random.default_rng(seed)
+    q = jnp.asarray(rng.standard_normal((b, sq, h, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((b, sk, hk, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, sk, hk, d)), jnp.float32)
+    do = jnp.asarray(rng.standard_normal((b, sq, h * d)), jnp.float32)
+    return q, k, v, do
+
+
+def _walk_against_composite(q, k, v, do, sub, block_q=256, fwd_block_k=1024,
+                            bwd_block_k=512, segment_ids=None):
+    """Forward, log-sum and the three gradients of the causal kernels at
+    sub-block width `sub` (the module's `_WALK`), each against the XLA
+    composite."""
+    b, sq, h, d = q.shape
+    sk, hk = k.shape[1], k.shape[2]
+    sc = 1.0 / np.sqrt(d)
+    qs = (q * sc).reshape(b, sq, h * d)
+    km, vm = k.reshape(b, sk, hk * d), v.reshape(b, sk, hk * d)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(fa, "_WALK", sub)
+        o, lse = fa._flash_fwd_fused(qs, km, vm, h, True, block_q=block_q,
+                                     block_k=fwd_block_k, interpret=True,
+                                     Hk=hk, segment_ids=segment_ids)
+        dq, dk, dv = fa._flash_bwd_fused(
+            qs, km, vm, o, lse, do, h, True, block_q=block_q,
+            block_k=bwd_block_k, interpret=True, Hk=hk,
+            segment_ids=segment_ids)
+
+    def comp(qm, km, vm):
+        return _xla_ref(qm.reshape(q.shape), km.reshape(k.shape),
+                        vm.reshape(v.shape), True, sc,
+                        segment_ids=segment_ids).reshape(b, sq, h * d)
+
+    ref, vjp = jax.vjp(comp, q.reshape(b, sq, h * d), km, vm)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(ref),
+                               rtol=5e-5, atol=5e-5)
+    for got, want in zip((dq * sc, dk, dv), vjp(do)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=5e-4, atol=5e-4)
+    # the log-sum of every row that sees a key
+    sco = jnp.einsum("bqhd,bkhd->bhqk", q,
+                     jnp.repeat(k, h // hk, axis=2)) * sc
+    ok = ((sk - sq) + jnp.arange(sq)[:, None]
+          >= jnp.arange(sk)[None, :])[None, None]
+    if segment_ids is not None:
+        ok = ok & (segment_ids[0][:, None, :, None]
+                   == segment_ids[1][:, None, None, :])
+    want = jax.scipy.special.logsumexp(
+        jnp.where(ok, sco, -jnp.inf), axis=-1)           # [b, h, sq]
+    got = lse.reshape(b, h, fa._SUBL, sq)[:, :, 0]
+    seen = np.broadcast_to(np.asarray(ok.any(-1)), want.shape)
+    np.testing.assert_allclose(np.asarray(got)[seen],
+                               np.asarray(want)[seen], rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("h,hk,d", [(2, 2, 64), (1, 1, 128), (1, 1, 256),
+                                    (4, 2, 64), (3, 3, 64)])
+@pytest.mark.parametrize("sub", [128, 256, 512])
+def test_walk_one_key_block_a_row(sub, h, hk, d):
+    """GPT-2 small's shape: sequence 1024 under a 1024-wide K/V block,
+    so the forward's only way to skip the masked half is the walk. Two
+    heads of 64 are one 128-lane slab, unless they are grouped on one
+    key/value head or odd in number (then every head keeps its own
+    slice); a head of 128 or 256 is a slab of its own."""
+    q, k, v, do = _walk_case(1, 1024, 1024, h, hk, d)
+    _walk_against_composite(q, k, v, do, sub)
+
+
+def test_walk_with_skipped_grid_steps():
+    """Sequence 2048: the forward's second key block and the backward's
+    early q blocks do no work, and their clamped index maps name a
+    neighbour's block."""
+    q, k, v, do = _walk_case(1, 2048, 2048, 2, 2, 64, seed=1)
+    _walk_against_composite(q, k, v, do, 256)
+
+
+@pytest.mark.parametrize("sq,sk", [(384, 768), (640, 384)])
+def test_walk_cross_length_bottom_right(sq, sk):
+    """sq != sk: the diagonal is bottom-right aligned and its offset
+    (384, -256) is no multiple of the sub-block; with sq > sk the first
+    rows see no key at all."""
+    q, k, v, do = _walk_case(1, sq, sk, 2, 2, 64, seed=2)
+    _walk_against_composite(q, k, v, do, 256, block_q=128,
+                            fwd_block_k=768, bwd_block_k=384)
+
+
+@pytest.mark.parametrize("sub", [128, 256])
+def test_walk_with_segment_ids(sub):
+    b, s = 2, 512
+    q, k, v, do = _walk_case(b, s, s, 2, 2, 64, seed=3)
+    seg0 = np.concatenate([np.zeros(200), np.ones(180),
+                           -np.ones(132)]).astype(np.int32)
+    seg = jnp.asarray(np.stack([seg0, np.zeros(s, np.int32)]))
+    _walk_against_composite(q, k, v, do, sub, segment_ids=(seg, seg))
+
+
+def test_walk_multi_query_20_to_1():
+    """Jamba's attention layer: 20 query heads on one key/value head."""
+    q, k, v, do = _walk_case(1, 512, 512, 20, 1, 128, seed=4)
+    _walk_against_composite(q, k, v, do, 256)
+
+
+def _parent_fwd_noncausal(q, k, v, H, block_q, block_k):
+    """The forward kernel as it stood before the walk, without causality:
+    whole-block body, statistics one column a head."""
+    from jax.experimental import pallas as pl
+    b, sq, HD = q.shape
+    sk, D = k.shape[1], HD // H
+
+    def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref):
+        ki, nk = pl.program_id(2), pl.num_programs(2)
+
+        @pl.when(ki == 0)
+        def _init():
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+            m_ref[:] = jnp.full_like(m_ref, fa._NEG_INF)
+            l_ref[:] = jnp.zeros_like(l_ref)
+
+        qf, kf, vf = q_ref[0], k_ref[0], v_ref[0]
+        for h in range(H):
+            sl = slice(h * D, (h + 1) * D)
+            s = jax.lax.dot_general(qf[:, sl], kf[:, sl],
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            m_prev = m_ref[:, h:h + 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[:, h:h + 1] = alpha * l_ref[:, h:h + 1] + jnp.sum(
+                p, axis=1, keepdims=True)
+            acc_ref[:, sl] = acc_ref[:, sl] * alpha + jax.lax.dot_general(
+                p.astype(vf.dtype), vf[:, sl], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[:, h:h + 1] = m_new
+
+        @pl.when(ki == nk - 1)
+        def _finalize():
+            l = l_ref[:]
+            for h in range(H):
+                sl = slice(h * D, (h + 1) * D)
+                o_ref[0, :, sl] = (acc_ref[:, sl] / l[:, h:h + 1]).astype(
+                    o_ref.dtype)
+            lse_ref[0] = m_ref[:] + jnp.log(l)
+
+    from jax.experimental.pallas import tpu as pltpu
+    return pl.pallas_call(
+        kernel, grid=(b, sq // block_q, sk // block_k),
+        in_specs=[pl.BlockSpec((1, block_q, HD), lambda b, i, j: (b, i, 0)),
+                  pl.BlockSpec((1, block_k, HD), lambda b, i, j: (b, j, 0)),
+                  pl.BlockSpec((1, block_k, HD), lambda b, i, j: (b, j, 0))],
+        out_specs=[pl.BlockSpec((1, block_q, HD), lambda b, i, j: (b, i, 0)),
+                   pl.BlockSpec((1, block_q, fa._LANES),
+                                lambda b, i, j: (b, i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((b, sq, HD), q.dtype),
+                   jax.ShapeDtypeStruct((b, sq, fa._LANES), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, HD), jnp.float32),
+                        pltpu.VMEM((block_q, fa._LANES), jnp.float32),
+                        pltpu.VMEM((block_q, fa._LANES), jnp.float32)],
+        interpret=True)(q, k, v)
+
+
+@pytest.mark.parametrize("d,dtype", [(64, jnp.float32), (128, jnp.bfloat16)])
+def test_noncausal_forward_is_the_parents_bit_for_bit(d, dtype):
+    """Without causality there is nothing to walk: one whole-block visit,
+    and the same float operations in the same order as before. Output and
+    log-sum equal the earlier kernel's to the bit (the statistics only
+    moved from one column a head to every lane)."""
+    h = 256 // d
+    q, k, v = (_fuse(x) for x in _make(b=1, s=512, h=h, d=d, dtype=dtype,
+                                       seed=5))
+    o, lse = fa._flash_fwd_fused(q, k, v, h, False, block_q=128,
+                                 block_k=256, interpret=True)
+    o_was, lse_was = _parent_fwd_noncausal(q, k, v, h, 128, 256)
+    np.testing.assert_array_equal(np.asarray(o), np.asarray(o_was))
+    got = lse.reshape(1, h, fa._SUBL, 512)[:, :, 0]          # [b, h, s]
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(lse_was[:, :, :h]).transpose(0, 2, 1))
+
+
+@pytest.mark.parametrize("sq,sk,block_k,sub,causal,want", [
+    (1024, 1024, 1024, 256, True, (10, 16)),    # GPT-2 small, forward
+    (1024, 1024, 512, 256, True, (10, 16)),     # ... and backward
+    (2048, 2048, 1024, 256, True, (36, 64)),    # GPT-3 1.3B, forward
+    (4096, 4096, 1024, 256, True, (136, 256)),  # Jamba's attention layer
+    (1024, 1024, 1024, 512, True, (6, 8)),
+    (384, 768, 768, 256, True, (8, 9)),         # bottom-right, bq 128
+    (1024, 1024, 1024, 256, False, (4, 4)),     # no causality: the total
+])
+def test_causal_tiles(sq, sk, block_k, sub, causal, want):
+    block_q = 128 if sq == 384 else 256
+    assert fa.causal_tiles(sq, sk, block_q, block_k, sub,
+                           causal=causal) == want
+
+
+def test_compile_record_says_what_the_flash_kernels_visit(monkeypatch):
+    """`compile_record("train_step")["flash_causal"]`: of the [s, s]
+    score tiles, how many each kernel of the traced step visits."""
+    import paddle_tpu as pt
+    from paddle_tpu.jit import TrainStep
+    from paddle_tpu.models import GPTForCausalLM, GPTPretrainingCriterion
+    from paddle_tpu.models.gpt import GPTConfig
+    from paddle_tpu.observability import perf
+    from paddle_tpu.optimizer import AdamW
+    _interpreted_kernels(monkeypatch)
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_AUTOTUNE", "0")
+    pt.seed(0)
+    model = GPTForCausalLM(GPTConfig(
+        vocab_size=256, hidden_size=128, num_layers=1, num_heads=2,
+        max_position_embeddings=512, use_flash_attention=True))
+    model.train()
+    crit = GPTPretrainingCriterion()
+    step = TrainStep(model, AdamW(learning_rate=1e-3,
+                                  parameters=model.parameters()),
+                     lambda m, ids, labels: crit(m(ids), labels))
+    perf._FAMILY_COMPILE.pop("train_step", None)
+    ids = np.zeros((1, 512), np.int32)
+    assert np.isfinite(float(step(ids, ids).numpy()))
+    assert perf.compile_record("train_step")["flash_causal"] == (
+        "fwd 3/4 of 256-wide tiles; bwd 3/4 of 256-wide tiles")
+
+
 @pytest.mark.skipif(jax.default_backend() != "tpu",
                     reason="compiled Pallas path needs TPU")
 def test_fwd_bwd_tpu_compiled():
