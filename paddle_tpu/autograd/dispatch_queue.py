@@ -58,9 +58,9 @@ fan-in/fan-out junctions, not stopping at them):
 Modes: ``whole_graph`` (default) > ``batched`` (the PR 10
 single-consumer-chain engine, kept verbatim as an A/B rung) >
 ``per_node`` (the legacy walker). ``PADDLE_TPU_BACKWARD_DISPATCH`` /
-``set_dispatch_mode`` / ``backward_dispatch_mode`` select;
-``bench.py --config dispatch`` A/Bs all three against TrainStep in one
-session. Gradients are bit-identical across all modes — pinned by
+``set_dispatch_mode`` / ``backward_dispatch_mode`` select; no
+benchmark cell runs any of them yet (ROADMAP C4). Gradients are
+bit-identical across all modes — pinned by
 tests/test_backward_dispatch.py.
 """
 from __future__ import annotations
@@ -105,8 +105,8 @@ def set_dispatch_mode(mode: str) -> str:
 
 
 class backward_dispatch_mode:
-    """Context manager pinning the backward dispatch mode (the bench
-    A/B and the bit-identical test suite run all modes through it)."""
+    """Context manager pinning the backward dispatch mode (the
+    bit-identical test suite runs all modes through it)."""
 
     def __init__(self, mode: str):
         self._new = mode

@@ -153,12 +153,12 @@ from .router import (Router, ReplicaSet,  # noqa: E402,F401
                      ReplicaHandle, ReplicaGone)
 # serving SLO control plane: SLO-driven elastic autoscaling over the
 # router's add_replica/retire_replica surface, plus the heavy-tailed
-# traffic harness that exercises it (see README "Serving SLO control
+# traffic generator that exercises it (see README "Serving SLO control
 # plane")
 from .autoscaler import (Autoscaler, RouterActuator,  # noqa: E402,F401
                          SCALE_ACTIONS)
 from .traffic import (Cohort, TrafficModel,  # noqa: E402,F401
-                      TrafficEvent, run_traffic)
+                      TrafficEvent)
 # prefill/decode disaggregation: role-based replica pools with
 # cross-process KV-page migration (see README "Prefill/decode
 # disaggregation")

@@ -46,9 +46,8 @@ TPOT-breach on the decode pool (inter-token latency is decode-bound),
 and retirement drains the pool that can best spare a replica, never
 stranding either role. Process-backed pools pass
 `process_role="engine_prefill"` / `"engine_decode"`
-(`process_engine_factory(role=...)`) so fleet telemetry, capacity
-lines, and `tools/perf_ledger.py --check` baselines split per role for
-free.
+(`process_engine_factory(role=...)`) so fleet telemetry and capacity
+lines split per role for free.
 
 Series: `paddle_tpu_disagg_handoffs_total{path=migrated|readmitted|
 fallback}`, `paddle_tpu_disagg_migrated_bytes_total`,

@@ -211,7 +211,7 @@ _CompileTimed = _pf.CompileTimed
 class _EngineStats(dict):
     """The ad-hoc stats dict, migrated onto the registry while staying
     a real dict: every increment site (`stats[k] += n`) keeps its exact
-    per-engine semantics (tests and bench read those), and the write
+    per-engine semantics (tests and chip_smoke.py read those), and the write
     mirrors the delta onto the process-global
     `paddle_tpu_engine_events_total{event=k}` counter. Mirroring is a
     no-op while observability is disabled — per-engine counts keep

@@ -139,8 +139,8 @@ def _worker_main(model_builder, model_kwargs, engine_kwargs, tp,
     parent's next RPC raises and becomes ReplicaGone). `role` is the
     fleet process_role this replica self-identifies as — "engine" by
     default; a disaggregated pool passes "engine_prefill" /
-    "engine_decode" so telemetry, capacity lines and perf-ledger
-    baselines split per role."""
+    "engine_decode" so telemetry and capacity lines split per
+    role."""
     from ..observability import fleet as _ofleet
     from ..observability import metrics as _om
     from ..distributed import rpc as _rpc

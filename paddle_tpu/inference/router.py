@@ -282,7 +282,7 @@ class Router:
                  slo_ttft_s: Optional[float] = None,
                  session_cache_size: int = 4096):
         """affinity: route on the prefix-cache peek (False = pure
-        least-loaded; the A/B the router bench measures).
+        least-loaded).
         affinity_max_inflight_factor: load headroom on the affinity
         pick — when the cached replica's inflight (counting this
         request) exceeds this factor times the least-loaded live
@@ -337,7 +337,7 @@ class Router:
         self._step_pool = None          # lazy: concurrent fleet steps
         self._probe_pool = None         # lazy: concurrent cache peeks
         self._retired_replica_s = 0.0   # replica-seconds of retirees
-        # per-router exact counts (plain dict — bench/tests read it;
+        # per-router exact counts (plain dict — tests read it;
         # the process-global series carry the same numbers)
         self.stats = dict(
             routed=0, shed=0, failovers=0, reroutes=0,
@@ -746,8 +746,7 @@ class Router:
     def replica_seconds(self) -> float:
         """Cumulative replica-alive seconds across the router's
         lifetime (retired replicas included) — the capacity cost an
-        elastic fleet is trying to minimize; the traffic bench
-        compares this against a static max-size fleet at equal work."""
+        elastic fleet is trying to minimize."""
         now = time.monotonic()
         return self._retired_replica_s + sum(
             now - h.t_added for h in self.replicas)

@@ -1,7 +1,7 @@
 """Heavy-tailed many-user serving traffic: the load shape production
-fleets actually see, as a deterministic generator + driver.
+fleets actually see, as a deterministic generator.
 
-Uniform prompt sweeps (every bench before PR 19) exercise the engine,
+Uniform prompt sweeps exercise the engine,
 not the fleet: real traffic is bursty (on/off arrival phases on top of
 Poisson), heavy-tailed (a few huge prompts and long generations under
 a mass of small ones), session-shaped (multi-turn conversations whose
@@ -17,24 +17,19 @@ is LRU-bounded like the router's session map.
 Cohorts model user populations: each has a shared token prefix (the
 "system prompt" every member re-hits), a lognormal body/output length
 distribution (the heavy tail), and a mean turn count (session churn).
-`run_traffic` drives the events against a `Router` in wall-clock
-time, optionally scanning an `Autoscaler` between fleet steps, and
-reports per-cohort accounting — affinity hit-token fraction (exact:
-read as the router's counter delta around each submit), shed rate,
-e2e percentiles — plus the fleet-level numbers the traffic bench
-ships to the BENCH line and perf ledger."""
+The benchmark's serving driver runs a copy of the generator
+(`benchmarks/harness/traffic_model.py`, held to this one event for
+event by `benchmarks/tests/test_traffic_model.py`)."""
 from __future__ import annotations
 
 import dataclasses
 import hashlib
-import time
 from collections import OrderedDict
-from typing import Dict, Iterator, List, Optional
+from typing import Iterator
 
 import numpy as np
 
-__all__ = ["Cohort", "TrafficEvent", "TrafficModel", "run_traffic",
-           "DEFAULT_COHORTS"]
+__all__ = ["Cohort", "TrafficEvent", "TrafficModel", "DEFAULT_COHORTS"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -51,7 +46,7 @@ class Cohort:
 
 
 # a chat-heavy mix with a long-tail batch cohort — sized for the tiny
-# CPU bench models (lengths are clipped by the driver to the engine's
+# CPU test models (lengths are clipped by the driver to the engine's
 # feasible range)
 DEFAULT_COHORTS = (
     Cohort("chat", weight=0.7, prefix_len=24, body_mu=2.2,
@@ -76,7 +71,7 @@ class TrafficEvent:
 
 class TrafficModel:
     """Deterministic event-stream generator (same seed -> identical
-    schedule, the property the A/B bench comparison rests on).
+    schedule, the property an A/B comparison rests on).
 
     Arrivals are an on/off modulated Poisson process: `base_rate`
     req/s during off (calm) phases, `burst_rate` during on phases,
@@ -119,7 +114,7 @@ class TrafficModel:
         # a distinct, deterministic stream per derivation key — the
         # stateless-session trick: nothing per-session is ever stored.
         # blake2s, NOT hash(): builtin string hashing is randomized
-        # per process, and the A/B bench comparison needs the same
+        # per process, and an A/B comparison needs the same
         # seed to mean the same schedule in every process
         digest = hashlib.blake2s(
             repr((self.seed,) + key).encode(), digest_size=8).digest()
@@ -190,117 +185,3 @@ class TrafficModel:
                 t=t, rid=f"r{i}", session=session,
                 cohort=self.cohorts[ci].name, turn=turn,
                 prompt=self.prompt(ci, session, turn), max_new=out)
-
-
-def _pctl(xs: List[float], q: float) -> Optional[float]:
-    if not xs:
-        return None
-    # host-side latency lists, no device tensors involved
-    return float(np.percentile(np.asarray(xs, np.float64), q))  # graftlint: disable=host-sync
-
-
-def run_traffic(router, events, *, autoscaler=None,
-                scan_every_s: float = 0.25,
-                time_scale: float = 1.0,
-                max_prompt: Optional[int] = None) -> dict:
-    """Drive an event stream against a Router in wall-clock time:
-    arrivals are submitted when their (time_scale-compressed)
-    timestamps come due, the fleet steps continuously, and the
-    optional autoscaler scans on its own cadence between steps.
-    Returns the accounting report: per-cohort {submitted, ok, shed,
-    hit/miss affinity tokens, e2e percentiles} + fleet totals.
-
-    time_scale < 1 compresses the schedule (a 20s trace in 10s of
-    wall time doubles every rate); max_prompt truncates prompts to
-    the fleet's feasible context (clipping, not shedding — the tail
-    stays heavy up to the cap)."""
-    evs = list(events)
-    evs.sort(key=lambda e: e.t)
-    stats = router.stats
-    per: Dict[str, dict] = {}
-
-    def cohort_slot(name):
-        s = per.get(name)
-        if s is None:
-            s = per[name] = dict(submitted=0, ok=0, shed=0, failed=0,
-                                 hit_tokens=0, miss_tokens=0, e2e=[])
-        return s
-
-    inflight: Dict[object, tuple] = {}      # rid -> (cohort, t_submit)
-    t0 = time.perf_counter()
-    last_scan = 0.0
-    i = 0
-    steps = 0
-    while i < len(evs) or router.has_unfinished or inflight:
-        now = time.perf_counter() - t0
-        while i < len(evs) and evs[i].t * time_scale <= now:
-            ev = evs[i]
-            i += 1
-            prompt = ev.prompt
-            if max_prompt is not None and len(prompt) > max_prompt:
-                prompt = prompt[:max_prompt]
-            s = cohort_slot(ev.cohort)
-            s["submitted"] += 1
-            h0 = stats["affinity_hit_tokens"]
-            m0 = stats["affinity_miss_tokens"]
-            router.submit(ev.rid, prompt, max_new_tokens=ev.max_new,
-                          session_id=ev.session)
-            # exact per-request affinity attribution: submit() routes
-            # synchronously, so the counter delta is this request's
-            # (failover re-routes happen inside step(), outside this
-            # window, and cannot be misattributed here)
-            s["hit_tokens"] += stats["affinity_hit_tokens"] - h0
-            s["miss_tokens"] += stats["affinity_miss_tokens"] - m0
-            inflight[ev.rid] = (ev.cohort, time.perf_counter())
-        for r in router.step():
-            rec = inflight.pop(r.request_id, None)
-            if rec is None:
-                continue
-            cohort, t_sub = rec
-            s = cohort_slot(cohort)
-            if r.ok:
-                s["ok"] += 1
-                s["e2e"].append(time.perf_counter() - t_sub)
-            elif r.finish_reason == "rejected":
-                s["shed"] += 1
-            else:
-                s["failed"] += 1
-        steps += 1
-        now = time.perf_counter() - t0
-        if autoscaler is not None and \
-                now - last_scan >= scan_every_s:
-            autoscaler.scan()
-            last_scan = now
-        if i < len(evs) and not router.has_unfinished:
-            # idle until the next arrival (bounded nap so the
-            # autoscaler cadence keeps running through lulls)
-            wait = evs[i].t * time_scale - now
-            if wait > 0:
-                time.sleep(min(wait, scan_every_s))
-    wall = time.perf_counter() - t0
-    report = {
-        "cohorts": {}, "wall_s": wall, "steps": steps,
-        "submitted": 0, "ok": 0, "shed": 0, "failed": 0,
-    }
-    for name, s in sorted(per.items()):
-        tok = s["hit_tokens"] + s["miss_tokens"]
-        report["cohorts"][name] = {
-            "submitted": s["submitted"], "ok": s["ok"],
-            "shed": s["shed"], "failed": s["failed"],
-            "shed_rate": s["shed"] / max(s["submitted"], 1),
-            "hit_token_fraction": s["hit_tokens"] / tok if tok else 0.0,
-            "e2e_p50_s": _pctl(s["e2e"], 50),
-            "e2e_p95_s": _pctl(s["e2e"], 95),
-        }
-        for k in ("submitted", "ok", "shed", "failed"):
-            report[k] += s[k]
-    report["req_per_s"] = report["ok"] / wall if wall > 0 else 0.0
-    report["shed_rate"] = report["shed"] / max(report["submitted"], 1)
-    if hasattr(router, "replica_seconds"):
-        report["replica_seconds"] = router.replica_seconds()
-    if autoscaler is not None:
-        report["decisions"] = [
-            {k: d[k] for k in ("seq", "action", "replica",
-                               "replicas_before", "replicas_after")}
-            for d in autoscaler.decisions]
-    return report

@@ -9,7 +9,9 @@ ordered reassembly, and large-sample collation runs through its
 parallel memcpy."""
 from __future__ import annotations
 
+import os
 import queue
+import secrets
 import threading
 import time
 from typing import Iterable, List, Optional
@@ -302,6 +304,10 @@ class DataLoader:
         # self-healing: how many times EACH spawned worker may be
         # respawned after dying without reporting (OOM kill, segfault)
         self.max_worker_restarts = max(0, int(max_worker_restarts))
+        # every SharedMemory segment this loader's workers create is
+        # named with this prefix (and the epoch's number after it)
+        self._shm_prefix = f"ptdl{os.getpid():x}x{secrets.token_hex(4)}"
+        self._shm_epochs = 0
         self._iterable_mode = isinstance(dataset, IterableDataset)
         if self._iterable_mode:
             self.batch_sampler = None
@@ -431,7 +437,8 @@ class DataLoader:
         the parent still needs; stale re-produced batches are discarded
         (their segments unlinked). On exit the parent joins workers
         FIRST and only then drains, so in-flight SharedMemory payloads
-        are always unlinked — no /dev/shm leak on early consumer exit."""
+        are always unlinked — no /dev/shm leak on early consumer exit —
+        and last unlinks by name what a killed worker never delivered."""
         import multiprocessing as mp
         import time as _time
         import warnings
@@ -468,12 +475,14 @@ class DataLoader:
         # time and ship their metric snapshots + trace events back
         # with the "done" farewell
         obs_on = (_om._ENABLED, _ot._ENABLED)
+        self._shm_epochs += 1
+        shm_stem = f"{self._shm_prefix}e{self._shm_epochs}"
 
         def spawn(w, resume_from=0, attempt=0):
             p = ctx.Process(
                 target=PW.worker_main,
                 args=(w, W, payload_bytes, idx_batches, queues[w], stop,
-                      resume_from, specs, attempt, obs_on),
+                      shm_stem, resume_from, specs, attempt, obs_on),
                 daemon=True)
             with cpu_only_child_env():
                 p.start()
@@ -608,6 +617,8 @@ class DataLoader:
                         # its metrics + trace) lands after the parent
                         # consumed the last batch — merge it here
                         _merge_farewell(payload)
+            # what a hard-killed worker created and never delivered
+            PW.unlink_stem(shm_stem)
 
     def _iter_buffered(self):
         q: queue.Queue = queue.Queue(maxsize=self.prefetch_factor)

@@ -24,14 +24,17 @@ detected by the parent's queue-wait loop and respawned with
 every SOFT exit path — orderly stop, early consumer exit, error —
 SharedMemory payloads that never reached the parent are unlinked
 (worker-side for unplaced ones, parent-side `discard()` after join for
-in-flight ones), so /dev/shm does not leak. Known residual window: a
-HARD kill landing strictly between segment creation in `_pack` and the
-payload reaching the parent's queue can leak that one batch's segments
-— only the dead worker knew their names (they are deliberately
-unregistered from the resource tracker so ownership can pass to the
-consumer)."""
+in-flight ones), so /dev/shm does not leak. A HARD kill landing
+strictly between segment creation in `_pack` and the payload reaching
+the parent's queue loses that batch's payload with the dead worker
+(its segments are deliberately unregistered from the resource tracker
+so ownership can pass to the consumer) — but not their names: every
+segment is named from the epoch's stem, and the parent ends the epoch
+with `unlink_stem`, which removes whatever of that stem is left where
+/dev/shm can be listed."""
 from __future__ import annotations
 
+import os
 import traceback
 
 import numpy as np
@@ -64,11 +67,15 @@ def np_collate(batch):
     return batch
 
 
-def _pack(obj, segments):
-    """Replace large ndarray leaves with shared-memory markers."""
+def _pack(obj, segments, stem):
+    """Replace large ndarray leaves with shared-memory markers. Segment
+    k of the payload is named `<stem>n<k>`: the stem starts with the
+    loader's own prefix (`DataLoader._shm_prefix`), so what a loader
+    left in /dev/shm can be told from everybody else's."""
     if isinstance(obj, np.ndarray) and obj.nbytes >= _SHM_THRESHOLD:
         from multiprocessing import shared_memory
-        seg = shared_memory.SharedMemory(create=True, size=obj.nbytes)
+        seg = shared_memory.SharedMemory(
+            name=f"{stem}n{len(segments)}", create=True, size=obj.nbytes)
         # ownership passes to the CONSUMER: unregister from this
         # process's resource tracker, or the tracker would unlink the
         # segment when this (short-lived) worker exits — before the
@@ -82,11 +89,12 @@ def _pack(obj, segments):
         segments.append(seg)
         return ("__shm__", seg.name, str(obj.dtype), obj.shape)
     if isinstance(obj, list):
-        return ["__list__"] + [_pack(x, segments) for x in obj]
+        return ["__list__"] + [_pack(x, segments, stem) for x in obj]
     if isinstance(obj, tuple):
-        return ("__tuple__",) + tuple(_pack(x, segments) for x in obj)
+        return ("__tuple__",) + tuple(
+            _pack(x, segments, stem) for x in obj)
     if isinstance(obj, dict):
-        return {k: _pack(v, segments) for k, v in obj.items()}
+        return {k: _pack(v, segments, stem) for k, v in obj.items()}
     return obj
 
 
@@ -151,6 +159,21 @@ def discard(obj):
             discard(v)
 
 
+def unlink_stem(stem):
+    """Unlink every segment whose name starts with `stem` — the
+    parent's last step of an epoch, after its workers are gone and its
+    queues drained: what is left then is what a hard-killed worker
+    created and never delivered. Where /dev/shm is not a directory
+    (not Linux) nothing can be listed and nothing is done."""
+    try:
+        names = os.listdir("/dev/shm")
+    except OSError:
+        return
+    for name in names:
+        if name.startswith(stem):
+            discard(("__shm__", name))
+
+
 def unpack(obj):
     """Parent-side inverse of _pack: attach, copy out, release."""
     from multiprocessing import shared_memory
@@ -177,8 +200,8 @@ def unpack(obj):
 
 
 def worker_main(wid, num_workers, payload_bytes, idx_batches, out_queue,
-                stop_event, resume_from=0, fault_specs=None, attempt=0,
-                obs_enabled=False):
+                stop_event, shm_stem, resume_from=0, fault_specs=None,
+                attempt=0, obs_enabled=False):
     """Entry point of a spawned worker process. Round-robin ownership:
     worker w produces batches w, w+W, w+2W, ... in order into its own
     bounded queue (deterministic reassembly, per-worker backpressure —
@@ -186,6 +209,9 @@ def worker_main(wid, num_workers, payload_bytes, idx_batches, out_queue,
 
     payload_bytes: pickle of (dataset, collate_fn_or_None,
     worker_init_fn_or_None).
+    shm_stem: this epoch's prefix of the loader's segment names; a
+    batch's segments are `<shm_stem>w<wid>a<attempt>b<bi>n<k>`, so a
+    respawn never meets a name its dead predecessor left.
     resume_from: first batch index the parent still needs; a worker
     respawned to replace a dead one skips its stripe's earlier batches.
     fault_specs: a faults.snapshot() from the parent, re-armed in this
@@ -252,7 +278,8 @@ def worker_main(wid, num_workers, payload_bytes, idx_batches, out_queue,
             batch = collate(samples)
             segments = []
             try:
-                payload = _pack(batch, segments)
+                payload = _pack(batch, segments,
+                                f"{shm_stem}w{wid}a{attempt}b{bi}")
             except BaseException:
                 # mid-pack failure (e.g. ENOSPC on /dev/shm): the
                 # segments created so far are unregistered from the

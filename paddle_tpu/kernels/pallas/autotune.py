@@ -165,8 +165,8 @@ def _off_trace(fn):
 
 def drain_sweeps() -> list:
     """Return and clear the sweep records accumulated since the last
-    drain (bench.py appends them to perf_ledger.jsonl; chip_smoke.py
-    prints their count and total seconds)."""
+    drain (`benchmarks/harness/runlib.py` counts them into a run's
+    set-up; chip_smoke.py prints their count and total seconds)."""
     out = list(_SWEEPS)
     _SWEEPS.clear()
     return out
@@ -184,7 +184,7 @@ def tune(key_parts, candidates, run_candidate, rounds=2, bw_window=None):
     discarded (defaults returned, nothing persisted) so a degraded
     window cannot freeze a noise winner into the cache. The sweep
     record (candidate timings, probes, verdict) is logged either way
-    for the perf ledger."""
+    for `drain_sweeps()`."""
     if getattr(_TUNING, "active", False):
         return candidates[0]
     hit = lookup(key_parts)

@@ -957,7 +957,7 @@ def _shape_reject_reason(q_shape, k_shape):
 
 def attention_path(q_shape, k_shape, masked=False):
     """('pallas'|'xla', reason) — which implementation flash_attention will
-    take for these shapes and why. Lets callers (bench.py asserts on it;
+    take for these shapes and why. Lets callers (chip_smoke.py checks it;
     nn.functional.flash_attention warns on fallback) see when the Pallas
     kernel disengages. masked=True means a dense attn_mask (XLA
     composite); segment-id masking stays on the Pallas path and needs no

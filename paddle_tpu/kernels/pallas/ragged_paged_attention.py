@@ -515,8 +515,8 @@ def _shape_reject_reason(T, T_pool, H, Hk, D, block_size, with_pool):
 def ragged_attention_path(T, T_pool, H, Hk, D, block_size,
                           with_pool=True):
     """('pallas'|'jnp', reason) — which implementation the dispatcher
-    takes for this launch shape and why (bench and the engine's
-    observability can surface fallbacks)."""
+    takes for this launch shape and why (the engine's observability
+    surfaces fallbacks)."""
     if not _pallas_available():
         return ("jnp", f"no TPU Pallas backend ({jax.default_backend()})")
     reason = _shape_reject_reason(T, T_pool, H, Hk, D, block_size,

@@ -304,7 +304,7 @@ class GPTPretrainingCriterion(Layer):
 
 
 def num_params(config: GPTConfig) -> int:
-    """Parameter count (for MFU math in bench.py)."""
+    """Parameter count."""
     h, v, L = config.hidden_size, config.vocab_size, config.num_layers
     i = config.intermediate_size
     per_layer = (3 * h * h + 3 * h) + (h * h + h) + (h * i + i) + (

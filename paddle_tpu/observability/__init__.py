@@ -121,16 +121,14 @@ def reset() -> None:
     every buffered trace event — the two stores move together so a
     fresh measurement window never mixes old spans with new counters
     (pinned by test_reset_clears_metrics_and_trace_ring). Use
-    `trace_clear()` for the narrow ring-only clear. The perf-ledger
-    window accumulators move with it (each bench config's ledger
-    record covers exactly its own window — the collective window in
-    observability.comms included; its per-process call-seq counters
-    survive, see comms.reset_window). The numerics plane's pending
-    bundle, sentinel windows and divergence latch move with it too
-    (numerics.reset_window — the enabled flag and config survive)."""
+    `trace_clear()` for the narrow ring-only clear. The goodput
+    accounting's collective seconds move with it (comms.reset_window;
+    the per-process call-seq counters survive, see there), and so do
+    the numerics plane's pending bundle, sentinel windows and
+    divergence latch (numerics.reset_window — the enabled flag and
+    config survive)."""
     registry().reset()
     tracing.clear()
-    perf.reset_window()
     comms.reset_window()
     numerics.reset_window()
 
@@ -153,9 +151,9 @@ def trace_clear() -> None:
 
 
 def summary() -> dict:
-    """Compact summary for machine consumers (bench.py attaches this to
-    BENCH json): non-zero counters/gauges as flat `name{k=v}` keys and
-    per-histogram {count, sum, mean, min, max, p50, p95} — the
+    """Compact summary for machine consumers: non-zero counters/gauges
+    as flat `name{k=v}` keys and per-histogram
+    {count, sum, mean, min, max, p50, p95} — the
     percentile estimates come from the bucket vectors
     (metrics.quantile_from_buckets), which stay out of the summary
     themselves; use to_prometheus()/to_json() for those."""

@@ -64,14 +64,9 @@ a single flag check when observability is off:
   time (max of flops/peak and bytes/peak) over the period, published
   only when the device peaks are known; `stall` = the remainder, only
   when compute is. Unknown device → comms fraction only — an honest
-  partial answer beats a made-up decomposition.
-
-The per-op window accumulators feed the perf ledger as `comms_<op>`
-pseudo-families (`family_records()`, merged into the bench record by
-`bench.py`): `tools/perf_ledger.py --check`'s existing per-family
-bytes/s rule then baselines achieved comms bandwidth per
-(config, op) with no new tooling. `reset_window()` clears them
-(`obs.reset()` calls it; call-seq counters survive, see above).
+  partial answer beats a made-up decomposition. `reset_window()`
+  clears the collective seconds gathered for the next step
+  (`obs.reset()` calls it; call-seq counters survive, see above).
 """
 from __future__ import annotations
 
@@ -85,7 +80,7 @@ from ..resilience import faults as _faults
 
 __all__ = [
     "start", "finish", "count", "note_reshard", "note_train_step",
-    "family_records", "reset_window", "window_comms_seconds",
+    "reset_window",
     "COLLECTIVE_BUCKETS",
 ]
 
@@ -158,32 +153,18 @@ def _metrics():
 
 # ---------------------------------------------------------------------------
 # per-process call-sequence counters (cross-rank straggler matching
-# key) and per-op window accumulators (the perf-ledger source)
+# key) and the goodput accounting's collective seconds
 # ---------------------------------------------------------------------------
 _SEQ: Dict[Tuple[str, str], int] = {}       # (op, group) -> calls so far
-_WINDOW: Dict[str, dict] = {}               # op -> runs/seconds/bytes
 _STEP_COMMS = [0.0]                         # timed comms s since last step
 
 
-def _window_slot(op: str) -> dict:
-    slot = _WINDOW.get(op)
-    if slot is None:
-        slot = _WINDOW[op] = {"runs": 0, "seconds": 0.0, "bytes": 0.0}
-    return slot
-
-
 def reset_window() -> None:
-    """Drop the per-op window accumulators and the goodput comms
-    accumulator (obs.reset() calls this). The per-process call-seq
-    counters survive deliberately: SPMD ranks match arrivals by them,
-    and a reset on one rank mid-run would desynchronize the key."""
-    _WINDOW.clear()
+    """Drop the goodput comms accumulator (obs.reset() calls this). The
+    per-process call-seq counters survive deliberately: SPMD ranks
+    match arrivals by them, and a reset on one rank mid-run would
+    desynchronize the key."""
     _STEP_COMMS[0] = 0.0
-
-
-def window_comms_seconds() -> float:
-    """Total timed collective seconds accumulated this window."""
-    return sum(s["seconds"] for s in _WINDOW.values())
 
 
 class _Rec:
@@ -255,10 +236,6 @@ def finish(rec: Optional[_Rec], out=None) -> None:
         _t.add_event("comms." + rec.op, rec.t0 * 1e6, dt * 1e6,
                      args={"group": rec.group, "bytes": rec.nbytes},
                      trace=rec.trace)
-    slot = _window_slot(rec.op)
-    slot["runs"] += 1
-    slot["seconds"] += dt
-    slot["bytes"] += rec.nbytes
     _STEP_COMMS[0] += dt
     if rec.nbytes and dt > 0:
         bw = rec.nbytes / dt
@@ -329,32 +306,3 @@ def note_train_step(period_s: float, cost) -> None:
     g.labels(component="compute").set(compute_f)
     g.labels(component="stall").set(
         max(0.0, 1.0 - compute_f - comms_f))
-
-
-def family_records() -> Dict[str, dict]:
-    """This window's per-op achieved summary in the perf-ledger family
-    record shape (`comms_<op>` keys, merged next to
-    perf.family_records() by bench.py): the existing per-family
-    bytes/s check rule baselines comms bandwidth per (config, op)
-    unchanged. utilization_ici only with known interconnect peaks."""
-    out = {}
-    ipeaks = _perf.interconnect_peaks()
-    for op, slot in sorted(_WINDOW.items()):
-        rec = {
-            "runs": slot["runs"],
-            "compiles": 0,
-            "seconds": round(slot["seconds"], 6),
-            "expected": None,
-            "achieved_flops_per_s": None,
-            "achieved_bytes_per_s": None,
-            "utilization_hbm": None,
-            "utilization_flops": None,
-            "utilization_ici": None,
-        }
-        if slot["runs"] and slot["seconds"] > 0 and slot["bytes"]:
-            bps = slot["bytes"] / slot["seconds"]
-            rec["achieved_bytes_per_s"] = round(bps, 1)
-            if ipeaks is not None and ipeaks.get("ici", 0) > 0:
-                rec["utilization_ici"] = round(bps / ipeaks["ici"], 6)
-        out["comms_" + op] = rec
-    return out

@@ -43,11 +43,9 @@ farewell into a standing plane:
 
 * **Capacity lines.** `capacity_records()` turns the merged
   per-process counters + shipped roofline gauges into achieved req/s,
-  tok/s and utilization per process, and
-  `append_capacity_ledger(path)` writes them to ``perf_ledger.jsonl``
-  keyed by ``process_role`` — the input ROADMAP item 2's SLO-aware
-  elastic scaler sizes the fleet from (`tools/perf_ledger.py --check`
-  baselines them per (config, process_role)).
+  tok/s and utilization per process, and refreshes the capacity
+  gauges the aggregator exports (`tools/obs_top.py`'s fleet panel
+  shows them).
 
 The DataLoader worker farewell now ships THIS bundle format
 (`worker_farewell` / `merge_bundle_local`): one wire shape, one merge
@@ -906,31 +904,6 @@ class FleetAggregator:
             out.append(rec)
         return out
 
-    def append_capacity_ledger(self, path: str, config: str = "fleet",
-                               rev: Optional[str] = None
-                               ) -> List[dict]:
-        """Append one perf-ledger JSONL record per process (keyed by
-        `process_role` — `tools/perf_ledger.py --check` baselines
-        capacity per (config, process_role) the way it already keys
-        (config, mode))."""
-        import json
-        recs = self.capacity_records()
-        rev = rev if rev is not None else _git_rev()
-        ts = round(time.time(), 3)
-        lines = []
-        for cap in recs:
-            lines.append({
-                "rev": rev, "config": config, "ts": ts,
-                "device": "fleet",
-                "process_role": cap["process_role"],
-                "process": cap["process"],
-                "capacity": cap, "families": {},
-            })
-        with open(path, "a", encoding="utf-8") as f:
-            for rec in lines:
-                f.write(json.dumps(rec, sort_keys=True) + "\n")
-        return lines
-
     # -- lifecycle --
     def close(self) -> None:
         global _AGGREGATOR
@@ -940,29 +913,6 @@ class FleetAggregator:
             self._server = None
         if _AGGREGATOR is self:
             _AGGREGATOR = None
-
-
-def _git_rev() -> str:
-    """Same rev string bench.py stamps its ledger records with —
-    including the +dirty suffix, so perf_ledger's same-rev-report-only
-    rule keeps distinguishing a dirty working tree from the committed
-    revision (a dirty-tree capacity regression must still fail
-    --check against the clean commit's baseline)."""
-    import subprocess
-    root = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    try:
-        sha = subprocess.run(
-            ["git", "rev-parse", "--short=12", "HEAD"], cwd=root,
-            capture_output=True, text=True, check=True).stdout.strip()
-        if not sha:
-            return "unknown"
-        dirty = subprocess.run(
-            ["git", "diff", "--quiet", "HEAD"], cwd=root,
-            capture_output=True).returncode != 0
-        return sha + ("+dirty" if dirty else "")
-    except Exception:
-        return "unknown"
 
 
 def serve_aggregator(bind: str = "127.0.0.1", port: int = 0,

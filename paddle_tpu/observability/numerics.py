@@ -74,10 +74,9 @@ one packed reduction to executables that already run and ≤1 async
 host pull per step, SAMPLED on the `interval` cadence (default every
 64th step; `interval=1` = every-step fidelity — see `enable()` for
 the detection-latency contract: divergence is absorbing, so the
-cadence bounds latency, not coverage). `bench.py --config dispatch`
-measures the on-vs-off overhead of the default cadence on the
-3-layer-MLP loop and records it on the BENCH line + perf ledger
-(`tools/perf_ledger.py --check` fails a future overhead regression). Stats are read-only taps: gradients and
+cadence bounds latency, not coverage). What the default cadence costs
+on the chip: not measured (no benchmark cell turns the plane on).
+Stats are read-only taps: gradients and
 optimizer states are bit-identical with the plane on vs off across
 all three backward dispatch modes (test-pinned). The gauges ride
 fleet bundles like every other series, so an aggregator sees
@@ -296,8 +295,7 @@ def last() -> Optional[dict]:
     """The most recently published step record (host-side plain data:
     grad_norm, per-group norms, per_param stats, param_norm,
     update_ratio, nonfinite counts, loss/lr, backward tap summary) —
-    readable with metrics disabled, which is how the bench overhead
-    window reads its grad-norm headline."""
+    readable with metrics disabled."""
     return _LAST
 
 
@@ -421,8 +419,8 @@ def submit(packed, names: Sequence[str], groups: Sequence[str],
 def flush() -> Optional[dict]:
     """Publish the pending bundle (and any backward taps that no
     optimizer submit has claimed) NOW — the explicit completion edge
-    for the end of training, tests and the bench reader. Returns the
-    last published record."""
+    for the end of training and tests. Returns the last published
+    record."""
     global _PENDING, _STEP
     if _PENDING is not None:
         pending, _PENDING = _PENDING, None

@@ -10,8 +10,8 @@ saying where a step's time goes relative to what the hardware allows:
 sub-surfaces, all near-zero when observability is disabled:
 
 * **Cost-model telemetry.** `read_cost_model(compiled)` is the ONE
-  reader over XLA's `cost_analysis()` / `memory_analysis()` (tools and
-  bench call it instead of re-parsing the dict shapes). Every compile
+  reader over XLA's `cost_analysis()` / `memory_analysis()` (callers
+  use it instead of re-parsing the dict shapes). Every compile
   that goes through `CompileTimed` (engine ragged/decode executables,
   the TrainStep) or the fused optimizer's AOT path records its
   expected work as gauges, keyed by the same compile families the
@@ -26,8 +26,8 @@ sub-surfaces, all near-zero when observability is disabled:
   turns a measured launch/step latency plus the recorded cost model
   into achieved flops/s and bytes/s and publishes them against the
   device peaks as `paddle_tpu_roofline_utilization{family=,
-  bound=hbm|flops}`. Peaks come from the per-chip spec tables below
-  (shared with bench.py); an UNKNOWN device (the CPU test box) gets NO
+  bound=hbm|flops}`. Peaks come from the per-chip spec tables below;
+  an UNKNOWN device (the CPU test box) gets NO
   roofline series — an honest absence beats a made-up denominator.
   Spec peaks are the denominator by convention; `set_device_peaks()`
   lets a test or a session pin another one.
@@ -42,13 +42,11 @@ sub-surfaces, all near-zero when observability is disabled:
   anyone tries to batch them. Single flag check per node when
   observability is off.
 
-Per-family run accumulators (`family_records()`) feed the perf ledger:
-`bench.py` appends expected/achieved records per family to
-`perf_ledger.jsonl` and `tools/perf_ledger.py` diffs runs against the
-ledger history, so a regression the round-over-round gate detects gets
-ATTRIBUTED to a family. `reset_window()` clears the accumulators (the
-top-level `obs.reset()` calls it) so each bench config reports its own
-window.
+What reads this module now: `tools/obs_top.py` and the fleet's capacity
+lines read the gauges; the benchmark's `step_lower_s.train` and
+`step_compile_s.train` read `compile_record()`. The benchmark's own
+utilizations come from the device trace and `benchmarks/harness/
+peaks.json`, not from the host-clock gauges here.
 """
 from __future__ import annotations
 
@@ -64,8 +62,7 @@ __all__ = [
     "CostModel", "read_cost_model", "CompileTimed", "record_compile",
     "compile_record", "trace_note",
     "observe_roofline", "note_dispatch_gap", "note_dispatch_batch",
-    "note_graph_cache", "family_records",
-    "reset_window", "device_peaks", "set_device_peaks", "lookup",
+    "note_graph_cache", "device_peaks", "set_device_peaks", "lookup",
     "interconnect_peaks", "set_interconnect_peaks",
     "PEAK_BF16_FLOPS", "HBM_BYTES_PER_SEC",
     "ICI_BYTES_PER_SEC", "DCN_BYTES_PER_SEC",
@@ -79,7 +76,7 @@ __all__ = [
 # Only generations where one jax device is one chip are listed: a
 # per-chip figure under a per-core device would be a wrong denominator.
 # A device kind that is not a key has NO peaks: the roofline gauges
-# publish nothing for it and bench.py refuses to compute a utilization.
+# publish nothing for it.
 #
 # Sources. "TPU v5 lite" (v5e): Google Cloud documentation, "TPU v5e" —
 # 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s chip-to-chip
@@ -315,15 +312,8 @@ def _metrics():
     return _METRICS
 
 
-# ---------------------------------------------------------------------------
-# per-family window accumulators (the perf-ledger source). Keyed by
-# compile family; reset per measurement window via reset_window()
-# (obs.reset() calls it).
-# ---------------------------------------------------------------------------
-_FAMILY_COST: Dict[str, CostModel] = {}     # last compile's expectation
-_FAMILY_RUNS: Dict[str, dict] = {}          # this window's executions
-# where each family's set-up seconds went, for the process's life
-# (CompileTimed writes it once per first call, metrics on or off)
+# where each compile family's set-up seconds went, for the process's
+# life (CompileTimed writes it once per first call, metrics on or off)
 _FAMILY_COMPILE: Dict[str, dict] = {}
 
 
@@ -376,32 +366,15 @@ def trace_note(key: str, value: str) -> None:
             notes[key] = f"{seen}; {value}"
 
 
-def _family_slot(family: str) -> dict:
-    slot = _FAMILY_RUNS.get(family)
-    if slot is None:
-        slot = _FAMILY_RUNS[family] = {
-            "runs": 0, "seconds": 0.0, "flops": 0.0, "bytes": 0.0,
-            "compiles": 0}
-    return slot
-
-
-def reset_window() -> None:
-    """Drop this window's per-family run/compile accumulators (the
-    recorded per-family cost models survive — they describe live
-    executables, not a measurement window)."""
-    _FAMILY_RUNS.clear()
-
-
 def record_compile(family: str, compiled) -> Optional[CostModel]:
-    """Read a freshly compiled executable's cost model, remember it for
-    the family, and (when observability is enabled) publish the
-    executable gauges. The read happens even while disabled: it is a
-    one-shot at compile time and tools (profile_engine's per-entry
-    columns) want the expectation regardless of metric recording."""
+    """Read a freshly compiled executable's cost model and (when
+    observability is enabled) publish the executable gauges. The read
+    happens even while disabled: it is a one-shot at compile time, and
+    `CompileTimed.expected` carries the expectation regardless of
+    metric recording."""
     cm = read_cost_model(compiled)
     if cm is None:
         return None
-    _FAMILY_COST[family] = cm
     if _m._ENABLED:
         m = _metrics()
         m["flops"].labels(family=family).set(cm.flops)
@@ -410,24 +383,17 @@ def record_compile(family: str, compiled) -> Optional[CostModel]:
         b.labels(family=family, kind="output").set(cm.bytes_output)
         b.labels(family=family, kind="argument").set(cm.bytes_argument)
         b.labels(family=family, kind="temp").set(cm.bytes_temp)
-        _family_slot(family)["compiles"] += 1
     return cm
 
 
 def observe_roofline(family: str, seconds: float,
                      cost: Optional[CostModel]) -> None:
     """Publish achieved-vs-peak utilization for one measured execution
-    (a blocking-timed engine launch, a steady-state train step) and
-    accumulate the window's per-family achieved record. No-op while
-    observability is disabled; the roofline gauges additionally demand
-    a KNOWN device peak (see device_peaks)."""
+    (a blocking-timed engine launch, a steady-state train step). No-op
+    while observability is disabled; the roofline gauges additionally
+    demand a KNOWN device peak (see device_peaks)."""
     if not _m._ENABLED or cost is None or seconds <= 0.0:
         return
-    slot = _family_slot(family)
-    slot["runs"] += 1
-    slot["seconds"] += seconds
-    slot["flops"] += cost.flops
-    slot["bytes"] += cost.bytes_accessed
     peaks = device_peaks()
     if peaks is None:
         return
@@ -462,42 +428,6 @@ def note_graph_cache(outcome: str) -> None:
     the dispatch engine, recorded once per backward in whole_graph
     mode. Caller guards on the metrics flag like note_dispatch_gap."""
     _metrics()["graph_cache"].labels(outcome=outcome).inc()
-
-
-def family_records() -> Dict[str, dict]:
-    """This window's per-family expected/achieved summary — the
-    perf-ledger record bench.py appends per config. Families appear
-    once they compiled or executed in the window; achieved rates need
-    at least one timed run (expected-only families — e.g. the fused
-    optimizer, whose launch is async-dispatched and never blocked on —
-    report null achieved honestly)."""
-    out = {}
-    peaks = device_peaks()
-    for family, slot in sorted(_FAMILY_RUNS.items()):
-        cm = _FAMILY_COST.get(family)
-        rec = {
-            "runs": slot["runs"],
-            "compiles": slot["compiles"],
-            "seconds": round(slot["seconds"], 6),
-            "expected": cm.as_dict() if cm is not None else None,
-            "achieved_flops_per_s": None,
-            "achieved_bytes_per_s": None,
-            "utilization_hbm": None,
-            "utilization_flops": None,
-        }
-        if slot["runs"] and slot["seconds"] > 0:
-            fps = slot["flops"] / slot["seconds"]
-            bps = slot["bytes"] / slot["seconds"]
-            rec["achieved_flops_per_s"] = round(fps, 1)
-            rec["achieved_bytes_per_s"] = round(bps, 1)
-            if peaks is not None:
-                peak_flops, peak_bw = peaks
-                if peak_flops > 0:
-                    rec["utilization_flops"] = round(fps / peak_flops, 6)
-                if peak_bw > 0:
-                    rec["utilization_hbm"] = round(bps / peak_bw, 6)
-        out[family] = rec
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -589,8 +519,8 @@ class CompileTimed:
                 # buffer, so re-dispatching through jit is safe — and if
                 # the TypeError was real, jit raises it again. The
                 # recorded cost model described the FIRST signature
-                # only: drop it so roofline/ledger reads go silent
-                # instead of silently wrong for the new shapes.
+                # only: drop it so roofline reads go silent instead of
+                # silently wrong for the new shapes.
                 self.fn = self.jit_fn
                 self.expected = None
                 return self.fn(*args)

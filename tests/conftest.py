@@ -54,6 +54,20 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: long-running (model zoo / many XLA compiles); "
         "excluded unless --runslow is given")
+    # The benchmark harness's own tests (benchmarks/tests: the accounting
+    # of failed operations, `correct`'s verdicts, the schema, the trace
+    # reducers) ride every run of this whole directory, so a change that
+    # breaks what the driver measures with is found here and not on the
+    # chip. They are collected where they live, under their own
+    # conftest.py, and first: their longest file takes minutes and is
+    # to start early, not as some worker's tail. A run of single files
+    # of tests/ does not take them.
+    here = os.path.dirname(os.path.abspath(__file__))
+    bench_tests = os.path.join(os.path.dirname(here), "benchmarks", "tests")
+    given = [os.path.abspath(os.path.join(config.invocation_params.dir, a))
+             for a in config.args]
+    if here in given and bench_tests not in given:
+        config.args.insert(0, bench_tests)
 
 
 def pytest_collection_modifyitems(config, items):

@@ -320,7 +320,7 @@ class TestCollectiveTelemetry:
         assert _nonzero("paddle_tpu_collective_seconds") == {}
         assert _nonzero("paddle_tpu_collective_launches_total") == {}
         assert _nonzero("paddle_tpu_collective_bytes_total") == {}
-        assert comms.family_records() == {}
+        assert comms._STEP_COMMS[0] == 0.0
 
 
 # ---------------------------------------------------------------------------
@@ -417,64 +417,6 @@ class TestGoodput:
         # compute/stall need the cost model; present when AOT worked
         if step._step_fn.expected is not None:
             assert ("compute",) in good and ("stall",) in good
-
-
-# ---------------------------------------------------------------------------
-# perf-ledger comms families
-# ---------------------------------------------------------------------------
-class TestCommsLedger:
-    def test_family_records_shape(self):
-        import numpy as np
-        import paddle_tpu as pt
-        import paddle_tpu.distributed as dist
-        from paddle_tpu import observability as obs
-        from paddle_tpu.observability import comms, perf
-        obs.enable()
-        perf.set_interconnect_peaks(ici=1e9)
-        g = dist.new_group()
-        x = np.ones((g.nranks, 256), np.float32)
-        for _ in range(3):
-            dist.all_reduce(pt.to_tensor(x))
-        recs = comms.family_records()
-        rec = recs["comms_all_reduce"]
-        assert rec["runs"] == 3
-        assert rec["achieved_bytes_per_s"] > 0
-        assert rec["utilization_ici"] == pytest.approx(
-            rec["achieved_bytes_per_s"] / 1e9, rel=0.05)
-        obs.reset()                              # window clears
-        assert comms.family_records() == {}
-
-    def test_perf_ledger_check_baselines_per_op(self, tmp_path):
-        from tools import perf_ledger
-
-        def rec(rev, bps):
-            return {"rev": rev, "config": "comms", "ts": 1.0,
-                    "device": "cpu", "families": {
-                        "comms_all_reduce": {
-                            "runs": 5, "compiles": 0, "seconds": 1.0,
-                            "expected": None,
-                            "achieved_flops_per_s": None,
-                            "achieved_bytes_per_s": bps,
-                            "utilization_hbm": None,
-                            "utilization_flops": None,
-                            "utilization_ici": None}}}
-
-        path = tmp_path / "ledger.jsonl"
-        with open(path, "w") as f:
-            f.write(json.dumps(rec("rev_a", 100e6)) + "\n")
-            f.write(json.dumps(rec("rev_b", 10e6)) + "\n")
-        records, bad = perf_ledger.load(str(path))
-        assert bad == 0
-        verdict = perf_ledger.check(records, tol=0.2)
-        assert not verdict["pass"]
-        fam = verdict["configs"]["comms"]["families"][
-            "comms_all_reduce"]
-        assert fam["regressed"] and fam["baseline_rev"] == "rev_a"
-        # recovery passes
-        with open(path, "a") as f:
-            f.write(json.dumps(rec("rev_c", 120e6)) + "\n")
-        records, _ = perf_ledger.load(str(path))
-        assert perf_ledger.check(records, tol=0.2)["pass"]
 
 
 # ---------------------------------------------------------------------------

@@ -1,6 +1,6 @@
 """Fleet observability plane (paddle_tpu/observability/fleet.py):
 snapshot-delta encoding, sequence-numbered shipping with rollback +
-dedupe, aggregator health/staleness, capacity ledger records, the
+dedupe, aggregator health/staleness, capacity records, the
 obs_top fleet panel, the disabled-mode overhead guard — and the real
 spawn boundary: N worker processes shipping metrics + spans to an
 aggregator over the HMAC RPC layer, one killed -9 mid-run.
@@ -494,7 +494,7 @@ class TestAgentShipping:
 
 
 # ---------------------------------------------------------------------------
-# capacity ledger + obs_top fleet panel
+# capacity records + obs_top fleet panel
 # ---------------------------------------------------------------------------
 def _capacity_agg(tok_pa=500.0, tok_pb=250.0):
     """Aggregator with two replica processes' worth of engine counters
@@ -525,7 +525,7 @@ def _capacity_agg(tok_pa=500.0, tok_pb=250.0):
     return agg
 
 
-class TestCapacityLedger:
+class TestCapacityRecords:
     def test_capacity_records(self):
         agg = _capacity_agg()
         recs = {r["process"]: r for r in agg.capacity_records()}
@@ -563,40 +563,6 @@ class TestCapacityLedger:
         rec = agg.capacity_records()[0]
         assert rec["tokens_total"] == 10100.0    # totals keep history
         assert rec["tok_per_s"] == pytest.approx(10.0, rel=0.2)
-
-    def test_ledger_append_and_check_keys_by_role(self, tmp_path):
-        from tools import perf_ledger
-        path = str(tmp_path / "ledger.jsonl")
-        agg = _capacity_agg()
-        lines = agg.append_capacity_ledger(path, config="fleet_smoke",
-                                           rev="rev_a")
-        assert len(lines) == 2
-        records, bad = perf_ledger.load(path)
-        assert bad == 0 and len(records) == 2
-        assert perf_ledger._config_key(records[0][1]) == \
-            "fleet_smoke@replica"
-        # same-rev-only history: self-consistent, passes
-        verdict = perf_ledger.check(records, tol=0.2)
-        assert verdict["pass"]
-
-    def test_capacity_regression_fails_check(self, tmp_path):
-        from tools import perf_ledger
-        path = str(tmp_path / "ledger.jsonl")
-        _capacity_agg(tok_pa=500.0, tok_pb=500.0).append_capacity_ledger(
-            path, config="fleet_smoke", rev="rev_a")
-        _capacity_agg(tok_pa=100.0, tok_pb=100.0).append_capacity_ledger(
-            path, config="fleet_smoke", rev="rev_b")
-        records, _ = perf_ledger.load(path)
-        verdict = perf_ledger.check(records, tol=0.2)
-        assert not verdict["pass"]
-        cfg = verdict["configs"]["fleet_smoke@replica"]
-        assert cfg["capacity"]["tok_per_s"]["regressed"]
-        assert cfg["capacity"]["tok_per_s"]["baseline_rev"] == "rev_a"
-        # improvement (or parity) passes
-        _capacity_agg(tok_pa=600.0, tok_pb=600.0).append_capacity_ledger(
-            path, config="fleet_smoke", rev="rev_c")
-        records, _ = perf_ledger.load(path)
-        assert perf_ledger.check(records, tol=0.2)["pass"]
 
 
 class TestObsTopFleetPanel:

@@ -13,8 +13,7 @@ else. Covers, per ISSUE 6:
     (line-shift survival, new-violation failure, occurrence counts);
   * the repo gate: zero non-baselined findings over paddle_tpu/ +
     tools/ with the checked-in baseline;
-  * the per-path exemption list pin, the check_metric_names shim, and
-    the bench.py lint config emitting graftlint_report.json.
+  * the per-path exemption list pin and the check_metric_names shim.
 """
 from __future__ import annotations
 
@@ -744,12 +743,6 @@ def test_exemption_list_pinned():
     assert glconfig.PATH_EXEMPTIONS == {
         "tools/obs_top.py": frozenset({"host-sync"}),
         "tools/obs_dump.py": frozenset({"host-sync"}),
-        "tools/profile_decode.py": frozenset({"host-sync"}),
-        "tools/profile_engine.py": frozenset({"host-sync"}),
-        "tools/profile_1p3b.py": frozenset({"host-sync"}),
-        "tools/dryfit_6p7b.py": frozenset({"host-sync"}),
-        "tools/ablate_engine_step.py": frozenset({"host-sync"}),
-        "tools/resnet_traffic.py": frozenset({"host-sync"}),
         "tools/gen_ops_parity.py": frozenset({"host-sync"}),
     }
     for rules_disabled in glconfig.PATH_EXEMPTIONS.values():
@@ -776,18 +769,3 @@ def test_check_metric_names_shim():
     assert cmn.check is obs_rules.check
     assert cmn.collect_series is obs_rules.collect_series
     assert cmn.main(ROOT) == 0
-
-
-def test_bench_lint_config(tmp_path, monkeypatch, capsys):
-    import bench
-    monkeypatch.chdir(tmp_path)
-    result = bench.bench_lint(on_tpu=False)
-    assert result["metric"] == "graftlint_new_findings"
-    assert result["value"] == 0 and result["vs_baseline"] == 1.0
-    report_path = result["extra"]["report"]
-    assert os.path.exists(report_path)
-    with open(report_path, encoding="utf-8") as f:
-        data = json.load(f)
-    assert data["counts"]["new"] == 0
-    assert result["extra"]["per_rule"].keys() == \
-        data["counts"]["per_rule"].keys()

@@ -405,9 +405,13 @@ def tensor_collate(batch):
     return (pt.to_tensor(np.stack(xs)), pt.to_tensor(np.asarray(ys)))
 
 
-def _shm_segments():
+def _shm_segments(loader):
+    """The /dev/shm names that are this loader's own: its workers name
+    every segment with `loader._shm_prefix`, so the accounting is blind
+    to what other processes of the box create and unlink meanwhile."""
     try:
-        return {f for f in os.listdir("/dev/shm")}
+        return {f for f in os.listdir("/dev/shm")
+                if f.startswith(loader._shm_prefix)}
     except FileNotFoundError:       # macOS etc. — skip the accounting
         return None
 
@@ -427,7 +431,7 @@ class TestSelfHealingDataLoader:
         # parent (correctly) respawns at batch 0 — so only the respawn
         # itself is asserted; the real contract is the batch-exact
         # healed epoch checked below. The /dev/shm accounting lives in
-        # its own (flaky-listed) test so THIS correctness contract can
+        # its own test so THIS correctness contract can
         # never ride out a timing race un-asserted.
         ds = ShmDs(n=24)
         serial = _collect(DataLoader(ds, batch_size=4, num_workers=0))
@@ -443,33 +447,42 @@ class TestSelfHealingDataLoader:
             np.testing.assert_array_equal(sy, py)
 
     def test_worker_kill_shm_leak_accounting(self):
-        # the shm-leak accounting for the same kill scenario, split
-        # out (ISSUE 13) so its timing race never exempts the healing
-        # contract above: _process_worker documents a real residual
-        # window (a hard kill landing strictly between segment
-        # creation in _pack and the payload reaching the parent's
-        # queue loses that batch's segment names with the dead
-        # worker), so one attempt can legitimately leak a segment —
-        # best-of-2, and the test is on tools/known_failures.json's
-        # "flaky" list (reported, not fatal) because the race loses
-        # both attempts under load on the shared box. A SYSTEMATIC
-        # leak still fails both attempts everywhere else.
-        ds = ShmDs(n=24)
-        leaked = None
-        for _attempt in range(2):
-            before = _shm_segments()
-            with faults.inject("io.worker.batch", exit_code=1, times=1,
-                               match={"bi": 2, "attempt": 0}):
-                with pytest.warns(UserWarning,
-                                  match="respawning at batch"):
-                    healed = _collect(DataLoader(ds, batch_size=4,
-                                                 num_workers=2))
-            assert len(healed) == 6
-            leaked = None if before is None \
-                else _shm_segments() - before
-            if not leaked:
-                break
-        assert not leaked, f"leaked /dev/shm segments twice: {leaked}"
+        # the shm-leak accounting for the same kill scenario. A hard
+        # kill can land between segment creation in _pack and the
+        # payload reaching the parent's queue (the worker's queue
+        # feeder thread races the exit), and that batch's payload dies
+        # with the worker; its segments do not outlive the epoch,
+        # because the parent unlinks by name what is left of its stem.
+        # Only the loader's own names are counted.
+        loader = DataLoader(ShmDs(n=24), batch_size=4, num_workers=2)
+        with faults.inject("io.worker.batch", exit_code=1, times=1,
+                           match={"bi": 2, "attempt": 0}):
+            with pytest.warns(UserWarning, match="respawning at batch"):
+                healed = _collect(loader)
+        assert len(healed) == 6
+        assert not _shm_segments(loader), "leaked /dev/shm segments"
+
+    def test_undelivered_segments_are_unlinked_by_their_stem(self):
+        # what the two accountings here rest on: a worker names each
+        # segment by the stem it was spawned with, leaf by leaf, and
+        # the parent can unlink by that stem what never reached it
+        from paddle_tpu.io import _process_worker as PW
+        loader = DataLoader(ShmDs(n=8), batch_size=4, num_workers=2)
+        if _shm_segments(loader) is None:
+            pytest.skip("/dev/shm cannot be listed here")
+        stem = loader._shm_prefix + "e1"
+        big = np.zeros(PW._SHM_THRESHOLD, np.uint8)
+        segments = []
+        try:
+            PW._pack([big, big], segments, stem + "w0a0b0")
+            assert [seg.name.lstrip("/") for seg in segments] == \
+                [stem + "w0a0b0n0", stem + "w0a0b0n1"]
+            assert len(_shm_segments(loader)) == 2
+        finally:
+            for seg in segments:
+                seg.close()
+            PW.unlink_stem(stem)    # the payload itself was "lost"
+        assert not _shm_segments(loader)
 
     def test_restart_budget_exhausts(self):
         ds = ShmDs(n=24)
@@ -485,18 +498,14 @@ class TestSelfHealingDataLoader:
 
     def test_early_exit_unlinks_all_segments(self):
         ds = ShmDs(n=64)
-        before = _shm_segments()
         loader = DataLoader(ds, batch_size=4, num_workers=2,
                             prefetch_factor=2)
         it = iter(loader)
         next(it)
         next(it)
         it.close()      # generator finally: stop -> join -> drain
-        if before is not None:
-            import time
-            time.sleep(0.2)
-            assert _shm_segments() <= before, \
-                "early consumer exit leaked /dev/shm segments"
+        assert not _shm_segments(loader), \
+            "early consumer exit leaked /dev/shm segments"
         # the loader is reusable afterwards
         assert len(_collect(loader)) == 16
 

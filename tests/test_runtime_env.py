@@ -1,7 +1,7 @@
 """What a process is told from outside: where its compiled programs are
 kept, which device it may open, and what its children may open
 (`paddle_tpu.utils.runtime_env`, `core.device`, `launch.scrub_backend_env`,
-`bench.py`'s platform check)."""
+`benchmarks/run.py`'s platform check)."""
 import os
 import subprocess
 import sys
@@ -69,7 +69,7 @@ class TestCompileCache:
                 hits += [os.path.join(root, f) for f in files
                          if f.endswith(".py")]
         hits += [os.path.join(REPO, f)
-                 for f in ("bench.py", "chip_smoke.py", "__graft_entry__.py")]
+                 for f in ("chip_smoke.py", "__graft_entry__.py")]
         needle = '"jax_compilation' + '_cache_dir"'
         setters = set()
         for path in hits:
@@ -153,26 +153,33 @@ class TestDeviceChoice:
             pt.to_tensor([1.0]).to("tpu")
 
 
-class TestBenchPlatformCheck:
-    def _bench(self, *args):
-        env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
-        return subprocess.run(
-            [sys.executable, os.path.join(REPO, "bench.py"), *args],
-            env=env, capture_output=True, text=True, timeout=600, cwd=REPO)
+class TestBenchmarkPlatformCheck:
+    """`benchmarks/run.py` measures on the chip or not at all (PERF.md
+    section 1: "No TPU, no result line")."""
 
-    def test_fails_without_a_chip_unless_the_cpu_smoke_is_named(self):
-        out = self._bench("--config", "gpt2s", "--no-obs")
+    def test_fails_without_a_chip_and_prints_no_result_line(self):
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        out = subprocess.run(
+            [sys.executable, os.path.join(REPO, "benchmarks", "run.py"),
+             "--workload", "gpt2-small.train-1k", "--seed", "1",
+             "--seconds", "1", "--trace", "0"],
+            env=env, capture_output=True, text=True, timeout=600, cwd=REPO)
         assert out.returncode != 0
-        assert "found platform 'cpu'" in out.stderr
+        assert "needs 1 TPU chip" in out.stderr
         assert out.stdout.strip() == ""         # and no result line
 
-    def test_cpu_smoke_prints_no_device_metric(self):
-        import json
-        out = self._bench("--cpu-smoke", "--config", "gpt2s", "--no-obs")
-        assert out.returncode == 0, out.stderr
-        rec = json.loads(out.stdout.strip().splitlines()[-1])
-        assert rec["metric"].startswith("cpu_smoke/")
-        # the child inherits this harness's 8 virtual devices
-        assert rec["device"] == {"platform": "cpu", "kind": "cpu",
-                                 "count": len(jax.devices())}
-        assert rec["vs_baseline"] is None and rec["extra"]["mfu"] is None
+    @pytest.mark.parametrize("device_kind", [
+        "TPU v5",           # exact match: not answered by "TPU v5 lite"
+        "TPU v6 lite",      # a chip the benchmark's table does not hold
+    ])
+    def test_a_device_kind_outside_the_table_has_no_peaks(self, device_kind):
+        """A share of a peak is read against the device's own peaks or
+        not at all: never against the v5e's by default."""
+        import importlib.util
+        spec = importlib.util.spec_from_file_location(
+            "bench_peaks",
+            os.path.join(REPO, "benchmarks", "harness", "peaks.py"))
+        peaks = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(peaks)
+        assert peaks.peaks(device_kind) is None
+        assert peaks.peaks("TPU v5 lite")["bf16_flops_per_s"] == 197e12

@@ -23,8 +23,7 @@ import pytest
 import paddle_tpu as pt
 from paddle_tpu import observability as obs
 from paddle_tpu.inference import (Autoscaler, LLMEngine, Router,
-                                  RouterActuator, TrafficModel,
-                                  run_traffic)
+                                  RouterActuator, TrafficModel)
 from paddle_tpu.models import GPTForCausalLM
 from paddle_tpu.models.gpt import gpt_tiny
 from paddle_tpu.observability import flight
@@ -626,7 +625,7 @@ def _serve_all(router, prompts, n_new):
 
 
 # ---------------------------------------------------------------------------
-# traffic harness: determinism + accounting
+# traffic generator: determinism
 # ---------------------------------------------------------------------------
 class TestTrafficModel:
     def test_deterministic_across_instances(self):
@@ -648,25 +647,6 @@ class TestTrafficModel:
         # multi-turn sessions exist: some session recurs
         sessions = [e.session for e in a if e.session is not None]
         assert len(sessions) > len(set(sessions))
-
-    def test_run_traffic_accounting_reconciles(self, tiny_gpt):
-        obs.enable()
-        tm = TrafficModel(seed=5, base_rate=50.0, burst_rate=100.0,
-                          max_body=40, max_out=6)
-        evs = list(tm.events(24))
-        router = Router(_engine_factory(tiny_gpt), n_replicas=2)
-        rep = run_traffic(router, evs, time_scale=0.0, max_prompt=40)
-        assert rep["submitted"] == 24
-        assert rep["ok"] + rep["shed"] + rep["failed"] == 24
-        assert rep["failed"] == 0
-        assert rep["replica_seconds"] > 0
-        per_cohort = sum(c["submitted"]
-                         for c in rep["cohorts"].values())
-        assert per_cohort == 24
-        for c in rep["cohorts"].values():
-            if c["ok"]:
-                assert c["e2e_p50_s"] is not None
-                assert c["e2e_p95_s"] >= c["e2e_p50_s"]
 
 
 # ---------------------------------------------------------------------------

@@ -1,5 +1,5 @@
-"""Paths that make billion-parameter single-chip training fit (bench.py
---config gpt1p3b): per-block remat, bf16 AdamW moments, AMP over raw
+"""Paths that make billion-parameter single-chip training fit (the
+`gpt3-1.3b.train-2k` cell): per-block remat, bf16 AdamW moments, AMP over raw
 batch inputs, conv autodiff under autocast, deepcopy buffer ownership.
 
 Ref test strategy: test/collective/fleet/ recompute + AMP payloads

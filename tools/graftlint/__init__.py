@@ -25,8 +25,7 @@ fault-point-naming, stats-key-naming). Suppress one line with
 
 graftlint is pure stdlib — it never imports jax or paddle_tpu, so it
 runs instantly anywhere (tier-1 wires it through
-tests/test_graftlint.py; ``bench.py --config lint`` emits
-``graftlint_report.json`` for the BENCH trajectory).
+tests/test_graftlint.py).
 """
 from .core import (                                  # noqa: F401
     Baseline, Finding, Module, Project, Report, analyze_module,

@@ -1,11 +1,10 @@
 """Per-path rule configuration.
 
 Analysis-exempt paths: operator-facing CLIs whose JOB is host I/O —
-profiling loops that block_until_ready around every measured window,
-dashboards that print — are exempt from the host-sync inventory (the
-warning-level round-trip burn-down rule). They are NOT exempt from the
-error-level rules: a donation bug or a trace-impure scan body in a
-profiling tool is still a bug.
+dashboards and dumps that print — are exempt from the host-sync
+inventory (the warning-level round-trip burn-down rule). They are NOT
+exempt from the error-level rules: a donation bug or a trace-impure
+scan body in a tool is still a bug.
 
 The exemption list is a public contract pinned by
 tests/test_graftlint.py::test_exemption_list_pinned — extending it is
@@ -17,16 +16,10 @@ from typing import FrozenSet
 
 # path (repo-root-relative, forward slashes) -> rule ids disabled there
 PATH_EXEMPTIONS = {
-    # demo/profiling CLIs: measuring and rendering host-side is their
-    # purpose, not a dispatch-path regression
+    # operator CLIs: reading and rendering host-side is their purpose,
+    # not a dispatch-path regression
     "tools/obs_top.py": frozenset({"host-sync"}),
     "tools/obs_dump.py": frozenset({"host-sync"}),
-    "tools/profile_decode.py": frozenset({"host-sync"}),
-    "tools/profile_engine.py": frozenset({"host-sync"}),
-    "tools/profile_1p3b.py": frozenset({"host-sync"}),
-    "tools/dryfit_6p7b.py": frozenset({"host-sync"}),
-    "tools/ablate_engine_step.py": frozenset({"host-sync"}),
-    "tools/resnet_traffic.py": frozenset({"host-sync"}),
     "tools/gen_ops_parity.py": frozenset({"host-sync"}),
 }
 

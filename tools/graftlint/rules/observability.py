@@ -454,8 +454,8 @@ class RoleLiteralDocumented(Rule):
                  "tuple vocabularies and role=/process_role= keyword "
                  "literals under paddle_tpu/inference/ — appears "
                  "verbatim in the README")
-    history = ("ISSUE 20: role strings split fleet telemetry, "
-               "capacity lines and perf-ledger baselines per pool "
+    history = ("ISSUE 20: role strings split fleet telemetry and "
+               "capacity lines per pool "
                "(engine_prefill vs engine_decode); a role value the "
                "README does not carry is a telemetry partition an "
                "operator cannot interpret")
