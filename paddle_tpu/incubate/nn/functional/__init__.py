@@ -87,17 +87,39 @@ def fused_flash_attention(query, key, value, attn_mask=None, causal=False,
             "attention dropout is not implemented on the TPU flash path; "
             "set dropout=0.0 (the reference routes it into the CUDA "
             "flash-attn library, which has no Pallas analog here yet)")
-    if attn_mask is None and jax.default_backend() == "tpu":
+    if attn_mask is None:
+        _warn_if_composite(query.shape, key.shape)
+    return pk.flash_attention(query, key, value, attn_mask=attn_mask,
+                              causal=causal, softmax_scale=softmax_scale,
+                              segment_ids=segment_ids)
+
+
+def _warn_if_composite(q_shape, k_shape):
+    if jax.default_backend() == "tpu":
         from ....kernels.pallas.flash_attention import attention_path
-        path, why = attention_path(query.shape, key.shape)
+        path, why = attention_path(q_shape, k_shape)
         if path == "xla":
             import warnings
             warnings.warn(
                 f"flash_attention fell back to the XLA composite: {why}",
-                RuntimeWarning, stacklevel=3)
-    return pk.flash_attention(query, key, value, attn_mask=attn_mask,
-                              causal=causal, softmax_scale=softmax_scale,
-                              segment_ids=segment_ids)
+                RuntimeWarning, stacklevel=4)
+
+
+@register_op("fused_flash_attention_qkv", amp_policy="white")
+def fused_flash_attention_qkv(qkv, num_heads, causal=False,
+                              softmax_scale=None, segment_ids=None):
+    """Flash attention on a fused projection's output: qkv [batch, seq,
+    3 * heads * dim] (q, k, v side by side) in, [batch, seq, heads * dim]
+    out. On the Pallas path the kernels read q, k and v where the
+    projection wrote them (`kernels/pallas/flash_attention.py:
+    flash_attention_qkv`); elsewhere it is `fused_flash_attention` on
+    the three [batch, seq, heads, dim] views, warning included."""
+    b, s, w = qkv.shape
+    shape = (b, s, num_heads, w // (3 * num_heads))
+    _warn_if_composite(shape, shape)
+    return pk.flash_attention_qkv(qkv, num_heads, causal=causal,
+                                  softmax_scale=softmax_scale,
+                                  segment_ids=segment_ids)
 
 
 @register_op("fused_linear", amp_policy="white")

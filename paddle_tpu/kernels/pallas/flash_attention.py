@@ -23,8 +23,16 @@ beats the per-head [b*h, s, d] fold two ways:
   * no HBM padding: minor dim h*d is lane-aligned, whereas a d=64 minor
     dim is padded to 128 lanes (2x footprint and bandwidth).
 
-sm_scale is folded into q before the kernel (drops one [bq, bk] VPU
-pass per head per block pair).
+sm_scale is folded into q inside the kernels, once a q block, where the
+block is loaded (no [bq, bk] VPU pass per head per block pair, and no
+pass over q in HBM before the call); dq's scale is applied where dq is
+rounded.
+
+Every operand is an (array, column block) pair: the kernels' BlockSpecs
+read q, k and v where they lie, three arrays at column block 0 for the
+[b, s, h, d] entry or one fused projection's [b, s, 3*h*d] at column
+blocks 0, 1, 2 (`flash_attention_qkv`), so nothing is sliced, copied or
+relaid between a fused projection and the kernels.
 
 Causality follows the diagonal inside the block a grid step holds: the
 step walks its resident K/V block in key sub-blocks of `_WALK` keys up to
@@ -44,8 +52,10 @@ Inputs are fed to the MXU in their native dtype (bf16 in, f32 accumulate
 via preferred_element_type) — no f32 upcast before the dot.
 
 Layout contract of the public API matches paddle: [batch, seq, heads,
-head_dim] (ref: python/paddle/nn/functional/flash_attention.py:146);
-the [b,s,h,d] <-> [b,s,h*d] reshape is free (no axis reordering).
+head_dim] (ref: python/paddle/nn/functional/flash_attention.py:146).
+The [b,s,h,d] <-> [b,s,h*d] reshape reorders no axis, but on a TPU the
+two are tiled differently and XLA materialises it: a caller with a fused
+projection takes `flash_attention_qkv`, which has none.
 """
 from __future__ import annotations
 
@@ -64,6 +74,10 @@ _NEG_INF = -1e30
 # raised scoped-VMEM budget: the 1024-wide resident K/V blocks need ~17MB
 # with double buffering (the default scoped limit is 16MB)
 _VMEM_LIMIT = 64 * 1024 * 1024
+# the backward's: beside k and v it holds their two float32 accumulators
+# and the gradient's block, 70 MB at 1024 keys of 2048 lanes, of the 128
+# MiB a core has
+_VMEM_LIMIT_BWD = 100 * 1024 * 1024
 _LANES = 128
 _SUBL = 8   # per-head stats ride as [b, h*_SUBL, s]: seq in lanes, each
             # head's row replicated over one sublane tile (minimum height)
@@ -177,6 +191,23 @@ def _div(x, n):
     return jax.lax.div(x, jnp.int32(n))
 
 
+def _scaled(x, sm_scale, dtype):
+    """x * sm_scale in float32, rounded to `dtype` once."""
+    return jax.lax.mul(x.astype(jnp.float32),
+                       jnp.full(x.shape, sm_scale, jnp.float32)).astype(dtype)
+
+
+def _heads_to_rows(dst, by_column, H):
+    """A per-row value a head, head h's in column h of `by_column`
+    [rows, _LANES], stored as the kernels read statistics: dst
+    [H*_SUBL, rows], a head's rows in lanes over one sublane tile, one
+    [rows, _LANES] transpose for all heads."""
+    by_row = jax.lax.transpose(by_column, (1, 0))
+    for h in range(H):
+        dst[h * _SUBL:(h + 1) * _SUBL, :] = jnp.broadcast_to(
+            by_row[h:h + 1], (_SUBL, by_row.shape[1]))
+
+
 def _walk_bounds(qi, ki, block_q, block_k, sub, offset):
     """How many of a causal (q block, k block) pair's block_k // sub key
     sub-blocks have a key some q row of the pair sees: those are visited,
@@ -220,13 +251,13 @@ def causal_tiles(sq, sk, block_q, block_k, sub, causal=True):
     return visited, nq * ns
 
 
-def _note_causal(kind, sq, sk, block_q, block_k, sub, causal):
+def _note_causal(kind, sq, sk, block_q, block_k, sub, causal, more=""):
     """Say in `compile_record(<family>)["flash_causal"]` how much of
-    [sq, sk] this kernel visits."""
+    [sq, sk] this kernel visits (and `more`: the backward's dq)."""
     from ...observability import perf
     visited, total = causal_tiles(sq, sk, block_q, block_k, sub, causal)
     perf.trace_note("flash_causal",
-                    f"{kind} {visited}/{total} of {sub}-wide tiles")
+                    f"{kind} {visited}/{total} of {sub}-wide tiles{more}")
 
 
 def _seg_tile_mask(row_ref, lane_ref, r0, rows, l0, lanes):
@@ -245,13 +276,14 @@ def _seg_tile_mask(row_ref, lane_ref, r0, rows, l0, lanes):
 
 # ======================= forward =======================
 
-def _fwd_kernel(*refs, causal, block_q, block_k, sub, H, Hk, D, offset,
-                has_seg):
+def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, sub, H, Hk, D,
+                offset, has_seg):
     if has_seg:
         (q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
-         o_ref, lse_ref, acc_ref, m_ref, l_ref) = refs
+         o_ref, lse_ref, qs_ref, acc_ref, m_ref, l_ref) = refs
     else:
-        q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
+        (q_ref, k_ref, v_ref,
+         o_ref, lse_ref, qs_ref, acc_ref, m_ref, l_ref) = refs
         qseg_ref = kseg_ref = None
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -259,6 +291,8 @@ def _fwd_kernel(*refs, causal, block_q, block_k, sub, H, Hk, D, offset,
 
     @pl.when(ki == 0)
     def _init():
+        # the q block, scaled once for all of its key blocks and visits
+        qs_ref[:] = _scaled(q_ref[0], sm_scale, qs_ref.dtype)
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
@@ -266,7 +300,7 @@ def _fwd_kernel(*refs, causal, block_q, block_k, sub, H, Hk, D, offset,
     def _visit(k0):
         """Keys k0 .. k0 + sub of the resident block against the q block,
         every head; the online-softmax state lives in scratch."""
-        qf = q_ref[0]                      # [bq, H*D], pre-scaled
+        qf = qs_ref[:]                     # [bq, H*D], scaled
         kf = k_ref[0, pl.ds(k0, sub), :]   # [sub, Hk*D]
         vf = v_ref[0, pl.ds(k0, sub), :]
         ok = (_causal_tile_mask(offset + qi * block_q, ki * block_k + k0,
@@ -334,10 +368,7 @@ def _fwd_kernel(*refs, causal, block_q, block_k, sub, H, Hk, D, offset,
             lse_c = jax.lax.select(
                 jax.lax.eq(lane, jax.lax.full_like(lane, h)),
                 jax.lax.add(m_ref[h], jax.lax.log(safe_l)), lse_c)
-        lse_t = jax.lax.transpose(lse_c, (1, 0))
-        for h in range(H):
-            lse_ref[0, h * _SUBL:(h + 1) * _SUBL, :] = jnp.broadcast_to(
-                lse_t[h:h + 1], (_SUBL, lse_t.shape[1]))
+        _heads_to_rows(lse_ref.at[0], lse_c, H)
 
 
 def _seg_operands(segment_ids, b, n_rows, n_lanes):
@@ -351,7 +382,7 @@ def _seg_operands(segment_ids, b, n_rows, n_lanes):
     return row_seg, lane_seg
 
 
-def _autotuned_blocks(kind, q, k, H, Hk, causal, has_seg, defaults,
+def _autotuned_blocks(kind, shape, H, Hk, causal, has_seg, defaults,
                       run_shape, normalize):
     """Per-(shape-class, device-generation) {block_q, block_k} search
     (ref: phi/kernels/autotune/switch_autotune.cc). First call measures
@@ -365,15 +396,13 @@ def _autotuned_blocks(kind, q, k, H, Hk, causal, has_seg, defaults,
         # the kill-switch restores hand-tuned defaults even when a
         # (possibly noise-picked) winner is already cached
         return defaults
-    b, sq, HD = q.shape
-    sk = k.shape[1]
-    HkD = k.shape[2]
+    sq, sk, D, dtype = shape
     # batch size is deliberately NOT in the key: blocks are per-tile
     # choices and b only multiplies the grid — keying on it would stall
     # a variable-batch serving workload with a fresh search per b
     # (the backward gets H/Hk back from custom_vjp residuals as typed
     # scalars: plain ints keep one spelling of the key)
-    key = (kind, sq, sk, int(H), int(Hk), HD // int(H), str(q.dtype),
+    key = (kind, sq, sk, int(H), int(Hk), int(D), dtype,
            int(causal), int(has_seg))
     hit = autotune.lookup(key)
     if hit is not None:
@@ -412,17 +441,21 @@ def _autotuned_blocks(kind, q, k, H, Hk, causal, has_seg, defaults,
 
 def _flash_fwd_fused(q, k, v, H, causal, block_q=256, block_k=1024,
                      interpret=False, Hk=None, segment_ids=None,
-                     autotune_ok=True):
-    """q: [b, s, H*D]; k,v: [b, sk, Hk*D] (q pre-scaled by sm_scale).
+                     autotune_ok=True, sm_scale=1.0, cols=(0, 0, 0),
+                     D=None):
+    """q: [b, s, H*D]; k,v: [b, sk, Hk*D], each read at its column block
+    of `cols` (in units of its own width): three arrays at (0, 0, 0), or
+    one [b, s, 3*H*D] projection passed three times at (0, 1, 2) with its
+    head size `D`. q is scaled by sm_scale in the kernel.
     Hk < H = grouped-query attention (q-head h reads kv-head h // (H//Hk)).
     segment_ids: optional (q_seg [b, sq], kv_seg [b, sk]) int32 — scores
     are masked to segment equality (padding/varlen-packing mask).
     Returns (out [b, s, H*D], lse [b, H*_SUBL, s] f32)."""
-    b, sq, HD = q.shape
+    b, sq = q.shape[:2]
     sk = k.shape[1]
-    D = HD // H
+    D = q.shape[2] // H if D is None else D
     Hk = H if Hk is None else Hk
-    HkD = Hk * D
+    HD, HkD = H * D, Hk * D
     has_seg = segment_ids is not None
     walk = _WALK if causal else None
     if autotune_ok and not interpret and (block_q, block_k) == (256, 1024):
@@ -453,8 +486,8 @@ def _flash_fwd_fused(q, k, v, H, causal, block_q=256, block_k=1024,
             return (_pick_block(sq, bq2), _pick_block(sk, bk2))
 
         block_q, block_k = _autotuned_blocks(
-            "fwd", q, k, H, Hk, causal, has_seg, (block_q, block_k),
-            run_shape, _norm_fwd)
+            "fwd", (sq, sk, D, str(q.dtype)), H, Hk, causal, has_seg,
+            (block_q, block_k), run_shape, _norm_fwd)
     block_q, block_k = _fit_blocks(block_q, block_k, HD, n_bufs_q=2,
                                    n_bufs_k=2, HDk=HkD, sub=walk,
                                    stat_heads=H)
@@ -462,32 +495,36 @@ def _flash_fwd_fused(q, k, v, H, causal, block_q=256, block_k=1024,
     block_k = _pick_block(sk, block_k)
     sub = _sub_block(block_k, walk)
     _note_causal("fwd", sq, sk, block_q, block_k, sub, causal)
-    return _fwd_call(q, k, v, segment_ids, H=H, Hk=Hk, causal=causal,
-                     block_q=block_q, block_k=block_k, sub=sub,
-                     interpret=interpret)
+    return _fwd_call(q, k, v, segment_ids, cols=cols,
+                     sm_scale=sm_scale, H=H, Hk=Hk, D=D,
+                     causal=causal, block_q=block_q, block_k=block_k,
+                     sub=sub, interpret=interpret)
 
 
 # The calls below are traced once for each shape and setting and inlined
 # wherever they are made: a model's layers call one kernel a dozen times
 # or more, and tracing its unrolled body each time was most of what the
 # kernels cost a step's lowering.
-_CALL_STATICS = ("H", "Hk", "causal", "block_q", "block_k", "sub",
-                 "interpret")
+_CALL_STATICS = ("cols", "sm_scale", "H", "Hk", "D", "causal", "block_q",
+                 "block_k", "sub", "interpret")
+_QKV = (0, 1, 2)    # q, k, v as column blocks of one fused projection
 
 
 @functools.partial(jax.jit, static_argnames=_CALL_STATICS, inline=True)
-def _fwd_call(q, k, v, segment_ids, *, H, Hk, causal, block_q, block_k, sub,
-              interpret):
-    b, sq, HD = q.shape
-    sk, HkD = k.shape[1], k.shape[2]
-    D = HD // H
+def _fwd_call(q, k, v, segment_ids, *, cols, sm_scale, H, Hk, D, causal,
+              block_q, block_k, sub, interpret):
+    b, sq = q.shape[:2]
+    sk = k.shape[1]
+    HD, HkD = H * D, Hk * D
+    cq, ck, cv = cols
     has_seg = segment_ids is not None
     offset = sk - sq
     nk = sk // block_k
     grid = (b, sq // block_q, nk)
     kernel = functools.partial(
-        _fwd_kernel, causal=causal, block_q=block_q, block_k=block_k,
-        sub=sub, H=H, Hk=Hk, D=D, offset=offset, has_seg=has_seg)
+        _fwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
+        block_k=block_k, sub=sub, H=H, Hk=Hk, D=D, offset=offset,
+        has_seg=has_seg)
 
     def kj(i, j):
         """The k block step (i, j) needs: a step wholly above the
@@ -500,9 +537,9 @@ def _fwd_call(q, k, v, segment_ids, *, H, Hk, causal, block_q, block_k, sub,
         return jnp.minimum(j, jnp.minimum(_div(last_q, block_k), nk - 1))
 
     in_specs = [
-        pl.BlockSpec((1, block_q, HD), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, block_k, HkD), lambda b, i, j: (b, kj(i, j), 0)),
-        pl.BlockSpec((1, block_k, HkD), lambda b, i, j: (b, kj(i, j), 0)),
+        pl.BlockSpec((1, block_q, HD), lambda b, i, j: (b, i, cq)),
+        pl.BlockSpec((1, block_k, HkD), lambda b, i, j: (b, kj(i, j), ck)),
+        pl.BlockSpec((1, block_k, HkD), lambda b, i, j: (b, kj(i, j), cv)),
     ]
     operands = [q, k, v]
     if has_seg:
@@ -526,6 +563,7 @@ def _fwd_call(q, k, v, segment_ids, *, H, Hk, causal, block_q, block_k, sub,
             jax.ShapeDtypeStruct((b, H * _SUBL, sq), jnp.float32),
         ],
         scratch_shapes=[
+            pltpu.VMEM((block_q, HD), q.dtype),      # the q block, scaled
             pltpu.VMEM((block_q, HD), jnp.float32),
             # running max and sum of a head's rows, in every lane: what
             # a row reduction leaves and a row-wise update takes, so no
@@ -543,29 +581,68 @@ def _fwd_call(q, k, v, segment_ids, *, H, Hk, causal, block_q, block_k, sub,
 
 # ======================= backward =======================
 
-def _bwd_kernel(*refs, causal, block_q, block_k, sub, H, Hk, D, offset,
-                has_seg):
+def _bwd_kernel(*refs, sm_scale, causal, block_q, block_k, sub, H, Hk, D,
+                offset, has_seg, one_array, dq_whole):
     """Single-pass backward: one s/p recompute per block pair feeds dk, dv
     AND this pair's dq contribution (vs. the classic two-kernel split that
     recomputes s/p and the dp dot twice). dq contributions can't accumulate
-    in scratch here (the k-block axis is the outer grid dim), so each pair
-    writes a partial into dqp [b, n_kblocks, sq, HD]; the caller sums
-    over the k-block axis in XLA — a few hundred MB of streaming traffic
-    that costs far less than a second full recompute pass. A causal pair
-    is walked in key sub-blocks up to the diagonal, like the forward's;
-    its partial is then the sum over the visited ones."""
-    if has_seg:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qseg_ref,
-         kseg_ref, dqp_ref, dk_ref, dv_ref, dk_acc, dv_acc, *dq_acc) = refs
-    else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         dqp_ref, dk_ref, dv_ref, dk_acc, dv_acc, *dq_acc) = refs
-        qseg_ref = kseg_ref = None
-    if causal:
-        dq_acc, = dq_acc    # [bq, HD] f32: the walk's sum of dq partials
+    in scratch across k blocks (the k-block axis is the outer grid dim).
+    Where one K/V block holds the key sequence there is nothing to
+    accumulate across (`dq_whole`): dq_ref is the pair's [bq, HD] block
+    of dq itself, summed over the walk in float32, scaled and rounded
+    once. Otherwise it is the pair's block of dqp [b, n_kblocks, sq, HD],
+    a scaled partial the caller sums over the k-block axis in XLA —
+    streaming traffic that costs far less than a second full recompute
+    pass. A causal pair is walked in key sub-blocks up to the diagonal,
+    like the forward's; its dq is then the sum over the visited ones.
+
+    `one_array`: q, k, v are column blocks of one projection, and the
+    gradient leaves the same way: the k block's rows of dqkv [b, s,
+    3*HD] are one output block, resident over the q steps, dk and dv
+    written into their columns at the last one and, when dq is whole
+    (then the block holds every row), each step's dq into its rows."""
+    n_in = 8 if has_seg else 6
+    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref = refs[:6]
+    qseg_ref, kseg_ref = refs[6:n_in] or (None, None)
     ki = pl.program_id(1)
     qi = pl.program_id(2)
     nq = pl.num_programs(2)
+    if one_array:
+        dqkv_ref, *rest = refs[n_in:]
+        HD = H * D
+        dk_ref = dqkv_ref.at[0, :, pl.ds(HD, HD)]
+        dv_ref = dqkv_ref.at[0, :, pl.ds(2 * HD, HD)]
+        if dq_whole:
+            dq_ref = dqkv_ref.at[0, pl.ds(pl.multiple_of(
+                qi * block_q, block_q), block_q), pl.ds(0, HD)]
+        else:
+            dq_ref, *rest = rest
+    else:
+        dq_ref, dk_ref, dv_ref, *rest = refs[n_in:]
+        dk_ref, dv_ref = dk_ref.at[0], dv_ref.at[0]
+    qs_ref, delta_ref, dk_acc, dv_acc, *dq_acc = rest
+    if causal:
+        dq_acc, = dq_acc    # [bq, HD] f32: the walk's sum of dq
+
+    def _load_q_block():
+        """What every visit of the step reads of its q block, made once:
+        q scaled, and delta_i = rowsum(do_i * o_i) a head, laid out like
+        the log-sums ([H*_SUBL, bq]: a head's q rows in lanes)."""
+        qs_ref[:] = _scaled(q_ref[0], sm_scale, qs_ref.dtype)
+        dof, of = do_ref[0], o_ref[0]
+        lane = jax.lax.broadcasted_iota(jnp.int32, (block_q, _LANES), 1)
+        delta_c = jnp.zeros((block_q, _LANES), jnp.float32)
+        for (c, w), _, heads in _head_slabs(H, Hk, D):
+            prod = jax.lax.mul(_cols(dof, c, w).astype(jnp.float32),
+                               _cols(of, c, w).astype(jnp.float32))
+            for h, mine in heads:
+                mine_prod = (prod if mine is None
+                             else _pick(mine, prod, jnp.zeros_like(prod)))
+                delta_c = jax.lax.select(
+                    jax.lax.eq(lane, jax.lax.full_like(lane, h)),
+                    _rows_to_lanes(jax.lax.reduce_sum(mine_prod, (1,)),
+                                   _LANES), delta_c)
+        _heads_to_rows(delta_ref, delta_c, H)
 
     def _visit(k0):
         """Keys k0 .. k0 + sub of the resident block against the q block.
@@ -574,7 +651,7 @@ def _bwd_kernel(*refs, causal, block_q, block_k, sub, H, Hk, D, offset,
         spread over sublanes for nothing, where a [bq, sub] tile would
         move each across lanes once a head and visit."""
         rows = pl.ds(k0, sub)
-        qf = q_ref[0]                        # [bq, HD] (pre-scaled)
+        qf = qs_ref[:]                       # [bq, HD], scaled
         dof = do_ref[0]
         kf = k_ref[0, rows, :]               # [sub, Hk*D]
         vf = v_ref[0, rows, :]
@@ -605,7 +682,7 @@ def _bwd_kernel(*refs, causal, block_q, block_k, sub, H, Hk, D, offset,
                 dv_h = _dot(p.astype(do2.dtype), do2)    # [sub, slab]
                 dp = _nt_dot(v2, do1)                    # [sub, bq]
                 ds = jax.lax.mul(p, jax.lax.sub(
-                    dp, _over_rows(delta_ref[0, st, :], sub))).astype(
+                    dp, _over_rows(delta_ref[st, :], sub))).astype(
                         q2.dtype)
                 dk_h = _dot(ds, q2)          # dk = ds^T @ q_scaled
                 dq_h = _dot(ds, k2, 0)       # this visit's dq: ds @ k
@@ -618,15 +695,10 @@ def _bwd_kernel(*refs, causal, block_q, block_k, sub, H, Hk, D, offset,
             sl, slk = slice(c, c + w), slice(ck, ck + wk)
             dv_acc[rows, slk] = jax.lax.add(dv_acc[rows, slk], dv)
             dk_acc[rows, slk] = jax.lax.add(dk_acc[rows, slk], dk)
-            # the block pair's dq partial is stored in dqp's dtype: the
-            # input dtype while nk <= 8 (each partial individually rounded
-            # before the f32-accumulated sum), f32 beyond that — the
-            # caller picks (ADVICE r2: _fit_blocks can shrink block_k so
-            # nk may exceed 8)
             if causal:      # summed over the walk in f32, rounded once
                 dq_acc[:, sl] = jax.lax.add(dq_acc[:, sl], dq)
             else:
-                dqp_ref[0, 0, :, sl] = dq.astype(dqp_ref.dtype)
+                dq_ref[:, sl] = _scaled(dq, sm_scale, dq_ref.dtype)
 
     @pl.when(qi == 0)
     def _init():
@@ -636,110 +708,117 @@ def _bwd_kernel(*refs, causal, block_q, block_k, sub, H, Hk, D, offset,
     if causal:
         n_visit = _walk_bounds(qi, ki, block_q, block_k, sub, offset)
 
-        # skipped pairs (fully above the diagonal) still own an output
-        # block in dqp — zero it so the XLA-side sum sees no garbage.
+        # a pair wholly above the diagonal still owns its block of dq
+        # (rows that see no key) or of dqp (the XLA-side sum reads it)
         @pl.when(n_visit == 0)
         def _skip():
-            dqp_ref[0, 0] = jnp.zeros_like(dqp_ref[0, 0])
+            dq_ref[:] = jnp.zeros(dq_ref.shape, dq_ref.dtype)
 
         @pl.when(n_visit > 0)
         def _run():
+            _load_q_block()
             dq_acc[:] = jnp.zeros_like(dq_acc)
             _walk(n_visit, sub, block_k, _visit)
-            dqp_ref[0, 0] = dq_acc[:].astype(dqp_ref.dtype)
+            # scaled where it is rounded
+            dq_ref[:] = _scaled(dq_acc[:], sm_scale, dq_ref.dtype)
     else:
+        _load_q_block()
         _visit(0)
 
     @pl.when(qi == nq - 1)
     def _finalize():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
 
 
 def _flash_bwd_fused(q, k, v, o, lse, do, H, causal,
-                     block_q=256, block_k=512, interpret=False,
-                     Hk=None, segment_ids=None, autotune_ok=True):
+                     block_q=256, block_k=None, interpret=False,
+                     Hk=None, segment_ids=None, autotune_ok=True,
+                     sm_scale=1.0, cols=(0, 0, 0), D=None):
     """Blockwise dq/dk/dv on the fused-head layout.
 
-    q,o,do: [b, sq, H*D] (q pre-scaled); k,v: [b, sk, Hk*D];
-    lse: [b, H*_SUBL, sq] f32.
-    Returns (dq_scaled f32, dk, dv) — caller multiplies dq by sm_scale.
-    """
-    b, sq, HD = q.shape
+    q: [b, sq, H*D]; k,v: [b, sk, Hk*D], each at its column block of
+    `cols` as in the forward; o,do: [b, sq, H*D]; lse: [b, H*_SUBL, sq]
+    f32. block_k None: the whole key sequence, as far as VMEM holds it.
+    Returns (dq [b, sq, H*D], dk, dv [b, sk, Hk*D]) in the operands'
+    dtype, dq with sm_scale applied; for one projection's column blocks
+    (`cols` (0, 1, 2)) the three side by side as it holds q, k, v:
+    dqkv [b, s, 3*H*D]."""
+    b, sq = q.shape[:2]
     sk = k.shape[1]
-    D = HD // H
+    D = q.shape[2] // H if D is None else D
     Hk = H if Hk is None else Hk
-    HkD = Hk * D
+    HD, HkD = H * D, Hk * D
     walk = _WALK if causal else None
-    if autotune_ok and not interpret and (block_q, block_k) == (256, 512):
+    # k-side blocks in VMEM: k, v and dk, dv, or the three-wide block of
+    # one gradient array
+    n_bufs_k = 5 if cols == _QKV else 4
+    if block_k is None:
+        block_k = sk
+        if autotune_ok and not interpret and block_q == 256:
 
-        def run_shape(bq, bk):
-            rng = np.random.default_rng(0)
-            qs = jnp.asarray(rng.standard_normal((b, sq, HD)) * 0.1,
-                             q.dtype)
-            ks = jnp.asarray(rng.standard_normal((sk, HkD)) * 0.1,
-                             q.dtype)[None].repeat(b, 0)
-            lses = jnp.full((b, H * _SUBL, sq), 3.0, jnp.float32)
-            seg = None
-            if segment_ids is not None:
-                seg = (jnp.zeros((b, sq), jnp.int32),
-                       jnp.zeros((b, sk), jnp.int32))
+            def run_shape(bq, bk):
+                rng = np.random.default_rng(0)
+                qs = jnp.asarray(rng.standard_normal((b, sq, HD)) * 0.1,
+                                 q.dtype)
+                ks = jnp.asarray(rng.standard_normal((sk, HkD)) * 0.1,
+                                 q.dtype)[None].repeat(b, 0)
+                lses = jnp.full((b, H * _SUBL, sq), 3.0, jnp.float32)
+                seg = None
+                if segment_ids is not None:
+                    seg = (jnp.zeros((b, sq), jnp.int32),
+                           jnp.zeros((b, sk), jnp.int32))
 
-            @jax.jit
-            def f(qs, ks, lses):
-                dq, _, _ = _flash_bwd_fused(
-                    qs, ks, ks, qs, lses, qs, H, causal, block_q=bq,
-                    block_k=bk, Hk=Hk, segment_ids=seg,
-                    autotune_ok=False)
-                return dq
+                @jax.jit
+                def f(qs, ks, lses):
+                    dq, _, _ = _flash_bwd_fused(
+                        qs, ks, ks, qs, lses, qs, H, causal, block_q=bq,
+                        block_k=bk, Hk=Hk, segment_ids=seg,
+                        autotune_ok=False)
+                    return dq
 
-            return lambda: f(qs, ks, lses)
+                return lambda: f(qs, ks, lses)
 
-        def _norm_bwd(bq, bk):
-            bk = max(bk, sk // 8)       # the use-site's long-seq grow
-            bq2, bk2 = _fit_blocks(bq, bk, HD, n_bufs_q=3, n_bufs_k=4,
-                                   HDk=HkD, sub=walk)
-            return (_pick_block(sq, bq2), _pick_block(sk, bk2))
+            def _norm_bwd(bq, bk):
+                bk = max(bk, sk // 8)       # the use-site's long-seq grow
+                bq2, bk2 = _fit_blocks(bq, bk, HD, n_bufs_q=4,
+                                       n_bufs_k=n_bufs_k, HDk=HkD, sub=walk,
+                                       budget=_VMEM_LIMIT_BWD, k_accs=2)
+                return (_pick_block(sq, bq2), _pick_block(sk, bk2))
 
-        block_q, block_k = _autotuned_blocks(
-            "bwd", q, k, H, Hk, causal, segment_ids is not None,
-            (block_q, block_k), run_shape, _norm_bwd)
-    # long sequences: grow K blocks so the dq partial-sum buffer
-    # (b * nk * sq * HD) stays bounded at nk <= 8 — _fit_blocks may shrink
+            block_q, block_k = _autotuned_blocks(
+                "bwd", (sq, sk, D, str(q.dtype)), H, Hk, causal,
+                segment_ids is not None, (block_q, block_k), run_shape,
+                _norm_bwd)
+    # long sequences: keep K blocks wide enough that the dq partials
+    # (b * nk * sq * HD) stay bounded at nk <= 8 — _fit_blocks may shrink
     # them back if HD is too wide for VMEM, which keeps correctness and
     # trades the extra partials for compile-safety.
     block_k = max(block_k, sk // 8)
-    block_q, block_k = _fit_blocks(block_q, block_k, HD, n_bufs_q=3,
-                                   n_bufs_k=4, HDk=HkD, sub=walk)
+    block_q, block_k = _fit_blocks(block_q, block_k, HD, n_bufs_q=4,
+                                   n_bufs_k=n_bufs_k, HDk=HkD, sub=walk,
+                                   budget=_VMEM_LIMIT_BWD, k_accs=2)
     block_q = _pick_block(sq, block_q)
     block_k = _pick_block(sk, block_k)
     sub = _sub_block(block_k, walk)
-    _note_causal("bwd", sq, sk, block_q, block_k, sub, causal)
-    return _bwd_call(q, k, v, o, lse, do, segment_ids, H=H, Hk=Hk,
+    nk = sk // block_k
+    _note_causal("bwd", sq, sk, block_q, block_k, sub, causal,
+                 f", dq partials {nk}" if nk > 1 else ", dq whole")
+    return _bwd_call(q, k, v, o, lse, do, segment_ids, cols=cols,
+                     sm_scale=sm_scale, H=H, Hk=Hk, D=D,
                      causal=causal, block_q=block_q, block_k=block_k,
                      sub=sub, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=_CALL_STATICS, inline=True)
-def _bwd_call(q, k, v, o, lse, do, segment_ids, *, H, Hk, causal, block_q,
-              block_k, sub, interpret):
-    b, sq, HD = q.shape
-    sk, HkD = k.shape[1], k.shape[2]
-    D = HD // H
+def _bwd_call(q, k, v, o, lse, do, segment_ids, *, cols, sm_scale, H, Hk, D,
+              causal, block_q, block_k, sub, interpret):
+    b, sq = q.shape[:2]
+    sk = k.shape[1]
+    HD, HkD = H * D, Hk * D
+    cq, ck, cv = cols
     offset = sk - sq
     nk, nq = sk // block_k, sq // block_q
-    # dq partials in the input dtype are only safe while few partials are
-    # summed; past nk=8 (e.g. _fit_blocks shrank block_k for a wide HD)
-    # keep them f32 so rounding doesn't scale with nk (ADVICE r2)
-    dqp_dtype = q.dtype if nk <= 8 else jnp.float32
-
-    # delta_i = rowsum(do_i * o_i) per head — fused elementwise in XLA,
-    # laid out like lse: [b, H*_SUBL, sq].
-    dof = do.reshape(b, sq, H, D).astype(jnp.float32)
-    of = o.reshape(b, sq, H, D).astype(jnp.float32)
-    delta = jnp.einsum("bshd,bshd->bhs", dof, of)         # [b, H, sq]
-    delta = jnp.broadcast_to(delta[:, :, None, :],
-                             (b, H, _SUBL, sq)).reshape(b, H * _SUBL, sq)
 
     def qi(j, i):
         """The q block step (j, i) needs: the q blocks wholly above k
@@ -750,17 +829,44 @@ def _bwd_call(q, k, v, o, lse, do, segment_ids, *, H, Hk, causal, block_q,
         first = _div(jnp.maximum(j * block_k - offset, 0), block_q)
         return jnp.maximum(i, jnp.minimum(first, nq - 1))
 
-    q_spec_i = pl.BlockSpec((1, block_q, HD),
-                            lambda b, j, i: (b, qi(j, i), 0))
-    k_spec_j = pl.BlockSpec((1, block_k, HkD), lambda b, j, i: (b, j, 0))
+    def q_spec_i(c):
+        return pl.BlockSpec((1, block_q, HD),
+                            lambda b, j, i: (b, qi(j, i), c))
+
+    def k_spec_j(c):
+        return pl.BlockSpec((1, block_k, HkD), lambda b, j, i: (b, j, c))
+
     stat_i = pl.BlockSpec((1, H * _SUBL, block_q),
                           lambda b, j, i: (b, 0, qi(j, i)))
-    dqp_spec = pl.BlockSpec((1, 1, block_q, HD),
-                            lambda b, j, i: (b, j, i, 0))
+    one_array = cols == _QKV
+    if one_array:
+        # the gradient as the projection's backward reads it
+        out_specs = [pl.BlockSpec((1, block_k, 3 * HD),
+                                  lambda b, j, i: (b, j, 0))]
+        out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
+    else:
+        out_specs = [k_spec_j(0), k_spec_j(0)]
+        out_shape = [jax.ShapeDtypeStruct((b, sk, HkD), k.dtype),
+                     jax.ShapeDtypeStruct((b, sk, HkD), v.dtype)]
+    dq_at = 1 if one_array else 0   # dqkv, dqp | dq or dqp, dk, dv
+    if nk > 1:
+        # partials in the input dtype are only safe while few are
+        # summed; past nk=8 (e.g. _fit_blocks shrank block_k for a wide
+        # HD) keep them f32 so rounding doesn't scale with nk (ADVICE r2)
+        out_specs.insert(dq_at, pl.BlockSpec(
+            (None, None, block_q, HD), lambda b, j, i: (b, j, i, 0)))
+        out_shape.insert(dq_at, jax.ShapeDtypeStruct(
+            (b, nk, sq, HD), q.dtype if nk <= 8 else jnp.float32))
+    elif not one_array:
+        # one K/V block holds the key sequence: dq leaves finished
+        out_specs.insert(0, pl.BlockSpec((None, block_q, HD),
+                                         lambda b, j, i: (b, i, 0)))
+        out_shape.insert(0, jax.ShapeDtypeStruct((b, sq, HD), q.dtype))
 
     has_seg = segment_ids is not None
-    in_specs = [q_spec_i, k_spec_j, k_spec_j, q_spec_i, stat_i, stat_i]
-    operands = [q, k, v, do, lse, delta]
+    in_specs = [q_spec_i(cq), k_spec_j(ck), k_spec_j(cv), q_spec_i(0),
+                q_spec_i(0), stat_i]
+    operands = [q, k, v, o, do, lse]
     if has_seg:
         # the backward's tiles have keys in rows and q in lanes
         kseg, qseg = _seg_operands(segment_ids[::-1], b, sk, sq)
@@ -771,33 +877,43 @@ def _bwd_call(q, k, v, o, lse, do, segment_ids, *, H, Hk, causal, block_q,
         ]
         operands += [qseg, kseg]
 
-    dqp, dk, dv = pl.pallas_call(
-        functools.partial(_bwd_kernel, causal=causal, block_q=block_q,
-                          block_k=block_k, sub=sub, H=H, Hk=Hk, D=D,
-                          offset=offset, has_seg=has_seg),
+    outs = list(pl.pallas_call(
+        functools.partial(_bwd_kernel, sm_scale=sm_scale, causal=causal,
+                          block_q=block_q, block_k=block_k, sub=sub, H=H,
+                          Hk=Hk, D=D, offset=offset, has_seg=has_seg,
+                          one_array=one_array, dq_whole=nk == 1),
         grid=(b, nk, nq),
         in_specs=in_specs,
-        out_specs=[dqp_spec, k_spec_j, k_spec_j],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, nk, sq, HD), dqp_dtype),
-            jax.ShapeDtypeStruct((b, sk, HkD), k.dtype),
-            jax.ShapeDtypeStruct((b, sk, HkD), v.dtype),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
+            pltpu.VMEM((block_q, HD), q.dtype),      # the q block, scaled
+            pltpu.VMEM((H * _SUBL, block_q), jnp.float32),   # its delta
             pltpu.VMEM((block_k, HkD), jnp.float32),
             pltpu.VMEM((block_k, HkD), jnp.float32),
         ] + ([pltpu.VMEM((block_q, HD), jnp.float32)] if causal else []),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=_VMEM_LIMIT),
+            vmem_limit_bytes=_VMEM_LIMIT_BWD),
         interpret=interpret,
         # XLA names the custom call after the innermost scope, which
         # `name=` is. `transpose` (jax's word for the backward pass)
         # stays in it because benchmarks/kernel_costs/flash.py:classify
         # tells the backward kernel from the forward one by that word
         name="flash_bwd_transpose",
-    )(*operands)
-    return jnp.sum(dqp, axis=1, dtype=jnp.float32), dk, dv
+    )(*operands))
+    if nk > 1:
+        # slice by slice: elementwise, so XLA fuses the sum into what
+        # reads it (a reduction is a pass of its own)
+        outs[dq_at] = functools.reduce(jax.lax.add, (
+            outs[dq_at][:, j].astype(jnp.float32) for j in range(nk))
+            ).astype(q.dtype)
+    if not one_array:
+        return tuple(outs)
+    if nk > 1:      # the kernel left dq's columns for the partials' sum
+        return jax.lax.dynamic_update_slice_in_dim(outs[0], outs[1], 0,
+                                                   axis=2)
+    return outs[0]
 
 
 def _pick_block(s, target):
@@ -811,7 +927,7 @@ def _pick_block(s, target):
 
 
 def _fit_blocks(block_q, block_k, HD, n_bufs_q, n_bufs_k, HDk=None,
-                budget=_VMEM_LIMIT, sub=None, stat_heads=0):
+                budget=None, sub=None, stat_heads=0, k_accs=1):
     """Shrink (block_q, block_k) until the kernel's VMEM appetite fits.
 
     The dominant consumers scale linearly with the operand widths
@@ -822,12 +938,15 @@ def _fit_blocks(block_q, block_k, HD, n_bufs_q, n_bufs_k, HDk=None,
     compile. HDk: k/v-side width (Hk*D) — narrower than HD under GQA/MQA,
     so k-side blocks aren't shrunk for q-side bytes. stat_heads: heads
     whose running statistics the kernel keeps in every lane (the
-    forward's; the backward keeps none)."""
+    forward's; the backward keeps none). k_accs: float32 accumulators of
+    a k-side block (the backward's dk and dv). budget: the kernel's
+    scoped-VMEM limit (the forward's unless given)."""
     HDk = HD if HDk is None else HDk
+    budget = _VMEM_LIMIT if budget is None else budget
 
     def est(bq, bk):
         io = 2 * (n_bufs_q * bq * HD + n_bufs_k * bk * HDk) * 2  # dbuf DMAs
-        acc = (bq * HD + bk * HDk) * 4                   # f32 accumulators
+        acc = (bq * HD + k_accs * bk * HDk) * 4          # f32 accumulators
         acc += 2 * stat_heads * bq * _LANES * 4          # the forward's m, l
         tile = 3 * bq * min(bk, sub or bk) * 4           # score transients
         return io + acc + tile
@@ -891,7 +1010,9 @@ def _pallas_available():
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def _flash_core(q, k, v, segment_ids, causal, sm_scale, use_pallas):
     """[b, s, h, d] in/out; k, v may carry fewer (kv) heads (GQA/MQA).
-    segment_ids: None or (q_seg [b,sq], kv_seg [b,sk]) int32."""
+    segment_ids: None or (q_seg [b,sq], kv_seg [b,sk]) int32. On the
+    Pallas path the kernels read q, k, v as [b, s, h*d] (three arrays,
+    column block 0 each), scale q themselves and return dq scaled."""
     out, _ = _flash_core_fwd(q, k, v, segment_ids, causal, sm_scale,
                              use_pallas)
     return out
@@ -899,14 +1020,17 @@ def _flash_core(q, k, v, segment_ids, causal, sm_scale, use_pallas):
 
 def _flash_core_fwd(q, k, v, segment_ids, causal, sm_scale, use_pallas):
     if use_pallas:
+        from ...observability import perf
+        perf.trace_note("flash_operands", "split")
         b, s, h, d = q.shape
         hk = k.shape[2]
-        qs = (q * sm_scale).astype(q.dtype).reshape(b, s, h * d)
+        qm = q.reshape(b, s, h * d)
         km = k.reshape(b, -1, hk * d)
         vm = v.reshape(b, -1, hk * d)
-        o, lse = _flash_fwd_fused(qs, km, vm, h, causal, Hk=hk,
-                                  segment_ids=segment_ids)
-        return o.reshape(b, s, h, d), (qs, km, vm, o, lse, h, hk,
+        o, lse = _flash_fwd_fused(qm, km, vm, h, causal, Hk=hk,
+                                  segment_ids=segment_ids,
+                                  sm_scale=sm_scale)
+        return o.reshape(b, s, h, d), (qm, km, vm, o, lse, h, hk,
                                        segment_ids)
     out = _xla_attention(q, k, v, None, causal, sm_scale,
                          segment_ids=segment_ids)
@@ -917,11 +1041,11 @@ def _flash_core_bwd(causal, sm_scale, use_pallas, res, g):
     q, k, v, o, lse, h, hk, segment_ids = res
     if use_pallas:
         b, s, hd = q.shape
-        gm = g.reshape(b, s, hd)
-        dq, dk, dv = _flash_bwd_fused(q, k, v, o, lse, gm, h, causal,
-                                      Hk=hk, segment_ids=segment_ids)
+        dq, dk, dv = _flash_bwd_fused(q, k, v, o, lse, g.reshape(b, s, hd),
+                                      h, causal, Hk=hk,
+                                      segment_ids=segment_ids,
+                                      sm_scale=sm_scale)
         d = hd // h
-        dq = (dq * sm_scale).astype(q.dtype)  # dq arrives as f32 partial-sum
         return (dq.reshape(b, s, h, d), dk.reshape(b, -1, hk, d),
                 dv.reshape(b, -1, hk, d), None)
     _, vjp = jax.vjp(
@@ -932,6 +1056,33 @@ def _flash_core_bwd(causal, sm_scale, use_pallas, res, g):
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _flash_core_qkv(qkv, segment_ids, h, causal, sm_scale):
+    """qkv [b, s, 3*h*d] in, o [b, s, h*d] out, the Pallas path only:
+    the kernels read q, k and v where the projection wrote them, and the
+    gradient leaves as the [b, s, 3*h*d] its backward reads."""
+    out, _ = _flash_core_qkv_fwd(qkv, segment_ids, h, causal, sm_scale)
+    return out
+
+
+def _flash_core_qkv_fwd(qkv, segment_ids, h, causal, sm_scale):
+    o, lse = _flash_fwd_fused(qkv, qkv, qkv, h, causal,
+                              segment_ids=segment_ids, sm_scale=sm_scale,
+                              cols=_QKV, D=qkv.shape[2] // (3 * h))
+    return o, (qkv, o, lse, segment_ids)
+
+
+def _flash_core_qkv_bwd(h, causal, sm_scale, res, g):
+    qkv, o, lse, segment_ids = res
+    dqkv = _flash_bwd_fused(qkv, qkv, qkv, o, lse, g, h, causal,
+                            segment_ids=segment_ids, sm_scale=sm_scale,
+                            cols=_QKV, D=qkv.shape[2] // (3 * h))
+    return dqkv, None
+
+
+_flash_core_qkv.defvjp(_flash_core_qkv_fwd, _flash_core_qkv_bwd)
 
 
 def _shapes_ok(q_shape, k_shape):
@@ -1016,6 +1167,16 @@ def _planned_specs(plan, q_shape, k_shape):
             P(batch_axes or None, None))
 
 
+def _scale(softmax_scale, d):
+    return float(1.0 / np.sqrt(d) if softmax_scale is None
+                 else softmax_scale)
+
+
+def _int32_pair(segment_ids):
+    return segment_ids and (jnp.asarray(segment_ids[0], jnp.int32),
+                            jnp.asarray(segment_ids[1], jnp.int32))
+
+
 def flash_attention(q, k, v, attn_mask=None, causal=False,
                     softmax_scale=None, segment_ids=None):
     """[b, s, h, d] in and out; k/v may have fewer heads (GQA/MQA).
@@ -1026,15 +1187,12 @@ def flash_attention(q, k, v, attn_mask=None, causal=False,
     Causal masking is bottom-right aligned when sq != sk (FA2 semantics,
     ref: python/paddle/nn/functional/flash_attention.py:146 routing to the
     FlashAttention-2 library)."""
-    d = q.shape[-1]
-    sm_scale = softmax_scale if softmax_scale is not None else 1.0 / np.sqrt(d)
+    sm_scale = _scale(softmax_scale, q.shape[-1])
     if attn_mask is not None:
         return _xla_attention(q, k, v, attn_mask, causal, sm_scale,
                               segment_ids=segment_ids)
     use_pallas = _pallas_available() and _shapes_ok(q.shape, k.shape)
-    if segment_ids is not None:
-        segment_ids = (jnp.asarray(segment_ids[0], jnp.int32),
-                       jnp.asarray(segment_ids[1], jnp.int32))
+    segment_ids = _int32_pair(segment_ids)
     plan = _MESH_PLAN.get()
     if plan is not None and use_pallas:
         spec, seg_spec = _planned_specs(plan, q.shape, k.shape)
@@ -1047,3 +1205,30 @@ def flash_attention(q, k, v, attn_mask=None, causal=False,
             out_specs=spec, check_vma=False)(q, k, v, segment_ids)
     return _flash_core(q, k, v, segment_ids, causal, sm_scale,
                        bool(use_pallas))
+
+
+def flash_attention_qkv(qkv, num_heads, causal=False, softmax_scale=None,
+                        segment_ids=None):
+    """Attention on a fused projection's output: qkv [b, s, 3*h*d] (q, k
+    and v side by side in the last axis, heads of each side by side) in,
+    [b, s, h*d] out, `flash_attention`'s other arguments as there.
+
+    On the Pallas path the kernels read the three where they lie and
+    return one gradient in the same layout. Everything else (no TPU, a
+    shape the kernels reject, a `mesh_plan`: a [b, s, 3*h*d] array split
+    over heads is not head-contiguous) splits qkv into [b, s, h, d] views
+    and takes `flash_attention`."""
+    b, s, w = qkv.shape
+    hd = w // 3
+    shape = (b, s, num_heads, hd // num_heads)
+    if (attention_path(shape, shape)[0] == "pallas"
+            and _MESH_PLAN.get() is None):
+        from ...observability import perf
+        perf.trace_note("flash_operands", "qkv in place")
+        return _flash_core_qkv(qkv, _int32_pair(segment_ids), num_heads,
+                               causal, _scale(softmax_scale, shape[3]))
+    q, k, v = (qkv[:, :, i * hd:(i + 1) * hd].reshape(shape)
+               for i in range(3))
+    return flash_attention(q, k, v, causal=causal,
+                           softmax_scale=softmax_scale,
+                           segment_ids=segment_ids).reshape(b, s, hd)

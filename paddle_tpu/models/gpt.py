@@ -112,6 +112,11 @@ class GPTAttention(Layer):
     def forward(self, x, cache=None):
         b, s, _ = x.shape
         qkv = self.qkv_proj(x)
+        if self.use_flash_attention and cache is None:
+            # the kernels read q, k, v where the projection wrote them
+            from ..incubate.nn.functional import fused_flash_attention_qkv
+            return self.out_proj(fused_flash_attention_qkv(
+                qkv, self.num_heads, causal=True))
         qkv = ops.reshape(qkv, (b, s, 3, self.num_heads, self.head_dim))
         q, k, v = ops.unbind(qkv, axis=2)  # each [b, s, h, d]
         if cache is not None:

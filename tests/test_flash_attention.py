@@ -34,11 +34,10 @@ def _fuse(x):
 
 def _interp_fwd(q, k, v, sm_scale, causal, block_q, block_k):
     h = q.shape[2]
-    qs = (q * sm_scale).astype(q.dtype)
-    o, lse = fa._flash_fwd_fused(_fuse(qs), _fuse(k), _fuse(v), h, causal,
+    o, lse = fa._flash_fwd_fused(_fuse(q), _fuse(k), _fuse(v), h, causal,
                                  block_q=block_q, block_k=block_k,
-                                 interpret=True)
-    return o, lse, (_fuse(qs), _fuse(k), _fuse(v))
+                                 interpret=True, sm_scale=sm_scale)
+    return o, lse, (_fuse(q), _fuse(k), _fuse(v))
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -87,8 +86,7 @@ def test_bwd_interpret_matches_composite(causal, s, block_q, block_k):
     do = jnp.asarray(rng.standard_normal(o.shape), o.dtype)
     dq, dk, dv = fa._flash_bwd_fused(qm, km, vm, o, lse, do, h, causal,
                                      block_q=block_q, block_k=block_k,
-                                     interpret=True)
-    dq = dq * sc  # kernel returns grad wrt the pre-scaled q
+                                     interpret=True, sm_scale=sc)
 
     def comp(qm, km, vm):
         qh = qm.reshape(b, s, h, d)
@@ -316,17 +314,18 @@ def _walk_against_composite(q, k, v, do, sub, block_q=256, fwd_block_k=1024,
     b, sq, h, d = q.shape
     sk, hk = k.shape[1], k.shape[2]
     sc = 1.0 / np.sqrt(d)
-    qs = (q * sc).reshape(b, sq, h * d)
+    qm = q.reshape(b, sq, h * d)
     km, vm = k.reshape(b, sk, hk * d), v.reshape(b, sk, hk * d)
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(fa, "_WALK", sub)
-        o, lse = fa._flash_fwd_fused(qs, km, vm, h, True, block_q=block_q,
+        o, lse = fa._flash_fwd_fused(qm, km, vm, h, True, block_q=block_q,
                                      block_k=fwd_block_k, interpret=True,
-                                     Hk=hk, segment_ids=segment_ids)
+                                     Hk=hk, segment_ids=segment_ids,
+                                     sm_scale=sc)
         dq, dk, dv = fa._flash_bwd_fused(
-            qs, km, vm, o, lse, do, h, True, block_q=block_q,
+            qm, km, vm, o, lse, do, h, True, block_q=block_q,
             block_k=bwd_block_k, interpret=True, Hk=hk,
-            segment_ids=segment_ids)
+            segment_ids=segment_ids, sm_scale=sc)
 
     def comp(qm, km, vm):
         return _xla_ref(qm.reshape(q.shape), km.reshape(k.shape),
@@ -336,7 +335,7 @@ def _walk_against_composite(q, k, v, do, sub, block_q=256, fwd_block_k=1024,
     ref, vjp = jax.vjp(comp, q.reshape(b, sq, h * d), km, vm)
     np.testing.assert_allclose(np.asarray(o), np.asarray(ref),
                                rtol=5e-5, atol=5e-5)
-    for got, want in zip((dq * sc, dk, dv), vjp(do)):
+    for got, want in zip((dq, dk, dv), vjp(do)):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=5e-4, atol=5e-4)
     # the log-sum of every row that sees a key
@@ -497,11 +496,24 @@ def test_causal_tiles(sq, sk, block_k, sub, causal, want):
 def test_compile_record_says_what_the_flash_kernels_visit(monkeypatch):
     """`compile_record("train_step")["flash_causal"]`: of the [s, s]
     score tiles, how many each kernel of the traced step visits."""
+    from paddle_tpu.observability import perf
+    step = _tiny_gpt_step(monkeypatch, "plain")
+    perf._FAMILY_COMPILE.pop("train_step", None)
+    ids = np.zeros((1, 512), np.int32)
+    assert np.isfinite(float(step(ids, ids).numpy()))
+    assert perf.compile_record("train_step")["flash_causal"] == (
+        "fwd 3/4 of 256-wide tiles; bwd 3/4 of 256-wide tiles, dq whole")
+
+
+def _tiny_gpt_step(monkeypatch, variant):
+    """A one-layer GPT `TrainStep` on the interpreted kernels: plain, with
+    a (zero-length) cache handed to every layer, or under a two-device
+    mesh."""
     import paddle_tpu as pt
+    from jax.sharding import Mesh, PartitionSpec as P
     from paddle_tpu.jit import TrainStep
     from paddle_tpu.models import GPTForCausalLM, GPTPretrainingCriterion
     from paddle_tpu.models.gpt import GPTConfig
-    from paddle_tpu.observability import perf
     from paddle_tpu.optimizer import AdamW
     _interpreted_kernels(monkeypatch)
     monkeypatch.setenv("PADDLE_TPU_PALLAS_AUTOTUNE", "0")
@@ -511,14 +523,223 @@ def test_compile_record_says_what_the_flash_kernels_visit(monkeypatch):
         max_position_embeddings=512, use_flash_attention=True))
     model.train()
     crit = GPTPretrainingCriterion()
-    step = TrainStep(model, AdamW(learning_rate=1e-3,
+
+    def loss_fn(m, ids, labels):
+        if variant != "cache":
+            return crit(m(ids), labels)
+        none = pt.zeros([ids.shape[0], 0, 2, 64])
+        return crit(m(ids, caches=[(none, none)])[0], labels)
+
+    mesh = {}
+    if variant == "mesh":
+        mesh = dict(mesh=Mesh(np.array(jax.devices()[:2]), ("dp",)),
+                    shard_data=P("dp", None))
+    return TrainStep(model, AdamW(learning_rate=1e-3,
                                   parameters=model.parameters()),
-                     lambda m, ids, labels: crit(m(ids), labels))
+                     loss_fn, **mesh)
+
+
+@pytest.mark.parametrize("variant,want", [
+    ("plain", "qkv in place"), ("cache", "split"), ("mesh", "split")])
+def test_compile_record_says_where_the_kernels_read_qkv(monkeypatch, variant,
+                                                        want):
+    """`compile_record("train_step")["flash_operands"]`: the kernels of a
+    GPT step read q, k, v in `qkv_proj`'s output; with a cache or under
+    a `mesh_plan` the projection is split into [b, s, h, d] as before."""
+    from paddle_tpu.observability import perf
+    step = _tiny_gpt_step(monkeypatch, variant)
     perf._FAMILY_COMPILE.pop("train_step", None)
-    ids = np.zeros((1, 512), np.int32)
+    ids = np.zeros((2, 512), np.int32)
     assert np.isfinite(float(step(ids, ids).numpy()))
-    assert perf.compile_record("train_step")["flash_causal"] == (
-        "fwd 3/4 of 256-wide tiles; bwd 3/4 of 256-wide tiles")
+    assert perf.compile_record("train_step")["flash_operands"] == want
+
+
+def test_nothing_stands_between_qkv_proj_and_the_kernels(monkeypatch):
+    """In the traced step both kernels take `qkv_proj`'s output itself,
+    three times: its dot and bias, and no slice, transpose or reshape;
+    the forward's `o` and the backward's `do` are what `out_proj` reads
+    and returns, three-dimensional, and `delta` is made in the kernel
+    from the two."""
+    step = _tiny_gpt_step(monkeypatch, "plain")
+    ids = jnp.zeros((2, 512), jnp.int32)
+    jaxpr = step._step_fn.jit_fn.trace(
+        step.params, step.opt_states, step.buffers, jax.random.PRNGKey(0),
+        jnp.float32(1e-3), [ids, ids], {}).jaxpr.jaxpr
+    made_by = {v: e for e in jaxpr.eqns for v in e.outvars}
+    kernels = {e.params["name"]: e for e in jaxpr.eqns
+               if e.primitive.name == "pallas_call"}
+    assert sorted(kernels) == ["flash_bwd_transpose", "flash_fwd"]
+    for call in kernels.values():
+        q, k, v = call.invars[:3]
+        assert q is k is v and q.aval.shape == (2, 512, 3 * 128)
+        chain = []
+        while chain[-1:] != ["dot_general"]:
+            chain.append(made_by[q].primitive.name)
+            q = made_by[q].invars[0]
+        assert chain == ["add", "dot_general"]          # bias, product
+    o = kernels["flash_fwd"].outvars[0]
+    assert o.aval.shape == (2, 512, 128)
+    readers = [e.primitive.name for e in jaxpr.eqns if o in e.invars]
+    assert "reshape" not in readers and "dot_general" in readers
+    o_again, do = kernels["flash_bwd_transpose"].invars[3:5]
+    assert o_again is o and do.aval.shape == (2, 512, 128)
+    assert made_by[do].primitive.name == "dot_general"   # out_proj's dx
+
+
+def _qkv_case(h, d, dtype, seed=0, b=2, s=256):
+    rng = np.random.default_rng(seed)
+    qkv = jnp.asarray(rng.standard_normal((b, s, 3 * h * d)), dtype)
+    g = jnp.asarray(rng.standard_normal((b, s, h * d)), dtype)
+    seg = np.zeros((b, s), np.int32)
+    seg[0, 100:180], seg[0, 180:] = 1, -1
+    return qkv, g, jnp.asarray(seg)
+
+
+@pytest.mark.parametrize("h,d", [(4, 64), (2, 128)])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("with_seg", [False, True])
+def test_qkv_in_place_is_the_split_entry_bit_for_bit(monkeypatch, h, d,
+                                                     causal, with_seg):
+    """The fused entry against the [b, s, h, d] entry on the same
+    numbers: the same tiles reach the same kernel bodies, so `o`, `lse`
+    and the gradient (the concatenation of the split entry's three) are
+    equal to the bit, the scale applied in the kernels on both sides."""
+    _interpreted_kernels(monkeypatch)
+    qkv, g, seg = _qkv_case(h, d, jnp.bfloat16)
+    b, s, _ = qkv.shape
+    seg = (seg, seg) if with_seg else None
+    sc = 1.0 / np.sqrt(d)
+    o, res = fa._flash_core_qkv_fwd(qkv, seg, h, causal, sc)
+    dqkv, _ = fa._flash_core_qkv_bwd(h, causal, sc, res, g)
+    q, k, v = (x.reshape(b, s, h, d) for x in jnp.split(qkv, 3, axis=2))
+    o4, res4 = fa._flash_core_fwd(q, k, v, seg, causal, sc, True)
+    grads = fa._flash_core_bwd(causal, sc, True, res4,
+                               g.reshape(b, s, h, d))
+    np.testing.assert_array_equal(np.asarray(o),
+                                  np.asarray(o4.reshape(b, s, h * d)))
+    np.testing.assert_array_equal(np.asarray(res[2]), np.asarray(res4[4]))
+    np.testing.assert_array_equal(
+        np.asarray(dqkv), np.asarray(jnp.concatenate(
+            [x.reshape(b, s, h * d) for x in grads[:3]], axis=2)))
+    # and the public entries agree with the composite
+    want = fa._xla_attention(q, k, v, None, causal, sc, segment_ids=seg)
+    np.testing.assert_allclose(
+        np.asarray(o, np.float32),
+        np.asarray(want.reshape(b, s, h * d), np.float32),
+        rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("h,d", [(4, 64), (2, 128)])
+def test_dq_whole_against_dq_in_partials(h, d):
+    """One K/V block over the key sequence: dq leaves the kernel summed
+    over the walk, scaled and rounded once, and the call has no partial
+    array; with two blocks it writes [b, 2, sq, HD] partials that XLA
+    sums into dq's columns of the one gradient array. Both within the
+    file's tolerance of each other, dk and dv too (their accumulation
+    order does not change)."""
+    qkv, do, _ = _qkv_case(h, d, jnp.float32, seed=1, b=1)
+    sc = 1.0 / np.sqrt(d)
+    kw = dict(interpret=True, sm_scale=sc, cols=(0, 1, 2), D=d)
+    o, lse = fa._flash_fwd_fused(qkv, qkv, qkv, h, True, **kw)
+
+    def bwd(block_k):
+        return lambda qkv, do: fa._flash_bwd_fused(
+            qkv, qkv, qkv, o, lse, do, h, True, block_k=block_k, **kw)
+
+    def kernel_outputs(block_k):
+        eqns = jax.make_jaxpr(bwd(block_k))(qkv, do).jaxpr.eqns
+        call, = [e for e in eqns if e.primitive.name == "pallas_call"]
+        return ([v.aval.shape for v in call.outvars],
+                [e.primitive.name for e in eqns])
+
+    hd = h * d
+    shapes, prims = kernel_outputs(None)
+    assert shapes == [(1, 256, 3 * hd)] and prims == ["pallas_call"]
+    shapes, prims = kernel_outputs(128)
+    assert shapes == [(1, 256, 3 * hd), (1, 2, 256, hd)]
+    assert "add" in prims and "dynamic_update_slice" in prims
+    np.testing.assert_allclose(np.asarray(bwd(None)(qkv, do)),
+                               np.asarray(bwd(128)(qkv, do)),
+                               rtol=5e-4, atol=5e-4)
+    # three arrays: dq, dk, dv, and dq's partials when there are any
+    q, k, v = jnp.split(qkv, 3, axis=2)
+    for block_k, first in ((None, (1, 256, hd)), (128, (1, 2, 256, hd))):
+        eqns = jax.make_jaxpr(lambda q, k, v, do: fa._flash_bwd_fused(
+            q, k, v, o, lse, do, h, True, block_k=block_k, interpret=True,
+            sm_scale=sc))(q, k, v, do).jaxpr.eqns
+        call, = [e for e in eqns if e.primitive.name == "pallas_call"]
+        assert [x.aval.shape for x in call.outvars] == [
+            first, (1, 256, hd), (1, 256, hd)]
+
+
+@pytest.mark.parametrize("h,d", [(4, 64), (2, 128)])
+def test_scale_in_the_kernels_is_the_scale_before_them(h, d):
+    """q scaled where its block is loaded, in float32 and rounded to the
+    operand dtype once, is `(q * sm_scale).astype(q.dtype)` handed to
+    unscaling kernels: the forward equal to the bit in bf16; dq, scaled
+    where it is rounded, equal to the bit in float32 (in bf16 it is
+    rounded once where scaling afterwards rounds twice)."""
+    sc = 1.0 / np.sqrt(d)              # numpy's float64, as the entries'
+    for dtype in (jnp.bfloat16, jnp.float32):
+        qkv, do, _ = _qkv_case(h, d, dtype, seed=2, b=1)
+        q, k, v = jnp.split(qkv, 3, axis=2)
+        qs = (q * sc).astype(dtype)
+        o, lse = fa._flash_fwd_fused(q, k, v, h, True, interpret=True,
+                                     sm_scale=sc)
+        o_was, lse_was = fa._flash_fwd_fused(qs, k, v, h, True,
+                                             interpret=True)
+        np.testing.assert_array_equal(np.asarray(o), np.asarray(o_was))
+        np.testing.assert_array_equal(np.asarray(lse), np.asarray(lse_was))
+    got = fa._flash_bwd_fused(q, k, v, o, lse, do, h, True, interpret=True,
+                              sm_scale=sc)
+    was = fa._flash_bwd_fused(qs, k, v, o, lse, do, h, True, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got[0]),
+                                  np.asarray(was[0] * sc))
+    for a, b in zip(got[1:], was[1:]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_gpt_attention_is_the_same_through_both_entries(monkeypatch, dtype):
+    """`GPTAttention` on the fused entry against the same layer on the
+    [b, s, h, d] entry (what a mesh plan or a cache makes it take): the
+    same output and the same parameter gradients."""
+    import contextlib
+    import paddle_tpu as pt
+    from paddle_tpu.autograd import tape
+    from paddle_tpu.jit import _functional_params
+    from paddle_tpu.models.gpt import GPTAttention, GPTConfig
+    _interpreted_kernels(monkeypatch)
+    pt.seed(0)
+    layer = GPTAttention(GPTConfig(
+        vocab_size=256, hidden_size=256, num_layers=1, num_heads=2,
+        max_position_embeddings=256, use_flash_attention=True))
+    tensors = list(layer.parameters())
+    params = [p._data.astype(dtype) for p in tensors]
+    x = jnp.asarray(np.random.default_rng(3).standard_normal((2, 256, 256)),
+                    dtype)
+    one_device = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("dp",))
+
+    def loss(params, x, planned):
+        plan = fa.mesh_plan(one_device) if planned else (
+            contextlib.nullcontext())
+        with _functional_params(tensors, params), tape.no_grad(), plan:
+            out = layer(pt.to_tensor(x))._data
+        return (out.astype(jnp.float32) ** 2).sum(), out
+
+    (_, out), grads = jax.value_and_grad(loss, has_aux=True)(
+        params, x, False)
+    (_, out4), grads4 = jax.value_and_grad(loss, has_aux=True)(
+        params, x, True)
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(out4, np.float32),
+                               rtol=tol, atol=tol)
+    for g, g4 in zip(grads, grads4):
+        scale = float(jnp.abs(g4.astype(jnp.float32)).max())
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(g4, np.float32),
+                                   rtol=tol, atol=tol * scale)
 
 
 @pytest.mark.skipif(jax.default_backend() != "tpu",

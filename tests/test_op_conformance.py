@@ -362,6 +362,7 @@ class TestRegistryCoverage:
     # ops covered by dedicated test modules (grep the name to find it)
     DEDICATED = {
         "scaled_dot_product_attention", "fused_flash_attention",
+        "fused_flash_attention_qkv",
         "softmax", "log_softmax", "cross_entropy", "layer_norm",
         "rms_norm", "batch_norm", "group_norm", "instance_norm",
         "linear", "embedding", "conv1d", "conv2d", "conv3d",

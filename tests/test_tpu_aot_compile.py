@@ -351,10 +351,15 @@ def test_the_jamba_step_holds_its_mosaic_kernels(jamba_step, kernel, calls):
 def test_the_jamba_step_says_which_paths_it_took(jamba_step):
     """Multi-query attention (20 query heads on 1 key/value head, no
     positions) takes the flash kernel's grouped-head path; the scan its
-    kernels at hand-set blocks; the head is deferred; and the flash
-    kernels walk sequence 4096 up to the diagonal in 256-wide visits."""
+    kernels at hand-set blocks; the head is deferred; the flash kernels
+    walk sequence 4096 up to the diagonal in 256-wide visits, read q, k
+    and v from the three projections' own arrays, and the backward holds
+    the one key/value head's 4096 keys in one block, so dq leaves it
+    whole."""
     _text, notes = jamba_step
     assert notes == {"ssm_scan": "pallas, chunk 64, tile 512",
                      "attention": "pallas", "head_loss": "fused, chunks 1",
+                     "flash_operands": "split",
                      "flash_causal": "fwd 136/256 of 256-wide tiles; "
-                                     "bwd 136/256 of 256-wide tiles"}
+                                     "bwd 136/256 of 256-wide tiles, "
+                                     "dq whole"}
