@@ -12,6 +12,16 @@ dispatch/combine einsum pair. Expert weights are stacked [E, ...] and
 sharded over the expert axis; the dispatch einsum's contraction over
 tokens->experts IS the all-to-all, inserted by GSPMD (SURVEY §7.1 "MoE
 alltoall layer").
+
+The capacity dispatch DROPS tokens: an assignment past an expert's
+capacity (top_k * capacity_factor * tokens / experts) is left out of the
+result, and the one-hot is quadratic in the experts (at 256 it is
+neither a published model's mathematics nor runnable). The dropless
+path, which computes every assignment whatever the imbalance and is told
+which experts it holds, is `paddle_tpu.nn.SparseExpertFFN`
+(nn/layers/moe.py, ops/moe_ops.py, kernels/pallas/grouped_matmul.py);
+it runs one chip's share without its exchange. This file stays until an
+expert-parallel mesh rule carries that layer's exchange (ROADMAP C12).
 """
 from __future__ import annotations
 

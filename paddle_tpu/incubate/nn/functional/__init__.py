@@ -70,10 +70,12 @@ def fused_rotary_position_embedding(q, k=None, v=None, sin=None, cos=None,
 @register_op("fused_flash_attention", amp_policy="white")
 def fused_flash_attention(query, key, value, attn_mask=None, causal=False,
                           dropout=0.0, training=True, softmax_scale=None,
-                          segment_ids=None):
+                          segment_ids=None, window=None):
     """Flash attention, [batch, seq, heads, dim] layout; key/value may
     carry fewer heads (GQA/MQA), segment_ids=(q_seg, kv_seg) masks
-    attention to equal ids on the Pallas path (padding / packed varlen)
+    attention to equal ids on the Pallas path (padding / packed varlen),
+    window (causal only) bounds the keys a row sees to its own position
+    and the window - 1 before it
     (ref: nn/functional/flash_attention.py:146 -> dynloaded CUDA kernel;
     here -> Pallas TPU kernel, fallback XLA attention).
 
@@ -91,7 +93,7 @@ def fused_flash_attention(query, key, value, attn_mask=None, causal=False,
         _warn_if_composite(query.shape, key.shape)
     return pk.flash_attention(query, key, value, attn_mask=attn_mask,
                               causal=causal, softmax_scale=softmax_scale,
-                              segment_ids=segment_ids)
+                              segment_ids=segment_ids, window=window)
 
 
 def _warn_if_composite(q_shape, k_shape):

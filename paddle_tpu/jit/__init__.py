@@ -331,6 +331,9 @@ class TrainStep:
         step.sync()   # write final params back into model tensors
 
     If loss_fn is None the model itself must return the scalar loss.
+    With `has_aux` the loss function returns (loss, aux): aux (arrays
+    the same program makes, not differentiated: an expert layer's load
+    counts) is `step.aux` after each call, to be read with the loss.
     """
 
     def __init__(self, model: Layer, optimizer, loss_fn: Callable = None,
@@ -340,6 +343,7 @@ class TrainStep:
         self.loss_fn = loss_fn
         self.optimizer = optimizer
         self.has_aux = has_aux
+        self.aux = None
         pnames, ptensors, bnames, btensors = _collect_params(model)
         self._pnames = pnames
         self._ptensors = ptensors
@@ -448,6 +452,7 @@ class TrainStep:
         ptensors = self._ptensors
         btensors = self._btensors
         trainable = self._trainable
+        has_aux = self.has_aux
 
         def compute_loss(train_params, frozen_params, buffers, seed, args,
                          kw):
@@ -466,9 +471,14 @@ class TrainStep:
                             loss = model(*args, **kw)
                         else:
                             loss = loss_fn(model, *args, **kw)
+            if has_aux:
+                loss, aux = loss
+                aux = jax.tree_util.tree_map(
+                    lambda t: t._data if isinstance(t, Tensor) else t, aux,
+                    is_leaf=lambda t: isinstance(t, Tensor))
             if isinstance(loss, Tensor):
                 loss = loss._data
-            return loss
+            return (loss, aux) if has_aux else loss
 
         # numerics stats variant (ISSUE 15): captured at build time —
         # __call__ rebuilds when the plane's flag flips, so the family
@@ -479,8 +489,10 @@ class TrainStep:
         def step(params, opt_states, buffers, seed, lr, args, kw):
             train_params = [p for p, t in zip(params, trainable) if t]
             frozen_params = [p for p, t in zip(params, trainable) if not t]
-            loss, grads = jax.value_and_grad(compute_loss)(
+            loss, grads = jax.value_and_grad(compute_loss, has_aux=has_aux)(
                 train_params, frozen_params, buffers, seed, args, kw)
+            if has_aux:
+                loss, aux = loss
             train_states = [s for s, t in zip(opt_states, trainable) if t]
             with jax.named_scope("optimizer"):
                 new_train, new_states = optimizer.functional_update(
@@ -499,9 +511,11 @@ class TrainStep:
                 # in-trace reduction bundle over (pre-update params,
                 # grads, post-update params) — read-only taps, the
                 # update math above is untouched
-                return loss, new_params, new_opt_states, _num.pack_stats(
-                    train_params, grads, new_train)
-            return loss, new_params, new_opt_states
+                out = (loss, new_params, new_opt_states, _num.pack_stats(
+                    train_params, grads, new_train))
+            else:
+                out = (loss, new_params, new_opt_states)
+            return out + (aux,) if has_aux else out
 
         donate_argnums = (0, 1) if donate else ()
         # CompileTimed: the train step joins the process-wide compile
@@ -563,6 +577,8 @@ class TrainStep:
                 out = self._step_fn(
                     self.params, self.opt_states, self.buffers, seed, lr,
                     args, kwargs)
+            if self.has_aux:
+                *out, self.aux = out
             if self._numerics_on:
                 loss, self.params, self.opt_states, packed = out
             else:

@@ -83,8 +83,9 @@ _SUBL = 8   # per-head stats ride as [b, h*_SUBL, s]: seq in lanes, each
             # head's row replicated over one sublane tile (minimum height)
 
 
-def _causal_tile_mask(q0, k0, shape, q_axis=0):
-    """Bool validity (q_pos >= k_pos) of a score tile of `shape` whose
+def _causal_tile_mask(q0, k0, shape, q_axis=0, window=None):
+    """Bool validity (q_pos >= k_pos, and q_pos - k_pos < window where
+    there is one) of a score tile of `shape` whose
     first q row sits at causal position q0 and whose first key at k0; q
     rows run along `q_axis`, keys along the other. Only called on tiles
     that straddle the diagonal.
@@ -95,7 +96,9 @@ def _causal_tile_mask(q0, k0, shape, q_axis=0):
     on top-left drift)."""
     q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
     k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
-    return q_pos >= k_pos
+    if window is None:
+        return q_pos >= k_pos
+    return jnp.logical_and(q_pos >= k_pos, q_pos - k_pos < window)
 
 
 # Keys a visit of the causal walk takes: the granularity at which the
@@ -218,8 +221,47 @@ def _walk_bounds(qi, ki, block_q, block_k, sub, offset):
     return _div(jnp.clip(seen, 0, block_k) + sub - 1, sub)
 
 
-def _walk(n_visit, sub, block_k, visit):
-    """visit(k0) over a causal pair's first n_visit key sub-blocks, k0 the
+def _walk_from(qi, ki, block_q, block_k, sub, offset, window):
+    """The first key sub-block of a windowed causal (q block, k block)
+    pair that some q row of the pair sees: the pair's first row sees no
+    key before its own position less window - 1, so the sub-blocks
+    wholly before that are skipped. All of them, for a pair wholly
+    before the window."""
+    unseen = offset + qi * block_q - (window - 1) - ki * block_k
+    return _div(jnp.clip(unseen, 0, block_k), sub)
+
+
+def _first_kblock(qi, block_q, block_k, offset, window):
+    """The first k block a windowed q block sees a key of."""
+    return _div(jnp.maximum(offset + qi * block_q - (window - 1), 0),
+                block_k)
+
+
+def _first_qblock(ki, block_q, block_k, offset):
+    """The first q block that sees a key of causal k block ki."""
+    return _div(jnp.maximum(ki * block_k - offset, 0), block_q)
+
+
+def _last_qblock(ki, block_q, block_k, offset, window, nq):
+    """The last q block that sees a key of windowed k block ki."""
+    last_row = (ki + 1) * block_k - 1 + (window - 1) - offset
+    return jnp.minimum(_div(jnp.maximum(last_row, 0), block_q), nq - 1)
+
+
+def _window_kblocks(block_q, block_k, window, nk):
+    """K blocks a windowed q block can see keys of: its rows' keys span
+    block_q + window - 1 positions."""
+    return min(nk, (block_q + window - 2) // block_k + 2)
+
+
+def _window_qblocks(block_q, block_k, window, nq):
+    """Q blocks that can see keys of one windowed k block."""
+    return min(nq, (block_k + window - 2) // block_q + 2)
+
+
+def _walk(n_visit, sub, block_k, visit, lo=0):
+    """visit(k0) over a causal pair's key sub-blocks lo .. n_visit (lo: a
+    window's lower edge, `_walk_from`), k0 the
     sub-block's first key within the block. The bound comes from the
     program ids, so the walk is a loop and not a second unrolled level
     under the unrolled heads; a block that is one sub-block is visited
@@ -227,35 +269,43 @@ def _walk(n_visit, sub, block_k, visit):
     all of them: a second, unmasked body for the sub-blocks wholly below
     the diagonal doubles what the kernels cost to trace and lower."""
     if sub == block_k:
-        pl.when(n_visit > 0)(lambda: visit(0))
+        pl.when(n_visit > lo)(lambda: visit(0))
         return
 
     def body(j, carry):
         visit(pl.multiple_of(j * sub, sub))
         return carry
-    jax.lax.fori_loop(0, n_visit, body, 0)
+    jax.lax.fori_loop(lo, n_visit, body, 0)
 
 
-def causal_tiles(sq, sk, block_q, block_k, sub, causal=True):
+def causal_tiles(sq, sk, block_q, block_k, sub, causal=True, window=None):
     """(visited, total) [block_q, sub]-tiles of the [sq, sk] scores a
     kernel with these blocks computes: pure arithmetic on shapes, the
-    host-side count of what `_walk_bounds` makes the kernels do."""
+    host-side count of what `_walk_bounds` (and, under a window,
+    `_walk_from`) makes the kernels do."""
     sub = _sub_block(block_k, sub if causal else None)
     nq, ns = sq // block_q, sk // sub
     if not causal:
         return nq * ns, nq * ns
     offset = sk - sq
-    visited = sum(
-        min(max(-(-(offset + (i + 1) * block_q) // sub), 0), ns)
-        for i in range(nq))
+    visited = 0
+    for i in range(nq):
+        hi = min(max(-(-(offset + (i + 1) * block_q) // sub), 0), ns)
+        lo = 0 if window is None else min(
+            max(offset + i * block_q - (window - 1), 0) // sub, ns)
+        visited += max(hi - lo, 0)
     return visited, nq * ns
 
 
-def _note_causal(kind, sq, sk, block_q, block_k, sub, causal, more=""):
+def _note_causal(kind, sq, sk, block_q, block_k, sub, causal, more="",
+                 window=None):
     """Say in `compile_record(<family>)["flash_causal"]` how much of
     [sq, sk] this kernel visits (and `more`: the backward's dq)."""
     from ...observability import perf
-    visited, total = causal_tiles(sq, sk, block_q, block_k, sub, causal)
+    visited, total = causal_tiles(sq, sk, block_q, block_k, sub, causal,
+                                  window)
+    if window is not None:
+        more += f", window {window}"
     perf.trace_note("flash_causal",
                     f"{kind} {visited}/{total} of {sub}-wide tiles{more}")
 
@@ -277,7 +327,7 @@ def _seg_tile_mask(row_ref, lane_ref, r0, rows, l0, lanes):
 # ======================= forward =======================
 
 def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, sub, H, Hk, D,
-                offset, has_seg):
+                offset, has_seg, window=None):
     if has_seg:
         (q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
          o_ref, lse_ref, qs_ref, acc_ref, m_ref, l_ref) = refs
@@ -288,6 +338,10 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, sub, H, Hk, D,
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
+    # the k block this step holds: under a window the grid's k axis
+    # counts from the first block the q block sees a key of
+    kb = ki if window is None else ki + _first_kblock(
+        qi, block_q, block_k, offset, window)
 
     @pl.when(ki == 0)
     def _init():
@@ -303,8 +357,9 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, sub, H, Hk, D,
         qf = qs_ref[:]                     # [bq, H*D], scaled
         kf = k_ref[0, pl.ds(k0, sub), :]   # [sub, Hk*D]
         vf = v_ref[0, pl.ds(k0, sub), :]
-        ok = (_causal_tile_mask(offset + qi * block_q, ki * block_k + k0,
-                                (block_q, sub)) if causal else None)
+        ok = (_causal_tile_mask(offset + qi * block_q, kb * block_k + k0,
+                                (block_q, sub), window=window)
+              if causal else None)
         if has_seg:
             seg_ok = _seg_tile_mask(qseg_ref, kseg_ref, 0, block_q, k0, sub)
             ok = seg_ok if ok is None else jnp.logical_and(ok, seg_ok)
@@ -345,8 +400,9 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, sub, H, Hk, D,
                 jax.lax.mul(acc_ref[:, sl], scale), pv)
 
     if causal:
-        _walk(_walk_bounds(qi, ki, block_q, block_k, sub, offset), sub,
-              block_k, _visit)
+        _walk(_walk_bounds(qi, kb, block_q, block_k, sub, offset), sub,
+              block_k, _visit, 0 if window is None else _walk_from(
+                  qi, kb, block_q, block_k, sub, offset, window))
     else:
         _visit(0)
 
@@ -442,7 +498,7 @@ def _autotuned_blocks(kind, shape, H, Hk, causal, has_seg, defaults,
 def _flash_fwd_fused(q, k, v, H, causal, block_q=256, block_k=1024,
                      interpret=False, Hk=None, segment_ids=None,
                      autotune_ok=True, sm_scale=1.0, cols=(0, 0, 0),
-                     D=None):
+                     D=None, window=None):
     """q: [b, s, H*D]; k,v: [b, sk, Hk*D], each read at its column block
     of `cols` (in units of its own width): three arrays at (0, 0, 0), or
     one [b, s, 3*H*D] projection passed three times at (0, 1, 2) with its
@@ -458,7 +514,8 @@ def _flash_fwd_fused(q, k, v, H, causal, block_q=256, block_k=1024,
     HD, HkD = H * D, Hk * D
     has_seg = segment_ids is not None
     walk = _WALK if causal else None
-    if autotune_ok and not interpret and (block_q, block_k) == (256, 1024):
+    if autotune_ok and not interpret and window is None \
+            and (block_q, block_k) == (256, 1024):
 
         def run_shape(bq, bk):
             rng = np.random.default_rng(0)
@@ -494,11 +551,12 @@ def _flash_fwd_fused(q, k, v, H, causal, block_q=256, block_k=1024,
     block_q = _pick_block(sq, block_q)
     block_k = _pick_block(sk, block_k)
     sub = _sub_block(block_k, walk)
-    _note_causal("fwd", sq, sk, block_q, block_k, sub, causal)
+    _note_causal("fwd", sq, sk, block_q, block_k, sub, causal,
+                 window=window)
     return _fwd_call(q, k, v, segment_ids, cols=cols,
                      sm_scale=sm_scale, H=H, Hk=Hk, D=D,
                      causal=causal, block_q=block_q, block_k=block_k,
-                     sub=sub, interpret=interpret)
+                     sub=sub, interpret=interpret, window=window)
 
 
 # The calls below are traced once for each shape and setting and inlined
@@ -506,13 +564,13 @@ def _flash_fwd_fused(q, k, v, H, causal, block_q=256, block_k=1024,
 # or more, and tracing its unrolled body each time was most of what the
 # kernels cost a step's lowering.
 _CALL_STATICS = ("cols", "sm_scale", "H", "Hk", "D", "causal", "block_q",
-                 "block_k", "sub", "interpret")
+                 "block_k", "sub", "interpret", "window")
 _QKV = (0, 1, 2)    # q, k, v as column blocks of one fused projection
 
 
 @functools.partial(jax.jit, static_argnames=_CALL_STATICS, inline=True)
 def _fwd_call(q, k, v, segment_ids, *, cols, sm_scale, H, Hk, D, causal,
-              block_q, block_k, sub, interpret):
+              block_q, block_k, sub, interpret, window=None):
     b, sq = q.shape[:2]
     sk = k.shape[1]
     HD, HkD = H * D, Hk * D
@@ -520,11 +578,17 @@ def _fwd_call(q, k, v, segment_ids, *, cols, sm_scale, H, Hk, D, causal,
     has_seg = segment_ids is not None
     offset = sk - sq
     nk = sk // block_k
-    grid = (b, sq // block_q, nk)
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
         block_k=block_k, sub=sub, H=H, Hk=Hk, D=D, offset=offset,
         has_seg=has_seg)
+    if window is None:
+        grid = (b, sq // block_q, nk)
+    else:
+        # the grid's k axis holds only the blocks a q block can see
+        kernel = functools.partial(kernel, window=window)
+        grid = (b, sq // block_q,
+                _window_kblocks(block_q, block_k, window, nk))
 
     def kj(i, j):
         """The k block step (i, j) needs: a step wholly above the
@@ -534,6 +598,8 @@ def _fwd_call(q, k, v, segment_ids, *, cols, sm_scale, H, Hk, D, causal,
         if not causal:
             return j
         last_q = jnp.maximum(offset + (i + 1) * block_q - 1, 0)
+        if window is not None:
+            j = j + _first_kblock(i, block_q, block_k, offset, window)
         return jnp.minimum(j, jnp.minimum(_div(last_q, block_k), nk - 1))
 
     in_specs = [
@@ -582,7 +648,8 @@ def _fwd_call(q, k, v, segment_ids, *, cols, sm_scale, H, Hk, D, causal,
 # ======================= backward =======================
 
 def _bwd_kernel(*refs, sm_scale, causal, block_q, block_k, sub, H, Hk, D,
-                offset, has_seg, one_array, dq_whole):
+                offset, has_seg, one_array, dq_whole, window=None,
+                n_qblocks=None):
     """Single-pass backward: one s/p recompute per block pair feeds dk, dv
     AND this pair's dq contribution (vs. the classic two-kernel split that
     recomputes s/p and the dp dot twice). dq contributions can't accumulate
@@ -600,13 +667,28 @@ def _bwd_kernel(*refs, sm_scale, causal, block_q, block_k, sub, H, Hk, D,
     gradient leaves the same way: the k block's rows of dqkv [b, s,
     3*HD] are one output block, resident over the q steps, dk and dv
     written into their columns at the last one and, when dq is whole
-    (then the block holds every row), each step's dq into its rows."""
+    (then the block holds every row), each step's dq into its rows.
+
+    Under a `window` the grid's q axis holds only the q blocks that can
+    see a key of the k block, counted from the first that does; a step
+    past the last such block (`n_qblocks`: how many q blocks there are)
+    does nothing, and its index maps name the last one's blocks again.
+    The partials then have a slot for each k block a q block can see
+    (`_window_kblocks`), not for every k block; a slot no pair writes
+    keeps the zero it was handed."""
     n_in = 8 if has_seg else 6
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref = refs[:6]
     qseg_ref, kseg_ref = refs[6:n_in] or (None, None)
+    if window is not None and not dq_whole:
+        n_in += 1           # the zeros the partials' slots start as
     ki = pl.program_id(1)
     qi = pl.program_id(2)
     nq = pl.num_programs(2)
+    step = qi               # the q step of this k block, for first and last
+    if window is not None:
+        qi = qi + _first_qblock(ki, block_q, block_k, offset)
+        in_sight = qi <= _last_qblock(ki, block_q, block_k, offset, window,
+                                      n_qblocks)
     if one_array:
         dqkv_ref, *rest = refs[n_in:]
         HD = H * D
@@ -656,7 +738,7 @@ def _bwd_kernel(*refs, sm_scale, causal, block_q, block_k, sub, H, Hk, D,
         kf = k_ref[0, rows, :]               # [sub, Hk*D]
         vf = v_ref[0, rows, :]
         ok = (_causal_tile_mask(offset + qi * block_q, ki * block_k + k0,
-                                (sub, block_q), q_axis=1)
+                                (sub, block_q), q_axis=1, window=window)
               if causal else None)
         if has_seg:
             seg_ok = _seg_tile_mask(kseg_ref, qseg_ref, k0, sub, 0, block_q)
@@ -700,12 +782,22 @@ def _bwd_kernel(*refs, sm_scale, causal, block_q, block_k, sub, H, Hk, D,
             else:
                 dq_ref[:, sl] = _scaled(dq, sm_scale, dq_ref.dtype)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    if causal:
+    if window is not None:
+        n_visit = _walk_bounds(qi, ki, block_q, block_k, sub, offset)
+        lo = _walk_from(qi, ki, block_q, block_k, sub, offset, window)
+
+        @pl.when(in_sight)
+        def _run():
+            _load_q_block()
+            dq_acc[:] = jnp.zeros_like(dq_acc)
+            _walk(n_visit, sub, block_k, _visit, lo)
+            dq_ref[:] = _scaled(dq_acc[:], sm_scale, dq_ref.dtype)
+    elif causal:
         n_visit = _walk_bounds(qi, ki, block_q, block_k, sub, offset)
 
         # a pair wholly above the diagonal still owns its block of dq
@@ -725,7 +817,7 @@ def _bwd_kernel(*refs, sm_scale, causal, block_q, block_k, sub, H, Hk, D,
         _load_q_block()
         _visit(0)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == nq - 1)
     def _finalize():
         dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
@@ -734,7 +826,7 @@ def _bwd_kernel(*refs, sm_scale, causal, block_q, block_k, sub, H, Hk, D,
 def _flash_bwd_fused(q, k, v, o, lse, do, H, causal,
                      block_q=256, block_k=None, interpret=False,
                      Hk=None, segment_ids=None, autotune_ok=True,
-                     sm_scale=1.0, cols=(0, 0, 0), D=None):
+                     sm_scale=1.0, cols=(0, 0, 0), D=None, window=None):
     """Blockwise dq/dk/dv on the fused-head layout.
 
     q: [b, sq, H*D]; k,v: [b, sk, Hk*D], each at its column block of
@@ -755,7 +847,8 @@ def _flash_bwd_fused(q, k, v, o, lse, do, H, causal,
     n_bufs_k = 5 if cols == _QKV else 4
     if block_k is None:
         block_k = sk
-        if autotune_ok and not interpret and block_q == 256:
+        if autotune_ok and not interpret and window is None \
+                and block_q == 256:
 
             def run_shape(bq, bk):
                 rng = np.random.default_rng(0)
@@ -802,31 +895,44 @@ def _flash_bwd_fused(q, k, v, o, lse, do, H, causal,
     block_k = _pick_block(sk, block_k)
     sub = _sub_block(block_k, walk)
     nk = sk // block_k
+    slots = nk if window is None else _window_kblocks(
+        block_q, block_k, window, nk)
     _note_causal("bwd", sq, sk, block_q, block_k, sub, causal,
-                 f", dq partials {nk}" if nk > 1 else ", dq whole")
+                 f", dq partials {slots}" if nk > 1 else ", dq whole",
+                 window=window)
     return _bwd_call(q, k, v, o, lse, do, segment_ids, cols=cols,
                      sm_scale=sm_scale, H=H, Hk=Hk, D=D,
                      causal=causal, block_q=block_q, block_k=block_k,
-                     sub=sub, interpret=interpret)
+                     sub=sub, interpret=interpret, window=window)
 
 
 @functools.partial(jax.jit, static_argnames=_CALL_STATICS, inline=True)
 def _bwd_call(q, k, v, o, lse, do, segment_ids, *, cols, sm_scale, H, Hk, D,
-              causal, block_q, block_k, sub, interpret):
+              causal, block_q, block_k, sub, interpret, window=None):
     b, sq = q.shape[:2]
     sk = k.shape[1]
     HD, HkD = H * D, Hk * D
     cq, ck, cv = cols
     offset = sk - sq
     nk, nq = sk // block_k, sq // block_q
+    # the grid's q axis and the partials' slots (see the kernel)
+    steps, slots = nq, nk
+    if window is not None:
+        steps = _window_qblocks(block_q, block_k, window, nq)
+        slots = _window_kblocks(block_q, block_k, window, nk)
 
     def qi(j, i):
         """The q block step (j, i) needs: the q blocks wholly above k
         block j's diagonal do no work, so they name the first one that
-        does, which the pipeline then fetches once, for all of them."""
+        does, which the pipeline then fetches once, for all of them.
+        Under a window step i is the i-th block from that first one,
+        and the steps past the last block in sight name it again."""
         if not causal:
             return i
         first = _div(jnp.maximum(j * block_k - offset, 0), block_q)
+        if window is not None:
+            return jnp.minimum(first + i, _last_qblock(
+                j, block_q, block_k, offset, window, nq))
         return jnp.maximum(i, jnp.minimum(first, nq - 1))
 
     def q_spec_i(c):
@@ -853,14 +959,21 @@ def _bwd_call(q, k, v, o, lse, do, segment_ids, *, cols, sm_scale, H, Hk, D,
         # partials in the input dtype are only safe while few are
         # summed; past nk=8 (e.g. _fit_blocks shrank block_k for a wide
         # HD) keep them f32 so rounding doesn't scale with nk (ADVICE r2)
+        def partial_at(b, j, i):
+            if window is None:
+                return (b, j, i, 0)
+            iq = qi(j, i)
+            return (b, j - _first_kblock(iq, block_q, block_k, offset,
+                                         window), iq, 0)
         out_specs.insert(dq_at, pl.BlockSpec(
-            (None, None, block_q, HD), lambda b, j, i: (b, j, i, 0)))
+            (None, None, block_q, HD), partial_at))
         out_shape.insert(dq_at, jax.ShapeDtypeStruct(
-            (b, nk, sq, HD), q.dtype if nk <= 8 else jnp.float32))
+            (b, slots, sq, HD), q.dtype if slots <= 8 else jnp.float32))
     elif not one_array:
         # one K/V block holds the key sequence: dq leaves finished
-        out_specs.insert(0, pl.BlockSpec((None, block_q, HD),
-                                         lambda b, j, i: (b, i, 0)))
+        def whole_at(b, j, i):
+            return (b, i if window is None else qi(j, i), 0)
+        out_specs.insert(0, pl.BlockSpec((None, block_q, HD), whole_at))
         out_shape.insert(0, jax.ShapeDtypeStruct((b, sq, HD), q.dtype))
 
     has_seg = segment_ids is not None
@@ -876,16 +989,31 @@ def _bwd_call(q, k, v, o, lse, do, segment_ids, *, cols, sm_scale, H, Hk, D,
             pl.BlockSpec((1, block_k, _LANES), lambda b, j, i: (b, j, 0)),
         ]
         operands += [qseg, kseg]
+    kernel = functools.partial(
+        _bwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
+        block_k=block_k, sub=sub, H=H, Hk=Hk, D=D, offset=offset,
+        has_seg=has_seg, one_array=one_array, dq_whole=nk == 1)
+    aliases = {}
+    if window is not None:
+        if one_array:
+            raise NotImplementedError(
+                "flash attention: no window on one fused projection")
+        kernel = functools.partial(kernel, window=window, n_qblocks=nq)
+        if nk > 1:
+            # a q block sees fewer k blocks than it has slots where the
+            # sequence starts: those slots keep these zeros
+            aliases = {len(operands): dq_at}
+            in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+            operands.append(jnp.zeros(out_shape[dq_at].shape,
+                                      out_shape[dq_at].dtype))
 
     outs = list(pl.pallas_call(
-        functools.partial(_bwd_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k, sub=sub, H=H,
-                          Hk=Hk, D=D, offset=offset, has_seg=has_seg,
-                          one_array=one_array, dq_whole=nk == 1),
-        grid=(b, nk, nq),
+        kernel,
+        grid=(b, nk, steps),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        input_output_aliases=aliases,
         scratch_shapes=[
             pltpu.VMEM((block_q, HD), q.dtype),      # the q block, scaled
             pltpu.VMEM((H * _SUBL, block_q), jnp.float32),   # its delta
@@ -906,7 +1034,7 @@ def _bwd_call(q, k, v, o, lse, do, segment_ids, *, cols, sm_scale, H, Hk, D,
         # slice by slice: elementwise, so XLA fuses the sum into what
         # reads it (a reduction is a pass of its own)
         outs[dq_at] = functools.reduce(jax.lax.add, (
-            outs[dq_at][:, j].astype(jnp.float32) for j in range(nk))
+            outs[dq_at][:, j].astype(jnp.float32) for j in range(slots))
             ).astype(q.dtype)
     if not one_array:
         return tuple(outs)
@@ -961,10 +1089,13 @@ def _fit_blocks(block_q, block_k, HD, n_bufs_q, n_bufs_k, HDk=None,
 
 # ======================= dispatch =======================
 
-def _xla_attention(q, k, v, attn_mask, causal, sm_scale, segment_ids=None):
+def _xla_attention(q, k, v, attn_mask, causal, sm_scale, segment_ids=None,
+                   window=None):
     """Reference composite ([b,s,h,d] in/out) — the non-Pallas fallback.
     Handles GQA (kv heads dividing q heads), bottom-right-aligned causal
-    masking for sq != sk (FA2 semantics), and segment-id masking."""
+    masking for sq != sk (FA2 semantics), a causal window (a row sees
+    its own position and the window - 1 before it), and segment-id
+    masking."""
     h, hk = q.shape[2], k.shape[2]
     if hk != h:
         rep = h // hk
@@ -981,6 +1112,8 @@ def _xla_attention(q, k, v, attn_mask, causal, sm_scale, segment_ids=None):
         qpos = (sk - sq) + jnp.arange(sq)[:, None]
         kpos = jnp.arange(sk)[None, :]
         s = jnp.where(qpos >= kpos, s, neg)
+        if window is not None:
+            s = jnp.where(qpos - kpos < window, s, neg)
     if segment_ids is not None:
         q_seg, kv_seg = segment_ids
         ok = (jnp.asarray(q_seg)[:, None, :, None]
@@ -1007,18 +1140,20 @@ def _pallas_available():
     return jax.default_backend() == "tpu"
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash_core(q, k, v, segment_ids, causal, sm_scale, use_pallas):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash_core(q, k, v, segment_ids, causal, sm_scale, use_pallas,
+                window=None):
     """[b, s, h, d] in/out; k, v may carry fewer (kv) heads (GQA/MQA).
     segment_ids: None or (q_seg [b,sq], kv_seg [b,sk]) int32. On the
     Pallas path the kernels read q, k, v as [b, s, h*d] (three arrays,
     column block 0 each), scale q themselves and return dq scaled."""
     out, _ = _flash_core_fwd(q, k, v, segment_ids, causal, sm_scale,
-                             use_pallas)
+                             use_pallas, window)
     return out
 
 
-def _flash_core_fwd(q, k, v, segment_ids, causal, sm_scale, use_pallas):
+def _flash_core_fwd(q, k, v, segment_ids, causal, sm_scale, use_pallas,
+                    window=None):
     if use_pallas:
         from ...observability import perf
         perf.trace_note("flash_operands", "split")
@@ -1029,28 +1164,29 @@ def _flash_core_fwd(q, k, v, segment_ids, causal, sm_scale, use_pallas):
         vm = v.reshape(b, -1, hk * d)
         o, lse = _flash_fwd_fused(qm, km, vm, h, causal, Hk=hk,
                                   segment_ids=segment_ids,
-                                  sm_scale=sm_scale)
+                                  sm_scale=sm_scale, window=window)
         return o.reshape(b, s, h, d), (qm, km, vm, o, lse, h, hk,
                                        segment_ids)
     out = _xla_attention(q, k, v, None, causal, sm_scale,
-                         segment_ids=segment_ids)
+                         segment_ids=segment_ids, window=window)
     return out, (q, k, v, None, None, None, None, segment_ids)
 
 
-def _flash_core_bwd(causal, sm_scale, use_pallas, res, g):
+def _flash_core_bwd(causal, sm_scale, use_pallas, window, res, g):
     q, k, v, o, lse, h, hk, segment_ids = res
     if use_pallas:
         b, s, hd = q.shape
         dq, dk, dv = _flash_bwd_fused(q, k, v, o, lse, g.reshape(b, s, hd),
                                       h, causal, Hk=hk,
                                       segment_ids=segment_ids,
-                                      sm_scale=sm_scale)
+                                      sm_scale=sm_scale, window=window)
         d = hd // h
         return (dq.reshape(b, s, h, d), dk.reshape(b, -1, hk, d),
                 dv.reshape(b, -1, hk, d), None)
     _, vjp = jax.vjp(
         lambda q_, k_, v_: _xla_attention(q_, k_, v_, None, causal, sm_scale,
-                                          segment_ids=segment_ids),
+                                          segment_ids=segment_ids,
+                                          window=window),
         q, k, v)
     return vjp(g) + (None,)
 
@@ -1178,8 +1314,13 @@ def _int32_pair(segment_ids):
 
 
 def flash_attention(q, k, v, attn_mask=None, causal=False,
-                    softmax_scale=None, segment_ids=None):
+                    softmax_scale=None, segment_ids=None, window=None):
     """[b, s, h, d] in and out; k/v may have fewer heads (GQA/MQA).
+
+    window: None, or how many keys a row sees, its own position and the
+    window - 1 before it (causal only). A static argument: None traces
+    the kernels as they are without one; with a window the kernels
+    neither fetch nor visit the key blocks wholly before it.
 
     segment_ids: (q_seg [b, sq], kv_seg [b, sk]) int32 — attention is
     masked to equal ids (padding / packed-varlen, stays on the Pallas
@@ -1188,9 +1329,11 @@ def flash_attention(q, k, v, attn_mask=None, causal=False,
     ref: python/paddle/nn/functional/flash_attention.py:146 routing to the
     FlashAttention-2 library)."""
     sm_scale = _scale(softmax_scale, q.shape[-1])
+    if window is not None and not causal:
+        raise ValueError("flash_attention: a window needs causal=True")
     if attn_mask is not None:
         return _xla_attention(q, k, v, attn_mask, causal, sm_scale,
-                              segment_ids=segment_ids)
+                              segment_ids=segment_ids, window=window)
     use_pallas = _pallas_available() and _shapes_ok(q.shape, k.shape)
     segment_ids = _int32_pair(segment_ids)
     plan = _MESH_PLAN.get()
@@ -1198,13 +1341,16 @@ def flash_attention(q, k, v, attn_mask=None, causal=False,
         spec, seg_spec = _planned_specs(plan, q.shape, k.shape)
         return jax.shard_map(
             lambda q, k, v, seg: _flash_core(q, k, v, seg, causal,
-                                             sm_scale, True),
+                                             sm_scale, True, window),
             mesh=plan[0],
             in_specs=(spec, spec, spec,
                       None if segment_ids is None else (seg_spec,) * 2),
             out_specs=spec, check_vma=False)(q, k, v, segment_ids)
+    if window is None:
+        return _flash_core(q, k, v, segment_ids, causal, sm_scale,
+                           bool(use_pallas))
     return _flash_core(q, k, v, segment_ids, causal, sm_scale,
-                       bool(use_pallas))
+                       bool(use_pallas), int(window))
 
 
 def flash_attention_qkv(qkv, num_heads, causal=False, softmax_scale=None,

@@ -1,0 +1,245 @@
+"""Pallas TPU grouped matmul: the expert products of a dropless
+mixture-of-experts layer.
+
+    gmm(x [R, K], w [G, K, N], group_sizes [G]) -> [R, N]
+        out[r] = x[r] @ w[g(r)]                      (kernel `moe_gmm`)
+    its gradient to the rows is the same kernel on w's other axis, and
+    gmm_dw(x [R, K], dy [R, N], group_sizes [G]) -> [G, K, N]
+        dw[g] = sum over the rows r of group g of x[r]^T dy[r]
+                                                     (kernel `moe_gmm_dw`)
+
+The rows are sorted by group, and the group sizes are known only on the
+device. Layout (`group_layout`): group g's rows start at a multiple of
+`ROW_TILE`, every group owns at least one tile, and the rows of a
+group's last tile past its size are padding the caller fills with rows
+whose gradient is zero (`ops.moe_ops` does). So a row tile belongs to
+one group, and the kernels are plain tiled matmuls whose weight block is
+chosen by a prefetched table (tile -> group): consecutive tiles of one
+group name the same weight block and the pipeline fetches it once, an
+empty group's tile of padding leaves its dw block zero, and a group
+boundary costs ROW_TILE / 2 rows of padding on average where a kernel
+over unaligned groups would compute the straddling tile twice.
+
+Shapes are static and sized by the caller for its worst case; the
+tiles past the used prefix are not visited: their grid steps name the
+last used tile's blocks (nothing is fetched) and do no work, and their
+rows of the output are left as they were (unspecified).
+
+bf16 (or float32) operands, float32 accumulation. On a TPU backend the
+kernels are the only path; elsewhere (CPU tests) `jax.lax.ragged_dot`
+over the padded sizes computes the same.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .flash_attention import _pallas_available
+
+ROW_TILE = 128
+_VMEM_LIMIT = 100 * 1024 * 1024
+F32 = jnp.float32
+
+
+def group_layout(group_sizes, n_tiles, row_tile=ROW_TILE):
+    """(row_starts [G], tile_group [n_tiles], tiles_used) of the padded
+    layout: group g's rows are row_starts[g] .. + group_sizes[g], tile t
+    < tiles_used belongs to group tile_group[t]; later tiles repeat the
+    last used one's group."""
+    sizes = group_sizes.astype(jnp.int32)
+    tiles = jnp.maximum((sizes + row_tile - 1) // row_tile, 1)
+    ends = jnp.cumsum(tiles)
+    used = ends[-1]
+    t = jnp.minimum(jnp.arange(n_tiles, dtype=jnp.int32), used - 1)
+    tile_group = jnp.searchsorted(ends, t, side="right").astype(jnp.int32)
+    return (ends - tiles) * row_tile, tile_group, used
+
+
+def padded_rows(assignments, groups, row_tile=ROW_TILE):
+    """Rows the layout needs at worst for `assignments` rows in
+    `groups` groups."""
+    return (assignments // row_tile + groups) * row_tile
+
+
+# ======================= kernels =======================
+
+def _gmm_kernel(group_ref, used_ref, x_ref, w_ref, o_ref, *, transpose_w):
+    del group_ref
+
+    @pl.when(pl.program_id(0) < used_ref[0])
+    def _():
+        contract = (((1,), (1 if transpose_w else 0,)), ((), ()))
+        o_ref[:] = jax.lax.dot_general(
+            x_ref[:], w_ref[0], contract,
+            preferred_element_type=F32).astype(o_ref.dtype)
+
+
+def _dw_kernel(group_ref, used_ref, x_ref, dy_ref, dw_ref, acc_ref):
+    i = pl.program_id(0)
+    last = used_ref[0] - 1
+    here = group_ref[jnp.minimum(i, last)]
+
+    @pl.when(i <= last)
+    def _():
+        @pl.when(jnp.logical_or(i == 0,
+                                group_ref[jnp.maximum(i - 1, 0)] != here))
+        def _first():
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+
+        acc_ref[:] += jax.lax.dot_general(
+            x_ref[:], dy_ref[:], (((0,), (0,)), ((), ())),
+            preferred_element_type=F32)
+
+        @pl.when(jnp.logical_or(i == last, group_ref[i + 1] != here))
+        def _last():
+            dw_ref[0] = acc_ref[:].astype(dw_ref.dtype)
+
+
+def _tile(i, used_ref):
+    return jnp.minimum(i, used_ref[0] - 1)
+
+
+@functools.partial(jax.jit, static_argnames=("transpose_w", "interpret"),
+                   inline=True)
+def _gmm_call(x, w, tile_group, used, *, transpose_w, interpret):
+    R, K = x.shape
+    N = w.shape[1] if transpose_w else w.shape[2]
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, transpose_w=transpose_w),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(R // ROW_TILE,),
+            in_specs=[
+                pl.BlockSpec((ROW_TILE, K),
+                             lambda i, g, u: (_tile(i, u), 0)),
+                pl.BlockSpec((1,) + w.shape[1:],
+                             lambda i, g, u: (g[i], 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((ROW_TILE, N),
+                                   lambda i, g, u: (_tile(i, u), 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((R, N), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name="moe_gmm",     # also the innermost jax.named_scope
+    )(tile_group, used, x, w)
+
+
+@functools.partial(jax.jit, static_argnames=("groups", "interpret"),
+                   inline=True)
+def _dw_call(x, dy, tile_group, used, *, groups, interpret):
+    R, K = x.shape
+    N = dy.shape[1]
+    # one entry past the table's end for the kernel's look at tile i + 1
+    table = jnp.concatenate([tile_group, tile_group[-1:]])
+    return pl.pallas_call(
+        _dw_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(R // ROW_TILE,),
+            in_specs=[
+                pl.BlockSpec((ROW_TILE, K),
+                             lambda i, g, u: (_tile(i, u), 0)),
+                pl.BlockSpec((ROW_TILE, N),
+                             lambda i, g, u: (_tile(i, u), 0)),
+            ],
+            out_specs=pl.BlockSpec((1, K, N),
+                                   lambda i, g, u: (g[i], 0, 0)),
+            scratch_shapes=[pltpu.VMEM((K, N), F32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((groups, K, N), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name="moe_gmm_dw",
+    )(table, used, x, dy)
+
+
+# ======================= the XLA path =======================
+
+def _padded_sizes(tile_group, used, groups):
+    """Rows of every group's tiles, padding included."""
+    n = tile_group.shape[0]
+    live = jnp.arange(n) < used
+    return jnp.zeros((groups,), jnp.int32).at[tile_group].add(
+        jnp.where(live, ROW_TILE, 0))
+
+
+def _xla_gmm(x, w, tile_group, used, transpose_w):
+    if transpose_w:
+        w = jnp.swapaxes(w, 1, 2)
+    return jax.lax.ragged_dot(
+        x, w, _padded_sizes(tile_group, used, w.shape[0]),
+        preferred_element_type=F32).astype(x.dtype)
+
+
+def _xla_dw(x, dy, tile_group, used, groups):
+    live = jnp.arange(tile_group.shape[0]) < used
+    onehot = (jnp.logical_and(
+        tile_group[:, None] == jnp.arange(groups)[None], live[:, None])
+    ).astype(F32)                                       # [tiles, G]
+    xt = x.reshape(-1, ROW_TILE, x.shape[1]).astype(F32)
+    dyt = dy.reshape(-1, ROW_TILE, dy.shape[1]).astype(F32)
+    per_tile = jnp.einsum("trk,trn->tkn", jnp.where(
+        live[:, None, None], xt, 0), jnp.where(live[:, None, None], dyt, 0))
+    return jnp.einsum("tg,tkn->gkn", onehot, per_tile).astype(x.dtype)
+
+
+# ======================= the entry =======================
+
+def _product(x, w, tile_group, used, use_pallas, interpret, transpose_w):
+    if use_pallas:
+        return _gmm_call(x, w, tile_group, used, transpose_w=transpose_w,
+                         interpret=interpret)
+    return _xla_gmm(x, w, tile_group, used, transpose_w)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _gmm(x, w, tile_group, used, use_pallas, interpret):
+    return _product(x, w, tile_group, used, use_pallas, interpret, False)
+
+
+def _gmm_fwd(x, w, tile_group, used, use_pallas, interpret):
+    return (_product(x, w, tile_group, used, use_pallas, interpret, False),
+            (x, w, tile_group, used))
+
+
+def _gmm_bwd(use_pallas, interpret, res, dy):
+    x, w, tile_group, used = res
+    dy = dy.astype(x.dtype)
+    dx = _product(dy, w, tile_group, used, use_pallas, interpret, True)
+    if use_pallas:
+        dw = _dw_call(x, dy, tile_group, used, groups=w.shape[0],
+                      interpret=interpret)
+    else:
+        dw = _xla_dw(x, dy, tile_group, used, w.shape[0])
+    return dx, dw.astype(w.dtype), None, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def gmm_path() -> str:
+    return "pallas" if _pallas_available() else "xla"
+
+
+def gmm(x, w, group_sizes, interpret=False):
+    """x [R, K] (R a multiple of ROW_TILE, rows in `group_layout`'s
+    order), w [G, K, N], group_sizes [G] int32 -> [R, N] in x's type.
+    Rows of tiles past the used prefix are unspecified on the way out
+    and must carry no gradient on the way back; dw of such rows and of
+    padding is what the caller's padding rows make it (zero, if their
+    gradient is zero)."""
+    R = x.shape[0]
+    if R % ROW_TILE:
+        raise ValueError(f"gmm: {R} rows are no multiple of {ROW_TILE}")
+    _starts, tile_group, used = group_layout(group_sizes, R // ROW_TILE)
+    return _gmm(x, w.astype(x.dtype), tile_group, used.reshape(1),
+                bool(interpret or _pallas_available()), bool(interpret))

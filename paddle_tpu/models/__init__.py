@@ -1,4 +1,4 @@
-"""Flagship model families (GPT / LLaMA / Jamba / BERT).
+"""Flagship model families (GPT / LLaMA / Jamba / Laguna / BERT).
 
 The reference keeps language models out-of-tree (PaddleNLP) but its
 north-star benchmarks are GPT-3/LLaMA hybrid-parallel training
@@ -17,6 +17,10 @@ from .llama import (  # noqa: F401
 from .jamba import (  # noqa: F401
     JambaConfig, JambaModel, JambaForCausalLM, JambaMambaMixer,
     JambaAttention, JambaMLP, JambaDecoderLayer, jamba_tiny,
+)
+from .laguna import (  # noqa: F401
+    LagunaConfig, LagunaModel, LagunaForCausalLM, LagunaAttention,
+    LagunaDecoderLayer, laguna_tiny, observe_expert_load,
 )
 from .bert import (  # noqa: F401
     BertConfig, BertModel, BertForMaskedLM, bert_tiny, bert_base,

@@ -18,6 +18,9 @@ from .layers.common import (  # noqa: F401
     Pad2D, Pad3D, ZeroPad2D, Bilinear, CosineSimilarity, PairwiseDistance,
     PixelShuffle, PixelUnshuffle, ChannelShuffle, Unfold,
 )
+from .layers.moe import (  # noqa: F401
+    SparseExpertFFN, SwiGLU, rope_tables, yarn_inv_freq,
+)
 from .layers.conv import (  # noqa: F401
     Conv1D, Conv2D, Conv3D, Conv2DTranspose, Conv1DTranspose,
     Conv3DTranspose,

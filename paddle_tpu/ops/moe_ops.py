@@ -1,0 +1,311 @@
+"""Dropless mixture-of-experts ops: the router, the permutation of
+assignments to the experts held here, and the expert products around
+`kernels/pallas/grouped_matmul.py`.
+
+No capacity and no dropped token: every assignment to a held expert is
+computed, whatever the imbalance. Shapes are static and sized for the
+worst case (every one of the tokens x top_k assignments held here); what
+is gathered into the experts' order, multiplied and activated follows
+the used prefix of the rows, which is known only on the device: the
+kernels skip the tiles past it and the XLA parts are loops over `_CHUNK`
+rows with a traced trip count (inside `jax.custom_vjp`s, so nothing
+differentiates through a loop). The way back to the tokens' order is a
+gather over all tokens x top_k slots (`_sum_slots`): the same cost
+whatever the load, and less than a scatter-add of the held rows.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from ..kernels.pallas import grouped_matmul as _gm
+from .registry import register_op
+
+__all__ = ["moe_route", "moe_experts"]
+
+_CHUNK = 2048       # rows a loop iteration gathers or activates
+F32 = jnp.float32
+
+
+@register_op("moe_route", amp_policy="black")
+def moe_route(x, router_weight, top_k, routed_scale=1.0):
+    """x [T, d], router_weight [d, E] -> (weights [T, top_k] float32,
+    experts [T, top_k] int32): scores = sigmoid(x W) in float32 (the
+    product at `highest` precision: a TPU's default rounds a float32
+    matmul's operands to bf16, and a top-k choice turns on the last
+    digits), the top_k largest chosen, their scores normalised to one
+    and multiplied by routed_scale. On amp's black list."""
+    scores = jax.nn.sigmoid(jnp.matmul(
+        x.astype(F32), router_weight.astype(F32),
+        precision=jax.lax.Precision.HIGHEST))
+    top, experts = _top_k(scores, top_k)
+    weights = top * (routed_scale / jnp.sum(top, axis=-1, keepdims=True))
+    return weights, experts
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _top_k(scores, k):
+    """`jax.lax.top_k` whose gradient is k masked passes over the scores
+    and no scatter of tokens x k scalars."""
+    top, experts = jax.lax.top_k(scores, k)
+    return top, experts.astype(jnp.int32)
+
+
+def _top_k_fwd(scores, k):
+    top, experts = _top_k(scores, k)
+    return (top, experts), (experts, jnp.zeros((0, scores.shape[-1]),
+                                               scores.dtype))
+
+
+def _top_k_bwd(k, res, cot):
+    experts, like = res
+    d_top = cot[0]
+    lanes = jnp.arange(like.shape[-1], dtype=jnp.int32)
+    d_scores = sum(
+        jnp.where(experts[..., j, None] == lanes, d_top[..., j, None], 0)
+        for j in range(k))
+    return (d_scores.astype(like.dtype),)
+
+
+_top_k.defvjp(_top_k_fwd, _top_k_bwd)
+
+
+# -- the permutation ----------------------------------------------------------
+def permutation(experts, first, count):
+    """Where each assignment to a held expert (first <= e < first + count)
+    goes among the rows of `grouped_matmul.group_layout`, sorted by
+    expert, slots of one expert in token order.
+
+    experts [T, k] int32 -> dict of
+      counts [count]        assignments to every held expert
+      rows_used ()          rows of the used prefix (a multiple of ROW_TILE)
+      slot_of_row [R]       the assignment (t * k + j) a row holds, 0 for
+                            padding and unused rows
+      live_row [R]          the row holds an assignment
+      row_of_slot [T * k]   the row an assignment went to, 0 if not held
+      held_slot [T * k]     the assignment's expert is held here
+    R = grouped_matmul.padded_rows(T * k, count), rounded up to _CHUNK."""
+    A = experts.size
+    R = -(-_gm.padded_rows(A, count) // _CHUNK) * _CHUNK
+    local = experts.reshape(A) - first
+    held = jnp.logical_and(local >= 0, local < count)
+    local = jnp.where(held, local, count)
+    # one sort of unique keys (group major, slot minor), and one more for
+    # the inverse permutation: every later index is a gather, and the
+    # counts are where the sorted keys change group (a scatter-add of
+    # every assignment into a few bins would run one update at a time)
+    slots = jnp.arange(A, dtype=jnp.int32)
+    keys = jnp.sort(local * A + slots)
+    order = keys % A                                # rank -> slot
+    counts = jnp.diff(jnp.searchsorted(
+        keys, jnp.arange(count + 1, dtype=jnp.int32) * A)).astype(jnp.int32)
+    _, rank = jax.lax.sort((order, slots), num_keys=1)   # slot -> rank
+    starts, tile_group, tiles_used = _gm.group_layout(
+        counts, R // _gm.ROW_TILE)
+    first_rank = jnp.cumsum(counts) - counts        # group -> its first rank
+    rows = jnp.arange(R, dtype=jnp.int32)
+
+    def of_row(per_group):      # a group's value on every row of its tiles
+        return jnp.repeat(per_group[tile_group], _gm.ROW_TILE)
+    within = rows - of_row(starts)
+    rows_used = tiles_used * _gm.ROW_TILE
+    live_row = jnp.logical_and(within < of_row(counts), rows < rows_used)
+    slot_of_row = jnp.where(
+        live_row, order[jnp.minimum(of_row(first_rank) + within, A - 1)], 0)
+    g = jnp.minimum(local, count - 1)
+    row_of_slot = jnp.where(held, starts[g] + rank - first_rank[g], 0)
+    return dict(counts=counts, rows_used=rows_used, slot_of_row=slot_of_row,
+                live_row=live_row, row_of_slot=row_of_slot, held_slot=held)
+
+
+def _over_chunks(rows_used, body, init):
+    """body(lo, carry) over the used prefix, `_CHUNK` rows at a time."""
+    n = (rows_used + _CHUNK - 1) // _CHUNK
+    return jax.lax.fori_loop(
+        0, n, lambda i, c: body(i * _CHUNK, c), init)
+
+
+def _rows(a, lo):
+    return jax.lax.dynamic_slice_in_dim(a, lo, _CHUNK, axis=0)
+
+
+def _put(buf, chunk, lo):
+    return jax.lax.dynamic_update_slice_in_dim(buf, chunk, lo, axis=0)
+
+
+def _gather_rows(x, index, rows_used):
+    """out[r] = x[index[r]] for r in the used prefix; the rest of out
+    [R, d] is unspecified."""
+    out = jax.lax.empty((index.shape[0], x.shape[1]), x.dtype)
+    return _over_chunks(
+        rows_used, lambda lo, out: _put(out, x[_rows(index, lo)], lo), out)
+
+
+_TOKENS = 2048      # tokens whose top_k rows `_sum_slots` holds at once
+
+
+def _sum_slots(vals, row_of_slot, held_slot, k, scale=None):
+    """out[t] = sum over token t's held assignments j of
+    (scale[t * k + j] *) vals[row_of_slot[t * k + j]], in float32; out
+    [T, d]. The way back from the experts' order, as a gather: on the
+    chip XLA's scatter-add of a row takes six times a gathered row's
+    time, so that all tokens x k slots gathered (what is not held read
+    from row 0 and masked) cost less than the held rows scattered
+    (PERF.md, PR 31)."""
+    T = row_of_slot.shape[0] // k
+    chunk = _TOKENS if T % _TOKENS == 0 else T
+
+    def some(args):
+        rows, held, scale = args
+        g = vals[rows].astype(F32)                  # [chunk * k, d]
+        if scale is not None:
+            g = g * scale[:, None]
+        g = jnp.where(held[:, None], g, 0)
+        return jnp.sum(g.reshape(chunk, k, -1), axis=1)
+
+    parts = jax.lax.map(some, tuple(
+        None if a is None else a.reshape(T // chunk, chunk * k)
+        for a in (row_of_slot, held_slot, scale)))
+    return parts.reshape(T, -1)
+
+
+# rows of x in the experts' order, and back
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def take_rows(x, token_of_row, rows_used, row_of_slot, held_slot, k):
+    return _gather_rows(x, token_of_row, rows_used)
+
+
+def _take_fwd(x, token_of_row, rows_used, row_of_slot, held_slot, k):
+    return (_gather_rows(x, token_of_row, rows_used),
+            (row_of_slot, held_slot))
+
+
+def _take_bwd(k, res, d_rows):
+    row_of_slot, held_slot = res
+    dx = _sum_slots(d_rows, row_of_slot, held_slot, k)
+    return dx.astype(d_rows.dtype), None, None, None, None
+
+
+take_rows.defvjp(_take_fwd, _take_bwd)
+
+
+@jax.custom_vjp
+def combine_rows(ys, weights, p):
+    """out[t] = sum over token t's held assignments j of weights[t, j] *
+    ys[row of (t, j)], in float32 and returned in ys's type; weights
+    [T, k] float32, p the `permutation`."""
+    return _combine(ys, weights, p)
+
+
+def _combine(ys, weights, p):
+    return _sum_slots(ys, p["row_of_slot"], p["held_slot"],
+                      weights.shape[1], weights.reshape(-1)).astype(ys.dtype)
+
+
+def _combine_fwd(ys, weights, p):
+    return _combine(ys, weights, p), (ys, weights, p)
+
+
+def _combine_bwd(res, dy):
+    ys, weights, p = res
+    k = weights.shape[1]
+    flat = weights.reshape(-1)
+
+    def body(lo, carry):
+        d_ys, d_w = carry
+        slot, live = _rows(p["slot_of_row"], lo), _rows(p["live_row"], lo)
+        g = dy[slot // k].astype(F32)                   # [chunk, d]
+        w = jnp.where(live, flat[slot], 0)
+        d_ys = _put(d_ys, (g * w[:, None]).astype(ys.dtype), lo)
+        dot = jnp.sum(g * _rows(ys, lo).astype(F32), axis=-1)
+        return d_ys, _put(d_w, jnp.where(live, dot, 0), lo)
+
+    # a row's weight gradient is read by its slot: zero past the prefix
+    d_ys, d_w_row = _over_chunks(
+        p["rows_used"], body,
+        (jax.lax.empty(ys.shape, ys.dtype),
+         jnp.zeros(p["live_row"].shape, F32)))
+    d_w = jnp.where(p["held_slot"], d_w_row[p["row_of_slot"]], 0)
+    return d_ys, d_w.reshape(weights.shape), None
+
+
+combine_rows.defvjp(_combine_fwd, _combine_bwd)
+
+
+@jax.custom_vjp
+def swiglu_rows(gate_up, rows_used):
+    """silu(gate) * up of the used prefix of gate_up [R, 2 * w] (gate in
+    the first w columns) -> [R, w]."""
+    return _swiglu(gate_up, rows_used)
+
+
+def _swiglu(gate_up, rows_used):
+    w = gate_up.shape[1] // 2
+
+    def body(lo, out):
+        gu = _rows(gate_up, lo).astype(F32)
+        return _put(out, (jax.nn.silu(gu[:, :w]) * gu[:, w:]
+                          ).astype(out.dtype), lo)
+    return _over_chunks(rows_used, body,
+                        jax.lax.empty((gate_up.shape[0], w), gate_up.dtype))
+
+
+def _swiglu_fwd(gate_up, rows_used):
+    return _swiglu(gate_up, rows_used), (gate_up, rows_used)
+
+
+def _swiglu_bwd(res, dh):
+    gate_up, rows_used = res
+    w = gate_up.shape[1] // 2
+
+    def body(lo, out):
+        gu = _rows(gate_up, lo).astype(F32)
+        g, u = gu[:, :w], gu[:, w:]
+        d = _rows(dh, lo).astype(F32)
+        sig = jax.nn.sigmoid(g)
+        dg = d * u * sig * (1 + g * (1 - sig))
+        return _put(out, jnp.concatenate([dg, d * g * sig], axis=1
+                                         ).astype(out.dtype), lo)
+    return (_over_chunks(rows_used, body,
+                         jax.lax.empty(gate_up.shape, gate_up.dtype)), None)
+
+
+swiglu_rows.defvjp(_swiglu_fwd, _swiglu_bwd)
+
+
+@register_op("moe_experts", amp_policy="keep")
+def moe_experts(x, weights, experts, w_gate_up, w_down, first=0,
+                interpret=False):
+    """The held experts' part of a sparse feed-forward's routed sum.
+
+    x [T, d]; weights, experts [T, k] (`moe_route`); w_gate_up
+    [count, d, 2 * width] (gate in the first `width` columns), w_down
+    [count, width, d]: the experts first .. first + count. Returns
+    (y [T, d] in x's type, counts [count] int32):
+
+        y[t] = sum over j with first <= experts[t, j] < first + count of
+               weights[t, j] * SwiGLU_{experts[t, j]}(x[t])
+
+    Under amp the products take bf16 operands (cast here: the op keeps
+    its routing weights float32) and accumulate in float32."""
+    from ..amp.state import amp_state
+    st = amp_state()
+    dt = st.dtype.np_dtype if st.enabled else x.dtype
+    xs = x.astype(dt)
+    count = w_gate_up.shape[0]
+    k = experts.shape[1]
+    with jax.named_scope("permute"):
+        p = permutation(experts, first, count)
+        used = p["rows_used"]
+        rows = take_rows(xs, p["slot_of_row"] // k, used, p["row_of_slot"],
+                         p["held_slot"], k)
+    with jax.named_scope("experts"):
+        gate_up = _gm.gmm(rows, w_gate_up.astype(dt), p["counts"],
+                          interpret=interpret)
+        h = swiglu_rows(gate_up, used)
+        ys = _gm.gmm(h, w_down.astype(dt), p["counts"], interpret=interpret)
+    with jax.named_scope("combine"):
+        y = combine_rows(ys, weights.astype(F32), p)
+    return y.astype(x.dtype), p["counts"]

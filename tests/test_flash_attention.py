@@ -613,7 +613,7 @@ def test_qkv_in_place_is_the_split_entry_bit_for_bit(monkeypatch, h, d,
     dqkv, _ = fa._flash_core_qkv_bwd(h, causal, sc, res, g)
     q, k, v = (x.reshape(b, s, h, d) for x in jnp.split(qkv, 3, axis=2))
     o4, res4 = fa._flash_core_fwd(q, k, v, seg, causal, sc, True)
-    grads = fa._flash_core_bwd(causal, sc, True, res4,
+    grads = fa._flash_core_bwd(causal, sc, True, None, res4,
                                g.reshape(b, s, h, d))
     np.testing.assert_array_equal(np.asarray(o),
                                   np.asarray(o4.reshape(b, s, h * d)))

@@ -1,0 +1,97 @@
+"""`kernels/pallas/grouped_matmul.py` against a loop over the groups:
+the product and both gradients, with empty groups, groups that are no
+multiple of the row tile and rows past the used prefix; on the XLA path
+the CPU takes and with the Pallas kernels run by the interpreter."""
+from importlib import import_module
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+gm = import_module("paddle_tpu.kernels.pallas.grouped_matmul")
+T = gm.ROW_TILE
+
+SIZES = {
+    "mixed": [130, 0, 5, 128, 0, 300],
+    "one_full": [0, 0, 0, 640, 0, 0],       # every row in one group
+    "all_empty_but_last": [0, 0, 0, 0, 0, 1],
+}
+
+
+def _case(sizes, K=64, N=128, spare_tiles=3, seed=0):
+    sizes = np.asarray(sizes, np.int32)
+    G = len(sizes)
+    R = gm.padded_rows(int(sizes.sum()), G) + spare_tiles * T
+    starts, tile_group, used = gm.group_layout(jnp.asarray(sizes), R // T)
+    starts = np.asarray(starts)
+    rng = np.random.default_rng(seed)
+    # rows outside every group are garbage on purpose: they may not leak
+    x = rng.standard_normal((R, K)).astype(np.float32)
+    live = np.zeros(R, bool)
+    for s, n in zip(starts, sizes):
+        live[s:s + n] = True
+        x[s + n:s + -(-max(n, 1) // T) * T] = 0.0    # a group's padding
+    w = rng.standard_normal((G, K, N)).astype(np.float32)
+    return sizes, starts, live, jnp.asarray(x), jnp.asarray(w), int(used)
+
+
+def _loop(x, w, sizes, starts):
+    out = jnp.zeros((x.shape[0], w.shape[2]), jnp.float32)
+    for g, (s, n) in enumerate(zip(starts, sizes)):
+        out = out.at[s:s + n].set(x[s:s + n] @ w[g])
+    return out
+
+
+def test_group_layout_pads_every_group_to_tiles_and_one_at_least():
+    starts, tile_group, used = gm.group_layout(
+        jnp.asarray(SIZES["mixed"], jnp.int32), 12)
+    assert list(np.asarray(starts)) == [0, 256, 384, 512, 640, 768]
+    assert int(used) == 9
+    assert list(np.asarray(tile_group)) == [0, 0, 1, 2, 3, 4, 5, 5, 5,
+                                            5, 5, 5]
+    assert gm.padded_rows(131072, 64) == 131072 + 64 * T
+
+
+@pytest.mark.parametrize("interpret", [False, True], ids=["xla", "pallas"])
+@pytest.mark.parametrize("name", list(SIZES))
+def test_gmm_and_both_gradients_match_a_loop_over_groups(name, interpret):
+    sizes, starts, live, x, w, _used = _case(SIZES[name])
+    mask = jnp.asarray(live)[:, None]
+
+    def ours(x, w):
+        y = gm.gmm(x, w, jnp.asarray(sizes), interpret=interpret)
+        return jnp.where(mask, y, 0)        # the caller's part: see gmm
+
+    def loop(x, w):
+        return _loop(x, w, sizes, starts)
+
+    np.testing.assert_allclose(ours(x, w), loop(x, w), atol=2e-4)
+    cot = jnp.asarray(np.random.default_rng(1).standard_normal(
+        (x.shape[0], w.shape[2])), jnp.float32)
+    gx, gw = jax.grad(lambda x, w: jnp.sum(ours(x, w) * cot), (0, 1))(x, w)
+    rx, rw = jax.grad(lambda x, w: jnp.sum(loop(x, w) * cot), (0, 1))(x, w)
+    np.testing.assert_allclose(jnp.where(mask, gx, 0), rx, atol=2e-3)
+    np.testing.assert_allclose(gw, rw, atol=2e-3)
+    # an empty group's weights get a zero gradient, not a stale block
+    for g, n in enumerate(sizes):
+        if n == 0:
+            assert not np.asarray(gw[g]).any()
+
+
+def test_tiles_past_the_used_prefix_are_not_computed():
+    """The interpreter leaves what a kernel never writes as it was
+    handed out; rows past the prefix come back untouched by the
+    product (here: not the product of their garbage)."""
+    sizes, starts, live, x, w, used = _case(SIZES["mixed"], spare_tiles=4)
+    y = gm.gmm(x, w, jnp.asarray(sizes), interpret=True)
+    tail = np.asarray(y[used * T:])
+    would_be = np.asarray(x[used * T:] @ w[-1])
+    assert tail.shape[0] >= 4 * T
+    assert not np.allclose(tail, would_be, atol=1e-3)
+
+
+def test_rows_must_be_whole_tiles():
+    with pytest.raises(ValueError, match="multiple"):
+        gm.gmm(jnp.zeros((T + 1, 8)), jnp.zeros((2, 8, 8)),
+               jnp.asarray([1, 1], jnp.int32))

@@ -454,6 +454,10 @@ class TestRegistryCoverage:
         # recurrence one step at a time, values and every gradient;
         # against a grouped convolution)
         "selective_scan", "causal_conv1d",
+        # covered by tests/test_moe_ops.py (against the routed sum written
+        # out expert by expert, values and every gradient; the rotation
+        # pair by pair)
+        "moe_route", "moe_experts", "rope_rotate_half",
     }
 
     def test_coverage_accounting(self):

@@ -363,3 +363,148 @@ def test_the_jamba_step_says_which_paths_it_took(jamba_step):
                      "flash_causal": "fwd 136/256 of 256-wide tiles; "
                                      "bwd 136/256 of 256-wide tiles, "
                                      "dq whole"}
+
+
+# ---------------------------------------------------------------------------
+# Laguna: the window in the flash kernels, the grouped-matmul kernels and a
+# step with a window layer and a full layer over sparse feed-forwards
+# ---------------------------------------------------------------------------
+gmm = import_module("paddle_tpu.kernels.pallas.grouped_matmul")
+LAGUNA_SEQ, LAGUNA_KV, LAGUNA_WINDOW = 8192, 8, 512     # laguna-xs2-l5-e64
+
+
+@pytest.mark.parametrize("heads,window", [(64, LAGUNA_WINDOW), (48, None)])
+def test_flash_attention_at_lagunas_heads_compiles(v5e, heads, window):
+    """64 query heads on 8 under the window (a grid of the key blocks in
+    sight, the partials' slots zero-filled through an alias) and 48 on 8
+    without, one row of 8192."""
+    one = SingleDeviceSharding(v5e[0])
+    q = jax.ShapeDtypeStruct((1, LAGUNA_SEQ, heads, D), jnp.bfloat16,
+                             sharding=one)
+    k = jax.ShapeDtypeStruct((1, LAGUNA_SEQ, LAGUNA_KV, D), jnp.bfloat16,
+                             sharding=one)
+
+    def loss(q, k, v):
+        return fa._flash_core(q, k, v, None, True, D ** -0.5, True,
+                              window).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, k, k)
+    assert text.count("tpu_custom_call") == 2
+
+
+@pytest.mark.parametrize("K,N", [(2048, 1024), (512, 2048)])
+def test_grouped_matmul_fwd_and_both_gradients_compile(v5e, K, N):
+    """The expert products at Laguna-XS.2's widths, 64 held experts and
+    the worst case's rows: `moe_gmm` forward and to the rows,
+    `moe_gmm_dw`."""
+    one = SingleDeviceSharding(v5e[0])
+    rows = gmm.padded_rows(16384 * 8, 64)
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def loss(x, w, sizes):
+        _s, tile_group, used = gmm.group_layout(sizes, rows // gmm.ROW_TILE)
+        y = gmm._gmm(x, w, tile_group, used.reshape(1), True, False)
+        return y.astype(jnp.float32).sum()
+
+    text = _compiled_text(
+        jax.value_and_grad(loss, argnums=(0, 1)),
+        S((rows, K), jnp.bfloat16), S((64, K, N), jnp.bfloat16),
+        S((64,), jnp.int32))
+    # alone, XLA names a kernel after the transformation it came from
+    names = re.findall(r"%(\w*moe_gmm\w*?)[.\d]* = .*custom-call\(", text)
+    assert sorted("dw" in n for n in names) == [False, False, True], names
+    assert text.count("tpu_custom_call") == 3
+
+
+@pytest.fixture(scope="module")
+def laguna_step(v5e):
+    """A window layer and a full layer over sparse feed-forwards at
+    Laguna-XS.2's widths (8 of its 256 experts held, an eighth of the
+    slice of the vocabulary, 1 x 2048 tokens), the step written as
+    benchmarks/drivers/laguna_train_window.py writes it, compiled for one
+    described v5e: (text, compile record)."""
+    import paddle_tpu as pt
+    from paddle_tpu import amp
+    from paddle_tpu.jit import TrainStep
+    from paddle_tpu.models import GPTPretrainingCriterion
+    from paddle_tpu.models.laguna import LagunaConfig, LagunaForCausalLM
+    from paddle_tpu.observability import perf
+    from paddle_tpu.optimizer import AdamW
+    one = SingleDeviceSharding(v5e[0])
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
+
+    crit = GPTPretrainingCriterion()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("PADDLE_TPU_PALLAS_AUTOTUNE", "0")
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        was_on = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        pt.seed(0)
+        model = LagunaForCausalLM(LagunaConfig(
+            vocab_size=3136, num_hidden_layers=2,
+            layer_types=["sliding_attention", "full_attention"],
+            mlp_layer_types=["sparse", "sparse"],
+            num_attention_heads_per_layer=[64, 48], experts_held=(0, 8),
+            use_flash_attention=True, recompute=True))
+        model.train()
+
+        def loss_fn(m, ids, labels):
+            with amp.auto_cast(enable=True, level="O1", dtype="bfloat16"):
+                logits = m(ids)
+            return crit(logits, labels), m.expert_counts
+
+        step = TrainStep(model, AdamW(
+            learning_rate=1e-4, parameters=model.parameters(),
+            moment_dtype="bfloat16"), loss_fn, has_aux=True)
+        ids = jax.ShapeDtypeStruct((1, 2048), jnp.int32, sharding=one)
+        notes = {}
+        outer, perf._TRACE_NOTES.notes = perf._TRACE_NOTES.notes, notes
+        try:
+            compiled = step._step_fn.jit_fn.lower(
+                [spec(p) for p in step.params],
+                [{k: spec(v) for k, v in st.items()}
+                 for st in step.opt_states],
+                [spec(b) for b in step.buffers],
+                spec(jax.random.PRNGKey(0)), spec(jnp.float32(1e-4)),
+                [ids, ids], {}).compile()
+        finally:
+            perf._TRACE_NOTES.notes = outer
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        compilation_cache.reset_cache()
+    return compiled.as_text(), notes
+
+
+@pytest.mark.parametrize("kernel,calls", [
+    ("moe_gmm", 12),        # two products a layer: forward, again, to rows
+    ("moe_gmm_dw", 4),
+    ("flash_fwd", 4),
+    ("flash_bwd_transpose", 2)])
+def test_the_laguna_step_holds_its_mosaic_kernels(laguna_step, kernel,
+                                                  calls):
+    text, _notes = laguna_step
+    found = re.findall(rf"%{kernel}[.\d]* = .*custom-call\(", text)
+    assert len(found) == calls, (kernel, len(found))
+    assert text.count("tpu_custom_call") == 22
+
+
+def test_the_laguna_step_says_which_paths_it_took(laguna_step):
+    """The window layer's kernels visit 3 tiles a q block of the 8 and
+    hold 2 partial slots; the full layer's walk to the diagonal as ever;
+    the expert products take the kernels; the step hands the counts
+    out."""
+    _text, notes = laguna_step
+    assert notes == {
+        "attention_window": "layer 0: 512", "attention": "pallas",
+        "flash_operands": "split",
+        "flash_causal": "fwd 21/64 of 256-wide tiles, window 512; "
+                        "fwd 36/64 of 256-wide tiles; "
+                        "bwd 36/64 of 256-wide tiles, dq partials 2; "
+                        "bwd 21/64 of 256-wide tiles, dq partials 2, "
+                        "window 512",
+        "moe": "pallas, experts 8 held of 256, top 8, tiles of 128 rows",
+        "head_loss": "fused, chunks 1"}
