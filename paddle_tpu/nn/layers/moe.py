@@ -62,7 +62,12 @@ class SparseExpertFFN(Layer):
     Weights: `gate_up_proj` [count, hidden, 2 * width] (gate in the first
     `width` columns) and `down_proj` [count, width, hidden]. forward
     returns (y, counts): counts [count] int32, the assignments each held
-    expert got."""
+    expert got. On a TPU the expert products are the grouped-matmul
+    kernels and the way back to the tokens' order the kernel
+    `moe_sum_rows`, which moves the rows of the held assignments alone;
+    elsewhere `jax.lax.ragged_dot` and a gather over every slot
+    (`ops.moe_experts`). The `moe` note of the step's compile record
+    says which."""
 
     def __init__(self, hidden, width, num_experts=256, top_k=8, held=None,
                  shared_width=512, routed_scale=2.5, std=0.02):
@@ -83,12 +88,14 @@ class SparseExpertFFN(Layer):
 
     def forward(self, x):
         from ...kernels.pallas.grouped_matmul import ROW_TILE, gmm_path
+        from ...ops.moe_ops import way_back_path
         shape = x.shape
         flat = ops.reshape(x, (-1, shape[-1]))
         weights, experts = self.router(flat)
         perf.trace_note("moe", f"{gmm_path()}, experts {self.count} held "
                         f"of {self.num_experts}, top {self.top_k}, "
-                        f"tiles of {ROW_TILE} rows")
+                        f"tiles of {ROW_TILE} rows, way back: "
+                        f"{way_back_path()}")
         y, counts = ops.moe_experts(flat, weights, experts,
                                     self.gate_up_proj, self.down_proj,
                                     self.first)

@@ -9,9 +9,12 @@ is gathered into the experts' order, multiplied and activated follows
 the used prefix of the rows, which is known only on the device: the
 kernels skip the tiles past it and the XLA parts are loops over `_CHUNK`
 rows with a traced trip count (inside `jax.custom_vjp`s, so nothing
-differentiates through a loop). The way back to the tokens' order is a
-gather over all tokens x top_k slots (`_sum_slots`): the same cost
-whatever the load, and less than a scatter-add of the held rows.
+differentiates through a loop). The way back to the tokens' order
+(`_way_back`: `combine_rows` forward, `take_rows` backward) sums the rows
+that hold an assignment and moves no other: on a TPU the kernel
+`kernels/pallas/moe_sum_rows.py`, which fetches them in the 16-row
+windows the chip's DMAs take; elsewhere (the CPU tests) `_sum_slots`, a
+plain gather over all tokens x top_k slots.
 """
 from __future__ import annotations
 
@@ -21,6 +24,8 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels.pallas import grouped_matmul as _gm
+from ..kernels.pallas import moe_sum_rows as _sr
+from ..kernels.pallas.flash_attention import _pallas_available
 from .registry import register_op
 
 __all__ = ["moe_route", "moe_experts"]
@@ -73,7 +78,7 @@ _top_k.defvjp(_top_k_fwd, _top_k_bwd)
 
 
 # -- the permutation ----------------------------------------------------------
-def permutation(experts, first, count):
+def permutation(experts, first, count, tile=None):
     """Where each assignment to a held expert (first <= e < first + count)
     goes among the rows of `grouped_matmul.group_layout`, sorted by
     expert, slots of one expert in token order.
@@ -86,6 +91,9 @@ def permutation(experts, first, count):
       live_row [R]          the row holds an assignment
       row_of_slot [T * k]   the row an assignment went to, 0 if not held
       held_slot [T * k]     the assignment's expert is held here
+    and, with `tile` (tokens),
+      way_back              `moe_sum_rows.plan`: what the kernel of the
+                            way back reads
     R = grouped_matmul.padded_rows(T * k, count), rounded up to _CHUNK."""
     A = experts.size
     R = -(-_gm.padded_rows(A, count) // _CHUNK) * _CHUNK
@@ -99,8 +107,14 @@ def permutation(experts, first, count):
     slots = jnp.arange(A, dtype=jnp.int32)
     keys = jnp.sort(local * A + slots)
     order = keys % A                                # rank -> slot
-    counts = jnp.diff(jnp.searchsorted(
-        keys, jnp.arange(count + 1, dtype=jnp.int32) * A)).astype(jnp.int32)
+    # the rank at which each group's slots of each token tile begin (one
+    # tile without `tile`: where the group begins)
+    per_tile = A if tile is None else tile * experts.shape[1]
+    begins = jnp.searchsorted(keys, (
+        jnp.arange(count + 1, dtype=jnp.int32)[:, None] * A
+        + jnp.arange(0, A, per_tile, dtype=jnp.int32)[None]).reshape(-1)
+    ).astype(jnp.int32).reshape(count + 1, -1)
+    counts = jnp.diff(begins[:, 0])
     _, rank = jax.lax.sort((order, slots), num_keys=1)   # slot -> rank
     starts, tile_group, tiles_used = _gm.group_layout(
         counts, R // _gm.ROW_TILE)
@@ -116,8 +130,17 @@ def permutation(experts, first, count):
         live_row, order[jnp.minimum(of_row(first_rank) + within, A - 1)], 0)
     g = jnp.minimum(local, count - 1)
     row_of_slot = jnp.where(held, starts[g] + rank - first_rank[g], 0)
-    return dict(counts=counts, rows_used=rows_used, slot_of_row=slot_of_row,
-                live_row=live_row, row_of_slot=row_of_slot, held_slot=held)
+    p = dict(counts=counts, rows_used=rows_used, slot_of_row=slot_of_row,
+             live_row=live_row, row_of_slot=row_of_slot, held_slot=held)
+    if tile is not None:
+        # the first row of an expert that holds an assignment of a token
+        # >= i * tile: tile i's rows of expert g are [i, g] .. [i + 1, g]
+        before = begins[:count] - begins[:count, :1]    # [count, tiles]
+        tile_rows = jnp.concatenate(
+            [starts[None] + before.T, (starts + counts)[None]])
+        p["way_back"] = _sr.plan(*(a.reshape(experts.shape) for a in (
+            row_of_slot, held, g)), tile_rows, tile)
+    return p
 
 
 def _over_chunks(rows_used, body, init):
@@ -149,11 +172,9 @@ _TOKENS = 2048      # tokens whose top_k rows `_sum_slots` holds at once
 def _sum_slots(vals, row_of_slot, held_slot, k, scale=None):
     """out[t] = sum over token t's held assignments j of
     (scale[t * k + j] *) vals[row_of_slot[t * k + j]], in float32; out
-    [T, d]. The way back from the experts' order, as a gather: on the
-    chip XLA's scatter-add of a row takes six times a gathered row's
-    time, so that all tokens x k slots gathered (what is not held read
-    from row 0 and masked) cost less than the held rows scattered
-    (PERF.md, PR 31)."""
+    [T, d]. The way back from the experts' order off the TPU, and what
+    the kernel is tested against: a gather over all tokens x k slots,
+    what is not held read from row 0 and masked."""
     T = row_of_slot.shape[0] // k
     chunk = _TOKENS if T % _TOKENS == 0 else T
 
@@ -171,44 +192,62 @@ def _sum_slots(vals, row_of_slot, held_slot, k, scale=None):
     return parts.reshape(T, -1)
 
 
+def way_back_reads_held_rows_only() -> bool:
+    """Whether the way back of a program traced now is the kernel."""
+    return _pallas_available()
+
+
+def way_back_path() -> str:
+    """The form of the way back a program traced now takes."""
+    if way_back_reads_held_rows_only():
+        return f"held rows in windows of {_sr.WINDOW} (moe_sum_rows)"
+    return "all slots (gather)"
+
+
+def _way_back(vals, p, k, way, scale=None):
+    """out[t] = sum over token t's held assignments j, in slot order, of
+    (scale[t, j] *) float32(vals[row of (t, j)]), rounded once to vals's
+    type; out [T, d]. `way`: None for `_sum_slots`, or `sum_rows`'s
+    (tile, interpret), the tile `p["way_back"]` was made for."""
+    if way is None:
+        flat = None if scale is None else scale.reshape(-1)
+        return _sum_slots(vals, p["row_of_slot"], p["held_slot"], k,
+                          flat).astype(vals.dtype)
+    tile, interpret = way
+    return _sr.sum_rows(vals, p["way_back"], scale, tile=tile,
+                        out_dtype=vals.dtype, interpret=interpret)
+
+
 # rows of x in the experts' order, and back
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def take_rows(x, token_of_row, rows_used, row_of_slot, held_slot, k):
-    return _gather_rows(x, token_of_row, rows_used)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def take_rows(x, p, k, way):
+    return _gather_rows(x, p["slot_of_row"] // k, p["rows_used"])
 
 
-def _take_fwd(x, token_of_row, rows_used, row_of_slot, held_slot, k):
-    return (_gather_rows(x, token_of_row, rows_used),
-            (row_of_slot, held_slot))
+def _take_fwd(x, p, k, way):
+    return take_rows(x, p, k, way), p
 
 
-def _take_bwd(k, res, d_rows):
-    row_of_slot, held_slot = res
-    dx = _sum_slots(d_rows, row_of_slot, held_slot, k)
-    return dx.astype(d_rows.dtype), None, None, None, None
+def _take_bwd(k, way, p, d_rows):
+    return _way_back(d_rows, p, k, way), None
 
 
 take_rows.defvjp(_take_fwd, _take_bwd)
 
 
-@jax.custom_vjp
-def combine_rows(ys, weights, p):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def combine_rows(ys, weights, p, way):
     """out[t] = sum over token t's held assignments j of weights[t, j] *
     ys[row of (t, j)], in float32 and returned in ys's type; weights
     [T, k] float32, p the `permutation`."""
-    return _combine(ys, weights, p)
+    return _way_back(ys, p, weights.shape[1], way, weights)
 
 
-def _combine(ys, weights, p):
-    return _sum_slots(ys, p["row_of_slot"], p["held_slot"],
-                      weights.shape[1], weights.reshape(-1)).astype(ys.dtype)
+def _combine_fwd(ys, weights, p, way):
+    return combine_rows(ys, weights, p, way), (ys, weights, p)
 
 
-def _combine_fwd(ys, weights, p):
-    return _combine(ys, weights, p), (ys, weights, p)
-
-
-def _combine_bwd(res, dy):
+def _combine_bwd(way, res, dy):
     ys, weights, p = res
     k = weights.shape[1]
     flat = weights.reshape(-1)
@@ -295,17 +334,19 @@ def moe_experts(x, weights, experts, w_gate_up, w_down, first=0,
     dt = st.dtype.np_dtype if st.enabled else x.dtype
     xs = x.astype(dt)
     count = w_gate_up.shape[0]
-    k = experts.shape[1]
+    T, k = experts.shape
+    way = None
+    if interpret or way_back_reads_held_rows_only():
+        way = (_sr.token_tile(T, k, count, x.shape[1], dt), bool(interpret))
     with jax.named_scope("permute"):
-        p = permutation(experts, first, count)
+        p = permutation(experts, first, count, way and way[0])
         used = p["rows_used"]
-        rows = take_rows(xs, p["slot_of_row"] // k, used, p["row_of_slot"],
-                         p["held_slot"], k)
+        rows = take_rows(xs, p, k, way)
     with jax.named_scope("experts"):
         gate_up = _gm.gmm(rows, w_gate_up.astype(dt), p["counts"],
                           interpret=interpret)
         h = swiglu_rows(gate_up, used)
         ys = _gm.gmm(h, w_down.astype(dt), p["counts"], interpret=interpret)
     with jax.named_scope("combine"):
-        y = combine_rows(ys, weights.astype(F32), p)
+        y = combine_rows(ys, weights.astype(F32), p, way)
     return y.astype(x.dtype), p["counts"]

@@ -113,12 +113,18 @@ def test_the_composite_path_sees_the_same_window():
     np.testing.assert_allclose(outs[0], outs[1], atol=2e-5)
 
 
-def test_load_gauges_from_a_steps_counts():
+@pytest.mark.parametrize("backend,way_back", [("cpu", 1.0), ("tpu", 0.25)])
+def test_load_gauges_from_a_steps_counts(monkeypatch, backend, way_back):
+    """The way back reads every slot where it is the gather and the held
+    rows where it is the kernel (a TPU backend)."""
+    import jax
     from paddle_tpu.observability import metrics
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
     counts = np.array([[10, 30, 20, 20], [40, 0, 20, 20]], np.int32)
     got = observe_expert_load(counts, 320)
     assert got == {"moe.assignments_held": 0.25,
-                   "moe.load_max_over_mean": (1.5 + 2.0) / 2}
+                   "moe.load_max_over_mean": (1.5 + 2.0) / 2,
+                   "moe.way_back_rows_share": way_back}
     metrics.enable()
     try:
         observe_expert_load(counts, 320)
@@ -127,3 +133,4 @@ def test_load_gauges_from_a_steps_counts():
         metrics.disable()
     assert "paddle_tpu_moe_assignments_held 0.25" in text
     assert "paddle_tpu_moe_load_max_over_mean 1.75" in text
+    assert f"paddle_tpu_moe_way_back_rows_share {way_back:g}\n" in text

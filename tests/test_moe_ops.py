@@ -16,6 +16,7 @@ from paddle_tpu import nn, ops
 
 mo = import_module("paddle_tpu.ops.moe_ops")
 gm = import_module("paddle_tpu.kernels.pallas.grouped_matmul")
+sr = import_module("paddle_tpu.kernels.pallas.moe_sum_rows")
 
 
 def _silu(x):
@@ -89,8 +90,101 @@ def test_permutation_puts_every_held_assignment_in_its_experts_rows():
     assert (np.asarray(p["held_slot"]) == held).all()
 
 
+def _choices(rng, T, k, E):
+    return np.stack([rng.permutation(E)[:k] for _ in range(T)])
+
+
+def _none_and_all_eight(rng, T, k, E):
+    """Token 0 chooses the held experts 4..11 and token 1 none of them."""
+    experts = _choices(rng, T, k, E)
+    experts[0] = 4 + rng.permutation(8)
+    experts[1] = np.concatenate([rng.permutation(4),
+                                 12 + rng.permutation(E - 12)[:k - 4]])
+    return experts
+
+
+def _one_takes_every_token(rng, T, k, E):
+    others = np.stack([1 + rng.permutation(E - 1)[:k - 1] for _ in range(T)])
+    return np.concatenate([np.zeros((T, 1), np.int64), others], axis=1)
+
+
+# name: tokens, top_k, experts, held (first, count), token tile, rows' type,
+# with the routing weights, the choices, rows that hold nothing are NaN
+WAY_BACK = {
+    "a_quarter_held": (64, 8, 32, (0, 8), 16, "bfloat16", True, _choices,
+                       False),
+    "every_expert_held": (48, 4, 8, (0, 8), 16, "float32", True, _choices,
+                          False),
+    "one_expert_of_many": (64, 8, 64, (5, 1), 16, "bfloat16", False,
+                           _choices, False),
+    "a_token_with_none_and_one_with_all_eight": (
+        40, 8, 32, (4, 8), 8, "float32", True, _none_and_all_eight, False),
+    "one_expert_takes_every_token": (64, 4, 16, (0, 4), 16, "bfloat16", True,
+                                     _one_takes_every_token, False),
+    "tokens_no_multiple_of_the_tile": (37, 4, 16, (4, 8), 16, "float32",
+                                       True, _choices, False),
+    "without_weights_float32": (48, 8, 32, (8, 16), 16, "float32", False,
+                                _choices, False),
+    "what_was_never_fetched_is_poison": (64, 8, 32, (8, 8), 16, "bfloat16",
+                                         True, _choices, True),
+}
+
+
+@pytest.mark.parametrize("name", sorted(WAY_BACK))
+def test_the_way_back_kernel_sums_the_held_rows_as_sum_slots_does(name):
+    """`moe_sum_rows` under the interpreter (whose VMEM starts as NaN: a
+    row of the buffer that no DMA wrote would show) against the gather
+    over all slots and against the sum written out slot by slot."""
+    T, k, E, (first, count), tile, dtype, scaled, choose, poison = \
+        WAY_BACK[name]
+    rng = np.random.default_rng(sorted(WAY_BACK).index(name))
+    experts = jnp.asarray(choose(rng, T, k, E), jnp.int32)
+    p = mo.permutation(experts, first, count, tile)
+    live = np.asarray(p["live_row"])
+    vals = rng.standard_normal((live.shape[0], 128)).astype(np.float32)
+    vals = np.asarray(jnp.asarray(vals, dtype).astype(jnp.float32))
+    scale = rng.uniform(0.1, 1.0, (T, k)).astype(np.float32)
+    held = np.asarray(p["held_slot"]).reshape(T, k)
+    rows = np.asarray(p["row_of_slot"]).reshape(T, k)
+    want = np.zeros((T, 128), np.float32)
+    for j in range(k):      # slot order, float32
+        term = vals[rows[:, j]] * (scale[:, j, None] if scaled else 1)
+        want += np.where(held[:, j, None], term.astype(np.float32), 0)
+    if poison:
+        vals = np.where(live[:, None], vals, np.nan)
+    args = (jnp.asarray(vals, dtype), p, k)
+    w = jnp.asarray(scale) if scaled else None
+    got = mo._way_back(*args, (tile, True), w)
+    gather = mo._way_back(*args, None, w)
+    assert got.dtype == gather.dtype == jnp.dtype(dtype)
+    got, gather = (np.asarray(a, np.float32) for a in (got, gather))
+    assert np.isfinite(got).all()
+    tol = dict(atol=1e-6, rtol=1e-6) if dtype == "float32" \
+        else dict(atol=0, rtol=2 ** -8)     # one rounding to bf16
+    np.testing.assert_allclose(got, want, **tol)
+    np.testing.assert_allclose(got, gather, **tol)
+    fetched = int(np.asarray(p["way_back"]["count"]).sum()) * sr.WINDOW
+    assert held.sum() <= fetched <= held.sum() + 2 * sr.WINDOW * min(
+        count * -(-T // tile), max(int(held.sum()), 1))
+
+
+def test_the_token_tile_fits_its_buffers_and_a_block_of_scalars():
+    """512 tokens at the cell's shapes; fewer where 256 experts' windows
+    would not fit; never under the 1024 slots a block of scalars has."""
+    assert sr.token_tile(16384, 8, 64, 2048, jnp.bfloat16) == 512
+    assert sr.token_tile(16384, 8, 64, 2048, jnp.float32) == 256
+    assert sr.token_tile(16384, 8, 256, 2048, jnp.bfloat16) == 128
+    assert sr.token_tile(48, 4, 8, 32, jnp.float32) == 256
+    assert sr.token_tile(48, 1, 8, 32, jnp.float32) == 1024
+
+
+@pytest.mark.parametrize("interpret", [False, True], ids=["xla", "pallas"])
 @pytest.mark.parametrize("first,count", [(0, 16), (4, 8), (12, 4)])
-def test_moe_experts_is_the_routed_sum_and_so_are_its_gradients(first, count):
+def test_moe_experts_is_the_routed_sum_and_so_are_its_gradients(
+        first, count, interpret):
+    """`interpret`: the grouped-matmul kernels and the way back's kernel
+    (`combine_rows` forward, `take_rows` backward) under the
+    interpreter."""
     rng = np.random.default_rng(2)
     T, d, width, k = 48, 32, 16, 4
     x = jnp.asarray(rng.standard_normal((T, d)), jnp.float32)
@@ -100,7 +194,8 @@ def test_moe_experts_is_the_routed_sum_and_so_are_its_gradients(first, count):
 
     def ours(x, wr, w_gu, w_down):
         w, e = mo.moe_route.op_def.fn(x, wr, k, 2.5)
-        y, counts = mo.moe_experts.op_def.fn(x, w, e, w_gu, w_down, first)
+        y, counts = mo.moe_experts.op_def.fn(x, w, e, w_gu, w_down, first,
+                                             interpret)
         return jnp.sum(y * cot), (y, counts, e)
 
     def loop(x, wr, w_gu, w_down):

@@ -418,6 +418,36 @@ def test_grouped_matmul_fwd_and_both_gradients_compile(v5e, K, N):
     assert text.count("tpu_custom_call") == 3
 
 
+@pytest.mark.parametrize("scaled", [True, False],
+                         ids=["combine", "take_rows_backward"])
+def test_moe_sum_rows_compiles_at_the_cells_shapes(v5e, scaled):
+    """The way back of `laguna-xs2-l5-e64.train-8k`: 2 x 8192 tokens, top
+    8, 64 held experts, the rows of the worst case, bf16, in tiles of 512
+    tokens; with the routing weights (`combine_rows` forward) and
+    without (`take_rows` backward)."""
+    sr = import_module("paddle_tpu.kernels.pallas.moe_sum_rows")
+    one = SingleDeviceSharding(v5e[0])
+    T, k, G = 16384, 8, 64
+    tile = sr.token_tile(T, k, G, HIDDEN, jnp.bfloat16)
+    assert tile == 512
+    n = T // tile
+
+    def S(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    plan = dict(first=S((n, G)), count=S((n, G)), n_held=S((n,)),
+                before=S((T, k)), at=S((T, k)), rank=S((T, k)))
+    text = _compiled_text(
+        lambda vals, plan, scale: sr.sum_rows(
+            vals, plan, scale if scaled else None, tile=tile,
+            out_dtype=jnp.bfloat16),
+        S((gmm.padded_rows(T * k, G), HIDDEN), jnp.bfloat16), plan,
+        S((T, k), jnp.float32))
+    assert len(re.findall(r"%moe_sum_rows[.\d]* = .*custom-call\(",
+                          text)) == 1
+    assert text.count("tpu_custom_call") == 1
+
+
 @pytest.fixture(scope="module")
 def laguna_step(v5e):
     """A window layer and a full layer over sparse feed-forwards at
@@ -482,6 +512,7 @@ def laguna_step(v5e):
 @pytest.mark.parametrize("kernel,calls", [
     ("moe_gmm", 12),        # two products a layer: forward, again, to rows
     ("moe_gmm_dw", 4),
+    ("moe_sum_rows", 4),    # a layer: combine forward, take_rows backward
     ("flash_fwd", 4),
     ("flash_bwd_transpose", 2)])
 def test_the_laguna_step_holds_its_mosaic_kernels(laguna_step, kernel,
@@ -489,7 +520,7 @@ def test_the_laguna_step_holds_its_mosaic_kernels(laguna_step, kernel,
     text, _notes = laguna_step
     found = re.findall(rf"%{kernel}[.\d]* = .*custom-call\(", text)
     assert len(found) == calls, (kernel, len(found))
-    assert text.count("tpu_custom_call") == 22
+    assert text.count("tpu_custom_call") == 26
 
 
 def test_the_laguna_step_says_which_paths_it_took(laguna_step):
@@ -506,5 +537,17 @@ def test_the_laguna_step_says_which_paths_it_took(laguna_step):
                         "bwd 36/64 of 256-wide tiles, dq partials 2; "
                         "bwd 21/64 of 256-wide tiles, dq partials 2, "
                         "window 512",
-        "moe": "pallas, experts 8 held of 256, top 8, tiles of 128 rows",
+        "moe": "pallas, experts 8 held of 256, top 8, tiles of 128 rows, "
+               "way back: held rows in windows of 16 (moe_sum_rows)",
         "head_loss": "fused, chunks 1"}
+
+
+def test_the_laguna_steps_way_back_gathers_no_slot(laguna_step):
+    """Under `moe/combine` the forward is the kernel: no gather reads a
+    row a slot (2048 tokens x top 8) as `_sum_slots` does, in chunks of
+    its 2048 tokens or whole."""
+    text, _notes = laguna_step
+    gathers = [line for line in text.splitlines()
+               if " gather(" in line and "/moe/combine" in line]
+    assert not [g for g in gathers if re.search(r"\[16384,\d+\]", g)], \
+        gathers
