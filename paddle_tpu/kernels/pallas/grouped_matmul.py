@@ -20,6 +20,16 @@ empty group's tile of padding leaves its dw block zero, and a group
 boundary costs ROW_TILE / 2 rows of padding on average where a kernel
 over unaligned groups would compute the straddling tile twice.
 
+A weight block is a group's whole [K, N] where that is at most
+`_BLOCK_BYTES` (4 MiB: [2048, 1024] in bf16, the largest the thin-expert
+shapes have, two of which the pipeline holds beside the row tiles). A
+wider expert's is cut into column tiles of that size (`column_tile`),
+and the grid gains an outer axis over them: for one column tile the
+kernel walks all the row tiles, so a group's consecutive tiles still
+name one weight block and it is fetched once, and the rows are read
+once a column tile. `moe_gmm_dw`'s float32 accumulator is one column
+tile wide the same way.
+
 Shapes are static and sized by the caller for its worst case; the
 tiles past the used prefix are not visited: their grid steps name the
 last used tile's blocks (nothing is fetched) and do no work, and their
@@ -42,7 +52,32 @@ from .flash_attention import _pallas_available
 
 ROW_TILE = 128
 _VMEM_LIMIT = 100 * 1024 * 1024
+_BLOCK_BYTES = 4 * 1024 * 1024
 F32 = jnp.float32
+
+
+def column_tile(contract, cols, itemsize):
+    """Columns of a weight block [contract, columns]: all `cols` where
+    that is at most `_BLOCK_BYTES`, else the largest multiple of 128
+    that divides `cols` and keeps the block within it (128 at least)."""
+    if contract * cols * itemsize <= _BLOCK_BYTES or cols % 128:
+        return cols
+    best = 128
+    for t in range(128, cols, 128):
+        if cols % t == 0 and contract * t * itemsize <= _BLOCK_BYTES:
+            best = t
+    return best
+
+
+def tiles_note(gate_up_shape, itemsize=2):
+    """For the `moe` note: how the two products' weight blocks are cut
+    into column tiles, or nothing where each is a group's whole."""
+    _g, hidden, two_wide = gate_up_shape
+    cut = (column_tile(hidden, two_wide, itemsize),
+           column_tile(two_wide // 2, hidden, itemsize))
+    if cut == (two_wide, hidden):
+        return ""
+    return f", weight blocks in column tiles of {cut[0]} and {cut[1]}"
 
 
 def group_layout(group_sizes, n_tiles, row_tile=ROW_TILE):
@@ -67,10 +102,11 @@ def padded_rows(assignments, groups, row_tile=ROW_TILE):
 
 # ======================= kernels =======================
 
-def _gmm_kernel(group_ref, used_ref, x_ref, w_ref, o_ref, *, transpose_w):
+def _gmm_kernel(group_ref, used_ref, x_ref, w_ref, o_ref, *, transpose_w,
+                row_axis):
     del group_ref
 
-    @pl.when(pl.program_id(0) < used_ref[0])
+    @pl.when(pl.program_id(row_axis) < used_ref[0])
     def _():
         contract = (((1,), (1 if transpose_w else 0,)), ((), ()))
         o_ref[:] = jax.lax.dot_general(
@@ -78,8 +114,9 @@ def _gmm_kernel(group_ref, used_ref, x_ref, w_ref, o_ref, *, transpose_w):
             preferred_element_type=F32).astype(o_ref.dtype)
 
 
-def _dw_kernel(group_ref, used_ref, x_ref, dy_ref, dw_ref, acc_ref):
-    i = pl.program_id(0)
+def _dw_kernel(group_ref, used_ref, x_ref, dy_ref, dw_ref, acc_ref, *,
+               row_axis):
+    i = pl.program_id(row_axis)
     last = used_ref[0] - 1
     here = group_ref[jnp.minimum(i, last)]
 
@@ -103,28 +140,54 @@ def _tile(i, used_ref):
     return jnp.minimum(i, used_ref[0] - 1)
 
 
+def _grid(n_tiles, n_cols):
+    """(grid, semantics, ij): the row tiles alone where a weight block
+    is a group's whole (the program the thin-expert shapes have always
+    had), else the column tiles outside them; `ij(index map arguments)`
+    -> (column tile, row tile, group table, used)."""
+    if n_cols == 1:
+        return ((n_tiles,), ("arbitrary",),
+                lambda i, g, u: (0, i, g, u))
+    return ((n_cols, n_tiles), ("arbitrary", "arbitrary"),
+            lambda j, i, g, u: (j, i, g, u))
+
+
 @functools.partial(jax.jit, static_argnames=("transpose_w", "interpret"),
                    inline=True)
 def _gmm_call(x, w, tile_group, used, *, transpose_w, interpret):
     R, K = x.shape
     N = w.shape[1] if transpose_w else w.shape[2]
+    cols = column_tile(K, N, x.dtype.itemsize)
+    grid, semantics, ij = _grid(R // ROW_TILE, N // cols)
+
+    def rows(*a):
+        _j, i, _g, u = ij(*a)
+        return _tile(i, u), 0
+
+    def weight(*a):
+        j, i, g, _u = ij(*a)
+        return (g[i], j, 0) if transpose_w else (g[i], 0, j)
+
+    def out(*a):
+        j, i, _g, u = ij(*a)
+        return _tile(i, u), j
+
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, transpose_w=transpose_w),
+        functools.partial(_gmm_kernel, transpose_w=transpose_w,
+                          row_axis=len(grid) - 1),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(R // ROW_TILE,),
+            grid=grid,
             in_specs=[
-                pl.BlockSpec((ROW_TILE, K),
-                             lambda i, g, u: (_tile(i, u), 0)),
-                pl.BlockSpec((1,) + w.shape[1:],
-                             lambda i, g, u: (g[i], 0, 0)),
+                pl.BlockSpec((ROW_TILE, K), rows),
+                pl.BlockSpec((1, cols, K) if transpose_w else (1, K, cols),
+                             weight),
             ],
-            out_specs=pl.BlockSpec((ROW_TILE, N),
-                                   lambda i, g, u: (_tile(i, u), 0)),
+            out_specs=pl.BlockSpec((ROW_TILE, cols), out),
         ),
         out_shape=jax.ShapeDtypeStruct((R, N), x.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
+            dimension_semantics=semantics,
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="moe_gmm",     # also the innermost jax.named_scope
@@ -136,26 +199,38 @@ def _gmm_call(x, w, tile_group, used, *, transpose_w, interpret):
 def _dw_call(x, dy, tile_group, used, *, groups, interpret):
     R, K = x.shape
     N = dy.shape[1]
+    cols = column_tile(K, N, x.dtype.itemsize)
+    grid, semantics, ij = _grid(R // ROW_TILE, N // cols)
     # one entry past the table's end for the kernel's look at tile i + 1
     table = jnp.concatenate([tile_group, tile_group[-1:]])
+
+    def rows(*a):
+        _j, i, _g, u = ij(*a)
+        return _tile(i, u), 0
+
+    def grads(*a):
+        j, i, _g, u = ij(*a)
+        return _tile(i, u), j
+
+    def out(*a):
+        j, i, g, _u = ij(*a)
+        return g[i], 0, j
+
     return pl.pallas_call(
-        _dw_kernel,
+        functools.partial(_dw_kernel, row_axis=len(grid) - 1),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(R // ROW_TILE,),
+            grid=grid,
             in_specs=[
-                pl.BlockSpec((ROW_TILE, K),
-                             lambda i, g, u: (_tile(i, u), 0)),
-                pl.BlockSpec((ROW_TILE, N),
-                             lambda i, g, u: (_tile(i, u), 0)),
+                pl.BlockSpec((ROW_TILE, K), rows),
+                pl.BlockSpec((ROW_TILE, cols), grads),
             ],
-            out_specs=pl.BlockSpec((1, K, N),
-                                   lambda i, g, u: (g[i], 0, 0)),
-            scratch_shapes=[pltpu.VMEM((K, N), F32)],
+            out_specs=pl.BlockSpec((1, K, cols), out),
+            scratch_shapes=[pltpu.VMEM((K, cols), F32)],
         ),
         out_shape=jax.ShapeDtypeStruct((groups, K, N), x.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
+            dimension_semantics=semantics,
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="moe_gmm_dw",

@@ -1,4 +1,4 @@
-"""Flagship model families (GPT / LLaMA / Jamba / Laguna / BERT).
+"""Flagship model families (GPT / LLaMA / Jamba / Laguna / ZAYA1 / BERT).
 
 The reference keeps language models out-of-tree (PaddleNLP) but its
 north-star benchmarks are GPT-3/LLaMA hybrid-parallel training
@@ -21,6 +21,9 @@ from .jamba import (  # noqa: F401
 from .laguna import (  # noqa: F401
     LagunaConfig, LagunaModel, LagunaForCausalLM, LagunaAttention,
     LagunaDecoderLayer, laguna_tiny, observe_expert_load,
+)
+from .zaya import (  # noqa: F401
+    ZayaConfig, ZayaModel, ZayaForCausalLM, ZayaDecoderLayer, zaya_tiny,
 )
 from .bert import (  # noqa: F401
     BertConfig, BertModel, BertForMaskedLM, bert_tiny, bert_base,

@@ -300,12 +300,14 @@ class GPTPretrainingCriterion(Layer):
         else:
             mask = ops.cast(ops.reshape(loss_mask, (n,)), "float32")
             weight = mask / ops.maximum(ops.sum(mask), 1e-6)
-        chunks = nn_ops._lce_plan(n, nn_ops.LCE_CHUNK)[0]
+        vocab = head.weight.shape[0 if head.transpose_y else 1]
+        chunk = nn_ops.lce_chunk(vocab)
+        chunks = nn_ops._lce_plan(n, chunk)[0]
         perf.trace_note("head_loss", f"fused, chunks {chunks}")
         with auto_cast(enable=False), traced_scope("lm_head"):
             return ops.linear_cross_entropy(
                 hidden, head.weight, ops.reshape(labels, (n,)), weight,
-                transpose_y=head.transpose_y)
+                transpose_y=head.transpose_y, chunk=chunk)
 
 
 def num_params(config: GPTConfig) -> int:

@@ -42,9 +42,9 @@ from ..nn.initializer import Normal
 from ..nn.layer import Layer, traced_scope
 from ..nn.layers.common import Embedding, Linear
 from ..nn.layers.container import LayerList
-from ..nn.layers.moe import SparseExpertFFN, SwiGLU, rope_tables
+from ..nn.layers.moe import (SparseExpertFFN, SwiGLU,  # noqa: F401
+                             observe_expert_load, rope_tables)
 from ..nn.layers.norm import RMSNorm
-from ..observability import metrics as _metrics
 from ..observability import perf
 from . import lm_head as _lm_head
 
@@ -312,41 +312,3 @@ class LagunaForCausalLM(Layer):
         return _lm_head.causal_lm_logits(
             self.training, hidden, self.laguna.embed_tokens.weight,
             self.lm_head)
-
-
-def observe_expert_load(counts, assignments: int) -> dict:
-    """From a step's counts (a numpy array [sparse layers, held experts]
-    the caller read with the loss: the step's program made them) the
-    gauges
-    `moe.assignments_held` (the share of all tokens x top_k assignments
-    that went to experts held here, a layer's mean),
-    `moe.load_max_over_mean` (the busiest held expert's assignments over
-    the mean, the layers' mean) and
-    `moe.way_back_rows_share` (the rows the way back to the tokens' order
-    reads into its sums, over tokens x top_k: the held assignments where
-    the kernel `moe_sum_rows` is the path, every slot where the gather
-    `_sum_slots` is). Returns the three; sets the gauges where metrics
-    are enabled."""
-    import numpy as np
-    from ..ops.moe_ops import way_back_reads_held_rows_only
-    c = counts.astype(np.float64)
-    held = float(c.sum(axis=1).mean() / assignments)
-    mean = np.maximum(c.mean(axis=1), 1e-9)
-    peak = float((c.max(axis=1) / mean).mean())
-    back = held if way_back_reads_held_rows_only() else 1.0
-    if _metrics._ENABLED:
-        reg = _metrics.registry()
-        reg.gauge("paddle_tpu_moe_assignments_held",
-                  "share of a step's tokens x top_k assignments routed to "
-                  "experts this process holds (moe.assignments_held)"
-                  ).set(held)
-        reg.gauge("paddle_tpu_moe_load_max_over_mean",
-                  "busiest held expert's assignments over the mean held "
-                  "expert's, mean over sparse layers "
-                  "(moe.load_max_over_mean)").set(peak)
-        reg.gauge("paddle_tpu_moe_way_back_rows_share",
-                  "rows the way back from the experts' order reads into "
-                  "its sums over tokens x top_k (moe.way_back_rows_share)"
-                  ).set(back)
-    return {"moe.assignments_held": held, "moe.load_max_over_mean": peak,
-            "moe.way_back_rows_share": back}

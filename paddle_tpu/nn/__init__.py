@@ -19,8 +19,10 @@ from .layers.common import (  # noqa: F401
     PixelShuffle, PixelUnshuffle, ChannelShuffle, Unfold,
 )
 from .layers.moe import (  # noqa: F401
-    SparseExpertFFN, SwiGLU, rope_tables, yarn_inv_freq,
+    SparseExpertFFN, SwiGLU, observe_expert_load, rope_tables,
+    yarn_inv_freq,
 )
+from .layers.cca import CompressedConvAttention, ResidualScale  # noqa: F401
 from .layers.conv import (  # noqa: F401
     Conv1D, Conv2D, Conv3D, Conv2DTranspose, Conv1DTranspose,
     Conv3DTranspose,

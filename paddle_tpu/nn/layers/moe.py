@@ -1,6 +1,7 @@
 """A sparse (mixture-of-experts) feed-forward that is told which experts
-it holds, and the rotary tables of layers that rotate part of a head or
-stretch their frequencies (YaRN)."""
+it holds, its two routers, the load gauges of a step's counts, and the
+rotary tables of layers that rotate part of a head or stretch their
+frequencies (YaRN)."""
 from __future__ import annotations
 
 import math
@@ -9,14 +10,16 @@ import jax.numpy as jnp
 import numpy as np
 
 from ... import ops
+from ...observability import metrics as _metrics
 from ...observability import perf
-from ..initializer import Normal
+from ..initializer import Constant, Normal
 from ..layer import Layer
 from .common import Linear
 
 
 class _Router(Layer):
     """scores -> the chosen experts and their weights (`ops.moe_route`)."""
+    carries_state = False
 
     def __init__(self, hidden, num_experts, top_k, routed_scale, std):
         super().__init__()
@@ -26,6 +29,31 @@ class _Router(Layer):
 
     def forward(self, x):
         return ops.moe_route(x, self.weight, self.top_k, self.routed_scale)
+
+
+class _MLPRouter(Layer):
+    """A down-projection whose output also runs from layer to layer
+    (depth averaging), an MLP, softmax, one expert a token
+    (`ops.moe_route_mlp`). forward(x, state) -> (weights, experts, the
+    state to hand to the next layer's router)."""
+    carries_state = True
+    top_k = 1
+
+    def __init__(self, hidden, num_experts, width, draws):
+        super().__init__()
+        def make(shape, std):
+            return self.create_parameter(shape, attr=Normal(std=std))
+        down, inner, out = draws
+        self.down_proj = make((hidden, width), down)
+        self.eda_scale = self.create_parameter(
+            (1,), default_initializer=Constant(1.0))
+        self.fc1 = make((width, width), inner)
+        self.fc2 = make((width, width), inner)
+        self.fc3 = make((width, num_experts), out)
+
+    def forward(self, x, state):
+        return ops.moe_route_mlp(x, state, self.down_proj, self.eda_scale,
+                                 self.fc1, self.fc2, self.fc3)
 
 
 class SwiGLU(Layer):
@@ -47,9 +75,18 @@ class SwiGLU(Layer):
 
 class SparseExpertFFN(Layer):
     """y = sum over a token's top_k experts e of w_e * SwiGLU_e(x)
-           + SwiGLU_shared(x),     w = routed_scale * s / sum_chosen s,
-    s = sigmoid(x W_r): dropless (no capacity; every assignment is
-    computed, whatever the imbalance).
+           + SwiGLU_shared(x): dropless (no capacity; every assignment is
+    computed, whatever the imbalance). Two routers exist:
+
+    * the linear sigmoid one (`ops.moe_route`; `laguna-xs2-l5-e64`):
+      w = routed_scale * s / sum_chosen s, s = sigmoid(x W_r), the top_k
+      largest chosen;
+    * `router_mlp=(width, draws)`: the MLP one (`ops.moe_route_mlp`;
+      `zaya1-8b-l5-e8`): a down-projection to `width` to which the
+      layer before's is added (depth averaging), two hidden layers,
+      softmax, the one largest chosen and w its probability. forward
+      then takes and returns the router's state: `(x, state) ->
+      (y, counts, state, weights, experts)`.
 
     `held = (first, count)`: the experts this layer's weights are, of
     `num_experts`. The router keeps its `num_experts` outputs and routes
@@ -70,15 +107,23 @@ class SparseExpertFFN(Layer):
     says which."""
 
     def __init__(self, hidden, width, num_experts=256, top_k=8, held=None,
-                 shared_width=512, routed_scale=2.5, std=0.02):
+                 shared_width=512, routed_scale=2.5, std=0.02,
+                 router_mlp=None):
         super().__init__()
         first, count = held or (0, num_experts)
         if first < 0 or count < 1 or first + count > num_experts:
             raise ValueError(f"held {held} is no range of {num_experts} "
                              "experts")
-        self.num_experts, self.top_k = num_experts, top_k
+        self.num_experts = num_experts
         self.first, self.count = first, count
-        self.router = _Router(hidden, num_experts, top_k, routed_scale, std)
+        if router_mlp is None:
+            self.router = _Router(hidden, num_experts, top_k, routed_scale,
+                                  std)
+        else:
+            if top_k != 1:
+                raise ValueError("the MLP router chooses one expert")
+            self.router = _MLPRouter(hidden, num_experts, *router_mlp)
+        self.top_k = top_k
         self.gate_up_proj = self.create_parameter(
             (count, hidden, 2 * width), attr=Normal(std=std))
         self.down_proj = self.create_parameter(
@@ -86,15 +131,23 @@ class SparseExpertFFN(Layer):
         self.shared_expert = SwiGLU(hidden, shared_width, std) \
             if shared_width else None
 
-    def forward(self, x):
-        from ...kernels.pallas.grouped_matmul import ROW_TILE, gmm_path
+    def forward(self, x, state=None):
+        from ...kernels.pallas.grouped_matmul import (ROW_TILE, gmm_path,
+                                                      tiles_note)
         from ...ops.moe_ops import way_back_path
         shape = x.shape
         flat = ops.reshape(x, (-1, shape[-1]))
-        weights, experts = self.router(flat)
+        carries = self.router.carries_state
+        if carries:
+            weights, experts, state = self.router(
+                flat, ops.reshape(state, (-1, state.shape[-1])))
+            state = ops.reshape(state, tuple(shape[:-1]) + (-1,))
+        else:
+            weights, experts = self.router(flat)
         perf.trace_note("moe", f"{gmm_path()}, experts {self.count} held "
                         f"of {self.num_experts}, top {self.top_k}, "
-                        f"tiles of {ROW_TILE} rows, way back: "
+                        f"tiles of {ROW_TILE} rows"
+                        f"{tiles_note(self.gate_up_proj.shape)}, way back: "
                         f"{way_back_path()}")
         y, counts = ops.moe_experts(flat, weights, experts,
                                     self.gate_up_proj, self.down_proj,
@@ -102,7 +155,55 @@ class SparseExpertFFN(Layer):
         y = ops.reshape(y, shape)
         if self.shared_expert is not None:
             y = y + self.shared_expert(x)
-        return y, counts
+        return (y, counts, state, weights, experts) if carries \
+            else (y, counts)
+
+
+def observe_expert_load(counts, assignments: int, top_weight=None) -> dict:
+    """From a step's counts (a numpy array [sparse layers, held experts]
+    the caller read with the loss: the step's program made them) the
+    gauges
+    `moe.assignments_held` (the share of all tokens x top_k assignments
+    that went to experts held here, a layer's mean),
+    `moe.load_max_over_mean` (the busiest held expert's assignments over
+    the mean, the layers' mean),
+    `moe.way_back_rows_share` (the rows the way back to the tokens' order
+    reads into its sums, over tokens x top_k: the held assignments where
+    the kernel `moe_sum_rows` is the path, every slot where the gather
+    `_sum_slots` is) and, with `top_weight` (a one-choice router's mean
+    chosen probability a layer, [sparse layers]),
+    `moe.top1_weight_mean` (their mean: 1 / num_experts says the router
+    is flat). Returns them; sets the gauges where metrics are enabled."""
+    from ...ops.moe_ops import way_back_reads_held_rows_only
+    c = counts.astype(np.float64)
+    held = float(c.sum(axis=1).mean() / assignments)
+    mean = np.maximum(c.mean(axis=1), 1e-9)
+    peak = float((c.max(axis=1) / mean).mean())
+    back = held if way_back_reads_held_rows_only() else 1.0
+    out = {"moe.assignments_held": held, "moe.load_max_over_mean": peak,
+           "moe.way_back_rows_share": back}
+    if top_weight is not None:
+        out["moe.top1_weight_mean"] = float(np.mean(top_weight))
+    if _metrics._ENABLED:
+        reg = _metrics.registry()
+        reg.gauge("paddle_tpu_moe_assignments_held",
+                  "share of a step's tokens x top_k assignments routed to "
+                  "experts this process holds (moe.assignments_held)"
+                  ).set(held)
+        reg.gauge("paddle_tpu_moe_load_max_over_mean",
+                  "busiest held expert's assignments over the mean held "
+                  "expert's, mean over sparse layers "
+                  "(moe.load_max_over_mean)").set(peak)
+        reg.gauge("paddle_tpu_moe_way_back_rows_share",
+                  "rows the way back from the experts' order reads into "
+                  "its sums over tokens x top_k (moe.way_back_rows_share)"
+                  ).set(back)
+        if top_weight is not None:
+            reg.gauge("paddle_tpu_moe_top1_weight_mean",
+                      "mean probability of the one expert a token chose, "
+                      "mean over sparse layers (moe.top1_weight_mean)"
+                      ).set(out["moe.top1_weight_mean"])
+    return out
 
 
 # -- rotary tables ------------------------------------------------------------

@@ -28,7 +28,7 @@ from ..kernels.pallas import moe_sum_rows as _sr
 from ..kernels.pallas.flash_attention import _pallas_available
 from .registry import register_op
 
-__all__ = ["moe_route", "moe_experts"]
+__all__ = ["moe_route", "moe_route_mlp", "moe_experts"]
 
 _CHUNK = 2048       # rows a loop iteration gathers or activates
 F32 = jnp.float32
@@ -41,13 +41,44 @@ def moe_route(x, router_weight, top_k, routed_scale=1.0):
     product at `highest` precision: a TPU's default rounds a float32
     matmul's operands to bf16, and a top-k choice turns on the last
     digits), the top_k largest chosen, their scores normalised to one
-    and multiplied by routed_scale. On amp's black list."""
+    and multiplied by routed_scale. On amp's black list. The router of
+    `laguna-xs2-l5-e64`; `zaya1-8b-l5-e8`'s is `moe_route_mlp`."""
     scores = jax.nn.sigmoid(jnp.matmul(
         x.astype(F32), router_weight.astype(F32),
         precision=jax.lax.Precision.HIGHEST))
     top, experts = _top_k(scores, top_k)
     weights = top * (routed_scale / jnp.sum(top, axis=-1, keepdims=True))
     return weights, experts
+
+
+@register_op("moe_route_mlp", amp_policy="black")
+def moe_route_mlp(x, state, w_down, state_scale, w1, w2, w3):
+    """The `zaya` router: a down-projection, depth averaging, a
+    two-hidden-layer MLP, softmax, one expert a token.
+
+    x [T, d], state [T, R] (the router's state of the layer before,
+    zero where there is none), w_down [d, R], state_scale [1],
+    w1, w2 [R, R], w3 [R, E] ->
+    (weights [T, 1] float32, experts [T, 1] int32, state [T, R] float32):
+
+        r = x W_down + state_scale * state          (the state handed on)
+        p = softmax(gelu(gelu(r W_1) W_2) W_3);  e = argmax p;  w = p_e
+
+    The weight is the chosen probability itself, not normalised over the
+    chosen (it would be 1). All of it in float32 with the products at
+    `highest` precision, as `moe_route`: one choice a token decides the
+    token's whole routed output, and it turns on the last digits where
+    the two largest probabilities nearly tie. On amp's black list."""
+    hi = jax.lax.Precision.HIGHEST
+    r = jnp.matmul(x.astype(F32), w_down.astype(F32), precision=hi) \
+        + state_scale.astype(F32) * state.astype(F32)
+    h = jax.nn.gelu(jnp.matmul(r, w1.astype(F32), precision=hi),
+                    approximate=False)
+    h = jax.nn.gelu(jnp.matmul(h, w2.astype(F32), precision=hi),
+                    approximate=False)
+    p = jax.nn.softmax(jnp.matmul(h, w3.astype(F32), precision=hi), axis=-1)
+    experts = jnp.argmax(p, axis=-1).astype(jnp.int32)[:, None]
+    return jnp.max(p, axis=-1, keepdims=True), experts, r
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))

@@ -731,6 +731,21 @@ def cross_entropy(input, label, weight=None, ignore_index=-100,
 # the chunk's bf16 logits are 412 MB at a 50304-wide vocabulary,
 # whatever the hidden size.
 LCE_CHUNK = 4096
+# A head wider than that takes fewer tokens a chunk (half at 131072 rows
+# and more, a quarter at 262144), 1024 at least, where the accumulator
+# costs as much as one of the chunk's three matmuls.
+LCE_WIDEST = 65536
+# ... and more chunks than this run one after the other in a loop: in
+# line, the step's program for a 131136-row tied head kept every chunk's
+# bf16 logits alive at once (nine of 1 GiB at 4096 tokens, thirty-two of
+# 256 MiB at 1024) and did not fit a v5e; as a loop it holds one chunk's
+# (PERF.md, PR 35). Up to here they stay in line (see `_lce_run`).
+LCE_INLINE_CHUNKS = 4
+
+
+def lce_chunk(vocab: int) -> int:
+    """Tokens in a chunk for a head `vocab` wide."""
+    return max(1024, LCE_CHUNK // max(1, vocab // LCE_WIDEST))
 
 
 def _lce_plan(n, chunk):
@@ -802,13 +817,14 @@ def _lce_run(hidden, weight, label, token_weight, transpose_y,
             dw_acc = dw_acc + dw
         return dw_acc, (ce, dh)
 
-    # unrolled: as a `while` the GPT-3 1.3B step does not fit a v5e
+    # a few chunks unrolled: as a `while` the GPT-3 1.3B step does not fit a v5e
     # (the float32 dW would have to live across the whole backward; in
     # line, XLA may compute a chunk's share of it late from logits made
     # again, as it does with whole logits under the same pressure), and
     # where nothing presses it was no faster (PERF.md, PR 26)
     dw0 = jnp.zeros(weight.shape, jnp.float32) if with_grads else None
-    dw, (ce, dh) = jax.lax.scan(body, dw0, xs, unroll=True)
+    dw, (ce, dh) = jax.lax.scan(body, dw0, xs,
+                                unroll=k <= LCE_INLINE_CHUNKS or 1)
     ce = jnp.where(valid, ce.reshape(k * c)[:n], 0.0)
     loss = jnp.sum(ce * token_weight.astype(jnp.float32))
     if not with_grads:

@@ -315,3 +315,10 @@ def test_under_a_mesh_the_step_takes_the_whole_path():
     (fused, _p), rec = _record_of(plain)
     assert rec["head_loss"] == "fused, chunks 1"
     np.testing.assert_allclose(sharded, fused, rtol=2e-5)
+
+
+@pytest.mark.parametrize("vocab,want", [
+    (25088, 4096), (50304, 4096), (65536, 4096),    # the cells that ran so
+    (100352, 4096), (131136, 2048), (262272, 1024)])
+def test_a_wider_head_takes_fewer_tokens_a_chunk(vocab, want):
+    assert nn_ops.lce_chunk(vocab) == want

@@ -95,3 +95,55 @@ def test_rows_must_be_whole_tiles():
     with pytest.raises(ValueError, match="multiple"):
         gm.gmm(jnp.zeros((T + 1, 8)), jnp.zeros((2, 8, 8)),
                jnp.asarray([1, 1], jnp.int32))
+
+
+@pytest.mark.parametrize("K,N,tiles", [
+    (2048, 4096, (4, 4, 4)),    # zaya1-8b's gate_up: 16 MB a group
+    (2048, 2048, (2, 2, 2)),    # ... and its down
+    (2048, 1024, (1, 1, 1)),    # laguna-xs2's: a group's whole block,
+    (512, 2048, (1, 1, 1)),     # the grid over the row tiles alone
+])
+def test_wide_weight_blocks_are_cut_into_column_tiles(K, N, tiles):
+    """bf16 at the two cells' widths, the kernels run by the
+    interpreter: the product, its gradient to the rows (the weight's
+    other axis cut) and `moe_gmm_dw` (its accumulator one column tile
+    wide) against a loop over the groups on the same rounded operands."""
+    assert (N // gm.column_tile(K, N, 2), K // gm.column_tile(N, K, 2),
+            N // gm.column_tile(K, N, 2)) == tiles
+    sizes, starts, live, x, w, _used = _case([130, 0, 5], K=K, N=N,
+                                             spare_tiles=1)
+    x, w = x.astype(jnp.bfloat16), (w / np.sqrt(K)).astype(jnp.bfloat16)
+    mask = jnp.asarray(live)[:, None]
+
+    def ours(x, w):
+        y = gm.gmm(x, w, jnp.asarray(sizes), interpret=True)
+        return jnp.where(mask, y, 0).astype(jnp.float32)
+
+    def loop(x, w):
+        return _loop(x.astype(jnp.float32), w.astype(jnp.float32), sizes,
+                     starts)
+
+    np.testing.assert_allclose(ours(x, w), loop(x, w), atol=3e-2)
+    cot = jnp.asarray(np.random.default_rng(1).standard_normal(
+        (x.shape[0], N)), jnp.bfloat16).astype(jnp.float32)
+    gx, gw = jax.grad(lambda x, w: jnp.sum(ours(x, w) * cot), (0, 1))(x, w)
+    rx, rw = jax.grad(lambda x, w: jnp.sum(loop(x, w) * cot), (0, 1))(x, w)
+    scale = float(jnp.abs(rx).max())
+    np.testing.assert_allclose(
+        jnp.where(mask, gx, 0).astype(jnp.float32) / scale,
+        rx.astype(jnp.float32) / scale, atol=2e-2)
+    scale = float(jnp.abs(rw).max())
+    np.testing.assert_allclose(gw.astype(jnp.float32) / scale,
+                               rw.astype(jnp.float32) / scale, atol=2e-2)
+    assert not np.asarray(gw[1]).any()      # the empty group's block
+
+
+def test_column_tile_keeps_a_block_within_four_mebibytes():
+    assert gm.column_tile(2048, 4096, 2) == 1024
+    assert gm.column_tile(4096, 2048, 2) == 512
+    assert gm.column_tile(2048, 1024, 2) == 1024    # whole
+    assert gm.column_tile(64, 128, 4) == 128
+    assert gm.column_tile(4096, 1000, 4) == 1000    # no multiple of 128
+    assert gm.tiles_note((8, 2048, 1024)) == ""
+    assert gm.tiles_note((8, 2048, 4096)) == (
+        ", weight blocks in column tiles of 1024 and 1024")

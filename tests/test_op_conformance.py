@@ -458,6 +458,11 @@ class TestRegistryCoverage:
         # out expert by expert, values and every gradient; the rotation
         # pair by pair)
         "moe_route", "moe_experts", "rope_rotate_half",
+        # covered by tests/test_zaya_model.py (the router against its
+        # equations; the convolutions position by position, the mean, the
+        # norms, the shifted head) and benchmarks/tests/test_zaya.py
+        # (every gradient against the plain reference)
+        "moe_route_mlp", "cca_mix",
     }
 
     def test_coverage_accounting(self):

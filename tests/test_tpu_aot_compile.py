@@ -551,3 +551,149 @@ def test_the_laguna_steps_way_back_gathers_no_slot(laguna_step):
                if " gather(" in line and "/moe/combine" in line]
     assert not [g for g in gathers if re.search(r"\[16384,\d+\]", g)], \
         gathers
+
+
+# ---------------------------------------------------------------------------
+# ZAYA1: the grouped-matmul kernels at experts 2048 wide (weight blocks in
+# column tiles), flash at 8 latent heads on 2 over 32768 keys, and a step
+# of two layers of compressed convolutional attention over one-choice
+# sparse feed-forwards
+# ---------------------------------------------------------------------------
+ZAYA_SEQ, ZAYA_HEADS, ZAYA_KV = 32768, 8, 2             # zaya1-8b-l5-e8
+
+
+@pytest.mark.parametrize("K,N,tiles", [(2048, 4096, 4), (2048, 2048, 2)])
+def test_grouped_matmul_at_wide_experts_compiles(v5e, K, N, tiles):
+    """ZAYA1-8B's gate_up (16 MB a group in bf16) and down (8 MB), 8 held
+    experts and the worst case's rows of one 32768-token row at one
+    choice a token: `moe_gmm` forward and to the rows, `moe_gmm_dw`,
+    their weight blocks cut into column tiles of 4 MB."""
+    one = SingleDeviceSharding(v5e[0])
+    rows = gmm.padded_rows(ZAYA_SEQ, 8)
+    assert N // gmm.column_tile(K, N, 2) == tiles
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def loss(x, w, sizes):
+        _s, tile_group, used = gmm.group_layout(sizes, rows // gmm.ROW_TILE)
+        y = gmm._gmm(x, w, tile_group, used.reshape(1), True, False)
+        return y.astype(jnp.float32).sum()
+
+    text = _compiled_text(
+        jax.value_and_grad(loss, argnums=(0, 1)),
+        S((rows, K), jnp.bfloat16), S((8, K, N), jnp.bfloat16),
+        S((8,), jnp.int32))
+    names = re.findall(r"%(\w*moe_gmm\w*?)[.\d]* = .*custom-call\(", text)
+    assert sorted("dw" in n for n in names) == [False, False, True], names
+    assert text.count("tpu_custom_call") == 3
+
+
+def test_flash_attention_at_zayas_latent_heads_compiles(v5e):
+    """8 query heads on 2 over one row of 32768 keys, no window: four
+    times the keys any other cell runs."""
+    one = SingleDeviceSharding(v5e[0])
+    q = jax.ShapeDtypeStruct((1, ZAYA_SEQ, ZAYA_HEADS, D), jnp.bfloat16,
+                             sharding=one)
+    k = jax.ShapeDtypeStruct((1, ZAYA_SEQ, ZAYA_KV, D), jnp.bfloat16,
+                             sharding=one)
+
+    def loss(q, k, v):
+        return fa._flash_core(q, k, v, None, True, D ** -0.5,
+                              True).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, k, k)
+    assert text.count("tpu_custom_call") == 2
+
+
+@pytest.fixture(scope="module")
+def zaya_step(v5e):
+    """Two layers at ZAYA1-8B's widths (2 of its 16 experts held, a
+    sixteenth of the slice of the vocabulary, 1 x 4096 tokens), the step
+    written as benchmarks/drivers/zaya_train_window.py writes it,
+    compiled for one described v5e: (text, compile record)."""
+    import paddle_tpu as pt
+    from paddle_tpu import amp
+    from paddle_tpu.jit import TrainStep
+    from paddle_tpu.models import GPTPretrainingCriterion
+    from paddle_tpu.models.zaya import ZayaConfig, ZayaForCausalLM
+    from paddle_tpu.observability import perf
+    from paddle_tpu.optimizer import AdamW
+    one = SingleDeviceSharding(v5e[0])
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
+
+    crit = GPTPretrainingCriterion()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("PADDLE_TPU_PALLAS_AUTOTUNE", "0")
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        was_on = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        pt.seed(0)
+        model = ZayaForCausalLM(ZayaConfig(
+            vocab_size=8192, num_hidden_layers=2, experts_held=(0, 2),
+            use_flash_attention=True, recompute=True))
+        model.train()
+
+        def loss_fn(m, ids, labels):
+            with amp.auto_cast(enable=True, level="O1", dtype="bfloat16"):
+                logits = m(ids)
+            return crit(logits, labels), (
+                m.expert_counts, m.router_top_weight, m.expert_choice)
+
+        step = TrainStep(model, AdamW(
+            learning_rate=1e-4, parameters=model.parameters(),
+            moment_dtype="bfloat16"), loss_fn, has_aux=True)
+        ids = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=one)
+        notes = {}
+        outer, perf._TRACE_NOTES.notes = perf._TRACE_NOTES.notes, notes
+        try:
+            compiled = step._step_fn.jit_fn.lower(
+                [spec(p) for p in step.params],
+                [{k: spec(v) for k, v in st.items()}
+                 for st in step.opt_states],
+                [spec(b) for b in step.buffers],
+                spec(jax.random.PRNGKey(0)), spec(jnp.float32(1e-4)),
+                [ids, ids], {}).compile()
+        finally:
+            perf._TRACE_NOTES.notes = outer
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        compilation_cache.reset_cache()
+    return compiled.as_text(), notes
+
+
+@pytest.mark.parametrize("kernel,calls", [
+    ("moe_gmm", 12),        # two products a layer: forward, again, to rows
+    ("moe_gmm_dw", 4),
+    ("moe_sum_rows", 6),    # a layer: combine forward and again (the
+                            # residual's alpha_o needs y), take_rows back
+    ("flash_fwd", 4),       # a layer: forward, again
+    ("flash_bwd_transpose", 2)])
+def test_the_zaya_step_holds_its_mosaic_kernels(zaya_step, kernel, calls):
+    text, _notes = zaya_step
+    found = re.findall(rf"%{kernel}[.\d]* = .*custom-call\(", text)
+    assert len(found) == calls, (kernel, len(found))
+    assert text.count("tpu_custom_call") == 28
+
+
+def test_the_zaya_step_says_which_paths_it_took(zaya_step):
+    """The latent and its taps; the flash kernels on three arrays (the
+    convolutions stand between the projection and them), walking to the
+    diagonal; the expert products on the kernels with their weight
+    blocks in column tiles; the head in one chunk at this small size."""
+    text, notes = zaya_step
+    for scope in ("attn_res/res_scale", "moe_res/res_scale", "attn/cca_mix",
+                  "moe/router"):
+        assert f"zaya/layers/1/{scope}/" in text, scope
+    assert notes == {
+        "cca": "latent 1024 q, 256 k, 256 v of 2048, 8 heads on 2, taps 2 "
+               "depthwise and 2 grouped, value shift on head 1",
+        "attention": "pallas", "flash_operands": "split",
+        "flash_causal": "fwd 136/256 of 256-wide tiles; "
+                        "bwd 136/256 of 256-wide tiles, dq whole",
+        "moe": "pallas, experts 2 held of 16, top 1, tiles of 128 rows, "
+               "weight blocks in column tiles of 1024 and 1024, "
+               "way back: held rows in windows of 16 (moe_sum_rows)",
+        "head_loss": "fused, chunks 1"}
