@@ -9,6 +9,11 @@ functionalised (Layer params become explicit vjp inputs) and wrapped in
 jax.checkpoint, so the eager tape's vjp closure holds only the block
 inputs and re-runs the forward during backward; under jit the same code
 gives XLA rematerialisation.
+
+What a block keeps besides its inputs is its `policy` (`_POLICIES`):
+"full" nothing, "dots" / "dots_no_batch" its matmul outputs, and
+"flash_outputs" the flash forward kernel's `o` and `lse`
+(2*b*s*h*d + 32*b*s*h bytes a layer), so the kernel runs once a layer.
 """
 from __future__ import annotations
 
@@ -20,6 +25,7 @@ from ...nn.layer import Layer
 from ...ops.registry import OpDef
 from ...ops import registry as _op_registry
 from ...autograd import tape
+from ...kernels.pallas.flash_attention import FLASH_O, FLASH_LSE
 
 
 #: Named rematerialisation policies (the reference's
@@ -29,12 +35,22 @@ from ...autograd import tape
 #: "dots" saves matmul outputs (recompute only the cheap elementwise
 #: tail — ~1/3 less recompute FLOPs at ~9*b*s*h extra bytes per block);
 #: "dots_no_batch" is the jax checkpoint_dots_with_no_batch_dims policy
-#: (saves plain matmuls, recomputes batched ones like attention scores).
+#: (saves plain matmuls, recomputes batched ones like attention scores);
+#: "flash_outputs" saves the flash forward kernel's `o` and `lse` and
+#: nothing else (2*b*s*h*d bytes of bf16 `o` and 32*b*s*h of float32
+#: `lse`, a head's row over a sublane tile of 8: at 32768 tokens of 8
+#: heads of 128, 67 + 8 MB a layer). The backward kernel reads q, k, v,
+#: o and lse; q, k and v are a projection to make again, o and lse the
+#: whole forward kernel, which a block that keeps them does not run a
+#: second time. Inert in a block with no flash kernel (nothing is named).
 _POLICIES = {
     "full": None,       # jax.checkpoint default: save only block inputs
     "dots": lambda: jax.checkpoint_policies.dots_saveable,
     "dots_no_batch":
         lambda: jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+    "flash_outputs":
+        lambda: jax.checkpoint_policies.save_only_these_names(
+            FLASH_O, FLASH_LSE),
 }
 
 
@@ -50,6 +66,36 @@ def _resolve_policy(policy):
     return entry() if entry is not None else None
 
 
+def flash_policy(attention):
+    """The policy of a recomputed block whose attention layer is
+    `attention` (None: it has none): "flash_outputs" where every key is
+    in a query's sight, else None.
+
+    What keeping buys for a byte of `o` is the number of keys a query row
+    meets: 16,384 on average over 32,768 causal tokens (ZAYA1, 243 ms a
+    GB kept on a v5e), 4,096 over 8,192 (Laguna's full layers, 51 ms a
+    GB), at most the window under one (512 at Laguna: 13 ms a GB, less
+    than a matmul output buys, so a window layer runs its forward again
+    and its bytes stay free). A layer with no flash kernel names nothing
+    and gets no policy."""
+    if (attention is None or not attention.use_flash_attention
+            or getattr(attention, "window", None) is not None):
+        return None
+    return "flash_outputs"
+
+
+def note_flash_kept(policies):
+    """Say in `compile_record(<family>)["flash_kept"]` in how many of a
+    model's recomputed layers (`policies`: one entry each) the flash
+    kernel's outputs are kept."""
+    from ...observability import perf
+    if policies:
+        kept = sum(p == "flash_outputs" for p in policies)
+        perf.trace_note(
+            "flash_kept", f"o and lse kept across recompute in {kept} of "
+            f"{len(policies)} recomputed layers")
+
+
 def recompute(function, *args, use_reentrant=True, preserve_rng_state=True,
               policy=None, **kwargs):
     """ref: recompute.py recompute(function, *args). `function` may be a
@@ -59,8 +105,9 @@ def recompute(function, *args, use_reentrant=True, preserve_rng_state=True,
     `policy` selects WHAT gets saved across the forward (the
     recompute_granularity analog): None/"full" saves only block inputs;
     "dots" / "dots_no_batch" save matmul outputs so backward re-runs
-    only the elementwise tail; or pass any jax.checkpoint_policies
-    callable directly."""
+    only the elementwise tail; "flash_outputs" saves the flash forward
+    kernel's two outputs so backward does not run it again; or pass any
+    jax.checkpoint_policies callable directly."""
     if isinstance(function, Layer):
         layer = function
         # forward is called past Layer.__call__: the block's

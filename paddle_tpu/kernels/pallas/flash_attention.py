@@ -67,6 +67,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -1140,6 +1141,18 @@ def _pallas_available():
     return jax.default_backend() == "tpu"
 
 
+#: The forward kernel's two outputs, by the names a `jax.checkpoint`
+#: policy can ask for (`recompute(..., policy="flash_outputs")`): a
+#: recomputed block that keeps both has nothing left to run the forward
+#: kernel for, since q, k, v, o and lse are all its backward reads. An
+#: identity where no policy names them.
+FLASH_O, FLASH_LSE = "flash_o", "flash_lse"
+
+
+def _kept(o, lse):
+    return checkpoint_name(o, FLASH_O), checkpoint_name(lse, FLASH_LSE)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def _flash_core(q, k, v, segment_ids, causal, sm_scale, use_pallas,
                 window=None):
@@ -1162,9 +1175,9 @@ def _flash_core_fwd(q, k, v, segment_ids, causal, sm_scale, use_pallas,
         qm = q.reshape(b, s, h * d)
         km = k.reshape(b, -1, hk * d)
         vm = v.reshape(b, -1, hk * d)
-        o, lse = _flash_fwd_fused(qm, km, vm, h, causal, Hk=hk,
-                                  segment_ids=segment_ids,
-                                  sm_scale=sm_scale, window=window)
+        o, lse = _kept(*_flash_fwd_fused(qm, km, vm, h, causal, Hk=hk,
+                                         segment_ids=segment_ids,
+                                         sm_scale=sm_scale, window=window))
         return o.reshape(b, s, h, d), (qm, km, vm, o, lse, h, hk,
                                        segment_ids)
     out = _xla_attention(q, k, v, None, causal, sm_scale,
@@ -1204,9 +1217,9 @@ def _flash_core_qkv(qkv, segment_ids, h, causal, sm_scale):
 
 
 def _flash_core_qkv_fwd(qkv, segment_ids, h, causal, sm_scale):
-    o, lse = _flash_fwd_fused(qkv, qkv, qkv, h, causal,
-                              segment_ids=segment_ids, sm_scale=sm_scale,
-                              cols=_QKV, D=qkv.shape[2] // (3 * h))
+    o, lse = _kept(*_flash_fwd_fused(
+        qkv, qkv, qkv, h, causal, segment_ids=segment_ids,
+        sm_scale=sm_scale, cols=_QKV, D=qkv.shape[2] // (3 * h)))
     return o, (qkv, o, lse, segment_ids)
 
 

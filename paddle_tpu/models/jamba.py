@@ -268,13 +268,16 @@ class JambaModel(Layer):
         x = self.embed_tokens(input_ids)
         cfg = self.config
         remat = cfg.recompute and self.training
-        if remat:
-            from ..distributed.meta_parallel.recompute import recompute
+        from ..distributed.meta_parallel.recompute import (
+            flash_policy, note_flash_kept, recompute)
+        kept = []
         for i, layer in enumerate(self.layers):
             if remat and i % cfg.recompute_interval == 0:
-                x = recompute(layer, x)
+                kept.append(flash_policy(getattr(layer, "attn", None)))
+                x = recompute(layer, x, policy=kept[-1])
             else:
                 x = layer(x)
+        note_flash_kept(kept)
         return self.final_layernorm(x)
 
 

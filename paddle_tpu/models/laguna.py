@@ -280,17 +280,20 @@ class LagunaModel(Layer):
                   for kind, params in cfg.rope_parameters.items()
                   if isinstance(params, dict) and kind in cfg.layer_types}
         remat = cfg.recompute and self.training
-        if remat:
-            from ..distributed.meta_parallel.recompute import recompute
-        counts = []
+        from ..distributed.meta_parallel.recompute import (
+            flash_policy, note_flash_kept, recompute)
+        counts, kept = [], []
         for i, layer in enumerate(self.layers):
             cos, sin = tables[cfg.layer_types[i]]
             if remat and i % cfg.recompute_interval == 0:
-                x, c = recompute(layer, x, cos, sin)
+                # a full layer keeps its flash outputs, a window layer not
+                kept.append(flash_policy(layer.attn))
+                x, c = recompute(layer, x, cos, sin, policy=kept[-1])
             else:
                 x, c = layer(x, cos, sin)
             if c is not None:
                 counts.append(c)
+        note_flash_kept(kept)
         return self.norm(x), counts
 
 

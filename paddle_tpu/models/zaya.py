@@ -197,15 +197,18 @@ class ZayaModel(Layer):
         # the second stream: the router's state, zero before layer 0
         r = ops.zeros([b, seq, cfg.router_hidden_size], "float32")
         remat = cfg.recompute and self.training
-        if remat:
-            from ..distributed.meta_parallel.recompute import recompute
-        told = []
+        from ..distributed.meta_parallel.recompute import (
+            flash_policy, note_flash_kept, recompute)
+        told, kept = [], []
         for i, layer in enumerate(self.layers):
             if remat and i % cfg.recompute_interval == 0:
-                x, r, *tell = recompute(layer, x, r, cos, sin)
+                kept.append(flash_policy(layer.attn))
+                x, r, *tell = recompute(layer, x, r, cos, sin,
+                                        policy=kept[-1])
             else:
                 x, r, *tell = layer(x, r, cos, sin)
             told.append(tell)
+        note_flash_kept(kept)
         return (self.norm(x),) + tuple(zip(*told))
 
 

@@ -579,6 +579,11 @@ def test_nothing_stands_between_qkv_proj_and_the_kernels(monkeypatch):
         assert chain == ["add", "dot_general"]          # bias, product
     o = kernels["flash_fwd"].outvars[0]
     assert o.aval.shape == (2, 512, 128)
+    # its name for a checkpoint policy (an identity), and nothing else
+    (named,) = [e for e in jaxpr.eqns if o in e.invars]
+    assert (named.primitive.name, named.params["name"]) == ("name",
+                                                            fa.FLASH_O)
+    o = named.outvars[0]
     readers = [e.primitive.name for e in jaxpr.eqns if o in e.invars]
     assert "reshape" not in readers and "dot_general" in readers
     o_again, do = kernels["flash_bwd_transpose"].invars[3:5]

@@ -339,13 +339,13 @@ def jamba_step(v5e):
 @pytest.mark.parametrize("kernel,calls", [
     ("ssm_scan_fwd", 2),            # the forward, and run again
     ("ssm_scan_bwd", 1),
-    ("flash_fwd", 2),
+    ("flash_fwd", 1),               # once: the block keeps its o and lse
     ("flash_bwd_transpose", 1)])
 def test_the_jamba_step_holds_its_mosaic_kernels(jamba_step, kernel, calls):
     text, _notes = jamba_step
     found = re.findall(rf"%{kernel}[.\d]* = .*custom-call\(", text)
     assert len(found) == calls, (kernel, len(found))
-    assert text.count("tpu_custom_call") == 6
+    assert text.count("tpu_custom_call") == 5
 
 
 def test_the_jamba_step_says_which_paths_it_took(jamba_step):
@@ -355,11 +355,14 @@ def test_the_jamba_step_says_which_paths_it_took(jamba_step):
     walk sequence 4096 up to the diagonal in 256-wide visits, read q, k
     and v from the three projections' own arrays, and the backward holds
     the one key/value head's 4096 keys in one block, so dq leaves it
-    whole."""
+    whole; of the two recomputed blocks the one with attention keeps the
+    forward kernel's outputs."""
     _text, notes = jamba_step
     assert notes == {"ssm_scan": "pallas, chunk 64, tile 512",
                      "attention": "pallas", "head_loss": "fused, chunks 1",
                      "flash_operands": "split",
+                     "flash_kept": "o and lse kept across recompute in "
+                                   "1 of 2 recomputed layers",
                      "flash_causal": "fwd 136/256 of 256-wide tiles; "
                                      "bwd 136/256 of 256-wide tiles, "
                                      "dq whole"}
@@ -513,25 +516,29 @@ def laguna_step(v5e):
     ("moe_gmm", 12),        # two products a layer: forward, again, to rows
     ("moe_gmm_dw", 4),
     ("moe_sum_rows", 4),    # a layer: combine forward, take_rows backward
-    ("flash_fwd", 4),
+    ("flash_fwd", 3),       # the window layer: forward, again; the full
+                            # layer keeps its o and lse: once
     ("flash_bwd_transpose", 2)])
 def test_the_laguna_step_holds_its_mosaic_kernels(laguna_step, kernel,
                                                   calls):
     text, _notes = laguna_step
     found = re.findall(rf"%{kernel}[.\d]* = .*custom-call\(", text)
     assert len(found) == calls, (kernel, len(found))
-    assert text.count("tpu_custom_call") == 26
+    assert text.count("tpu_custom_call") == 25
 
 
 def test_the_laguna_step_says_which_paths_it_took(laguna_step):
     """The window layer's kernels visit 3 tiles a q block of the 8 and
-    hold 2 partial slots; the full layer's walk to the diagonal as ever;
-    the expert products take the kernels; the step hands the counts
+    hold 2 partial slots; the full layer's walk to the diagonal as ever,
+    and its block keeps the forward kernel's outputs, the window layer's
+    not; the expert products take the kernels; the step hands the counts
     out."""
     _text, notes = laguna_step
     assert notes == {
         "attention_window": "layer 0: 512", "attention": "pallas",
         "flash_operands": "split",
+        "flash_kept": "o and lse kept across recompute in 1 of 2 "
+                      "recomputed layers",
         "flash_causal": "fwd 21/64 of 256-wide tiles, window 512; "
                         "fwd 36/64 of 256-wide tiles; "
                         "bwd 36/64 of 256-wide tiles, dq partials 2; "
@@ -669,13 +676,13 @@ def zaya_step(v5e):
     ("moe_gmm_dw", 4),
     ("moe_sum_rows", 6),    # a layer: combine forward and again (the
                             # residual's alpha_o needs y), take_rows back
-    ("flash_fwd", 4),       # a layer: forward, again
+    ("flash_fwd", 2),       # a layer: once, its block keeps o and lse
     ("flash_bwd_transpose", 2)])
 def test_the_zaya_step_holds_its_mosaic_kernels(zaya_step, kernel, calls):
     text, _notes = zaya_step
     found = re.findall(rf"%{kernel}[.\d]* = .*custom-call\(", text)
     assert len(found) == calls, (kernel, len(found))
-    assert text.count("tpu_custom_call") == 28
+    assert text.count("tpu_custom_call") == 26
 
 
 def test_the_zaya_step_says_which_paths_it_took(zaya_step):
@@ -691,6 +698,8 @@ def test_the_zaya_step_says_which_paths_it_took(zaya_step):
         "cca": "latent 1024 q, 256 k, 256 v of 2048, 8 heads on 2, taps 2 "
                "depthwise and 2 grouped, value shift on head 1",
         "attention": "pallas", "flash_operands": "split",
+        "flash_kept": "o and lse kept across recompute in 2 of 2 "
+                      "recomputed layers",
         "flash_causal": "fwd 136/256 of 256-wide tiles; "
                         "bwd 136/256 of 256-wide tiles, dq whole",
         "moe": "pallas, experts 2 held of 16, top 1, tiles of 128 rows, "
