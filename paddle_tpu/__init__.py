@@ -8,6 +8,10 @@ DP/TP/SP/PP/EP + ZeRO sharding lowered to GSPMD + ICI collectives.
 """
 from __future__ import annotations
 
+import time as _time
+
+_T_IMPORT = _time.perf_counter()     # the set-up phase `import` (below)
+
 __version__ = "0.1.0"
 
 # ---- core ----
@@ -57,6 +61,11 @@ from .tensor_array import (  # noqa: F401
     create_array, array_write, array_read, array_length,
 )
 from .hapi.model_api import Model, summary  # noqa: F401
+from .observability import perf as _perf
+
+# top to bottom of this file: `perf.setup_record()["import"]`. It holds
+# `jax`'s import when the caller has not imported it first.
+_perf.setup_since("import", _T_IMPORT)
 
 
 def __getattr__(name):

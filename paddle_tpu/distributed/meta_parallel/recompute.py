@@ -21,7 +21,7 @@ import jax
 
 from ...core.tensor import Tensor
 from ...core.generator import rng_scope, next_key
-from ...nn.layer import Layer
+from ...nn.layer import Layer, traced_scope
 from ...ops.registry import OpDef
 from ...ops import registry as _op_registry
 from ...autograd import tape
@@ -115,7 +115,7 @@ def recompute(function, *args, use_reentrant=True, preserve_rng_state=True,
         scope = layer.scope_name()
 
         def fn(*inputs, **kw):
-            with jax.named_scope(scope):
+            with traced_scope(scope):
                 return layer.forward(*inputs, **kw)
     else:
         layer = getattr(function, "__self__", None)

@@ -1616,19 +1616,7 @@ class LLMEngine:
         with _ot.span("engine.decode_chunk"):
             out = self._run_decode_chunk_impl(only)
         if out:     # skip empty calls (no active slots)
-            t1 = time.perf_counter()
-            _metrics()["decode"].observe(t1 - t0)
-            if _ot._ENABLED:
-                for slot in out:
-                    s = self.slots[slot]
-                    if s is None or s.trace_id is None:
-                        continue
-                    _ot.add_event(
-                        "request.decode_chunk", t0 * 1e6,
-                        (t1 - t0) * 1e6,
-                        trace=(s.trace_id, _ot.new_span_id(),
-                               s.root_span),
-                        args={"request_id": str(s.rid)})
+            _metrics()["decode"].observe(time.perf_counter() - t0)
         return out
 
     def _run_decode_chunk_impl(self, only: Optional[_Seq] = None

@@ -671,11 +671,6 @@ class Router:
         failover victims drain onto it immediately."""
         h = self.replicas.add(engine_factory)
         self.stats["grown"] += 1
-        if _ot._ENABLED:
-            _ot.add_event(
-                "router.scale", time.perf_counter() * 1e6, 0.0,
-                args={"action": "grow", "replica": h.name,
-                      "replicas": len(self.replicas)})
         self._drain_pending()
         self._update_gauges()
         return h.name
@@ -733,12 +728,6 @@ class Router:
             for state in ("healthy", "probation", "dead"):
                 m["state"].labels(replica=h.name, state=state).set(0.0)
             m["inflight"].labels(replica=h.name).set(0)
-        if _ot._ENABLED:
-            _ot.add_event(
-                "router.scale", time.perf_counter() * 1e6, 0.0,
-                args={"action": "retire", "replica": h.name,
-                      "replicas": len(self.replicas),
-                      "victims": len(victims)})
         self._reroute(victims)
         self._update_gauges()
         return h.name

@@ -336,6 +336,7 @@ class TrainStep:
     counts) is `step.aux` after each call, to be read with the loss.
     """
 
+    @_pf.setup_phase("build.train_step")
     def __init__(self, model: Layer, optimizer, loss_fn: Callable = None,
                  has_aux=False, donate=True, mesh=None, shard_param=None,
                  shard_data=None):
@@ -494,7 +495,8 @@ class TrainStep:
             if has_aux:
                 loss, aux = loss
             train_states = [s for s, t in zip(opt_states, trainable) if t]
-            with jax.named_scope("optimizer"):
+            with jax.named_scope("optimizer"), \
+                    _pf.trace_timed("optimizer"):
                 new_train, new_states = optimizer.functional_update(
                     train_params, grads, train_states, lr)
             new_params, new_opt_states = [], []
@@ -617,6 +619,11 @@ class TrainStep:
                 self.optimizer._accumulators[id(p)] = (
                     {k: jnp.copy(v) for k, v in st.items()} if copy else st)
         return self.model
+
+
+# a program built in the middle of a step is logged with the step's id
+# (`perf.program_log`), which the log finds on the stack
+_pf.STEP_CALLS[TrainStep._call.__code__] = "step_id"
 
 
 def _export_specs(input_spec):

@@ -70,6 +70,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from ...observability import perf as _pf
 
 _NEG_INF = -1e30
 # raised scoped-VMEM budget: the 1024-wide resident K/V blocks need ~17MB
@@ -302,13 +303,12 @@ def _note_causal(kind, sq, sk, block_q, block_k, sub, causal, more="",
                  window=None):
     """Say in `compile_record(<family>)["flash_causal"]` how much of
     [sq, sk] this kernel visits (and `more`: the backward's dq)."""
-    from ...observability import perf
     visited, total = causal_tiles(sq, sk, block_q, block_k, sub, causal,
                                   window)
     if window is not None:
         more += f", window {window}"
-    perf.trace_note("flash_causal",
-                    f"{kind} {visited}/{total} of {sub}-wide tiles{more}")
+    _pf.trace_note("flash_causal",
+                   f"{kind} {visited}/{total} of {sub}-wide tiles{more}")
 
 
 def _seg_tile_mask(row_ref, lane_ref, r0, rows, l0, lanes):
@@ -570,6 +570,7 @@ _QKV = (0, 1, 2)    # q, k, v as column blocks of one fused projection
 
 
 @functools.partial(jax.jit, static_argnames=_CALL_STATICS, inline=True)
+@_pf.trace_timed_call("flash_fwd")
 def _fwd_call(q, k, v, segment_ids, *, cols, sm_scale, H, Hk, D, causal,
               block_q, block_k, sub, interpret, window=None):
     b, sq = q.shape[:2]
@@ -908,6 +909,7 @@ def _flash_bwd_fused(q, k, v, o, lse, do, H, causal,
 
 
 @functools.partial(jax.jit, static_argnames=_CALL_STATICS, inline=True)
+@_pf.trace_timed_call("flash_bwd_transpose")
 def _bwd_call(q, k, v, o, lse, do, segment_ids, *, cols, sm_scale, H, Hk, D,
               causal, block_q, block_k, sub, interpret, window=None):
     b, sq = q.shape[:2]
@@ -1168,8 +1170,7 @@ def _flash_core(q, k, v, segment_ids, causal, sm_scale, use_pallas,
 def _flash_core_fwd(q, k, v, segment_ids, causal, sm_scale, use_pallas,
                     window=None):
     if use_pallas:
-        from ...observability import perf
-        perf.trace_note("flash_operands", "split")
+        _pf.trace_note("flash_operands", "split")
         b, s, h, d = q.shape
         hk = k.shape[2]
         qm = q.reshape(b, s, h * d)
@@ -1382,8 +1383,7 @@ def flash_attention_qkv(qkv, num_heads, causal=False, softmax_scale=None,
     shape = (b, s, num_heads, hd // num_heads)
     if (attention_path(shape, shape)[0] == "pallas"
             and _MESH_PLAN.get() is None):
-        from ...observability import perf
-        perf.trace_note("flash_operands", "qkv in place")
+        _pf.trace_note("flash_operands", "qkv in place")
         return _flash_core_qkv(qkv, _int32_pair(segment_ids), num_heads,
                                causal, _scale(softmax_scale, shape[3]))
     q, k, v = (qkv[:, :, i * hd:(i + 1) * hd].reshape(shape)

@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from ...observability import perf as _pf
 
 from .flash_attention import _pallas_available
 
@@ -154,6 +155,7 @@ def _grid(n_tiles, n_cols):
 
 @functools.partial(jax.jit, static_argnames=("transpose_w", "interpret"),
                    inline=True)
+@_pf.trace_timed_call("moe_gmm")
 def _gmm_call(x, w, tile_group, used, *, transpose_w, interpret):
     R, K = x.shape
     N = w.shape[1] if transpose_w else w.shape[2]
@@ -196,6 +198,7 @@ def _gmm_call(x, w, tile_group, used, *, transpose_w, interpret):
 
 @functools.partial(jax.jit, static_argnames=("groups", "interpret"),
                    inline=True)
+@_pf.trace_timed_call("moe_gmm_dw")
 def _dw_call(x, dy, tile_group, used, *, groups, interpret):
     R, K = x.shape
     N = dy.shape[1]

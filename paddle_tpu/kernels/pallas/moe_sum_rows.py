@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from ...observability import perf as _pf
 
 WINDOW = 16         # rows a DMA moves: a whole tile of bf16 (or two of f32)
 _VMEM_LIMIT = 100 * 1024 * 1024
@@ -189,6 +190,7 @@ def _kernel(first_ref, count_ref, n_ref, at_ref, before_ref, scale_ref,
 
 @functools.partial(jax.jit, static_argnames=("tile", "out_dtype",
                                              "interpret"), inline=True)
+@_pf.trace_timed_call("moe_sum_rows")
 def sum_rows(vals, plan, scale=None, *, tile, out_dtype, interpret=False):
     """vals [R, d] (R a multiple of WINDOW); plan: `plan`'s, for this
     `tile`; scale [T, k] float32 or None. Returns [T, d] in

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from ...observability import perf as _pf
 
 from .flash_attention import _pallas_available
 
@@ -130,6 +131,7 @@ def _params():
         vmem_limit_bytes=_VMEM_LIMIT)
 
 
+@_pf.trace_timed_call("ssm_scan_fwd")
 def _scan_fwd_pallas(x, delta, At, Bw, Cw, Dv, T, tE, interpret=False):
     """x [b, L, E]; delta [b, L, E] f32; At [N, E]; Bw, Cw [b, L, N, 128];
     Dv [1, E]. L % T == 0, E % tE == 0. -> (y like x, starts
@@ -212,6 +214,7 @@ def _bwd_kernel(x_ref, d_ref, a_ref, b_ref, c_ref, dv_ref, hs_ref, dy_ref,
     ddv_ref[0, 0] = jnp.sum(dyf * xf, axis=0, keepdims=True)
 
 
+@_pf.trace_timed_call("ssm_scan_bwd")
 def _scan_bwd_pallas(x, delta, At, Bw, Cw, Dv, starts, dy, T, tE,
                      interpret=False):
     """-> dx like x, ddelta f32, dA partials [b, L/T, N, E], dB and dC

@@ -7,6 +7,7 @@ with buffers, train/eval mode, forward pre/post hooks, to()/astype.
 from __future__ import annotations
 
 import contextlib
+import functools
 import itertools
 import threading
 from collections import OrderedDict
@@ -18,6 +19,7 @@ import numpy as np
 
 from ..core import dtype as dtypes
 from ..core.tensor import Tensor
+from ..observability import perf as _pf
 
 
 class Parameter(Tensor):
@@ -56,9 +58,11 @@ class _Tracing(threading.local):
     `jax.named_scope(<the name its parent holds it by>)`, so every
     operation's `op_name` carries a path like `gpt/layers/3/attn` that
     the profiler's trace hands back (benchmarks/harness/
-    trace_scopes.py). Per thread: an engine prewarm tracing on one
-    thread leaves eager calls on the others as they are. Never on the
-    eager path: one attribute read there."""
+    trace_scopes.py), and the seconds the trace spends in it go to the
+    program's `compile_record(family)["trace_by_scope"]`. Per thread: an
+    engine prewarm tracing on one thread leaves eager calls on the
+    others as they are. Never on the eager path: one attribute read
+    there."""
     depth = 0
 
 
@@ -66,10 +70,17 @@ _TRACING = _Tracing()
 _NO_SCOPE = contextlib.nullcontext()
 
 
+@contextlib.contextmanager
+def _scope(name: str):
+    with jax.named_scope(name), _pf.trace_timed(name):
+        yield
+
+
 def traced_scope(name: str):
-    """`jax.named_scope(name)` while a program is being traced, else a
-    shared no-op: for what is not a Layer (a tied head, a method)."""
-    return jax.named_scope(name) if _TRACING.depth else _NO_SCOPE
+    """`jax.named_scope(name)` while a program is being traced (and the
+    trace's seconds under that name, `perf.trace_timed`), else a shared
+    no-op: for what is not a Layer (a tied head, a method)."""
+    return _scope(name) if _TRACING.depth else _NO_SCOPE
 
 
 def _is_holder(layer: "Layer") -> bool:
@@ -107,7 +118,24 @@ class HookRemoveHelper:
         self._hooks.pop(self._hid, None)
 
 
+def _built(init):
+    """A constructor inside the set-up phase `build.model`
+    (`perf.setup_record`): the outermost constructor's seconds are the
+    model's, the sublayers' count as entries."""
+    @functools.wraps(init)
+    def __init__(self, *args, **kwargs):
+        with _pf.setup_phase("build.model"):
+            init(self, *args, **kwargs)
+    return __init__
+
+
 class Layer:
+    def __init_subclass__(cls, **kwargs):
+        super().__init_subclass__(**kwargs)
+        if "__init__" in cls.__dict__:
+            cls.__init__ = _built(cls.__dict__["__init__"])
+
+    @_built
     def __init__(self, name_scope=None, dtype="float32"):
         self.training = True
         self._dtype = dtype
@@ -211,7 +239,9 @@ class Layer:
             init = default_initializer
         if init is None:
             init = Constant(0.0) if is_bias else XavierUniform()
-        data = init(tuple(int(s) for s in shape), dtypes.to_jnp(dtype))
+        with _pf.setup_phase("build.params") as phase:
+            data = init(tuple(int(s) for s in shape), dtypes.to_jnp(dtype))
+            phase.count(params=1, bytes=int(getattr(data, "nbytes", 0)))
         p = Parameter(data, name=name)
         return p
 
@@ -350,7 +380,7 @@ class Layer:
             if res is not None:
                 inputs = res if isinstance(res, tuple) else (res,)
         if _TRACING.depth:
-            with jax.named_scope(self.scope_name()):
+            with _scope(self.scope_name()):
                 out = self.forward(*inputs, **kwargs)
         else:
             out = self.forward(*inputs, **kwargs)

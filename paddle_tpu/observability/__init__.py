@@ -27,8 +27,17 @@ numbers from. Built-in instrumentation (recorded only while enabled):
   see one event stream.
 * `jit.TrainStep` — `train_step` / `train_step.feed` /
   `train_step.dispatch` spans; every `CompileTimed` first call —
-  `compile.lower` / `compile.backend` / `compile.first_run` spans and
-  `perf.compile_record(family)`, written metrics on or off.
+  `compile.lower` (and inside it `compile.trace`) / `compile.backend` /
+  `compile.first_run` spans and `perf.compile_record(family)` (`lower_s`
+  and its part `trace_s`, `backend_s`, `first_run_s`, `trace_by_scope`:
+  the trace's seconds by layer path and kernel), written metrics on or
+  off.
+* set-up — `setup.build.model` / `.params` / `.optimizer` /
+  `.train_step` spans where layers, parameters, accumulators and the
+  `TrainStep` are built; their seconds and clock positions, the
+  package's `import` and every first call's parts in
+  `perf.setup_record()`, and every program JAX traces, lowers, compiles
+  or loads in `perf.program_log()`, both written metrics on or off.
 
 Every span is also a profiler annotation (`tracing.py`) while a profiler
 session records (tracing enabled or not): the program's spans then lie

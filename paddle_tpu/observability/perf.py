@@ -42,25 +42,54 @@ sub-surfaces, all near-zero when observability is disabled:
   anyone tries to batch them. Single flag check per node when
   observability is off.
 
+* **Set-up, told from inside the program.** `setup_record()` is the
+  process's set-up by phase (`import`, `build.model`, `build.params`,
+  `build.optimizer`, `build.train_step`, and `<family>.lower` /
+  `.trace` / `.backend` / `.first_run` of every `CompileTimed` first
+  call), each phase's seconds, entries and first and last instant on
+  `time.perf_counter`, the clock a caller takes its own marks on; a
+  phase is also a `setup.<phase>` span (a first call's keep their
+  older names, `compile.<part>`). `program_log()` is every program JAX
+  traced, lowered, compiled or loaded from its persistent cache, by
+  JAX's own duration events, each row with the function's name, the
+  compile family, the phase and the `TrainStep` step it fell into.
+  `compile_record(family)["trace_by_scope"]` says which layers and
+  kernels the first call's trace spent its seconds in. All three are
+  one-shots at build and compile time, written metrics on or off; a
+  warm step enters no phase and fires no event.
+
 What reads this module now: `tools/obs_top.py` and the fleet's capacity
-lines read the gauges; the benchmark's `step_lower_s.train` and
-`step_compile_s.train` read `compile_record()`. The benchmark's own
-utilizations come from the device trace and `benchmarks/harness/
-peaks.json`, not from the host-clock gauges here.
+lines read the gauges; the benchmark's `step_lower_s.train`,
+`step_compile_s.train`, `step_trace_s.train` and
+`step_first_run_s.train` read `compile_record()`; its
+`setup_import_s.train`, `setup_build_s.train`,
+`setup_other_programs_s.train` and `setup_named_share.train` read
+`setup_record()` and `program_log()` between the process's start and
+the window's first instant, and `benchmarks/tools/setup_table.py`
+prints all three for a cell. The benchmark's own utilizations come from
+the device trace and `benchmarks/harness/peaks.json`, not from the
+host-clock gauges here.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
+import sys
 import threading
 import time
-from typing import Dict, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
+
+from jax import monitoring as _monitoring
 
 from . import metrics as _m
 from . import tracing as _t
 
 __all__ = [
     "CostModel", "read_cost_model", "CompileTimed", "record_compile",
-    "compile_record", "trace_note",
+    "compile_record", "trace_note", "setup_phase", "setup_record",
+    "program_log", "ProgramRow", "trace_timed", "trace_timed_call",
+    "STEP_CALLS",
     "observe_roofline", "note_dispatch_gap", "note_dispatch_batch",
     "note_graph_cache", "device_peaks", "set_device_peaks", "lookup",
     "interconnect_peaks", "set_interconnect_peaks",
@@ -320,31 +349,54 @@ _FAMILY_COMPILE: Dict[str, dict] = {}
 def compile_record(family: str) -> Optional[dict]:
     """Where the family's first calls spent their time in this process,
     or None before its first: `compiles` (first calls so far) and, summed
-    over them, `lower_s` (tracing and lowering the program), `backend_s`
+    over them, `lower_s` (tracing the program and lowering it), of which
+    `trace_s` is the trace to a jaxpr alone, so that `lower_s - trace_s`
+    is the jaxpr's lowering to MLIR (absent where the function has no
+    `.trace` or it raised), `backend_s`
     (XLA's compile, or the persistent cache's load when it hits; with an
     executable store, the store's load) and `first_run_s` (the first
     execution, not waited for), with the last one's `outcome`
-    (compile | disk_hit) and whatever the traced code noted of itself
-    (`trace_note`). Written whether or not metrics are enabled:
+    (compile | disk_hit), whatever the traced code noted of itself
+    (`trace_note`) and `trace_by_scope`: the trace's seconds by the
+    layer that spent them (self time, under its `jax.named_scope` path
+    with layer indices folded: `gpt/layers/*/attn`) or by the Pallas
+    kernel (`trace_timed`). Written whether or not metrics are enabled:
     a one-shot at compile time costs the hot path nothing."""
     rec = _FAMILY_COMPILE.get(family)
-    return dict(rec) if rec is not None else None
+    if rec is None:
+        return None
+    rec = dict(rec)
+    if "trace_by_scope" in rec:
+        rec["trace_by_scope"] = dict(rec["trace_by_scope"])
+    return rec
 
 
 def _note_compile(family: str, parts: dict, outcome: str,
-                  notes: Optional[dict] = None) -> None:
+                  notes: Optional[dict] = None,
+                  scopes: Optional[dict] = None) -> None:
     rec = _FAMILY_COMPILE.setdefault(family, {
         "compiles": 0, "lower_s": 0.0, "backend_s": 0.0,
         "first_run_s": 0.0})
     rec["compiles"] += 1
     for part, seconds in parts.items():
-        rec[part + "_s"] += seconds
+        rec[part + "_s"] = rec.get(part + "_s", 0.0) + seconds
     rec["outcome"] = outcome
     rec.update(notes or {})
+    if scopes:
+        by_scope = rec.setdefault("trace_by_scope", {})
+        for key, seconds in scopes.items():
+            by_scope[key] = by_scope.get(key, 0.0) + seconds
 
 
 class _TraceNotes(threading.local):
-    notes = None    # a dict while a CompileTimed's first call runs here
+    notes = None    # a dict while a CompileTimed's first call runs here,
+    family = None   # that CompileTimed's family
+    step = None     # and the TrainStep step that made the call, if one
+    scopes = None   # a dict while that call traces its program
+    timed = None    # the innermost open `trace_timed`
+    phases = ()     # the open set-up phases, outermost first
+    tracing = 0     # functions JAX is tracing here, one inside another
+    lowered = None  # the program lowered last: a cache load is its
 
 
 _TRACE_NOTES = _TraceNotes()
@@ -364,6 +416,308 @@ def trace_note(key: str, value: str) -> None:
             notes[key] = value
         elif value not in seen.split("; "):
             notes[key] = f"{seen}; {value}"
+
+
+class _Timed:
+    """One layer's or kernel's stretch of a first call's trace: its self
+    time (its seconds less those of the stretches opened inside it) goes
+    to the call's `trace_by_scope`."""
+
+    __slots__ = ("key", "path", "outer", "inner", "t0")
+
+    def __init__(self, name: str, path: bool):
+        self.key, self.path = name, path
+
+    def __enter__(self):
+        th = _TRACE_NOTES
+        outer = self.outer = th.timed
+        if self.path:
+            self.key = "/".join("*" if part.isdigit() else part
+                                for part in self.key.split("/"))
+            if outer is not None and outer.path:
+                self.key = f"{outer.key}/{self.key}"
+        self.inner = 0.0
+        th.timed = self
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        seconds = time.perf_counter() - self.t0
+        th = _TRACE_NOTES
+        th.timed = self.outer
+        if self.outer is not None:
+            self.outer.inner += seconds
+        scopes = th.scopes
+        if scopes is not None:
+            scopes[self.key] = (scopes.get(self.key, 0.0)
+                                + seconds - self.inner)
+        return False
+
+
+_NOT_TIMED = contextlib.nullcontext()
+
+
+def trace_timed(name: str, path: bool = True):
+    """Around what runs while a program is traced: the stretch's self
+    time lands in `compile_record(family)["trace_by_scope"]` of the
+    `CompileTimed` whose first call is tracing on this thread. A layer's
+    `name` is its `jax.named_scope`, and its key the path of the open
+    stretches with layer indices folded (`gpt/layers/*/attn`); a Pallas
+    kernel's entry gives `path=False` and is keyed by the kernel's
+    `name=` wherever it is called. Outside such a trace (an eager call
+    never comes here, `nn/layer.py`) it times nothing."""
+    if _TRACE_NOTES.scopes is None:
+        return _NOT_TIMED
+    return _Timed(name, path)
+
+
+def trace_timed_call(name: str):
+    """`trace_timed(name, path=False)` around every call of a function:
+    the decorator of a Pallas kernel's entry, under its `jax.jit` so that
+    only a call that traces the body is timed."""
+    def decorate(fn):
+        @functools.wraps(fn)
+        def call(*args, **kwargs):
+            with trace_timed(name, path=False):
+                return fn(*args, **kwargs)
+        return call
+    return decorate
+
+
+# ---------------------------------------------------------------------------
+# set-up by phase, and every program JAX builds or loads
+# ---------------------------------------------------------------------------
+_LOCK = threading.Lock()
+_SETUP: Dict[str, dict] = {}
+
+
+class setup_phase(contextlib.ContextDecorator):
+    """One stretch of set-up, timed where the work is: a context manager
+    (or a decorator) that adds to `setup_record()[name]` and is the span
+    `setup.<name>` (`span=` gives a first call's parts their older
+    names). Phases nest: the record keeps the phase a phase was first
+    opened inside (`parent`), and a phase opened inside itself (a
+    layer's constructor building its sublayers) counts its entry and no
+    seconds twice. `seconds` is the last stretch's, after it closed.
+    For one-shots only: it takes a lock and two clock reads."""
+
+    def __init__(self, name: str, span: Optional[str] = None, **attrs):
+        self.name = name
+        self.span = span or "setup." + name
+        self.attrs = attrs
+        self.seconds = 0.0
+
+    def __enter__(self):
+        th = _TRACE_NOTES
+        outer = th.phases
+        span = _t.span(self.span, **self.attrs)
+        span.__enter__()
+        t0 = time.perf_counter()
+        with _LOCK:
+            rec = _SETUP.get(self.name)
+            if rec is None:
+                rec = _SETUP[self.name] = _new_phase(
+                    t0, outer[-1][0] if outer else None)
+            rec["n"] += 1
+        th.phases = outer + ((self.name, t0, span),)
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        th = _TRACE_NOTES
+        *outer, (name, t0, span) = th.phases
+        th.phases = tuple(outer)
+        self.seconds = t1 - t0
+        with _LOCK:
+            rec = _SETUP[name]
+            rec["t1"] = max(rec["t1"], t1)
+            if all(name != open_name for open_name, _t0, _sp in outer):
+                rec["s"] += t1 - t0
+            if not outer:
+                _add_stretch(rec, t0, t1)
+        span.__exit__(*exc)
+        return False
+
+    def count(self, **counts) -> None:
+        """Add to the phase's own counters (`params`, `bytes`)."""
+        with _LOCK:
+            rec = _SETUP[self.name]
+            for key, n in counts.items():
+                rec[key] = rec.get(key, 0) + n
+
+
+PHASE_STRETCHES = 32
+
+
+def _new_phase(t0: float, parent: Optional[str]) -> dict:
+    return {"s": 0.0, "n": 0, "t0": t0, "t1": t0, "parent": parent,
+            "stretches": []}
+
+
+def _add_stretch(rec: dict, t0: float, t1: float) -> None:
+    stretches = rec["stretches"]
+    if len(stretches) < PHASE_STRETCHES:
+        stretches.append((t0, t1))
+    else:       # the last one grows: the extent stays, the gaps go
+        stretches[-1] = (stretches[-1][0], t1)
+
+
+def setup_since(name: str, t0: float) -> None:
+    """A phase that began at `t0` on `time.perf_counter`, before this
+    module could be imported, and ends now: the package's `import`. No
+    span: the ring and the profiler's session start after it."""
+    t1 = time.perf_counter()
+    with _LOCK:
+        rec = _SETUP.setdefault(name, _new_phase(t0, None))
+        rec["s"] += t1 - t0
+        rec["n"] += 1
+        rec["t1"] = max(rec["t1"], t1)
+        _add_stretch(rec, t0, t1)
+
+
+def setup_record() -> Dict[str, dict]:
+    """The process's set-up as the program saw it: {phase: {`s` seconds
+    summed over its entries, `n` entries, `t0` its first entry and `t1`
+    its last exit on `time.perf_counter`, `parent` the phase it was
+    first opened inside or None, `stretches` the (start, end) of each
+    time it was opened with no phase around it (the first
+    `PHASE_STRETCHES`; the last one grows after that): what a reader
+    lays on the clock, since a phase inside another is covered by it;
+    and the phase's own counters}}. Phases:
+    `import` (top to bottom of `paddle_tpu/__init__.py`; a `jax` the
+    caller imported first is before it), `build.model` (every `Layer`
+    constructor, the outermost one's seconds), `build.params` inside it
+    (the initialisers' calls, with `params` and `bytes`),
+    `build.optimizer` (the accumulators, when they are first asked for),
+    `build.train_step` (`TrainStep.__init__`), and `<family>.lower`
+    with `<family>.trace` inside it, `<family>.backend` and
+    `<family>.first_run` of every `CompileTimed` first call. A phase's
+    self time is its seconds less what the phases opened inside it
+    cover. A reader lays the record between two marks of its own on the
+    same clock and counts what lies between them."""
+    with _LOCK:
+        return {name: dict(rec, stretches=list(rec["stretches"]))
+                for name, rec in _SETUP.items()}
+
+
+class ProgramRow(NamedTuple):
+    """One of JAX's duration events: a function traced to a jaxpr
+    (`trace`; the outermost one: the functions traced inside it are
+    counted in the totals, as `traced_inside`, and have no rows), a
+    jaxpr lowered to a module (`lower`), a module compiled,
+    or looked up in the persistent cache and loaded (`backend`), and
+    inside that, the cache's load alone (`load`). `t` is the event's end
+    on `time.perf_counter` and `seconds` its length, so `t - seconds` is
+    its start; `family`, `phase` and `step` are the `CompileTimed`
+    family, the set-up phase and the `TrainStep` step it fell into, each
+    None where there was none."""
+    t: float
+    kind: str
+    fun_name: Optional[str]
+    seconds: float
+    family: Optional[str]
+    phase: Optional[str]
+    step: Optional[int]
+
+
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_PROGRAM_KINDS = {
+    _TRACE_EVENT: "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "load",
+}
+PROGRAM_LOG_ROWS = 4096
+_PROGRAMS: List[ProgramRow] = []
+_PROGRAM_TOTALS = {kind: {"n": 0, "s": 0.0}
+                   for kind in (*_PROGRAM_KINDS.values(), "traced_inside")}
+
+# {code object of a function that runs one step: the name of its local
+# that holds the step's id}: how a program built in the middle of a
+# step is told which step that was. `jit/__init__.py` lists
+# `TrainStep._call`. The step itself pays nothing to be found: the
+# listener looks at the stack, and it runs when JAX builds a program.
+STEP_CALLS: Dict[object, str] = {}
+
+
+def _in_flight(depth: int = 2):
+    """(compile family, step id) of the calls on this thread's stack:
+    the innermost `CompileTimed.__call__` and the innermost function of
+    `STEP_CALLS`, None where there is none."""
+    family = None
+    frame = sys._getframe(depth)
+    while frame is not None:
+        code = frame.f_code
+        if code is _COMPILE_TIMED_CALL:
+            if family is None:
+                family = frame.f_locals["self"].family
+        elif code in STEP_CALLS:
+            return family, frame.f_locals.get(STEP_CALLS[code])
+        frame = frame.f_back
+    return family, None
+
+
+def _on_trace_begins(event: str, _value, **_kw) -> None:
+    # JAX says when a trace begins (a scalar, its start time) and when
+    # it has ended (the duration below): between the two, the functions
+    # a model calls are traced into the outer program by the thousand
+    if event == _TRACE_EVENT:
+        _TRACE_NOTES.tracing += 1
+
+
+def _on_program(event: str, seconds: float, fun_name=None, **_kw) -> None:
+    kind = _PROGRAM_KINDS.get(event)
+    if kind is None:
+        return
+    t = time.perf_counter()
+    th = _TRACE_NOTES
+    row = None
+    if kind == "trace":
+        th.tracing = max(th.tracing - 1, 0)
+        if th.tracing:
+            kind = "traced_inside"      # counted, and no row
+    if kind != "traced_inside":
+        if fun_name is not None:
+            # a module is named after its function: jit(build) is build
+            fun_name = str(fun_name)
+            if fun_name.startswith("jit(") and fun_name.endswith(")"):
+                fun_name = fun_name[4:-1]
+        if kind == "lower":
+            th.lowered = fun_name
+        elif kind == "load":
+            fun_name = th.lowered   # the cache's event carries no name
+        if th.notes is not None:
+            family, step = th.family, th.step
+        else:
+            family, step = _in_flight()
+        phases = th.phases
+        row = ProgramRow(t, kind, fun_name, seconds, family,
+                         phases[-1][0] if phases else None, step)
+    with _LOCK:
+        total = _PROGRAM_TOTALS[kind]
+        total["n"] += 1
+        total["s"] += seconds
+        if row is not None and len(_PROGRAMS) < PROGRAM_LOG_ROWS:
+            _PROGRAMS.append(row)
+
+
+def program_log() -> dict:
+    """Every program JAX traced, lowered, compiled or loaded in this
+    process, by JAX's own duration events: `rows`, the first
+    `PROGRAM_LOG_ROWS` `ProgramRow`s in the order the events ended (a
+    cache load ends before the `backend` event around it), and `totals`,
+    {kind: {`n`, `s`}} over all of them, which go on when the rows are
+    full. It answers
+    what set-up spent on programs that are not the step's (rows whose
+    `family` is not the step's, by name), and which step built a program
+    after its family's first call (a row with a `step` and no first-call
+    phase: a new input signature, which `CompileTimed` serves through
+    the polymorphic function without a word). A warm step fires no
+    event, so the listener costs it nothing."""
+    with _LOCK:
+        return {"rows": list(_PROGRAMS),
+                "totals": {kind: dict(total)
+                           for kind, total in _PROGRAM_TOTALS.items()}}
 
 
 def record_compile(family: str, compiled) -> Optional[CostModel]:
@@ -447,11 +801,13 @@ class CompileTimed:
     executable; `expected` carries the CostModel for roofline
     accounting at the call sites.
 
-    The first call's three phases are spans (`compile.lower`,
-    `compile.backend`, `compile.first_run`, each with `family=`) and
-    their seconds go to `compile_record(family)`, metrics on or off:
-    what a run's set-up spent lowering and compiling (or loading) the
-    family's programs.
+    The first call's parts are four spans (`compile.lower` and, inside
+    it, `compile.trace`: the trace to a jaxpr apart from the jaxpr's
+    lowering; `compile.backend`; `compile.first_run`; each with
+    `family=`), each a phase of `setup_record()` (`<family>.lower`, ...)
+    and their seconds go to `compile_record(family)`, metrics on or off:
+    what a run's set-up spent tracing, lowering and compiling (or
+    loading) the family's programs.
 
     Degradation contract: if AOT lowering/compiling raises (an exotic
     backend, a sharding the AOT path rejects) the shim falls back to
@@ -529,20 +885,40 @@ class CompileTimed:
         out = None
         ran = False
         parts = {"lower": 0.0, "backend": 0.0, "first_run": 0.0}
-        notes = {}
+        notes, scopes = {}, {}
+        th = _TRACE_NOTES
+        step = _in_flight(1)[1]
 
         def timed(part, fn, *a):
-            # one phase of the first call: a `compile.<part>` span, its
-            # seconds kept for the family's compile_record beside what
-            # the traced code noted of itself (`trace_note`)
-            with _t.span("compile." + part, family=self.family):
-                t = time.perf_counter()
-                outer, _TRACE_NOTES.notes = _TRACE_NOTES.notes, notes
-                try:
+            # one part of the first call: a `compile.<part>` span and a
+            # phase of the set-up record, its seconds kept for the
+            # family's compile_record beside what the traced code noted
+            # of itself (`trace_note`, and `trace_timed` while it traces)
+            outer = th.notes, th.family, th.step, th.scopes
+            th.notes, th.family, th.step = notes, self.family, step
+            if part in ("lower", "trace"):
+                th.scopes = scopes
+            phase = setup_phase(f"{self.family}.{part}",
+                                span="compile." + part, family=self.family)
+            try:
+                with phase:
                     return fn(*a)
-                finally:
-                    _TRACE_NOTES.notes = outer
-                    parts[part] += time.perf_counter() - t
+            finally:
+                th.notes, th.family, th.step, th.scopes = outer
+                parts[part] = parts.get(part, 0.0) + phase.seconds
+
+        def lower():
+            # `jit_fn.lower(*args)` is `.trace(*args).lower()`: the same
+            # work in two calls, so that the trace is timed by itself
+            trace = getattr(self.jit_fn, "trace", None)
+            if trace is not None:
+                try:
+                    traced = timed("trace", trace, *args)
+                except Exception:
+                    parts.pop("trace")      # `trace_s` stays absent
+                else:
+                    return traced.lower()
+            return self.jit_fn.lower(*args)
 
         compiled = None
         if self.store is not None:
@@ -559,7 +935,7 @@ class CompileTimed:
                 compiled = None
         if compiled is None:
             try:
-                lowered = timed("lower", self.jit_fn.lower, *args)
+                lowered = timed("lower", lower)
                 # a cache load when jax's persistent cache hits
                 compiled = timed("backend", lowered.compile)
             except Exception:
@@ -574,7 +950,7 @@ class CompileTimed:
         # retry — which pays the compile again or hits jax's cache —
         # records it instead of losing the count
         self.pending = False
-        _note_compile(self.family, parts, outcome, notes)
+        _note_compile(self.family, parts, outcome, notes, scopes)
         if compiled is not None:
             self.fn = compiled
             self.expected = record_compile(self.family, compiled)
@@ -584,3 +960,8 @@ class CompileTimed:
             h.labels(family=self.family).observe(
                 time.perf_counter() - t0)
         return out
+
+
+_COMPILE_TIMED_CALL = CompileTimed.__call__.__code__
+_monitoring.register_scalar_listener(_on_trace_begins)
+_monitoring.register_event_duration_secs_listener(_on_program)

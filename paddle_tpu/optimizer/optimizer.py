@@ -166,7 +166,8 @@ class Optimizer:
     def _get_state(self, p: Tensor) -> Dict[str, jax.Array]:
         st = self._accumulators.get(id(p))
         if st is None:
-            st = self._init_state(p)
+            with _pf.setup_phase("build.optimizer"):
+                st = self._init_state(p)
             self._accumulators[id(p)] = st
         return st
 

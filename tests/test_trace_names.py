@@ -448,8 +448,46 @@ def test_train_step_spans_and_the_compile_record(tiny_step):
     assert [p["name"] for p in phases] == [
         "compile.lower", "compile.backend", "compile.first_run"]
     assert all(p["args"] == {"family": "train_step"} for p in phases)
-    assert sum(e["name"].startswith("compile.") for e in evs) == 3
+    # the trace to a jaxpr is a span of its own inside the lowering's
+    trace, = [e for e in evs if e.get("parent_id") == phases[0]["span_id"]]
+    assert trace["name"] == "compile.trace"
+    assert trace["args"] == {"family": "train_step"}
+    assert sum(e["name"].startswith("compile.") for e in evs) == 4
     assert phases[0]["dur"] * 1e-6 == pytest.approx(rec["lower_s"], rel=0.05)
+    assert trace["dur"] * 1e-6 == pytest.approx(rec["trace_s"], rel=0.05)
+    assert 0 < rec["trace_s"] < rec["lower_s"]
+
+
+def test_set_ups_phases_are_spans_by_their_names():
+    """With the ring on, what `perf.setup_record()` counts is also a
+    `setup.<phase>` span: sublayers' constructors and the initialisers
+    inside their parent's, the accumulators inside `TrainStep.__init__`."""
+    tracing.enable()
+    pt.seed(0)
+    model = nn.Sequential(nn.Linear(4, 4), nn.Linear(4, 2))
+    step = TrainStep(model, AdamW(learning_rate=1e-3,
+                                  parameters=model.parameters()),
+                     lambda m, x: pt.ops.mean(m(x)))
+    tracing.disable()
+    evs = tracing.events()
+    by_id = {e["span_id"]: e for e in evs}
+
+    def parents(name):
+        return {by_id[e["parent_id"]]["name"] if "parent_id" in e else None
+                for e in evs if e["name"] == name}
+
+    assert {e["name"] for e in evs} == {
+        "setup.build.model", "setup.build.params", "setup.build.optimizer",
+        "setup.build.train_step"}
+    # Sequential's own constructor is the root; Layer.__init__ and the
+    # two Linears' constructors run inside one
+    assert parents("setup.build.model") == {None, "setup.build.model"}
+    assert parents("setup.build.params") == {"setup.build.model"}
+    assert parents("setup.build.optimizer") == {"setup.build.train_step"}
+    assert parents("setup.build.train_step") == {None}
+    assert sum(e["name"] == "setup.build.params" for e in evs) == 4
+    assert sum(e["name"] == "setup.build.optimizer" for e in evs) == 4
+    assert step._step_fn.pending                # nothing was compiled
 
 
 # ---------------------------------------------------------------------------
@@ -488,6 +526,9 @@ def test_engine_step_splits_into_phase_spans():
     assert children(kids[3]) == [
         "engine.schedule", "engine.pack", "compile.lower",
         "compile.backend", "compile.first_run"]
+    lower, = [e for e in evs if e["name"] == "compile.lower"
+              and e.get("parent_id") == kids[3]["span_id"]]
+    assert children(lower) == ["compile.trace"]
     assert perf.compile_record("engine_decode")["compiles"] >= 1
     # every engine span of the step hangs under engine.step
     for e in evs:
