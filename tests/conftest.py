@@ -70,24 +70,44 @@ def pytest_configure(config):
         config.args.insert(0, bench_tests)
 
 
-# benchmarks/tests/test_laguna.py pins the number of cells at four. The PR
-# that added the fifth may add files under benchmarks/ and edit none
-# (the driver refuses a PR that edits a benchmark file), so that one
-# assertion cannot be repaired where it stands: its other assertions are
-# held, without the count, by benchmarks/tests/test_zaya.py::
-# test_lagunas_cell_reports_what_it_did. The next `benchmark` PR changes
-# the line and takes this entry out (PERF.md section 7).
-_PINNED_CELL_COUNT = ("test_laguna.py::"
-                      "test_the_cell_joins_the_shared_metrics_and_brings_"
-                      "its_own")
+# Assertions under benchmarks/tests that pin what a later PR had to add
+# to. Such a PR may add files under benchmarks/ and edit none (the driver
+# refuses a PR that edits a benchmark file), and its entries go to the end
+# of BENCHMARK.json's lists (one put in the middle reads to the driver as
+# a change to what was there), so these cannot be repaired where they
+# stand. Every other assertion of each is held, as it was, by a test that
+# the adding PR brought. The marks are strict: a test here that passes is
+# an error. The next `benchmark` PR changes the lines and takes these
+# entries out (PERF.md section 7).
+_PINNED = {
+    # four cells; the fifth came in PR 35, by files alone. Held by
+    # test_zaya.py::test_lagunas_cell_reports_what_it_did, now by
+    # test_rope_trace.py's test of the two cells
+    "test_laguna.py::test_the_cell_joins_the_shared_metrics_and_brings_"
+    "its_own": "pins four cells",
+    # the Zaya and the Laguna cell's sets of metrics, and the six set-up
+    # entries as the list's last: `rope_ms.train` joined both cells at
+    # the list's end in PR 38. Held by test_rope_trace.py::
+    # test_the_two_cells_report_what_they_did_and_the_rotary and
+    # test_the_set_up_entries_stand_as_they_were
+    "test_zaya.py::test_the_cell_joins_the_shared_metrics_and_brings_"
+    "its_own": "pins the cell's metrics",
+    "test_zaya.py::test_lagunas_cell_reports_what_it_did":
+        "pins the cell's metrics",
+    "test_setup_metrics.py::test_the_entries_of_benchmark_json":
+        "pins the last six entries of per_layer",
+}
 
 
 def pytest_collection_modifyitems(config, items):
     for item in items:
-        if item.nodeid.endswith(_PINNED_CELL_COUNT):
+        test = item.nodeid.split("[")[0]
+        why = next((w for k, w in _PINNED.items()
+                    if test.endswith("benchmarks/tests/" + k)), None)
+        if why:
             item.add_marker(pytest.mark.xfail(
-                reason="pins four cells; a fifth was added by files "
-                       "alone (see tests/conftest.py)", strict=False))
+                reason=f"{why}; an entry was added by files alone (see "
+                       "tests/conftest.py)", strict=True))
     if config.getoption("--runslow"):
         return
     skip = pytest.mark.skip(reason="slow tier: run with --runslow")

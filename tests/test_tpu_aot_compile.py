@@ -518,13 +518,14 @@ def laguna_step(v5e):
     ("moe_sum_rows", 4),    # a layer: combine forward, take_rows backward
     ("flash_fwd", 3),       # the window layer: forward, again; the full
                             # layer keeps its o and lse: once
-    ("flash_bwd_transpose", 2)])
+    ("flash_bwd_transpose", 2),
+    ("rope_rotate", 12)])   # a layer's q, its k: forward, again, back
 def test_the_laguna_step_holds_its_mosaic_kernels(laguna_step, kernel,
                                                   calls):
     text, _notes = laguna_step
     found = re.findall(rf"%{kernel}[.\d]* = .*custom-call\(", text)
     assert len(found) == calls, (kernel, len(found))
-    assert text.count("tpu_custom_call") == 25
+    assert text.count("tpu_custom_call") == 37
 
 
 def test_the_laguna_step_says_which_paths_it_took(laguna_step):
@@ -546,6 +547,10 @@ def test_the_laguna_step_says_which_paths_it_took(laguna_step):
                         "window 512",
         "moe": "pallas, experts 8 held of 256, top 8, tiles of 128 rows, "
                "way back: held rows in windows of 16 (moe_sum_rows)",
+        "rope": "rope_rotate: 64 heads, rot 128 of 128; "
+                "rope_rotate: 8 heads, rot 128 of 128; "
+                "rope_rotate: 48 heads, rot 64 of 128; "
+                "rope_rotate: 8 heads, rot 64 of 128",
         "head_loss": "fused, chunks 1"}
 
 
@@ -613,6 +618,27 @@ def test_flash_attention_at_zayas_latent_heads_compiles(v5e):
     assert text.count("tpu_custom_call") == 2
 
 
+@pytest.mark.parametrize("back", [False, True], ids=["turn", "turn_back"])
+@pytest.mark.parametrize("heads", [ZAYA_HEADS, ZAYA_KV], ids=["q", "k"])
+def test_rope_rotate_compiles_at_zayas_shape(v5e, heads, back):
+    """The rotary of `zaya1-8b-l5-e8.train-32k`: 32768 positions, q on 8
+    latent heads and k on 2, the first 64 of a head's 128 dimensions
+    turned, so both rolls (by 32 and by 96 lanes) and the lanes that
+    pass."""
+    rope = import_module("paddle_tpu.kernels.pallas.rope")
+    one = SingleDeviceSharding(v5e[0])
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    text = _compiled_text(
+        lambda x, cos, sin: rope.rotate(x, cos, sin, back=back),
+        S((1, ZAYA_SEQ, heads, 128), jnp.bfloat16),
+        S((ZAYA_SEQ, 64), jnp.float32), S((ZAYA_SEQ, 64), jnp.float32))
+    assert len(re.findall(r"%rope_rotate[.\d]* = .*custom-call\(",
+                          text)) == 1
+
+
 @pytest.fixture(scope="module")
 def zaya_step(v5e):
     """Two layers at ZAYA1-8B's widths (2 of its 16 experts held, a
@@ -677,12 +703,13 @@ def zaya_step(v5e):
     ("moe_sum_rows", 6),    # a layer: combine forward and again (the
                             # residual's alpha_o needs y), take_rows back
     ("flash_fwd", 2),       # a layer: once, its block keeps o and lse
-    ("flash_bwd_transpose", 2)])
+    ("flash_bwd_transpose", 2),
+    ("rope_rotate", 12)])   # a layer's q, its k: forward, again, back
 def test_the_zaya_step_holds_its_mosaic_kernels(zaya_step, kernel, calls):
     text, _notes = zaya_step
     found = re.findall(rf"%{kernel}[.\d]* = .*custom-call\(", text)
     assert len(found) == calls, (kernel, len(found))
-    assert text.count("tpu_custom_call") == 26
+    assert text.count("tpu_custom_call") == 38
 
 
 def test_the_zaya_step_says_which_paths_it_took(zaya_step):
@@ -705,4 +732,6 @@ def test_the_zaya_step_says_which_paths_it_took(zaya_step):
         "moe": "pallas, experts 2 held of 16, top 1, tiles of 128 rows, "
                "weight blocks in column tiles of 1024 and 1024, "
                "way back: held rows in windows of 16 (moe_sum_rows)",
+        "rope": "rope_rotate: 8 heads, rot 64 of 128; "
+                "rope_rotate: 2 heads, rot 64 of 128",
         "head_loss": "fused, chunks 1"}
