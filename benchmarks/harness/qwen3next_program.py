@@ -1,0 +1,31 @@
+"""The glue to the program's Qwen3-Next: its model object at a
+configuration's sizes and share, holding the seed's weights."""
+from __future__ import annotations
+
+
+def build_model(cfg: dict, seed: int, ref, **model_kw):
+    """`Qwen3NextForCausalLM` at `cfg`'s sizes with the seed's float32
+    weights. The program builds its parameters as placeholders
+    (`paddle_tpu.LazyGuard`: its own draws would be thrown away, and
+    cost a cold run 30 s of compiles); each is then handed the harness's
+    array of the same name and shape (`qwen3next_reference.make`: a
+    function of the seed)."""
+    import jax.numpy as jnp
+    import paddle_tpu as pt
+    from paddle_tpu.models.qwen3_next import (Qwen3NextConfig,
+                                              Qwen3NextForCausalLM)
+
+    with pt.LazyGuard():
+        model = Qwen3NextForCausalLM(
+            Qwen3NextConfig.from_dict(cfg, **model_kw))
+    specs = ref.param_specs(cfg)
+    arrays = dict(zip((n for n, _s, _i in specs),
+                      ref.make(seed, specs, jnp.float32)))
+    for name, p in model.named_parameters():
+        if tuple(p.shape) != tuple(arrays[name].shape):
+            raise RuntimeError(f"{name}: the program has {p.shape}, the "
+                               f"reference {arrays[name].shape}")
+        p._data = arrays.pop(name)
+    if arrays:
+        raise RuntimeError(f"the program lacks {sorted(arrays)}")
+    return model

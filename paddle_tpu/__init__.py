@@ -41,6 +41,7 @@ from .autograd import is_grad_enabled  # noqa: F401
 # ---- subpackages ----
 from . import autograd  # noqa: F401
 from . import nn  # noqa: F401
+from .nn.layer import LazyGuard  # noqa: F401
 from . import optimizer  # noqa: F401
 from . import amp  # noqa: F401
 from . import io  # noqa: F401

@@ -68,8 +68,10 @@ def _resolve_policy(policy):
 
 def flash_policy(attention):
     """The policy of a recomputed block whose attention layer is
-    `attention` (None: it has none): "flash_outputs" where every key is
-    in a query's sight, else None.
+    `attention` (None: it has none, as a layer whose mixer is a
+    recurrence: `models/jamba.py`'s Mamba layers, `models/qwen3_next.py`'s
+    Gated DeltaNet layers): "flash_outputs" where every key is in a
+    query's sight, else None.
 
     What keeping buys for a byte of `o` is the number of keys a query row
     meets: 16,384 on average over 32,768 causal tokens (ZAYA1, 243 ms a

@@ -25,6 +25,10 @@ from .laguna import (  # noqa: F401
 from .zaya import (  # noqa: F401
     ZayaConfig, ZayaModel, ZayaForCausalLM, ZayaDecoderLayer, zaya_tiny,
 )
+from .qwen3_next import (  # noqa: F401
+    Qwen3NextConfig, Qwen3NextModel, Qwen3NextForCausalLM,
+    Qwen3NextAttention, Qwen3NextDecoderLayer, qwen3_next_tiny,
+)
 from .bert import (  # noqa: F401
     BertConfig, BertModel, BertForMaskedLM, bert_tiny, bert_base,
 )

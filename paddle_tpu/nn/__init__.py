@@ -23,6 +23,7 @@ from .layers.moe import (  # noqa: F401
     yarn_inv_freq,
 )
 from .layers.cca import CompressedConvAttention, ResidualScale  # noqa: F401
+from .layers.gdn import GatedDeltaNet, ZeroCenteredRMSNorm  # noqa: F401
 from .layers.conv import (  # noqa: F401
     Conv1D, Conv2D, Conv3D, Conv2DTranspose, Conv1DTranspose,
     Conv3DTranspose,

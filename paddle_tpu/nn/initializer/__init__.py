@@ -31,8 +31,15 @@ class Normal(Initializer):
         self.mean, self.std = mean, std
 
     def __call__(self, shape, dtype=jnp.float32):
-        return (jax.random.normal(next_key(), shape, dtype) * self.std
-                + self.mean)
+        # drawn as [rows, last dimension] and viewed as `shape`: the same
+        # numbers (a draw is a function of the key and the element's
+        # flat index), and the stacked experts' three-dimensional leaf
+        # compiles for a TPU in a third of the time (3 s against 10 at
+        # [64, 2048, 1024])
+        flat = (math.prod(shape[:-1]), shape[-1]) if len(shape) > 2 \
+            else shape
+        return (jax.random.normal(next_key(), flat, dtype) * self.std
+                + self.mean).reshape(shape)
 
 
 class TruncatedNormal(Initializer):

@@ -67,6 +67,22 @@ class _Tracing(threading.local):
 
 
 _TRACING = _Tracing()
+
+
+class LazyGuard:
+    """Inside it a Layer's parameters are placeholders of their shape and
+    type (zeros), not draws: for a model whose weights are loaded next
+    (`set_state_dict`, a harness's arrays). A draw that is thrown away
+    costs a compile a shape on a cold TPU (30 s at 45 leaves of 1 B
+    parameters). ref: python/paddle/lazy_init.py (LazyGuard)."""
+    on = False
+
+    def __enter__(self):
+        self._before, LazyGuard.on = LazyGuard.on, True
+        return self
+
+    def __exit__(self, *exc):
+        LazyGuard.on = self._before
 _NO_SCOPE = contextlib.nullcontext()
 
 
@@ -240,7 +256,9 @@ class Layer:
         if init is None:
             init = Constant(0.0) if is_bias else XavierUniform()
         with _pf.setup_phase("build.params") as phase:
-            data = init(tuple(int(s) for s in shape), dtypes.to_jnp(dtype))
+            shape = tuple(int(s) for s in shape)
+            data = jnp.zeros(shape, dtypes.to_jnp(dtype)) if LazyGuard.on \
+                else init(shape, dtypes.to_jnp(dtype))
             phase.count(params=1, bytes=int(getattr(data, "nbytes", 0)))
         p = Parameter(data, name=name)
         return p

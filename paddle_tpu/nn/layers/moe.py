@@ -1,5 +1,5 @@
 """A sparse (mixture-of-experts) feed-forward that is told which experts
-it holds, its two routers, the load gauges of a step's counts, and the
+it holds, its three routers, the load gauges of a step's counts, and the
 rotary tables of layers that rotate part of a head or stretch their
 frequencies (YaRN)."""
 from __future__ import annotations
@@ -21,14 +21,17 @@ class _Router(Layer):
     """scores -> the chosen experts and their weights (`ops.moe_route`)."""
     carries_state = False
 
-    def __init__(self, hidden, num_experts, top_k, routed_scale, std):
+    def __init__(self, hidden, num_experts, top_k, routed_scale, std,
+                 score="sigmoid"):
         super().__init__()
         self.top_k, self.routed_scale = top_k, routed_scale
+        self.score = score
         self.weight = self.create_parameter((hidden, num_experts),
                                             attr=Normal(std=std))
 
     def forward(self, x):
-        return ops.moe_route(x, self.weight, self.top_k, self.routed_scale)
+        return ops.moe_route(x, self.weight, self.top_k, self.routed_scale,
+                             self.score)
 
 
 class _MLPRouter(Layer):
@@ -76,17 +79,23 @@ class SwiGLU(Layer):
 class SparseExpertFFN(Layer):
     """y = sum over a token's top_k experts e of w_e * SwiGLU_e(x)
            + SwiGLU_shared(x): dropless (no capacity; every assignment is
-    computed, whatever the imbalance). Two routers exist:
+    computed, whatever the imbalance). Three routers exist:
 
     * the linear sigmoid one (`ops.moe_route`; `laguna-xs2-l5-e64`):
       w = routed_scale * s / sum_chosen s, s = sigmoid(x W_r), the top_k
       largest chosen;
+    * `router_score="softmax"`: the linear softmax one (`ops.moe_route`
+      with `score="softmax"`; `qwen3-next-80b-l4-e64`): the same with
+      s = softmax(x W_r) over all `num_experts`;
     * `router_mlp=(width, draws)`: the MLP one (`ops.moe_route_mlp`;
       `zaya1-8b-l5-e8`): a down-projection to `width` to which the
       layer before's is added (depth averaging), two hidden layers,
       softmax, the one largest chosen and w its probability. forward
       then takes and returns the router's state: `(x, state) ->
       (y, counts, state, weights, experts)`.
+
+    `shared_gate=True`: the shared expert's output is multiplied by
+    sigmoid(x w_g), w_g [hidden, 1] (`shared_expert_gate`), a token.
 
     `held = (first, count)`: the experts this layer's weights are, of
     `num_experts`. The router keeps its `num_experts` outputs and routes
@@ -108,7 +117,8 @@ class SparseExpertFFN(Layer):
 
     def __init__(self, hidden, width, num_experts=256, top_k=8, held=None,
                  shared_width=512, routed_scale=2.5, std=0.02,
-                 router_mlp=None):
+                 router_mlp=None, router_score="sigmoid",
+                 shared_gate=False):
         super().__init__()
         first, count = held or (0, num_experts)
         if first < 0 or count < 1 or first + count > num_experts:
@@ -118,7 +128,7 @@ class SparseExpertFFN(Layer):
         self.first, self.count = first, count
         if router_mlp is None:
             self.router = _Router(hidden, num_experts, top_k, routed_scale,
-                                  std)
+                                  std, router_score)
         else:
             if top_k != 1:
                 raise ValueError("the MLP router chooses one expert")
@@ -130,6 +140,11 @@ class SparseExpertFFN(Layer):
             (count, width, hidden), attr=Normal(std=std))
         self.shared_expert = SwiGLU(hidden, shared_width, std) \
             if shared_width else None
+        self.shared_expert_gate = Linear(
+            hidden, 1, weight_attr=Normal(std=std), bias_attr=False) \
+            if shared_gate else None
+        self._scores_note = ", softmax scores" \
+            if router_mlp is None and router_score == "softmax" else ""
 
     def forward(self, x, state=None):
         from ...kernels.pallas.grouped_matmul import (ROW_TILE, gmm_path,
@@ -148,13 +163,16 @@ class SparseExpertFFN(Layer):
                         f"of {self.num_experts}, top {self.top_k}, "
                         f"tiles of {ROW_TILE} rows"
                         f"{tiles_note(self.gate_up_proj.shape)}, way back: "
-                        f"{way_back_path()}")
+                        f"{way_back_path()}{self._scores_note}")
         y, counts = ops.moe_experts(flat, weights, experts,
                                     self.gate_up_proj, self.down_proj,
                                     self.first)
         y = ops.reshape(y, shape)
         if self.shared_expert is not None:
-            y = y + self.shared_expert(x)
+            shared = self.shared_expert(x)
+            if self.shared_expert_gate is not None:
+                shared = shared * ops.sigmoid(self.shared_expert_gate(x))
+            y = y + shared
         return (y, counts, state, weights, experts) if carries \
             else (y, counts)
 

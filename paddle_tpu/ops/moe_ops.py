@@ -1,4 +1,5 @@
-"""Dropless mixture-of-experts ops: the router, the permutation of
+"""Dropless mixture-of-experts ops: the three routers (`moe_route` with
+sigmoid or softmax scores, `moe_route_mlp`), the permutation of
 assignments to the experts held here, and the expert products around
 `kernels/pallas/grouped_matmul.py`.
 
@@ -35,15 +36,19 @@ F32 = jnp.float32
 
 
 @register_op("moe_route", amp_policy="black")
-def moe_route(x, router_weight, top_k, routed_scale=1.0):
+def moe_route(x, router_weight, top_k, routed_scale=1.0, score="sigmoid"):
     """x [T, d], router_weight [d, E] -> (weights [T, top_k] float32,
-    experts [T, top_k] int32): scores = sigmoid(x W) in float32 (the
-    product at `highest` precision: a TPU's default rounds a float32
-    matmul's operands to bf16, and a top-k choice turns on the last
-    digits), the top_k largest chosen, their scores normalised to one
-    and multiplied by routed_scale. On amp's black list. The router of
-    `laguna-xs2-l5-e64`; `zaya1-8b-l5-e8`'s is `moe_route_mlp`."""
-    scores = jax.nn.sigmoid(jnp.matmul(
+    experts [T, top_k] int32): scores = sigmoid(x W), or with
+    `score="softmax"` softmax(x W) over all E, in float32 (the product
+    at `highest` precision: a TPU's default rounds a float32 matmul's
+    operands to bf16, and a top-k choice turns on the last digits), the
+    top_k largest chosen, their scores normalised to one and multiplied
+    by routed_scale (of a softmax that is the softmax over the chosen
+    logits). On amp's black list. The sigmoid is the router of
+    `laguna-xs2-l5-e64`, the softmax of `qwen3-next-80b-l4-e64`;
+    `zaya1-8b-l5-e8`'s is `moe_route_mlp`."""
+    squash = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}[score]
+    scores = squash(jnp.matmul(
         x.astype(F32), router_weight.astype(F32),
         precision=jax.lax.Precision.HIGHEST))
     top, experts = _top_k(scores, top_k)

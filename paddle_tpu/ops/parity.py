@@ -283,3 +283,36 @@ def classify():
         else:
             unmapped.append(name)
     return table, unmapped
+
+
+# Registry ops the reference's YAML surface has no row for: the ops the
+# benchmark's model families brought. name -> (where it is written, the
+# kernel behind it on a TPU or "-", the layer and model that call it).
+BEYOND_YAML = {
+    "selective_scan": (
+        "ops/ssm_ops.py", "kernels/pallas/selective_scan.py "
+        "(ssm_scan_fwd, ssm_scan_bwd)",
+        "models.jamba.JambaMambaMixer"),
+    "causal_conv1d": (
+        "ops/ssm_ops.py", "-",
+        "models.jamba.JambaMambaMixer, nn.GatedDeltaNet"),
+    "moe_route": (
+        "ops/moe_ops.py", "-",
+        "nn.SparseExpertFFN: sigmoid scores (models.laguna), softmax "
+        "scores (models.qwen3_next)"),
+    "moe_route_mlp": ("ops/moe_ops.py", "-",
+                      "nn.SparseExpertFFN(router_mlp=...) (models.zaya)"),
+    "moe_experts": (
+        "ops/moe_ops.py", "kernels/pallas/grouped_matmul.py (moe_gmm, "
+        "moe_gmm_dw), kernels/pallas/moe_sum_rows.py",
+        "nn.SparseExpertFFN"),
+    "cca_mix": ("ops/cca_ops.py", "-",
+                "nn.CompressedConvAttention (models.zaya)"),
+    "rope_rotate_half": (
+        "ops/rope_ops.py", "kernels/pallas/rope.py (rope_rotate)",
+        "models.laguna, models.zaya, models.qwen3_next"),
+    "gated_delta_rule": (
+        "ops/linear_attn_ops.py", "kernels/pallas/gated_delta.py "
+        "(gdn_state_fwd, gdn_state_bwd)",
+        "nn.GatedDeltaNet (models.qwen3_next)"),
+}

@@ -463,6 +463,10 @@ class TestRegistryCoverage:
         # norms, the shifted head) and benchmarks/tests/test_zaya.py
         # (every gradient against the plain reference)
         "moe_route_mlp", "cca_mix",
+        # covered by tests/test_gated_delta_rule.py (against the
+        # token-by-token recurrence in float64, values and all five
+        # gradients; the kernels against the scan)
+        "gated_delta_rule",
     }
 
     def test_coverage_accounting(self):

@@ -45,3 +45,24 @@ def test_doc_is_in_sync():
     table, unmapped = classify()
     assert "UNMAPPED" not in text
     assert f"**{len(table)} YAML forward ops**" in text
+
+
+def test_the_ops_beyond_the_yaml_surface_resolve_and_are_listed():
+    """Every op of `BEYOND_YAML` is in the registry, in no YAML file, its
+    source file exists, and OPS_PARITY.md has its row."""
+    import os
+    from paddle_tpu.ops.parity import BEYOND_YAML
+    from paddle_tpu.ops.registry import OPS
+    table, _unmapped = classify()
+    root = os.path.join(os.path.dirname(__file__), "..")
+    text = open(os.path.join(root, "OPS_PARITY.md")).read()
+    assert {"gated_delta_rule", "moe_route", "cca_mix"} <= set(BEYOND_YAML)
+    for name, (where, kernel, _caller) in BEYOND_YAML.items():
+        assert name in OPS and name not in table, name
+        assert os.path.isfile(os.path.join(root, "paddle_tpu", where)), where
+        if kernel != "-":
+            for path in (w for w in kernel.replace(",", " ").split()
+                         if w.endswith(".py")):
+                assert os.path.isfile(os.path.join(root, "paddle_tpu",
+                                                   path)), path
+        assert f"| `{name}` | {where} |" in text, name

@@ -735,3 +735,199 @@ def test_the_zaya_step_says_which_paths_it_took(zaya_step):
         "rope": "rope_rotate: 8 heads, rot 64 of 128; "
                 "rope_rotate: 2 heads, rot 64 of 128",
         "head_loss": "fused, chunks 1"}
+
+
+# ---------------------------------------------------------------------------
+# Qwen3-Next: the gated delta rule's state kernels at a 128 x 128 state and
+# 16384 tokens, flash at 16 heads on 2 of 256 (a head size no cell had run),
+# the rotary on a quarter of such a head, and a step of one Gated DeltaNet
+# layer and one gated attention layer over softmax-routed experts
+# ---------------------------------------------------------------------------
+gdr = import_module("paddle_tpu.kernels.pallas.gated_delta")
+Q3_SEQ, Q3_HEADS, Q3_KV, Q3_D = 16384, 16, 2, 256   # qwen3-next-80b-l4-e64
+Q3_LINEAR = 32                                      # value heads of 128
+
+
+@pytest.mark.parametrize("back", [False, True], ids=["fwd", "bwd"])
+def test_the_gated_delta_state_kernels_compile(v5e, back):
+    """`gdn_state_fwd` and `gdn_state_bwd` at the cell's shape: 32 value
+    heads, 256 chunks of 64 tokens, a 128 x 128 float32 state a head, four
+    heads a kernel instance."""
+    one = SingleDeviceSharding(v5e[0])
+    B, nc, C, d = Q3_LINEAR, Q3_SEQ // gdr.CHUNK, gdr.CHUNK, 128
+
+    def S(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)
+
+    ins = [S(B, nc, C, d)] * 4 + [S(B, nc, C, C), S(B, nc, 1, d)]
+    if back:
+        text = _compiled_text(gdr._state_bwd_pallas, *ins, S(B, nc, d, d),
+                              S(B, nc, C, d))
+    else:
+        text = _compiled_text(gdr._state_fwd_pallas, *ins)
+    name = "gdn_state_bwd" if back else "gdn_state_fwd"
+    assert len(re.findall(rf"%{name}[.\d]* = .*custom-call\(", text)) == 1
+
+
+def test_the_whole_gated_delta_rule_compiles_with_its_gradients(v5e):
+    """`gated_delta_rule` as a step meets it: float32 operands of 32
+    heads over 4096 tokens, the chunk preparation in XLA around the two
+    kernels, differentiated to all five operands."""
+    one = SingleDeviceSharding(v5e[0])
+
+    def S(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)
+
+    x, g = S(1, 4096, Q3_LINEAR, 128), S(1, 4096, Q3_LINEAR)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        text = _compiled_text(jax.grad(
+            lambda *xs: gdr.gated_delta_rule(*xs).sum(),
+            argnums=range(5)), x, x, x, g, g)
+    for name in ("gdn_state_fwd", "gdn_state_bwd"):
+        assert len(re.findall(rf"%\w*{name}\w*[.\d]* = .*custom-call\(",
+                              text)) == 1, name
+    assert text.count("tpu_custom_call") == 2
+
+
+def test_flash_attention_at_head_size_256_compiles(v5e):
+    """16 query heads on 2 of 256 over one row of 16384 keys: the head
+    size `_shape_reject_reason` admits and no cell had run."""
+    one = SingleDeviceSharding(v5e[0])
+    q = jax.ShapeDtypeStruct((1, Q3_SEQ, Q3_HEADS, Q3_D), jnp.bfloat16,
+                             sharding=one)
+    k = jax.ShapeDtypeStruct((1, Q3_SEQ, Q3_KV, Q3_D), jnp.bfloat16,
+                             sharding=one)
+    assert fa._shape_reject_reason(q.shape, k.shape) is None
+
+    def loss(q, k, v):
+        return fa._flash_core(q, k, v, None, True, Q3_D ** -0.5,
+                              True).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, k, k)
+    assert text.count("tpu_custom_call") == 2
+
+
+@pytest.mark.parametrize("back", [False, True], ids=["turn", "turn_back"])
+@pytest.mark.parametrize("heads", [Q3_HEADS, Q3_KV], ids=["q", "k"])
+def test_rope_rotate_compiles_at_a_quarter_of_a_256_head(v5e, heads, back):
+    """The rotary of `qwen3-next-80b-l4-e64.train-16k`: the first 64 of a
+    head's 256 dimensions turned, the other 192 passed through."""
+    rope = import_module("paddle_tpu.kernels.pallas.rope")
+    one = SingleDeviceSharding(v5e[0])
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    text = _compiled_text(
+        lambda x, cos, sin: rope.rotate(x, cos, sin, back=back),
+        S((1, Q3_SEQ, heads, Q3_D), jnp.bfloat16),
+        S((Q3_SEQ, 64), jnp.float32), S((Q3_SEQ, 64), jnp.float32))
+    assert len(re.findall(r"%rope_rotate[.\d]* = .*custom-call\(",
+                          text)) == 1
+
+
+@pytest.fixture(scope="module")
+def qwen3next_step(v5e):
+    """Two layers at Qwen3-Next-80B-A3B's widths (one Gated DeltaNet
+    layer and one gated attention layer: an interval of 2; 8 of the 512
+    experts held, a sixteenth of the slice of the vocabulary, 1 x 4096
+    tokens), the step written as
+    benchmarks/drivers/qwen3next_train_window.py writes it, compiled for
+    one described v5e: (text, compile record)."""
+    import paddle_tpu as pt
+    from paddle_tpu import amp
+    from paddle_tpu.jit import TrainStep
+    from paddle_tpu.models import GPTPretrainingCriterion
+    from paddle_tpu.models.qwen3_next import (Qwen3NextConfig,
+                                              Qwen3NextForCausalLM)
+    from paddle_tpu.observability import perf
+    from paddle_tpu.optimizer import AdamW
+    one = SingleDeviceSharding(v5e[0])
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
+
+    crit = GPTPretrainingCriterion()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("PADDLE_TPU_PALLAS_AUTOTUNE", "0")
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        was_on = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        pt.seed(0)
+        model = Qwen3NextForCausalLM(Qwen3NextConfig(
+            vocab_size=1187, num_hidden_layers=2, full_attention_interval=2,
+            experts_held=(0, 8), use_flash_attention=True, recompute=True))
+        model.train()
+
+        def loss_fn(m, ids, labels):
+            with amp.auto_cast(enable=True, level="O1", dtype="bfloat16"):
+                logits = m(ids)
+            return crit(logits, labels), m.expert_counts
+
+        step = TrainStep(model, AdamW(
+            learning_rate=1e-4, parameters=model.parameters(),
+            moment_dtype="bfloat16"), loss_fn, has_aux=True)
+        ids = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=one)
+        notes = {}
+        outer, perf._TRACE_NOTES.notes = perf._TRACE_NOTES.notes, notes
+        try:
+            compiled = step._step_fn.jit_fn.lower(
+                [spec(p) for p in step.params],
+                [{k: spec(v) for k, v in st.items()}
+                 for st in step.opt_states],
+                [spec(b) for b in step.buffers],
+                spec(jax.random.PRNGKey(0)), spec(jnp.float32(1e-4)),
+                [ids, ids], {}).compile()
+        finally:
+            perf._TRACE_NOTES.notes = outer
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        compilation_cache.reset_cache()
+    return compiled.as_text(), notes
+
+
+@pytest.mark.parametrize("kernel,calls", [
+    ("gdn_state_fwd", 2),   # the linear layer: forward, and run again
+    ("gdn_state_bwd", 1),
+    ("flash_fwd", 1),       # the full layer: once, its block keeps o, lse
+    ("flash_bwd_transpose", 1),
+    ("moe_gmm", 12),        # two products a layer: forward, again, to rows
+    ("moe_gmm_dw", 4),
+    ("moe_sum_rows", 4),    # a layer: combine forward, take_rows back
+    ("rope_rotate", 6)])    # the full layer's q, its k: forward, again, back
+def test_the_qwen3next_step_holds_its_mosaic_kernels(qwen3next_step, kernel,
+                                                     calls):
+    text, _notes = qwen3next_step
+    found = re.findall(rf"%{kernel}[.\d]* = .*custom-call\(", text)
+    assert len(found) == calls, (kernel, len(found))
+    assert text.count("tpu_custom_call") == 31
+
+
+def test_the_qwen3next_step_says_which_paths_it_took(qwen3next_step):
+    """The Gated DeltaNet's heads, state and chunk with its state pass on
+    the kernels; the softmax router over 512 with its share; the flash
+    kernels at head size 256 on three arrays, walking to the diagonal; of
+    the two recomputed blocks the one with attention keeps its flash
+    outputs; a quarter of a 256 head turned."""
+    text, notes = qwen3next_step
+    root = "model/layers"
+    for scope in ("0/gdn/in_proj_qkvz", "0/gdn/conv", "0/gdn/gates",
+                  "0/gdn/delta_rule", "0/gdn/gated_norm", "0/gdn/out_proj",
+                  "1/attn/rope", "1/attn/out_gate", "1/moe/router",
+                  "0/moe/shared_expert_gate"):
+        assert f"{root}/{scope}/" in text, scope
+    assert notes == {
+        "gdn": "heads 32 on 16, state 128 x 128, chunk 64, conv 4 taps, "
+               "state pass: pallas",
+        "attention": "pallas", "flash_operands": "split",
+        "flash_kept": "o and lse kept across recompute in 1 of 2 "
+                      "recomputed layers",
+        "flash_causal": "fwd 136/256 of 256-wide tiles; "
+                        "bwd 136/256 of 256-wide tiles, dq whole",
+        "moe": "pallas, experts 8 held of 512, top 10, tiles of 128 rows, "
+               "way back: held rows in windows of 16 (moe_sum_rows), "
+               "softmax scores",
+        "rope": "rope_rotate: 16 heads, rot 64 of 256; "
+                "rope_rotate: 2 heads, rot 64 of 256",
+        "head_loss": "fused, chunks 1"}

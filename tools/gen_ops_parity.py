@@ -73,6 +73,19 @@ def render_md():
         lines.append(f"| `{name}` | {kind} | {detail} | {srcs} |")
     for name in unmapped:
         lines.append(f"| `{name}` | **UNMAPPED** | — | |")
+    from paddle_tpu.ops.parity import BEYOND_YAML
+    lines += [
+        "",
+        "## Registry ops beyond the YAML surface",
+        "",
+        "The ops the benchmark's model families brought "
+        "(`paddle_tpu/ops/parity.py:BEYOND_YAML`).",
+        "",
+        "| op | written in | kernel on a TPU | called by |",
+        "|---|---|---|---|",
+    ]
+    for name, (where, kernel, caller) in sorted(BEYOND_YAML.items()):
+        lines.append(f"| `{name}` | {where} | {kernel} | {caller} |")
     out = os.path.join(os.path.dirname(__file__), "../OPS_PARITY.md")
     with open(out, "w") as fh:
         fh.write("\n".join(lines) + "\n")
