@@ -1,5 +1,5 @@
-"""The gated delta rule in chunks, and its sequential pass as Pallas TPU
-kernels, forward and backward.
+"""The gated delta rule in chunks: the chunk preparation and the
+sequential pass as Pallas TPU kernels, forward and backward.
 
 A head's recurrence over tokens (Yang, Kautz & Hatamizadeh 2024, "Gated
 Delta Networks"), S in R^{dk x dv} float32 from S_0 = 0:
@@ -18,11 +18,19 @@ and, with S the state entering the chunk:
 
     V' = U - W S;   O = Qg S + P V';   S <- a S + Kd^T V'
 
-`prepare` computes the first block for every chunk of `CHUNK` tokens at
-once (XLA, under autodiff, scoped `prepare`; float32, the products at
-`highest` precision). Only the three lines with S are sequential: the
-state pass (`_state_vjp_fwd`, `_state_vjp_bwd`). `gated_delta_rule` is
-one `jax.custom_vjp` over both.
+`prepare` states the first block for every chunk of `CHUNK` tokens at
+once (float32, the products at `highest` precision); it runs scoped
+`prepare`. Only the three lines with S are sequential: the state pass
+(`_state_vjp_fwd`, `_state_vjp_bwd`). `gated_delta_rule` is one
+`jax.custom_vjp` over both.
+
+* the preparation (`gdn_prepare_fwd`, `gdn_prepare_bwd`): no state is
+  carried, so a grid step takes any `PAIRS` pairs of chunks and every
+  step is free. A chunk's masks, K K^T, Q K^T, A, the inverse's powers
+  and partial products stay in VMEM; out go U, W, Qg, Kd, P, a and T,
+  which the backward kernel reads with the state pass's six gradients
+  (`_pair_bwd` has its formulas). Off a TPU `prepare` runs in XLA under
+  autodiff (`prepare_path`).
 
 * forward (`gdn_state_fwd`): grid (batch x head in blocks of `HEADS`,
   chunk), the chunk axis sequential, S a [dk, dv] float32 scratch a
@@ -38,9 +46,9 @@ one `jax.custom_vjp` over both.
 
 A block holds `HEADS` heads whose chains are independent: the scheduler
 overlaps one head's products with another's. On a TPU backend the
-kernels are the only path (`state_path`). Elsewhere (the CPU tests) the
-same three lines and the same backward formulas run as a `lax.scan`
-over chunks.
+kernels are the only path (`state_path`, `prepare_path`). Elsewhere (the
+CPU tests) the same three lines and the same backward formulas run as a
+`lax.scan` over chunks.
 """
 from __future__ import annotations
 
@@ -285,18 +293,16 @@ def _inverse_bwd(T, dT):
 _inverse.defvjp(_inverse_fwd, _inverse_bwd)
 
 
-def prepare(q, k, v, g, beta):
-    """q, k [b, s, H, dk], v [b, s, H, dv], g, beta [b, s, H], float32 ->
-    (U, W, Qg, Kd, P, a) as the state pass takes them, B = b * H and
-    nc = s / CHUNK."""
-    b, s, H, _dk = q.shape
-    chunk, nc = CHUNK, s // CHUNK
+def _heads_first(x, H):
+    """[b, s, H, ...] -> [b * H, nc, CHUNK, ...]."""
+    x = jnp.moveaxis(x, 2, 1)
+    return x.reshape((-1, x.shape[2] // CHUNK, CHUNK) + x.shape[3:])
 
-    def heads_first(x):     # [b, s, H, ...] -> [b * H, nc, chunk, ...]
-        x = jnp.moveaxis(x, 2, 1)
-        return x.reshape((b * H, nc, chunk) + x.shape[3:])
 
-    q, k, v, g, beta = (heads_first(x) for x in (q, k, v, g, beta))
+def _prepare(q, k, v, g, beta):
+    """`prepare` and the chunks' T [B, nc, C, C] behind it."""
+    chunk, H = CHUNK, q.shape[2]
+    q, k, v, g, beta = (_heads_first(x, H) for x in (q, k, v, g, beta))
     gam = jnp.cumsum(g, axis=-1)                        # [B, nc, C]
     rows = jnp.arange(chunk)
     seen = rows[:, None] >= rows[None, :]
@@ -313,7 +319,318 @@ def prepare(q, k, v, g, beta):
     P = jnp.einsum("bnid,bnjd->bnij", q, k, precision=_HI) * decay
     last = gam[..., -1]
     Kd = k * jnp.exp(last[..., None] - gam)[..., None]
-    return U, W, q * e_gam, Kd, P, jnp.exp(last)
+    return U, W, q * e_gam, Kd, P, jnp.exp(last), T
+
+
+def prepare(q, k, v, g, beta):
+    """q, k [b, s, H, dk], v [b, s, H, dv], g, beta [b, s, H], float32 ->
+    (U, W, Qg, Kd, P, a) as the state pass takes them, B = b * H and
+    nc = s / CHUNK. The plain statement of the chunk preparation: the
+    path off a TPU, under autodiff, and what the kernels are held to."""
+    return _prepare(q, k, v, g, beta)[:6]
+
+
+# ======================= the preparation as kernels =======================
+#
+# A grid step holds PAIRS pairs of chunks; no state is carried, so any two
+# chunks make a pair and every step is free. A pair's tokens stand stacked
+# ([2C, d]: q, k, v, U, W, Qg, Kd and their gradients), its [C, C] matrices
+# side by side ([C, 2C]: the decay mask, A, P, T and the inverse's powers),
+# so that they fill the 128 lanes and one product against a block-diagonal
+# [2C, 2C] right operand squares or multiplies both at once. g and beta
+# come as rows ([8, 2C] a step, the first PAIRS used); what scales a
+# token's row is their transpose's column.
+
+PAIRS = 4           # pairs of chunks a grid step holds (at most _ROWS)
+_ROWS = 8           # rows of a step's block of g, beta and their gradients
+
+
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _same_half(C):
+    """[2C, 2C]: row and column in the same chunk of the pair."""
+    r, c = _iota((2 * C, 2 * C), 0), _iota((2 * C, 2 * C), 1)
+    return (r < C) == (c < C), r, c
+
+
+def _pair_masks(C):
+    """Of a [C, 2C] pair of matrices: the left one's lanes, i >= j, i > j,
+    i == j."""
+    rows, lanes = _iota((C, 2 * C), 0), _iota((C, 2 * C), 1)
+    left = lanes < C
+    col = jnp.where(left, lanes, lanes - C)
+    return left, rows >= col, rows > col, rows == col
+
+
+def _bd(x, left):
+    """[X1 | X2] -> [[X1, 0], [0, X2]]."""
+    return jnp.concatenate([jnp.where(left, x, 0.0),
+                            jnp.where(left, 0.0, x)], axis=0)
+
+
+def _side(x, left):
+    """The diagonal blocks of a [2C, 2C] matrix, side by side."""
+    C = x.shape[0] // 2
+    return jnp.where(left, x[:C], x[C:])
+
+
+def _pair_rows(g, beta):
+    """g, beta [_ROWS, 2C], a pair a row -> (gam, the running sum of g in
+    each chunk; last, a chunk's whole sum on each of its lanes; cols
+    [2C, 2C], whose column t * _ROWS + n is pair n's gam, beta, e^gam,
+    e^{last - gam} for t = 0 .. 3, down the pair's stacked tokens)."""
+    C = g.shape[1] // 2
+    same, r, c = _same_half(C)
+    gam = _dot(g, jnp.where(same & (r <= c), 1.0, 0.0), _NN)
+    lanes = _iota(g.shape, 1)
+    last = jnp.where(lanes < C, gam[:, C - 1:C], gam[:, 2 * C - 1:])
+    rows = [gam, beta, jnp.exp(gam), jnp.exp(last - gam)]
+    rows.append(jnp.zeros((2 * C - len(rows) * _ROWS, 2 * C), F32))
+    return gam, last, jnp.concatenate(rows, axis=0).T
+
+
+def _pair_cols(cols, n, left):
+    """Pair n's columns of `_pair_rows`' cols: gam beside each matrix of
+    the pair [C, 2C]; beta, e^gam, e^{last - gam} [2C, 1]."""
+    C = cols.shape[0] // 2
+    gam, beta, eg, ekd = (cols[:, t * _ROWS + n:t * _ROWS + n + 1]
+                          for t in range(4))
+    return jnp.where(left, gam[:C], gam[C:]), beta, eg, ekd
+
+
+def _pair_scores(q, k, gam_row, gc, bc, masks):
+    """-> decay, beta * K, A, P of a pair: the masks and the two score
+    products (one product: beta K and Q stacked against K)."""
+    left, seen, strict, _eye = masks
+    # e^{gam_i - gam_j} where i >= j (at most 1), 0 above the diagonal
+    decay = jnp.where(seen, jnp.exp(gc - gam_row), 0.0)
+    kb = k * bc
+    S = _dot(jnp.concatenate([kb, q], axis=0), k, _NT)
+    n = k.shape[0]
+    A = jnp.where(strict, -_side(S[:n], left) * decay, 0.0)
+    return decay, kb, A, _side(S[n:], left) * decay
+
+
+def _pair_inverse(A, left, eye):
+    """`_inverse`'s doubling on both matrices of a pair at once. A power
+    squares and multiplies T in one product (the two stacked against
+    the power): T (I + A^n) as T + T A^n."""
+    C = A.shape[0]
+    T, power, n = jnp.where(eye, 1.0, 0.0) + A, _dot(A, _bd(A, left), _NN), 2
+    while 2 * n < C:
+        both = _dot(jnp.concatenate([power, T], axis=0), _bd(power, left),
+                    _NN)
+        T, power, n = T + both[C:], both[:C], 2 * n
+    return T + _dot(T, _bd(power, left), _NN)
+
+
+def _pair_fwd(q, k, v, gam_row, gc, bc, eg, ekd, masks):
+    """`prepare` for one pair of chunks -> U, W, Qg, Kd [2C, d], P and T
+    [C, 2C]."""
+    left, _seen, _strict, eye = masks
+    _decay, kb, A, P = _pair_scores(q, k, gam_row, gc, bc, masks)
+    T = _pair_inverse(A, left, eye)
+    both = _bd(T, left)
+    return (_dot(both, v * bc, _NN), _dot(both, kb * eg, _NN), q * eg,
+            k * ekd, P, T)
+
+
+def _pair_bwd(q, k, v, gam_row, gc, bc, eg, ekd, T, dU, dW, dQg, dKd, dP,
+              masks):
+    """`prepare`'s transpose for one pair, T read and not remade. With
+    bV = beta V, bKg = beta K e^gam, and M the strict lower mask:
+        dT = dU bV^T + dW bKg^T;  dbV = T^T dU;  dbKg = T^T dW
+        dA = M * (T^T dT T^T)            (`_inverse_bwd`)
+        d(beta K K^T) = -dA * decay;  d(Q K^T) = dP * decay
+        d decay * decay = E = dP * P + dA * A
+        dgam_i = sum_j E_ij - sum_j E_ji + rows of (dbKg * bKg + dQg * Qg
+                 - dKd * Kd);  dlast = sum(dKd * Kd) (+ da a, the caller's)
+    -> dq, dk, dv [2C, d]; columns [2C, 1]: what reaches gam through a
+    token's row, dbeta; rows [1, 2C]: what reaches gam through a token's
+    column of E and through last."""
+    left, _seen, strict, _eye = masks
+    decay, kb, A, P = _pair_scores(q, k, gam_row, gc, bc, masks)
+    C = T.shape[0]
+    bkg = kb * eg
+    bothT = _bd(T, left).T                      # [[T1^T, 0], [0, T2^T]]
+    dT = _side(_dot(dU, v * bc, _NT) + _dot(dW, bkg, _NT), left)
+    dbv, dbkg = _dot(bothT, dU, _NN), _dot(bothT, dW, _NN)
+    dA = _dot(_dot(bothT[:C] + bothT[C:], _bd(dT, left), _NN), bothT, _NN)
+    dA = jnp.where(strict, dA, 0.0)
+    E = dP * P + dA * A
+    dS = jnp.concatenate([_bd(-dA * decay, left), _bd(dP * decay, left)],
+                         axis=0)
+    back = _dot(dS, k, _NN)                     # d(beta K), dQ by the scores
+    dkb = back[:2 * C] + dbkg * eg
+    kdk = dKd * k * ekd
+    dk = _dot(dS, jnp.concatenate([kb, q], axis=0), _TN) + dkb * bc \
+        + dKd * ekd
+
+    def rows(x):
+        return jnp.sum(x, axis=1, keepdims=True)
+
+    dgam = rows(dbkg * bkg + dQg * q * eg - kdk) + jnp.concatenate(
+        [rows(jnp.where(left, E, 0.0)), rows(jnp.where(left, 0.0, E))],
+        axis=0)
+    dbeta = rows(dkb * k) + rows(dbv * v)
+    lanes = _iota((1, 2 * C), 1)
+    through_last = sum(
+        jnp.where(lanes == at + C - 1,
+                  rows(jnp.sum(kdk[at:at + C], axis=0, keepdims=True)), 0.0)
+        for at in (0, C))
+    return (back[2 * C:] + dQg * eg, dk, dbv * bc, dgam, dbeta,
+            through_last - jnp.sum(E, axis=0, keepdims=True))
+
+
+def _prepare_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, u_ref, w_ref,
+                        qg_ref, kd_ref, p_ref, a_ref, t_ref):
+    C = t_ref.shape[2]
+    masks = _pair_masks(C)
+    gam, last, cols = _pair_rows(g_ref[0], b_ref[0])
+    a_ref[0] = jnp.exp(last)
+    for n in range(q_ref.shape[1]):
+        U, W, Qg, Kd, P, T = _pair_fwd(
+            q_ref[0, n], k_ref[0, n], v_ref[0, n], gam[n:n + 1],
+            *_pair_cols(cols, n, masks[0]), masks)
+        u_ref[0, n], w_ref[0, n], qg_ref[0, n], kd_ref[0, n] = U, W, Qg, Kd
+        t_ref[0, n] = T
+        p_ref[0, n, 0] = P[:, :C]
+        p_ref[0, n, 1] = P[:, C:]
+
+
+def _prepare_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, t_ref, du_ref,
+                        dw_ref, dqg_ref, dkd_ref, dp_ref, da_ref,
+                        dq_ref, dk_ref, dv_ref, dg_ref, db_ref):
+    C = t_ref.shape[2]
+    masks = _pair_masks(C)
+    gam, last, cols = _pair_rows(g_ref[0], b_ref[0])
+    lanes, pair = _iota((2 * C, 2 * C), 1), _iota((_ROWS, 2 * C), 0)
+    down = jnp.zeros((2 * C, 2 * C), F32)       # columns, a pair a lane
+    along = da_ref[0] * jnp.exp(last)           # rows: da a, at last
+    for n in range(q_ref.shape[1]):
+        dP = jnp.concatenate([dp_ref[0, n, 0], dp_ref[0, n, 1]], axis=1)
+        dq, dk, dv, dgam, dbeta, row = _pair_bwd(
+            q_ref[0, n], k_ref[0, n], v_ref[0, n], gam[n:n + 1],
+            *_pair_cols(cols, n, masks[0]), t_ref[0, n], du_ref[0, n],
+            dw_ref[0, n], dqg_ref[0, n], dkd_ref[0, n], dP, masks)
+        dq_ref[0, n], dk_ref[0, n], dv_ref[0, n] = dq, dk, dv
+        down = jnp.where(lanes == n, dgam, down)
+        down = jnp.where(lanes == _ROWS + n, dbeta, down)
+        along = along + jnp.where(pair == n, row, 0.0)
+    across = down.T
+    db_ref[0] = across[_ROWS:2 * _ROWS]
+    # dg_m = sum of dgam_i over the chunk's i >= m
+    same, r, c = _same_half(C)
+    dg_ref[0] = _dot(across[:_ROWS] + along,
+                     jnp.where(same & (r >= c), 1.0, 0.0), _NN)
+
+
+def _in_pairs(x):
+    """[B, nc, CHUNK, ...] or rows [B, nc, CHUNK] -> the chunks in steps of
+    PAIRS pairs [steps, PAIRS, 2 CHUNK, ...] (rows: [steps, _ROWS,
+    2 CHUNK]), zero chunks appended to a whole step."""
+    n = x.shape[0] * x.shape[1]
+    x = x.reshape((n,) + x.shape[2:])
+    x = jnp.pad(x, ((0, -n % (2 * PAIRS)),) + ((0, 0),) * (x.ndim - 1))
+    x = x.reshape((-1, PAIRS, 2 * CHUNK) + x.shape[2:])
+    if x.ndim == 3:
+        x = jnp.pad(x, ((0, 0), (0, _ROWS - PAIRS), (0, 0)))
+    return x
+
+
+def _from_pairs(x, B, nc, *chunk):
+    """`_in_pairs` undone, for what a kernel wrote by pairs: -> [B, nc,
+    *chunk], a chunk's own shape given."""
+    if x.ndim == 3:
+        x = x[:, :PAIRS]
+    return x.reshape((-1,) + chunk)[:B * nc].reshape((B, nc) + chunk)
+
+
+def _prepare_specs(dk, dv):
+    def at(*block):
+        return pl.BlockSpec((1,) + block,
+                            lambda i: (i,) + (0,) * len(block))
+    C = CHUNK
+    return dict(k=at(PAIRS, 2 * C, dk), v=at(PAIRS, 2 * C, dv),
+                row=at(_ROWS, 2 * C), p=at(PAIRS, 2, C, C),
+                t=at(PAIRS, C, 2 * C))
+
+
+def _prepare_params():
+    return pltpu.CompilerParams(dimension_semantics=("parallel",),
+                                vmem_limit_bytes=_VMEM_LIMIT)
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+@_pf.trace_timed_call("gdn_prepare_fwd")
+def _prepare_fwd_pallas(q, k, v, g, beta, interpret=False):
+    """`_prepare` as a kernel: the same operands -> (U, W, Qg, Kd, P, a,
+    T in pairs [steps, PAIRS, C, 2C]: the backward kernel's residual).
+    Under `jax.jit`, as the backward's wrapper is, so that a step's
+    layers and passes trace and lower the kernel once."""
+    b, s, H, dk = q.shape
+    dv, B, nc, C = v.shape[-1], b * H, s // CHUNK, CHUNK
+    ins = [_in_pairs(_heads_first(x, H)) for x in (q, k, v, g, beta)]
+    steps = ins[0].shape[0]
+    sp = _prepare_specs(dk, dv)
+    like = jax.ShapeDtypeStruct
+    wide_k, wide_v = ins[0].shape, ins[2].shape
+    U, W, Qg, Kd, P, a, T = pl.pallas_call(
+        _prepare_fwd_kernel,
+        grid=(steps,),
+        in_specs=[sp["k"], sp["k"], sp["v"], sp["row"], sp["row"]],
+        out_specs=[sp["v"], sp["k"], sp["k"], sp["k"], sp["p"], sp["row"],
+                   sp["t"]],
+        out_shape=[like(wide_v, F32), like(wide_k, F32), like(wide_k, F32),
+                   like(wide_k, F32), like((steps, PAIRS, 2, C, C), F32),
+                   like(ins[3].shape, F32),
+                   like((steps, PAIRS, C, 2 * C), F32)],
+        compiler_params=_prepare_params(),
+        interpret=interpret,
+        name="gdn_prepare_fwd",
+    )(*ins)
+    return (_from_pairs(U, B, nc, C, dv),
+            *(_from_pairs(x, B, nc, C, dk) for x in (W, Qg, Kd)),
+            _from_pairs(P, B, nc, C, C), _from_pairs(a, B, nc, C)[..., -1],
+            T)
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+@_pf.trace_timed_call("gdn_prepare_bwd")
+def _prepare_bwd_pallas(q, k, v, g, beta, T, grads, interpret=False):
+    """`_prepare`'s transpose as a kernel: its operands, the forward
+    kernel's T and the gradients to U, W, Qg, Kd, P [B, nc, ...] and a
+    [B, nc] -> dq, dk, dv, dg, dbeta like the operands."""
+    b, s, H, dk = q.shape
+    dv, B, nc, C = v.shape[-1], b * H, s // CHUNK, CHUNK
+    dU, dW, dQg, dKd, dP, da = grads
+    # da beside the chunk's last token, where `last` is read
+    da = da[..., None] * (jnp.arange(C) == C - 1)
+    ins = [_in_pairs(_heads_first(x, H)) for x in (q, k, v, g, beta)]
+    ins += [T] + [_in_pairs(x) for x in (dU, dW, dQg, dKd)]
+    steps = T.shape[0]
+    ins += [_in_pairs(dP).reshape((steps, PAIRS, 2, C, C)), _in_pairs(da)]
+    sp = _prepare_specs(dk, dv)
+    like = jax.ShapeDtypeStruct
+    wide_k, wide_v, row = ins[0].shape, ins[2].shape, ins[3].shape
+    grads = pl.pallas_call(
+        _prepare_bwd_kernel,
+        grid=(steps,),
+        in_specs=[sp["k"], sp["k"], sp["v"], sp["row"], sp["row"], sp["t"],
+                  sp["v"], sp["k"], sp["k"], sp["k"], sp["p"], sp["row"]],
+        out_specs=[sp["k"], sp["k"], sp["v"], sp["row"], sp["row"]],
+        out_shape=[like(wide_k, F32), like(wide_k, F32), like(wide_v, F32),
+                   like(row, F32), like(row, F32)],
+        compiler_params=_prepare_params(),
+        interpret=interpret,
+        name="gdn_prepare_bwd",
+    )(*ins)
+    chunks = [(C, dk), (C, dk), (C, dv), (C,), (C,)]
+    return tuple(
+        jnp.moveaxis(_from_pairs(x, B, nc, *c).reshape(
+            (b, H, s) + c[1:]), 1, 2) for x, c in zip(grads, chunks))
 
 
 # ======================= dispatch =======================
@@ -322,6 +639,14 @@ def state_path() -> str:
     """What the state pass of a program traced now runs as: `pallas` |
     `lax.scan`."""
     return "pallas" if _pallas_available() else "lax.scan"
+
+
+def prepare_path() -> str:
+    """What the chunk preparation of a program traced now runs as:
+    `pallas` (`gdn_prepare_fwd`, `gdn_prepare_bwd`) | `xla` (`prepare`
+    under autodiff). The CPU tests also give `interpret`: the kernels in
+    Pallas's interpreter."""
+    return "pallas" if _pallas_available() else "xla"
 
 
 def gated_delta_rule(q, k, v, g, beta):
@@ -354,28 +679,45 @@ def _tokens_first(O, b, H):
 
 
 def _rule_fwd(q, k, v, g, beta):
-    ins = (q, k, v, g, beta)
+    ins, mode = (q, k, v, g, beta), prepare_path()
     with jax.named_scope("prepare"):
-        made = prepare(*_f32(ins))
+        if mode == "xla":
+            made = prepare(*_f32(ins))
+        else:
+            *made, _T = _prepare_fwd_pallas(
+                *_f32(ins), interpret=mode == "interpret")
     O, res = _state_vjp_fwd(*made, state_path())
     b, _s, H, _dv = v.shape
     return _tokens_first(O, b, H).astype(v.dtype), (ins, res[-1])
 
 
 def _rule_bwd(res, do):
-    """The chunk preparation is made again here and differentiated:
-    what it makes (some 3.5 GB a layer at 16,384 tokens and 32 heads)
-    lives through this function alone, not from the forward on. The
-    states entering the chunks are the forward kernel's."""
+    """What the chunk preparation makes for the state pass (U, W, Qg, Kd,
+    P: 1.2 GB a layer at 16,384 tokens and 32 heads, and on the kernels
+    the chunks' T, 134 MB) is made again here and lives through this
+    function alone, not from the forward on. The states entering the
+    chunks are the forward kernel's.
+
+    The kernels' forward call stands behind a barrier with dO. Without
+    it XLA finds that a recomputed forward (`jax.checkpoint`) has just
+    made the same and runs the kernel once for both: a call saved, and
+    its 1.2 GB alive across everything between that forward and this
+    backward (the step's reported peak 16.89 GB for 15.73 at
+    `qwen3-next-80b-l4-e64`, PERF.md section 6, PR 40)."""
     ins, states = res
-    b, s, H, dv = do.shape
+    dO = _heads_first(do.astype(F32), do.shape[2])
+    mode = prepare_path()
     with jax.named_scope("prepare"):
-        made, back = jax.vjp(prepare, *_f32(ins))
-    dO = jnp.moveaxis(do.astype(F32), 2, 1).reshape(
-        (b * H, s // CHUNK, CHUNK, dv))
+        if mode == "xla":
+            made, back = jax.vjp(prepare, *_f32(ins))
+        else:
+            *made, T = _prepare_fwd_pallas(
+                *jax.lax.optimization_barrier((_f32(ins), dO))[0],
+                interpret=mode == "interpret")
     grads = _state_vjp_bwd(state_path(), (*made, states), dO)
     with jax.named_scope("prepare"):
-        grads = back(grads)
+        grads = back(grads) if mode == "xla" else _prepare_bwd_pallas(
+            *_f32(ins), T, grads, interpret=mode == "interpret")
     return tuple(d.astype(x.dtype) for d, x in zip(grads, ins))
 
 

@@ -76,13 +76,15 @@ class GatedDeltaNet(Layer):
                                weight_attr=Normal(std=out_std or std))
 
     def forward(self, u):
-        from ...kernels.pallas.gated_delta import CHUNK, state_path
+        from ...kernels.pallas.gated_delta import (CHUNK, prepare_path,
+                                                   state_path)
         b, s, _ = u.shape
         Hk, Hv, d = self.key_heads, self.value_heads, self.head_dim
         rep = Hv // Hk
         perf.trace_note(
             "gdn", f"heads {Hv} on {Hk}, state {d} x {d}, chunk {CHUNK}, "
-            f"conv {self.taps} taps, state pass: {state_path()}")
+            f"conv {self.taps} taps, chunk preparation: {prepare_path()}, "
+            f"state pass: {state_path()}")
         qkvz = ops.reshape(self.in_proj_qkvz(u), (b, s, Hk, (2 + 2 * rep) * d))
         ba = ops.reshape(self.in_proj_ba(u), (b, s, Hk, 2 * rep))
         q, k, v, z = ops.split(qkvz, [d, d, rep * d, rep * d], axis=-1)

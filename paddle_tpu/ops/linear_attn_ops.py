@@ -21,8 +21,8 @@ def gated_delta_rule(q, k, v, g, beta):
     `CHUNK`; a row that is no whole number of them is padded with tokens
     the state passes through): what depends on no state (the
     chunk's triangular inverse and its masked products) for all chunks
-    at once in XLA, the pass that carries the state as Pallas kernels on
-    a TPU and as a `lax.scan` elsewhere. One `jax.custom_vjp` with
+    at once, and the pass that carries the state: both as Pallas kernels
+    on a TPU, in XLA and as a `lax.scan` elsewhere. One `jax.custom_vjp` with
     gradients to all five. Under amp the operands are cast to float32
     (black list): the state is a sum over thousands of tokens."""
     from ..kernels.pallas.gated_delta import gated_delta_rule as rule

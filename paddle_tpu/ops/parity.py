@@ -313,6 +313,6 @@ BEYOND_YAML = {
         "models.laguna, models.zaya, models.qwen3_next"),
     "gated_delta_rule": (
         "ops/linear_attn_ops.py", "kernels/pallas/gated_delta.py "
-        "(gdn_state_fwd, gdn_state_bwd)",
+        "(gdn_prepare_fwd, gdn_prepare_bwd, gdn_state_fwd, gdn_state_bwd)",
         "nn.GatedDeltaNet (models.qwen3_next)"),
 }

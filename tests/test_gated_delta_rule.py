@@ -3,7 +3,9 @@ the chunked form against the token-by-token recurrence in float64
 (values and all five gradients, at 1, 2 and 5 chunks and a row that is
 no whole number of them, with a decay near 0 and near 1), the Pallas
 state kernels in interpret mode against the `lax.scan` pass and against
-jax's own differentiation of it, the triangular inverse, and amp's black
+jax's own differentiation of it, the chunk preparation's kernels in
+interpret mode against `prepare` in XLA and its `jax.vjp`, the whole rule
+on them against the recurrence, the triangular inverse, and amp's black
 list. The kernels compiled for the chip: tests/test_tpu_aot_compile.py."""
 import jax
 import jax.numpy as jnp
@@ -144,6 +146,113 @@ def test_the_inverse_of_a_nilpotent_matrix_and_its_gradient():
     assert gap(got, want) < 1e-4
 
 
+def _chunks_apart(T, n):
+    """The forward kernel's T, a pair side by side [steps, PAIRS, C, 2C]
+    -> the first n chunks' [n, C, C]."""
+    C = gd.CHUNK
+    return jnp.moveaxis(T.reshape(-1, C, 2, C), 2, 1).reshape(-1, C, C)[:n]
+
+
+def _close(got, want, tol=1e-5):
+    assert got.shape == want.shape
+    assert float(jnp.max(jnp.abs(got - want))) <= tol * max(
+        float(jnp.max(jnp.abs(want))), 1e-30)
+
+
+PREPARED = [    # tokens a row, heads: the chunks a step holds, and beyond
+    pytest.param(64, 1, id="heads1-one-chunk"),         # padded to a step
+    pytest.param(256, 2, id="heads2-one-step"),         # 8 chunks: a step
+    pytest.param(320, 4, id="heads4-steps-and-a-part"),     # 20: 2.5 steps
+    pytest.param(128, 2, id="a-row-padded-to-a-chunk")]     # 24 tokens of it
+
+
+def _prepare_operands(s, H, decay, pad=0, **kw):
+    """float32 operands of one row of s tokens, the last `pad` of them
+    as `gated_delta_rule` pads a row to a chunk: zeros, so beta = 0 and
+    g = 0."""
+    ins = operands(s, decay, b=1, H=H, **kw)
+    keep = (jnp.arange(s) < s - pad).astype(jnp.float32)
+    return tuple((x * keep.reshape((1, s) + (1,) * (x.ndim - 2)))
+                 .astype(jnp.float32) for x in ins)
+
+
+@pytest.mark.parametrize("decay", [0.05, 0.999], ids=["fast", "slow"])
+@pytest.mark.parametrize("s,H", PREPARED)
+def test_the_preparation_kernel_is_prepare(s, H, decay, request):
+    """`gdn_prepare_fwd` in Pallas's interpreter against `prepare` in XLA:
+    U, W, Qg, Kd, P, a and the chunks' T, where the chunks fill a grid
+    step, fall short of one and run over, and where a row's last 24
+    tokens are padding."""
+    pad = 24 if "padded" in request.node.name else 0
+    ins = _prepare_operands(s, H, decay, pad)
+    want = gd._prepare(*ins)
+    *got, T = gd._prepare_fwd_pallas(*ins, interpret=True)
+    for a, b in zip(got, want):
+        _close(a, b)
+    _close(_chunks_apart(T, H * s // gd.CHUNK),
+           want[6].reshape((-1,) + want[6].shape[2:]))
+
+
+@pytest.mark.parametrize("decay", [0.05, 0.999], ids=["fast", "slow"])
+@pytest.mark.parametrize("s,H", PREPARED)
+def test_the_preparation_backward_kernel_is_prepares_own_gradient(
+        s, H, decay, request):
+    """`gdn_prepare_bwd` in Pallas's interpreter (T read from the forward
+    kernel, the formulas of `_pair_bwd`) against `jax.vjp(prepare)`: the
+    gradients to q, k, v, g and beta for a cotangent to each of U, W, Qg,
+    Kd, P and a."""
+    pad = 24 if "padded" in request.node.name else 0
+    ins = _prepare_operands(s, H, decay, pad)
+    made, back = jax.vjp(gd.prepare, *ins)
+    r = np.random.default_rng(4)
+    cots = tuple(jnp.asarray(r.normal(size=x.shape), jnp.float32)
+                 for x in made)
+    T = gd._prepare_fwd_pallas(*ins, interpret=True)[6]
+    got = gd._prepare_bwd_pallas(*ins, T, cots, interpret=True)
+    for name, a, b in zip("q k v g beta".split(), got, back(cots)):
+        assert a.shape == b.shape and gap(a, b) < 2e-5, name
+
+
+def test_the_preparation_kernels_at_the_chips_widths():
+    """Keys and values of 128, as the cell runs them: the seven outputs
+    and the five gradients."""
+    ins = _prepare_operands(512, 2, 0.9, dk=128, dv=128)
+    want = gd._prepare(*ins)
+    *got, T = gd._prepare_fwd_pallas(*ins, interpret=True)
+    for a, b in zip(got, want):
+        _close(a, b)
+    _close(_chunks_apart(T, 16), want[6].reshape((-1, 64, 64)))
+    cots = tuple(jnp.asarray(np.random.default_rng(5).normal(size=x.shape),
+                             jnp.float32) for x in want[:6])
+    grads = jax.vjp(gd.prepare, *ins)[1](cots)
+    got = gd._prepare_bwd_pallas(*ins, T, cots, interpret=True)
+    for name, a, b in zip("q k v g beta".split(), got, grads):
+        assert gap(a, b) < 2e-5, name
+
+
+@pytest.mark.parametrize("decay", [0.05, 0.999], ids=["fast", "slow"])
+@pytest.mark.parametrize("s", [64, 128, 320, 40])
+def test_the_chunked_rule_on_the_preparation_kernels_is_the_recurrence(
+        x64, monkeypatch, s, decay):
+    """`test_the_chunked_rule_is_the_recurrence` with the chunk
+    preparation on its kernels (in Pallas's interpreter), forward and
+    backward, around the `lax.scan` pass."""
+    monkeypatch.setattr(gd, "prepare_path", lambda: "interpret")
+    ins = operands(s, decay)
+    w = jnp.asarray(np.random.default_rng(1).normal(size=ins[2].shape))
+    want = recurrence(*ins)
+    want_g = jax.grad(lambda *xs: jnp.sum(recurrence(*xs) * w),
+                      argnums=range(5))(*ins)
+    xs = tuple(x.astype(jnp.float32) for x in ins)
+    got = gd.gated_delta_rule(*xs)
+    got_g = jax.grad(
+        lambda *xs: jnp.sum(gd.gated_delta_rule(*xs) * w.astype(xs[0].dtype)),
+        argnums=range(5))(*xs)
+    assert got.dtype == jnp.float32 and gap(got, want) < 2e-5
+    for name, a, b in zip("q k v g beta".split(), got_g, want_g):
+        assert a.shape == b.shape and gap(a, b) < 5e-5, name
+
+
 def test_amp_keeps_the_rule_in_float32():
     """On amp's black list: bf16 operands reach the rule as float32, and
     the output is v's type."""
@@ -160,3 +269,4 @@ def test_amp_keeps_the_rule_in_float32():
 
 def test_which_path_a_program_traced_here_takes():
     assert gd.state_path() == "lax.scan"        # no TPU here
+    assert gd.prepare_path() == "xla"

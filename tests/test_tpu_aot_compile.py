@@ -769,10 +769,37 @@ def test_the_gated_delta_state_kernels_compile(v5e, back):
     assert len(re.findall(rf"%{name}[.\d]* = .*custom-call\(", text)) == 1
 
 
+@pytest.mark.parametrize("back", [False, True], ids=["fwd", "bwd"])
+def test_the_gated_delta_preparation_kernels_compile(v5e, back):
+    """`gdn_prepare_fwd` and `gdn_prepare_bwd` at the cell's shape: 32
+    value heads, 256 chunks of 64 tokens, keys and values of 128, the
+    chunks in pairs."""
+    one = SingleDeviceSharding(v5e[0])
+    B, nc, C, d = Q3_LINEAR, Q3_SEQ // gdr.CHUNK, gdr.CHUNK, 128
+
+    def S(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)
+
+    ins = [S(1, Q3_SEQ, B, d)] * 3 + [S(1, Q3_SEQ, B)] * 2
+    if back:
+        made = S(B, nc, C, d)
+        text = _compiled_text(
+            gdr._prepare_bwd_pallas, *ins,
+            S(B * nc // (2 * gdr.PAIRS), gdr.PAIRS, C, 2 * C),
+            (made, made, made, made, S(B, nc, C, C), S(B, nc)))
+    else:
+        text = _compiled_text(gdr._prepare_fwd_pallas, *ins)
+    name = "gdn_prepare_bwd" if back else "gdn_prepare_fwd"
+    assert len(re.findall(rf"%{name}[.\d]* = .*custom-call\(", text)) == 1
+
+
 def test_the_whole_gated_delta_rule_compiles_with_its_gradients(v5e):
     """`gated_delta_rule` as a step meets it: float32 operands of 32
-    heads over 4096 tokens, the chunk preparation in XLA around the two
-    kernels, differentiated to all five operands."""
+    heads over 4096 tokens, the chunk preparation's two kernels around
+    the state pass's two, differentiated to all five operands. The
+    backward makes U, W, Qg, Kd and P again with a forward call of its
+    own, behind a barrier (they are not held from the forward on): two
+    forward calls, one backward."""
     one = SingleDeviceSharding(v5e[0])
 
     def S(*shape):
@@ -784,10 +811,11 @@ def test_the_whole_gated_delta_rule_compiles_with_its_gradients(v5e):
         text = _compiled_text(jax.grad(
             lambda *xs: gdr.gated_delta_rule(*xs).sum(),
             argnums=range(5)), x, x, x, g, g)
-    for name in ("gdn_state_fwd", "gdn_state_bwd"):
+    for name, calls in (("gdn_prepare_fwd", 2), ("gdn_state_fwd", 1),
+                        ("gdn_state_bwd", 1), ("gdn_prepare_bwd", 1)):
         assert len(re.findall(rf"%\w*{name}\w*[.\d]* = .*custom-call\(",
-                              text)) == 1, name
-    assert text.count("tpu_custom_call") == 2
+                              text)) == calls, name
+    assert text.count("tpu_custom_call") == 5
 
 
 def test_flash_attention_at_head_size_256_compiles(v5e):
@@ -890,6 +918,8 @@ def qwen3next_step(v5e):
 @pytest.mark.parametrize("kernel,calls", [
     ("gdn_state_fwd", 2),   # the linear layer: forward, and run again
     ("gdn_state_bwd", 1),
+    ("gdn_prepare_fwd", 3),     # forward, again, and the backward's own:
+    ("gdn_prepare_bwd", 1),     # merged with the second it would hold 1.2 GB
     ("flash_fwd", 1),       # the full layer: once, its block keeps o, lse
     ("flash_bwd_transpose", 1),
     ("moe_gmm", 12),        # two products a layer: forward, again, to rows
@@ -901,7 +931,7 @@ def test_the_qwen3next_step_holds_its_mosaic_kernels(qwen3next_step, kernel,
     text, _notes = qwen3next_step
     found = re.findall(rf"%{kernel}[.\d]* = .*custom-call\(", text)
     assert len(found) == calls, (kernel, len(found))
-    assert text.count("tpu_custom_call") == 31
+    assert text.count("tpu_custom_call") == 35
 
 
 def test_the_qwen3next_step_says_which_paths_it_took(qwen3next_step):
@@ -919,7 +949,7 @@ def test_the_qwen3next_step_says_which_paths_it_took(qwen3next_step):
         assert f"{root}/{scope}/" in text, scope
     assert notes == {
         "gdn": "heads 32 on 16, state 128 x 128, chunk 64, conv 4 taps, "
-               "state pass: pallas",
+               "chunk preparation: pallas, state pass: pallas",
         "attention": "pallas", "flash_operands": "split",
         "flash_kept": "o and lse kept across recompute in 1 of 2 "
                       "recomputed layers",
