@@ -14,6 +14,16 @@ What a block keeps besides its inputs is its `policy` (`_POLICIES`):
 "full" nothing, "dots" / "dots_no_batch" its matmul outputs, and
 "flash_outputs" the flash forward kernel's `o` and `lse`
 (2*b*s*h*d + 32*b*s*h bytes a layer), so the kernel runs once a layer.
+
+`scan_passes` runs one function several times over, each pass's output
+the next one's input, as ONE traced body (`jax.lax.scan`): the weights
+are the loop's invariants and the body is lowered once, whatever the
+number of passes. What such a loop keeps a pass for its backward is what
+the body's blocks keep (each recomputed block its input and what its
+policy names, stacked over the passes by the scan) and the pass's output;
+the weights' cotangents are summed over the passes in the transposed
+scan's carry, in the weights' own type, and are whole only after the
+first pass's backward.
 """
 from __future__ import annotations
 
@@ -153,6 +163,44 @@ def recompute(function, *args, use_reentrant=True, preserve_rng_state=True,
     flat, _ = jax.tree_util.tree_flatten(
         out, is_leaf=lambda x: isinstance(x, Tensor))
     return jax.tree_util.tree_unflatten(raw._out_tree, flat)
+
+
+def scan_passes(function, passes, x, *args, parameters):
+    """`function(x, *args) -> x'` run `passes` times over, each pass from
+    the one before's output, with the same weights: one `jax.lax.scan`
+    whose carry is x, whose invariants are the parameters (made explicit
+    inputs, as `recompute` makes a block's) and `args`, and whose stacked
+    outputs, [passes, *x.shape], are what is returned (the last of them
+    is the loop's result). x' has x's shape and type.
+
+    `function` is a bound method or a plain function; `parameters` are
+    the tensors it reads, every one of them (a weight left out would be
+    a constant of the body and get no gradient).
+    Blocks inside it may be recomputed (`recompute`): the scan stacks
+    what each keeps over the passes. Differentiated, it is JAX's
+    transposed scan: every parameter's cotangent is summed over the
+    passes in the carry. One form: traced under a step it is a `while`
+    of the lowered program; called eagerly it is the same scan, run
+    op by op's rules (the tape sees one op)."""
+    ptensors = list(parameters)
+    passes = int(passes)
+
+    from ...jit import _functional_params
+
+    def raw(seed, params, x, inputs):
+        def one(carry, t):
+            with rng_scope(jax.random.fold_in(seed, t)), \
+                    _functional_params(ptensors, list(params)), \
+                    tape.no_grad():
+                out = function(carry, *inputs)
+            out = out._data if isinstance(out, Tensor) else out
+            return out, out
+
+        return jax.lax.scan(one, x, jax.numpy.arange(passes))[1]
+
+    opdef = OpDef(f"scan_passes_{function.__name__}", raw)
+    return _op_registry.dispatch(
+        opdef, (next_key(), ptensors, x, list(args)), {})
 
 
 def recompute_sequential(ctx, functions, *args, **kwargs):

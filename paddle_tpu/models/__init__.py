@@ -1,4 +1,5 @@
-"""Flagship model families (GPT / LLaMA / Jamba / Laguna / ZAYA1 / BERT).
+"""Flagship model families (GPT / LLaMA / Jamba / Laguna / ZAYA1 /
+Qwen3-Next / Ouro / BERT).
 
 The reference keeps language models out-of-tree (PaddleNLP) but its
 north-star benchmarks are GPT-3/LLaMA hybrid-parallel training
@@ -28,6 +29,11 @@ from .zaya import (  # noqa: F401
 from .qwen3_next import (  # noqa: F401
     Qwen3NextConfig, Qwen3NextModel, Qwen3NextForCausalLM,
     Qwen3NextAttention, Qwen3NextDecoderLayer, qwen3_next_tiny,
+)
+from .ouro import (  # noqa: F401
+    OuroConfig, OuroModel, OuroForCausalLM, OuroAttention,
+    OuroDecoderLayer, OuroPretrainingCriterion, exit_distribution,
+    ouro_tiny,
 )
 from .bert import (  # noqa: F401
     BertConfig, BertModel, BertForMaskedLM, bert_tiny, bert_base,

@@ -14,11 +14,8 @@ import math
 from dataclasses import dataclass, field
 
 from .. import ops
-from ..amp import auto_cast
 from ..core.tensor import DeferredTensor
-from ..nn.layer import Layer, traced_scope
-from ..observability import perf
-from ..ops import nn_ops
+from ..nn.layer import Layer
 from . import lm_head as _lm_head
 from .lm_head import _Head
 from ..nn.layers.common import Linear, Embedding, Dropout
@@ -290,24 +287,13 @@ class GPTPretrainingCriterion(Layer):
 
     def _from_head(self, head, labels, loss_mask):
         """The same mean from the head's operands, the logits never
-        whole (ops.linear_cross_entropy). Operations keep the `lm_head`
-        scope beside this criterion's."""
-        h = head.hidden.shape[-1]
-        hidden = ops.reshape(head.hidden, (-1, h))
-        n = hidden.shape[0]
+        whole (`lm_head.head_cross_entropy`)."""
         if loss_mask is None:
             weight = None                       # 1/n each: the mean
         else:
-            mask = ops.cast(ops.reshape(loss_mask, (n,)), "float32")
+            mask = ops.cast(ops.reshape(loss_mask, (-1,)), "float32")
             weight = mask / ops.maximum(ops.sum(mask), 1e-6)
-        vocab = head.weight.shape[0 if head.transpose_y else 1]
-        chunk = nn_ops.lce_chunk(vocab)
-        chunks = nn_ops._lce_plan(n, chunk)[0]
-        perf.trace_note("head_loss", f"fused, chunks {chunks}")
-        with auto_cast(enable=False), traced_scope("lm_head"):
-            return ops.linear_cross_entropy(
-                hidden, head.weight, ops.reshape(labels, (n,)), weight,
-                transpose_y=head.transpose_y, chunk=chunk)
+        return _lm_head.head_cross_entropy(head, labels, weight)
 
 
 def num_params(config: GPTConfig) -> int:

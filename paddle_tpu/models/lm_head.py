@@ -13,13 +13,16 @@ from ..autograd import tape
 from ..core.tensor import DeferredTensor, Tensor
 from ..nn.layer import traced_scope, _TRACING
 from ..observability import perf
+from ..ops import nn_ops
 
 
 class _Head(NamedTuple):
-    """What deferred logits are the product of: `hidden` and `weight` as
-    amp cast them for the projection when the model ran, `weight`
-    [vocab, hidden] if `transpose_y` (the tied embedding) else
-    [hidden, vocab] (an untied Linear's)."""
+    """What deferred logits are the product of: `hidden` [..., hidden]
+    (any leading axes: [batch, seq], or [passes, batch, seq] where a
+    model reads its stream several times) and `weight` as amp cast them
+    for the projection when the model ran, `weight` [vocab, hidden] if
+    `transpose_y` (the tied embedding) else [hidden, vocab] (an untied
+    Linear's)."""
     hidden: Tensor
     weight: Tensor
     transpose_y: bool
@@ -37,11 +40,14 @@ def lm_logits(hidden, tied_weight, head=None):
 
 
 def deferred_logits(training, hidden, tied_weight, head=None):
-    """The logits as a promise, where the program is such that the
-    criterion can do without them: traced for training with jax's own
-    autodiff (no tape), on one device (under a mesh the tied embedding
-    is sharded and the whole product is the path that is tested there),
-    and nothing hooked onto an untied head. Else None."""
+    """The logits [*hidden.shape[:-1], vocab] as a promise, where the
+    program is such that the criterion can do without them: traced for
+    training with jax's own autodiff (no tape), on one device (under a
+    mesh the tied embedding is sharded and the whole product is the path
+    that is tested there), and nothing hooked onto an untied head. Else
+    None. `hidden` may carry a leading axis of passes: the promise is
+    then one for all of them, and a criterion settles every pass's rows
+    in one `head_cross_entropy`."""
     if not (_TRACING.depth and training
             and not tape.is_grad_enabled()
             and (head is None or not (head._forward_pre_hooks
@@ -65,7 +71,7 @@ def deferred_logits(training, hidden, tied_weight, head=None):
                               transpose_y=made.transpose_y)._data
 
     vocab = w.shape[0] if head is None else w.shape[1]
-    return DeferredTensor(whole, hidden.shape[:-1] + [vocab],
+    return DeferredTensor(whole, list(hidden.shape[:-1]) + [vocab],
                           made.hidden._data.dtype, producer=made)
 
 
@@ -75,3 +81,29 @@ def causal_lm_logits(training, hidden, tied_weight, head=None):
     logits = deferred_logits(training, hidden, tied_weight, head)
     return lm_logits(hidden, tied_weight, head) if logits is None \
         else logits
+
+
+def head_cross_entropy(head: _Head, labels, weight=None, with_rows=False):
+    """sum_i weight[i] * cross_entropy(head.hidden[i] . W, labels[i])
+    over all of the head's rows (every leading axis of `hidden`
+    flattened; `labels` and `weight` of as many elements; `weight` None:
+    1/rows each, the mean), the logits never whole
+    (`ops.linear_cross_entropy`): one call, so the head's float32 `dW`
+    is made and held once however many passes the rows come from. With
+    `with_rows`: (the sum, every row's cross-entropy as a reading).
+    Operations keep the `lm_head` scope beside the caller's."""
+    hidden = ops.reshape(head.hidden, (-1, head.hidden.shape[-1]))
+    n = hidden.shape[0]
+    vocab = head.weight.shape[0 if head.transpose_y else 1]
+    chunk = nn_ops.lce_chunk(vocab)
+    chunks = nn_ops._lce_plan(n, chunk)[0]
+    note = f"fused, chunks {chunks}"
+    if head.hidden.ndim > 3:    # the rows of several passes
+        note += f", rows {n}"
+    perf.trace_note("head_loss", note)
+    if weight is not None:
+        weight = ops.reshape(weight, (n,))
+    with auto_cast(enable=False), traced_scope("lm_head"):
+        return ops.linear_cross_entropy(
+            hidden, head.weight, ops.reshape(labels, (n,)), weight,
+            transpose_y=head.transpose_y, chunk=chunk, with_rows=with_rows)

@@ -60,17 +60,19 @@ class _MLPRouter(Layer):
 
 
 class SwiGLU(Layer):
-    """down(silu(gate(x)) * up(x)), no bias."""
+    """down(silu(gate(x)) * up(x)), no bias. `out_std`: the draw of
+    `down_proj`, which writes to the stream (None: `std`)."""
 
-    def __init__(self, hidden, width, std=0.02):
+    def __init__(self, hidden, width, std=0.02, out_std=None):
         super().__init__()
         attr = Normal(std=std)
         self.gate_proj = Linear(hidden, width, weight_attr=attr,
                                 bias_attr=False)
         self.up_proj = Linear(hidden, width, weight_attr=attr,
                               bias_attr=False)
-        self.down_proj = Linear(width, hidden, weight_attr=attr,
-                                bias_attr=False)
+        self.down_proj = Linear(
+            width, hidden, bias_attr=False,
+            weight_attr=attr if out_std is None else Normal(std=out_std))
 
     def forward(self, x):
         return self.down_proj(ops.silu(self.gate_proj(x)) * self.up_proj(x))

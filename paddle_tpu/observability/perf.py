@@ -404,7 +404,7 @@ _TRACE_NOTES = _TraceNotes()
 
 def trace_note(key: str, value: str) -> None:
     """From code that runs while a program is traced: which of its paths
-    it took (`head_loss`: `fused, chunks 2` | `whole`, models/gpt.py).
+    it took (`head_loss`: `fused, chunks 2` | `whole`, models/lm_head.py).
     The note lands in `compile_record(family)` of the `CompileTimed`
     whose first call is tracing on this thread, under `key`; paths taken
     side by side in one program are joined by `; `. Outside such a call

@@ -828,7 +828,7 @@ def _lce_run(hidden, weight, label, token_weight, transpose_y,
     ce = jnp.where(valid, ce.reshape(k * c)[:n], 0.0)
     loss = jnp.sum(ce * token_weight.astype(jnp.float32))
     if not with_grads:
-        return loss, None
+        return loss, (None, None, ce)
     return loss, (dh.reshape(k * c, h)[:n], dw, ce)
 
 
@@ -860,10 +860,37 @@ def _lce_bwd(transpose_y, ignore_index, chunk, res, g):
 _lce.defvjp(_lce_fwd, _lce_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _lce_rows(hidden, weight, label, token_weight, transpose_y,
+              ignore_index, chunk):
+    """`_lce`, and beside the loss every row's cross-entropy [n]
+    float32, as a reading: no gradient flows back through it (its
+    cotangent is dropped: the rows' `d hidden` was folded into the
+    loss's when the forward ran). A `custom_vjp` of its own beside
+    `_lce`, and not a second output of `_lce`, so that the step text of
+    every caller that reads no rows stays what it was, character for
+    character (the accepted cells' lowered steps are compared so)."""
+    loss, (_dh, _dw, ce) = _lce_run(hidden, weight, label, token_weight,
+                                    transpose_y, ignore_index, chunk, False)
+    return loss, ce
+
+
+def _lce_rows_fwd(*args):
+    loss, res = _lce_fwd(*args)
+    return (loss, res[2]), res
+
+
+def _lce_rows_bwd(transpose_y, ignore_index, chunk, res, g):
+    return _lce_bwd(transpose_y, ignore_index, chunk, res, g[0])
+
+
+_lce_rows.defvjp(_lce_rows_fwd, _lce_rows_bwd)
+
+
 @register_op("linear_cross_entropy", amp_policy="keep")
 def linear_cross_entropy(hidden, weight, label, token_weight=None,
                          transpose_y=True, ignore_index=-100,
-                         chunk=LCE_CHUNK):
+                         chunk=LCE_CHUNK, with_rows=False):
     """sum_i token_weight[i] * cross_entropy(hidden[i] @ W, label[i]):
     the vocabulary projection and the loss in one op, so that no
     [tokens, vocab] array exists. `hidden` [n, h]; `weight` [v, h] (a
@@ -884,12 +911,20 @@ def linear_cross_entropy(hidden, weight, label, token_weight=None,
     Rounding is `matmul`'s and `cross_entropy`'s: logits in the
     operands' dtype from a float32 accumulator, log-sum-exp and softmax
     in float32, the logits' gradient rounded once to their dtype.
-    Operands are used as given (amp "keep": the caller casts)."""
+    Operands are used as given (amp "keep": the caller casts).
+
+    `token_weight` is differentiable: its gradient is each row's
+    cross-entropy (a loss whose weights are themselves learnt, as an
+    expected loss over a learnt distribution, gets the distribution's
+    gradient from here). With `with_rows` the op returns (loss, rows):
+    every row's cross-entropy [n] float32 beside the sum, for reading
+    (a mean by group); nothing is differentiated through the rows."""
     if token_weight is None:
         token_weight = jnp.full((hidden.shape[0],), 1.0 / hidden.shape[0],
                                 jnp.float32)
-    return _lce(hidden, weight, label, token_weight, bool(transpose_y),
-                int(ignore_index), int(chunk))
+    run = _lce_rows if with_rows else _lce
+    return run(hidden, weight, label, token_weight, bool(transpose_y),
+               int(ignore_index), int(chunk))
 
 
 @register_op("softmax_with_cross_entropy", amp_policy="black")

@@ -1,0 +1,416 @@
+"""`models/ouro.py` and `recompute.scan_passes` on the CPU at a tiny size
+(`ouro_tiny`: 3 layers x 64, 4 heads of 16, vocabulary 512, T = 4),
+seeded weights, against the plain reference
+(`benchmarks/harness/ouro_reference.py`): the four logits and gates, the
+loss and every leaf's gradient, the fused criterion against the whole
+one, the loop against T untied copies of the stack, recompute on and
+off, the exit distribution, and what the lowered step holds."""
+import os
+import re
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as pt
+from paddle_tpu import amp, ops
+from paddle_tpu.distributed.meta_parallel.recompute import scan_passes
+from paddle_tpu.jit import TrainStep
+from paddle_tpu.models import (GPTPretrainingCriterion, OuroConfig,
+                               OuroForCausalLM, OuroPretrainingCriterion,
+                               exit_distribution, ouro_tiny)
+from paddle_tpu.models import lm_head
+from paddle_tpu.observability import perf
+from paddle_tpu.optimizer import AdamW
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmarks")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+BETA = 0.05
+
+
+def _cfg_dict(**kw):
+    """`ouro_tiny` as a benchmark configuration's dict, for the
+    reference."""
+    c = ouro_tiny(**kw)
+    return dict(
+        vocab_size=c.vocab_size, real_vocab_size=c.vocab_size,
+        hidden_size=c.hidden_size, intermediate_size=c.intermediate_size,
+        num_hidden_layers=c.num_hidden_layers,
+        num_attention_heads=c.num_attention_heads,
+        num_key_value_heads=c.num_key_value_heads, head_dim=c.head_dim,
+        rms_norm_eps=c.rms_norm_eps, rope_theta=c.rope_theta,
+        total_ut_steps=c.total_ut_steps, initializer_range=0.15,
+        seeded_draws={"embedding": 1.0, "residual_output": 0.1,
+                      "norm_weight": 0.1},
+        training={"exit_entropy_beta": BETA})
+
+
+def _pair(seed=5, recompute=False, **kw):
+    """(the program's model holding the reference's seeded weights, the
+    reference's model)."""
+    from harness import ouro_program, ouro_reference
+    cfg = _cfg_dict(**kw)
+    model = ouro_program.build_model(cfg, seed, ouro_reference,
+                                     recompute=recompute)
+    return model, ouro_reference.Model(cfg, seed)
+
+
+def _batch(rows=2, seq=24, vocab=512, seed=0):
+    toks = np.random.default_rng(seed).integers(
+        0, vocab, (rows, seq + 1)).astype(np.int32)
+    return toks[:, :-1], toks[:, 1:]
+
+
+def _ref_loss(plain, ids, labels):
+    """The reference's loss as one function of its parameter list."""
+    from harness import ouro_reference as r
+    cfg, L = plain.cfg, plain.n_layers
+
+    def loss(params):
+        x = params[0][jnp.asarray(ids)]
+        tail = params[1 + L * r.N_LAYER:]
+        hs = []
+        for _t in range(plain.T):
+            for i in range(L):
+                lo = 1 + i * r.N_LAYER
+                x = r.block(params[lo:lo + r.N_LAYER], x,
+                            plain.rope(ids.shape[1]), cfg=cfg, rnd=r.exact)
+            x = r.rms_norm(x, tail[0], cfg["rms_norm_eps"])
+            hs.append(x)
+        total, _aux = r.exit_loss(jnp.stack(hs), tail[1], tail[2], tail[3],
+                                  jnp.asarray(labels), beta=plain.beta,
+                                  rnd=r.exact)
+        return total / ids.size
+    return loss
+
+
+# -- the model's description -------------------------------------------------
+def test_the_published_defaults_are_ouro_2_6b():
+    c = OuroConfig()
+    assert (c.hidden_size, c.num_hidden_layers, c.vocab_size,
+            c.intermediate_size) == (2048, 48, 49152, 5632)
+    assert (c.num_attention_heads, c.num_key_value_heads, c.head_dim) == (
+        16, 16, 128)
+    assert (c.total_ut_steps, c.rope_theta, c.rms_norm_eps,
+            c.max_position_embeddings) == (4, 1e6, 1e-6, 65536)
+    assert c.out_std == pytest.approx(0.02 / (2 * 192) ** 0.5)
+
+
+@pytest.mark.parametrize("key,value", [
+    ("rope_scaling", {"type": "yarn"}), ("use_sliding_window", True),
+    ("sliding_window", 512), ("tie_word_embeddings", True),
+    ("hidden_act", "gelu"), ("layer_types", ("sliding_attention",) * 48)])
+def test_what_the_model_cannot_run_is_refused(key, value):
+    with pytest.raises(NotImplementedError):
+        OuroConfig(**{key: value})
+
+
+def test_from_dict_reads_the_depth_cut_of_a_benchmark_configuration():
+    d = dict(num_hidden_layers=8, total_ut_steps=4, model_type="ouro",
+             max_window_layers=48, early_exit_threshold=1,
+             layer_types=["full_attention"] * 48,
+             published={"num_hidden_layers": 48}, reduced=["x"])
+    c = OuroConfig.from_dict(d, recompute=True)
+    assert (c.num_hidden_layers, c.residual_depth, c.recompute) == (
+        8, 192, True)
+    assert c.out_std == pytest.approx(0.02 / 384 ** 0.5)
+
+
+def test_a_layer_is_the_sandwich_and_the_parameters_are_the_references():
+    from harness import ouro_reference
+    model, plain = _pair()
+    names = [n for n, _p in model.named_parameters()]
+    assert names == [n for n, _s, _i in plain.specs]
+    assert len(names) == 1 + 3 * 11 + 4
+    assert sum(".input_layernorm" in n or ".post_attention_layernorm" in n
+               for n in names) == 4 * 3
+    assert names[-2:] == ["exit_gate.weight", "exit_gate.bias"]
+    assert ouro_reference.n_params(plain.cfg) == sum(
+        int(np.prod(p.shape)) for _n, p in model.named_parameters())
+
+
+# -- the program against the reference ------------------------------------
+def test_the_four_logits_and_gates_match_the_reference():
+    model, plain = _pair()
+    model.eval()
+    ids, _labels = _batch()
+    logits, gates = model(pt.to_tensor(ids))
+    want_logits, want_gates = plain.logits(ids)
+    assert tuple(logits.shape) == (4, 2, 24, 512)
+    assert tuple(gates.shape) == (4, 2, 24)
+    np.testing.assert_allclose(logits.numpy(), want_logits, atol=2e-4)
+    np.testing.assert_allclose(gates.numpy(), want_gates, atol=2e-5)
+    # the passes differ: the loop is no repetition of one reading
+    assert np.abs(logits.numpy()[0] - logits.numpy()[3]).max() > 0.1
+
+
+def test_the_loss_and_every_gradient_leaf_match_the_reference():
+    model, plain = _pair()
+    model.train()
+    ids, labels = _batch()
+    loss, aux = OuroPretrainingCriterion(BETA)(
+        model(pt.to_tensor(ids)), pt.to_tensor(labels))
+    loss.backward()
+    want_loss, want = jax.value_and_grad(_ref_loss(plain, ids, labels))(
+        plain.params)
+    np.testing.assert_allclose(float(loss.numpy()), float(want_loss),
+                               rtol=2e-6)
+    assert tuple(aux.shape) == (2, 4)
+    np.testing.assert_allclose(aux.numpy()[1].sum(), 1.0, rtol=1e-6)
+    for (name, p), g in zip(model.named_parameters(), want):
+        scale = float(jnp.abs(g).max())
+        assert scale > 0, name          # the gate's two leaves among them
+        np.testing.assert_allclose(p.grad.numpy() / scale, g / scale,
+                                   atol=2e-4, err_msg=name)
+
+
+def test_the_exit_distribution_sums_to_one_and_follows_the_products():
+    g = np.random.default_rng(0).normal(0, 2.0, (4, 3, 7)).astype(np.float32)
+    p, log_p = exit_distribution(pt.to_tensor(g))
+    p, lam = p.numpy(), 1 / (1 + np.exp(-g.astype(np.float64)))
+    np.testing.assert_allclose(p.sum(0), 1.0, rtol=1e-6)
+    stay = np.cumprod(1 - lam, axis=0)
+    want = np.concatenate([lam[:1], lam[1:3] * stay[:2], stay[2:3]])
+    np.testing.assert_allclose(p, want, rtol=1e-5)
+    np.testing.assert_allclose(np.exp(log_p.numpy()), p, rtol=1e-6)
+    # one pass: the distribution is the whole of it
+    one, _ = exit_distribution(pt.to_tensor(g[:1]))
+    np.testing.assert_allclose(one.numpy(), 1.0)
+
+
+def test_one_pass_gives_the_plain_criterions_mean_on_the_same_logits():
+    model, _plain = _pair(total_ut_steps=1)
+    model.eval()
+    ids, labels = _batch()
+    logits, gates = model(pt.to_tensor(ids))
+    loss, aux = OuroPretrainingCriterion(BETA)((logits, gates),
+                                               pt.to_tensor(labels))
+    want = GPTPretrainingCriterion()(logits[0], pt.to_tensor(labels))
+    np.testing.assert_allclose(float(loss.numpy()), float(want.numpy()),
+                               rtol=1e-6)
+    np.testing.assert_allclose(aux.numpy(), [[float(want.numpy())], [1.0]],
+                               rtol=1e-6)
+
+
+# -- the fused criterion ------------------------------------------------------
+def _criterion_on(hidden, weight, gates, labels, fused):
+    """loss and aux from raw arrays, through the promise (one fused head
+    call over T x n rows) or through whole logits."""
+    crit = OuroPretrainingCriterion(BETA)
+    head = lm_head._Head(pt.Tensor._wrap(hidden), pt.Tensor._wrap(weight),
+                         False)
+    if fused:
+        from paddle_tpu.core.tensor import DeferredTensor
+        logits = DeferredTensor(
+            lambda: None, list(hidden.shape[:-1]) + [weight.shape[1]],
+            hidden.dtype, producer=head)
+    else:
+        logits = ops.matmul(head.hidden, head.weight)
+    loss, aux = crit((logits, pt.Tensor._wrap(gates)),
+                     pt.Tensor._wrap(labels))
+    return loss._data, aux._data
+
+
+def test_the_fused_criterion_equals_the_whole_one_value_and_gradients():
+    rng = np.random.default_rng(1)
+    hidden = jnp.asarray(rng.normal(0, 1, (4, 2, 24, 64)), jnp.float32)
+    weight = jnp.asarray(rng.normal(0, 0.2, (64, 512)), jnp.float32)
+    gates = jnp.asarray(rng.normal(0, 1, (4, 2, 24)), jnp.float32)
+    labels = jnp.asarray(rng.integers(0, 512, (2, 24)), jnp.int32)
+
+    def run(fused):
+        def f(hidden, weight, gates):
+            return _criterion_on(hidden, weight, gates, labels, fused)
+        (loss, aux), grads = jax.value_and_grad(f, argnums=(0, 1, 2),
+                                                has_aux=True)(
+            hidden, weight, gates)
+        return loss, aux, grads
+
+    with pt.no_grad():
+        loss_f, aux_f, g_f = run(True)
+        loss_w, aux_w, g_w = run(False)
+    np.testing.assert_allclose(loss_f, loss_w, rtol=2e-6)
+    np.testing.assert_allclose(aux_f, aux_w, rtol=2e-6)
+    for a, b in zip(g_f, g_w):
+        np.testing.assert_allclose(a, b, atol=2e-6 * float(jnp.abs(b).max())
+                                   + 1e-9)
+    # the gate learns through the head's weights: d loss / d token_weight
+    assert float(jnp.abs(g_f[2]).max()) > 1e-4
+
+
+def test_linear_cross_entropy_hands_back_the_rows_and_the_weights_gradient():
+    rng = np.random.default_rng(2)
+    hidden = jnp.asarray(rng.normal(0, 1, (48, 64)), jnp.float32)
+    weight = jnp.asarray(rng.normal(0, 0.2, (64, 512)), jnp.float32)
+    labels = jnp.asarray(rng.integers(0, 512, (48,)), jnp.int32)
+    w = jnp.asarray(rng.uniform(0.1, 1.0, (48,)), jnp.float32)
+    lce = ops.linear_cross_entropy.raw_fn
+
+    def rows(hidden, weight, w):
+        return lce(hidden, weight, labels, w, transpose_y=False,
+                   with_rows=True)
+
+    (loss, ce), grads = jax.value_and_grad(rows, argnums=(0, 1, 2),
+                                           has_aux=True)(hidden, weight, w)
+    logp = jax.nn.log_softmax(jnp.dot(hidden, weight, precision="highest"))
+    want = -jnp.take_along_axis(logp, labels[:, None], 1)[:, 0]
+    np.testing.assert_allclose(ce, want, rtol=2e-5)
+    np.testing.assert_allclose(loss, jnp.sum(w * want), rtol=2e-6)
+    np.testing.assert_allclose(grads[2], want, rtol=2e-5)   # d / d weight
+    plain = jax.grad(lambda h, m, w: lce(h, m, labels, w, transpose_y=False),
+                     argnums=(0, 1, 2))(hidden, weight, w)
+    for a, b in zip(grads, plain):
+        np.testing.assert_allclose(a, b, rtol=1e-6)
+
+
+# -- the loop -----------------------------------------------------------------
+def test_scan_passes_runs_a_function_n_times_and_stacks_what_it_gave():
+    w = pt.to_tensor(np.float32(3.0))
+    w.stop_gradient = False
+
+    def f(x, shift):
+        return x * w + shift
+
+    out = scan_passes(f, 3, pt.to_tensor(np.ones((2,), np.float32)),
+                      pt.to_tensor(np.float32(1.0)), parameters=[w])
+    np.testing.assert_allclose(out.numpy(), [[4, 4], [13, 13], [40, 40]])
+    ops.sum(out[-1]).backward()
+    # d/dw of (((w + 1) w + 1) w + 1) = 3 w^2 + 2 w + 1, twice
+    np.testing.assert_allclose(w.grad.numpy(), 2 * (27 + 6 + 1))
+
+
+def test_the_looped_weights_gradient_is_the_sum_over_untied_copies():
+    """The test that ties the loop to the model: T copies of the stack
+    with the same values, one after the other, each its own leaves; the
+    looped model's gradient of a leaf is the sum of its T copies'."""
+    from harness import ouro_reference as r
+    model, plain = _pair()
+    model.train()
+    ids, labels = _batch()
+    loss, _aux = OuroPretrainingCriterion(BETA)(
+        model(pt.to_tensor(ids)), pt.to_tensor(labels))
+    loss.backward()
+    L, n_stack = plain.n_layers, plain.n_layers * r.N_LAYER + 1
+    stack = plain.params[1:1 + n_stack]         # the layers, the final norm
+    tail = plain.params[1 + n_stack:]
+
+    def untied(copies):
+        x = plain.params[0][jnp.asarray(ids)]
+        hs = []
+        for p in copies:
+            for i in range(L):
+                x = r.block(p[i * r.N_LAYER:(i + 1) * r.N_LAYER], x,
+                            plain.rope(ids.shape[1]), cfg=plain.cfg,
+                            rnd=r.exact)
+            x = r.rms_norm(x, p[-1], plain.cfg["rms_norm_eps"])
+            hs.append(x)
+        total, _ = r.exit_loss(jnp.stack(hs), *tail, jnp.asarray(labels),
+                               beta=BETA, rnd=r.exact)
+        return total / ids.size
+
+    per_copy = jax.grad(untied)([list(stack) for _ in range(plain.T)])
+    assert len(per_copy) == 4
+    looped = [p.grad.numpy() for _n, p in model.named_parameters()]
+    for k, (name, _s, _i) in enumerate(plain.specs[1:1 + n_stack]):
+        parts = [np.asarray(c[k]) for c in per_copy]
+        want = sum(parts)
+        scale = np.abs(want).max()
+        np.testing.assert_allclose(looped[1 + k] / scale, want / scale,
+                                   atol=2e-4, err_msg=name)
+        # no one copy's gradient is the sum: every pass has its say
+        assert max(np.abs(p).max() for p in parts) > 0
+        assert not np.allclose(parts[0] / scale, want / scale, atol=1e-3)
+
+
+def test_recompute_on_and_off_agree():
+    ids, labels = _batch()
+    got = []
+    for remat in (False, True):
+        model, _plain = _pair(recompute=remat)
+        model.train()
+        loss, _aux = OuroPretrainingCriterion(BETA)(
+            model(pt.to_tensor(ids)), pt.to_tensor(labels))
+        loss.backward()
+        got.append((float(loss.numpy()),
+                    [p.grad.numpy() for p in model.parameters()]))
+    np.testing.assert_allclose(got[0][0], got[1][0], rtol=1e-6)
+    for a, b in zip(*(g for _l, g in got)):
+        np.testing.assert_allclose(a, b, atol=1e-5 * np.abs(a).max() + 1e-9)
+
+
+# -- the step -------------------------------------------------------------
+def _step(T, layers=3):
+    pt.seed(0)
+    model = OuroForCausalLM(ouro_tiny(total_ut_steps=T, recompute=True,
+                                      num_hidden_layers=layers))
+    model.train()
+    crit = OuroPretrainingCriterion(BETA)
+
+    def loss_fn(m, ids, labels):
+        with amp.auto_cast(enable=True, level="O1", dtype="bfloat16"):
+            outputs = m(ids)
+        return crit(outputs, labels)
+
+    return TrainStep(model, AdamW(
+        learning_rate=1e-3, parameters=model.parameters(),
+        moment_dtype="bfloat16"), loss_fn, has_aux=True)
+
+
+def _lowered(step):
+    ids, labels = _batch()
+    return step._step_fn.jit_fn.lower(
+        step.params, step.opt_states, step.buffers, jax.random.PRNGKey(0),
+        jnp.float32(1e-3), [ids, labels], {}).as_text()
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    """The lowered step's text by (passes, layers)."""
+    return {key: _lowered(_step(*key)) for key in ((2, 3), (4, 3), (4, 2))}
+
+
+def _dots(text):
+    return len(re.findall(r"stablehlo\.dot_general", text))
+
+
+def test_the_lowered_step_holds_the_loop_as_a_while(lowered):
+    for text in lowered.values():
+        assert "stablehlo.while" in text
+
+
+def test_each_layers_products_are_lowered_once_whatever_the_passes(lowered):
+    """The count of `dot_general`s does not grow with T: the body is one.
+    A layer adds its seven matrices forward, run again and two products
+    back each (28) and, here on the CPU, the composite attention's two
+    products the same way (8) and the composite rotary's permutation
+    product of q and of k forward, again and back (6): the chip runs the
+    flash and the rotary kernels in their place."""
+    assert _dots(lowered[2, 3]) == _dots(lowered[4, 3])
+    assert _dots(lowered[4, 3]) - _dots(lowered[4, 2]) == 42
+
+
+def test_the_step_trains_and_says_which_paths_it_took():
+    step = _step(4)
+    ids, labels = _batch()
+    losses = [float(step(ids, labels).numpy()) for _ in range(4)]
+    assert losses[-1] < losses[0]
+    aux = np.asarray(step.aux)
+    assert aux.shape == (2, 4) and aux.dtype == np.float32
+    np.testing.assert_allclose(aux[1].sum(), 1.0, rtol=1e-5)
+    record = perf.compile_record("train_step")
+    assert record["ut_loop"] == "scan, 4 x 3 layers"
+    assert record["head_loss"] == "fused, chunks 1, rows 192"
+    assert record["flash_kept"].startswith("o and lse kept")
+    scopes = record["trace_by_scope"]
+    for scope in ("ouroforcausallm/model/ut_loop/layers/*/attn/rope",
+                  "ouroforcausallm/model/ut_loop/layers/*/input_layernorm_2",
+                  "ouroforcausallm/model/ut_loop/norm",
+                  "ouroforcausallm/exit_gate",
+                  "ouropretrainingcriterion/exit_loss",
+                  "ouropretrainingcriterion/lm_head"):
+        assert scope in scopes, scope

@@ -961,3 +961,107 @@ def test_the_qwen3next_step_says_which_paths_it_took(qwen3next_step):
         "rope": "rope_rotate: 16 heads, rot 64 of 256; "
                 "rope_rotate: 2 heads, rot 64 of 256",
         "head_loss": "fused, chunks 1"}
+
+
+# -- Ouro-2.6B: the looped stack at the cell's sizes ---------------------
+@pytest.fixture(scope="module")
+def ouro_step(v5e):
+    """The step of `ouro-2.6b-l8.train-4k` at the cell's own sizes (8
+    layers x 2048 run four times, 2 x 4096 tokens, the whole vocabulary;
+    placeholders for weights: nothing runs), written as
+    benchmarks/drivers/ouro_train_window.py writes it, compiled for one
+    described v5e: (text, compile record, memory analysis)."""
+    import json
+    import os
+    import paddle_tpu as pt
+    from paddle_tpu import amp
+    from paddle_tpu.jit import TrainStep
+    from paddle_tpu.models.ouro import (OuroConfig, OuroForCausalLM,
+                                        OuroPretrainingCriterion)
+    from paddle_tpu.observability import perf
+    from paddle_tpu.optimizer import AdamW
+    one = SingleDeviceSharding(v5e[0])
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs",
+            "ouro-2.6b-l8.json")) as f:
+        cfg = json.load(f)
+    crit = OuroPretrainingCriterion(cfg["training"]["exit_entropy_beta"])
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("PADDLE_TPU_PALLAS_AUTOTUNE", "0")
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        was_on = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        with pt.LazyGuard():
+            model = OuroForCausalLM(OuroConfig.from_dict(
+                cfg, use_flash_attention=True, recompute=True))
+        model.train()
+
+        def loss_fn(m, ids, labels):
+            with amp.auto_cast(enable=True, level="O1", dtype="bfloat16"):
+                outputs = m(ids)
+            return crit(outputs, labels)
+
+        step = TrainStep(model, AdamW(
+            learning_rate=1e-4, parameters=model.parameters(),
+            moment_dtype="bfloat16"), loss_fn, has_aux=True)
+        ids = jax.ShapeDtypeStruct((2, 4096), jnp.int32, sharding=one)
+        notes = {}
+        outer, perf._TRACE_NOTES.notes = perf._TRACE_NOTES.notes, notes
+        try:
+            compiled = step._step_fn.jit_fn.lower(
+                [spec(p) for p in step.params],
+                [{k: spec(v) for k, v in st.items()}
+                 for st in step.opt_states],
+                [spec(b) for b in step.buffers],
+                spec(jax.random.PRNGKey(0)), spec(jnp.float32(1e-4)),
+                [ids, ids], {}).compile()
+        finally:
+            perf._TRACE_NOTES.notes = outer
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        compilation_cache.reset_cache()
+    return compiled.as_text(), notes, compiled.memory_analysis()
+
+
+@pytest.mark.parametrize("kernel,calls", [
+    ("flash_fwd", 8),       # a layer once: the body is one, o and lse kept
+    ("flash_bwd_transpose", 8),
+    ("rope_rotate", 48)])   # a layer's q and k: forward, again, back
+def test_the_ouro_step_holds_each_layers_kernels_once(ouro_step, kernel,
+                                                      calls):
+    """32 layer applications a step, and the program holds 8 layers'
+    kernels: the loop over the passes is a `while`."""
+    text, _notes, _memory = ouro_step
+    found = re.findall(rf"%{kernel}[.\d]* = .*custom-call\(", text)
+    assert len(found) == calls, (kernel, len(found))
+    assert text.count("tpu_custom_call") == 64
+    # the passes forward, the passes back, the head's eight chunks
+    assert len(re.findall(r" while\(", text)) == 3
+
+
+def test_the_ouro_step_fits_the_chip_and_says_which_paths_it_took(ouro_step):
+    text, notes, memory = ouro_step
+    arguments = memory.argument_size_in_bytes
+    assert arguments == pytest.approx(8 * 612438017, rel=1e-3)
+    total = (arguments + memory.output_size_in_bytes
+             + memory.temp_size_in_bytes - memory.alias_size_in_bytes)
+    # a v5e holds 16 GiB; the cell's floor is a quarter of it
+    assert 0.25 * 2 ** 34 < total < 0.95 * 2 ** 34, total
+    for scope in ("model/ut_loop/while/body", "layers/7/attn/rope",
+                  "layers/0/input_layernorm_2",
+                  "layers/3/post_attention_layernorm_2", "exit_gate/",
+                  "exit_loss/", "lm_head/while/body"):
+        assert scope in text, scope
+    assert notes == {
+        "ut_loop": "scan, 4 x 8 layers", "attention": "pallas",
+        "flash_operands": "split",
+        "flash_kept": "o and lse kept across recompute in 8 of 8 "
+                      "recomputed layers",
+        "flash_causal": "fwd 136/256 of 256-wide tiles; "
+                        "bwd 136/256 of 256-wide tiles, dq partials 4",
+        "rope": "rope_rotate: 16 heads, rot 128 of 128",
+        "head_loss": "fused, chunks 8, rows 32768"}
