@@ -44,6 +44,16 @@ once (float32, the products at `highest` precision); it runs scoped
   (dP is the gradient to every entry of P; `prepare`'s mask zeroes the
   upper ones under autodiff.)
 
+q and k may come at Hk key heads where v, g and beta have H value heads
+(Hk dividing H: a Gated DeltaNet's 16 on 32). The preparation's grid
+then runs a key head's steps by its H / Hk value heads: the value
+head's step reads its key head's block of q and k where it lies, and
+the backward adds the value heads' dq and dk in the key head's output
+block. Nothing is copied a value head, forward or back. The operands
+reach `_rule` heads first and in chunks ([B, nc, C, d]): from tokens
+first through `gated_delta_rule`, or as `gdn_operands.py` wrote them
+through `gated_delta_rule_heads_first`.
+
 A block holds `HEADS` heads whose chains are independent: the scheduler
 overlaps one head's products with another's. On a TPU backend the
 kernels are the only path (`state_path`, `prepare_path`). Elsewhere (the
@@ -293,16 +303,32 @@ def _inverse_bwd(T, dT):
 _inverse.defvjp(_inverse_fwd, _inverse_bwd)
 
 
-def _heads_first(x, H):
+def _heads_first(x):
     """[b, s, H, ...] -> [b * H, nc, CHUNK, ...]."""
     x = jnp.moveaxis(x, 2, 1)
     return x.reshape((-1, x.shape[2] // CHUNK, CHUNK) + x.shape[3:])
 
 
+def _tokens_first(O, b):
+    """[b * H, nc, C, dv] -> [b, s, H, dv]."""
+    return jnp.moveaxis(O.reshape((b, O.shape[0] // b, -1, O.shape[-1])),
+                        1, 2)
+
+
+def _at_value_heads(q, k, B):
+    """q and k at their key heads [Bk, ...] -> a copy a value head
+    [B, ...] (a key head serves B / Bk value heads that follow each
+    other)."""
+    rep = B // q.shape[0]
+    if rep == 1:
+        return q, k
+    return jnp.repeat(q, rep, axis=0), jnp.repeat(k, rep, axis=0)
+
+
 def _prepare(q, k, v, g, beta):
     """`prepare` and the chunks' T [B, nc, C, C] behind it."""
-    chunk, H = CHUNK, q.shape[2]
-    q, k, v, g, beta = (_heads_first(x, H) for x in (q, k, v, g, beta))
+    chunk = CHUNK
+    q, k = _at_value_heads(q, k, v.shape[0])
     gam = jnp.cumsum(g, axis=-1)                        # [B, nc, C]
     rows = jnp.arange(chunk)
     seen = rows[:, None] >= rows[None, :]
@@ -323,10 +349,12 @@ def _prepare(q, k, v, g, beta):
 
 
 def prepare(q, k, v, g, beta):
-    """q, k [b, s, H, dk], v [b, s, H, dv], g, beta [b, s, H], float32 ->
-    (U, W, Qg, Kd, P, a) as the state pass takes them, B = b * H and
-    nc = s / CHUNK. The plain statement of the chunk preparation: the
-    path off a TPU, under autodiff, and what the kernels are held to."""
+    """The operands heads first and in chunks (`_heads_first`): q, k
+    [Bk, nc, C, dk], v [B, nc, C, dv], g, beta [B, nc, C], float32, B = b
+    * H value heads and Bk = b * Hk key heads, Hk dividing H -> (U, W,
+    Qg, Kd, P, a) as the state pass takes them. The plain statement of
+    the chunk preparation: the path off a TPU, under autodiff, and what
+    the kernels are held to."""
     return _prepare(q, k, v, g, beta)[:6]
 
 
@@ -502,7 +530,9 @@ def _prepare_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, u_ref, w_ref,
 
 def _prepare_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, t_ref, du_ref,
                         dw_ref, dqg_ref, dkd_ref, dp_ref, da_ref,
-                        dq_ref, dk_ref, dv_ref, dg_ref, db_ref):
+                        dq_ref, dk_ref, dv_ref, dg_ref, db_ref, *, rep):
+    """dq and dk are a key head's: its rep value heads' steps follow each
+    other on the grid's second axis and add into one block."""
     C = t_ref.shape[2]
     masks = _pair_masks(C)
     gam, last, cols = _pair_rows(g_ref[0], b_ref[0])
@@ -515,6 +545,10 @@ def _prepare_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, t_ref, du_ref,
             q_ref[0, n], k_ref[0, n], v_ref[0, n], gam[n:n + 1],
             *_pair_cols(cols, n, masks[0]), t_ref[0, n], du_ref[0, n],
             dw_ref[0, n], dqg_ref[0, n], dkd_ref[0, n], dP, masks)
+        if rep > 1:
+            first = pl.program_id(1) == 0
+            dq = jnp.where(first, dq, dq_ref[0, n] + dq)
+            dk = jnp.where(first, dk, dk_ref[0, n] + dk)
         dq_ref[0, n], dk_ref[0, n], dv_ref[0, n] = dq, dk, dv
         down = jnp.where(lanes == n, dgam, down)
         down = jnp.where(lanes == _ROWS + n, dbeta, down)
@@ -548,19 +582,40 @@ def _from_pairs(x, B, nc, *chunk):
     return x.reshape((-1,) + chunk)[:B * nc].reshape((B, nc) + chunk)
 
 
-def _prepare_specs(dk, dv):
-    def at(*block):
-        return pl.BlockSpec((1,) + block,
-                            lambda i: (i,) + (0,) * len(block))
+def _key_heads(q, k, B, nc):
+    """-> (q, k, rep, the grid steps a head): q and k as the kernels'
+    grid reads them. Where a head's chunks fill whole steps, a value
+    head's step reads its key head's block and q and k stay at their Bk
+    = B / rep heads; else they are copied a value head (rep 1, the grid
+    over all chunks in a row)."""
+    rep = B // q.shape[0]
+    if rep > 1 and nc % (2 * PAIRS):
+        q, k = _at_value_heads(q, k, B)
+        rep = 1
+    return q, k, rep, nc // (2 * PAIRS) if rep > 1 else 1
+
+
+def _prepare_specs(dk, dv, rep, per_head):
+    """The grid is (a key head's steps, its rep value heads): step
+    (i, r) holds the chunks of value head `i // per_head * rep + r` that
+    key step i holds of its head, so q's and k's block stays where it is
+    while r runs. With rep 1 a value step is step i."""
+    def at(*block, key=False):
+        zeros = (0,) * len(block)
+        if key:
+            return pl.BlockSpec((1,) + block, lambda i, r: (i,) + zeros)
+        return pl.BlockSpec((1,) + block, lambda i, r: (
+            (i // per_head * rep + r) * per_head + i % per_head,) + zeros)
     C = CHUNK
     return dict(k=at(PAIRS, 2 * C, dk), v=at(PAIRS, 2 * C, dv),
                 row=at(_ROWS, 2 * C), p=at(PAIRS, 2, C, C),
-                t=at(PAIRS, C, 2 * C))
+                t=at(PAIRS, C, 2 * C), key=at(PAIRS, 2 * C, dk, key=True))
 
 
 def _prepare_params():
-    return pltpu.CompilerParams(dimension_semantics=("parallel",),
-                                vmem_limit_bytes=_VMEM_LIMIT)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=_VMEM_LIMIT)
 
 
 @functools.partial(jax.jit, static_argnames="interpret")
@@ -570,17 +625,18 @@ def _prepare_fwd_pallas(q, k, v, g, beta, interpret=False):
     T in pairs [steps, PAIRS, C, 2C]: the backward kernel's residual).
     Under `jax.jit`, as the backward's wrapper is, so that a step's
     layers and passes trace and lower the kernel once."""
-    b, s, H, dk = q.shape
-    dv, B, nc, C = v.shape[-1], b * H, s // CHUNK, CHUNK
-    ins = [_in_pairs(_heads_first(x, H)) for x in (q, k, v, g, beta)]
-    steps = ins[0].shape[0]
-    sp = _prepare_specs(dk, dv)
+    B, nc, C, dv = v.shape
+    q, k, rep, per_head = _key_heads(q, k, B, nc)
+    dk = q.shape[-1]
+    ins = [_in_pairs(x) for x in (q, k, v, g, beta)]
+    steps = ins[2].shape[0]
+    sp = _prepare_specs(dk, dv, rep, per_head)
     like = jax.ShapeDtypeStruct
-    wide_k, wide_v = ins[0].shape, ins[2].shape
+    wide_k, wide_v = (steps,) + ins[0].shape[1:], ins[2].shape
     U, W, Qg, Kd, P, a, T = pl.pallas_call(
         _prepare_fwd_kernel,
-        grid=(steps,),
-        in_specs=[sp["k"], sp["k"], sp["v"], sp["row"], sp["row"]],
+        grid=(steps // rep, rep),
+        in_specs=[sp["key"], sp["key"], sp["v"], sp["row"], sp["row"]],
         out_specs=[sp["v"], sp["k"], sp["k"], sp["k"], sp["p"], sp["row"],
                    sp["t"]],
         out_shape=[like(wide_v, F32), like(wide_k, F32), like(wide_k, F32),
@@ -602,35 +658,41 @@ def _prepare_fwd_pallas(q, k, v, g, beta, interpret=False):
 def _prepare_bwd_pallas(q, k, v, g, beta, T, grads, interpret=False):
     """`_prepare`'s transpose as a kernel: its operands, the forward
     kernel's T and the gradients to U, W, Qg, Kd, P [B, nc, ...] and a
-    [B, nc] -> dq, dk, dv, dg, dbeta like the operands."""
-    b, s, H, dk = q.shape
-    dv, B, nc, C = v.shape[-1], b * H, s // CHUNK, CHUNK
+    [B, nc] -> dq, dk, dv, dg, dbeta like the operands: dq and dk at q's
+    heads, a key head's value heads summed."""
+    B, nc, C, dv = v.shape
+    Bk = q.shape[0]
+    q, k, rep, per_head = _key_heads(q, k, B, nc)
+    dk = q.shape[-1]
     dU, dW, dQg, dKd, dP, da = grads
     # da beside the chunk's last token, where `last` is read
     da = da[..., None] * (jnp.arange(C) == C - 1)
-    ins = [_in_pairs(_heads_first(x, H)) for x in (q, k, v, g, beta)]
+    ins = [_in_pairs(x) for x in (q, k, v, g, beta)]
     ins += [T] + [_in_pairs(x) for x in (dU, dW, dQg, dKd)]
     steps = T.shape[0]
     ins += [_in_pairs(dP).reshape((steps, PAIRS, 2, C, C)), _in_pairs(da)]
-    sp = _prepare_specs(dk, dv)
+    sp = _prepare_specs(dk, dv, rep, per_head)
     like = jax.ShapeDtypeStruct
     wide_k, wide_v, row = ins[0].shape, ins[2].shape, ins[3].shape
-    grads = pl.pallas_call(
-        _prepare_bwd_kernel,
-        grid=(steps,),
-        in_specs=[sp["k"], sp["k"], sp["v"], sp["row"], sp["row"], sp["t"],
-                  sp["v"], sp["k"], sp["k"], sp["k"], sp["p"], sp["row"]],
-        out_specs=[sp["k"], sp["k"], sp["v"], sp["row"], sp["row"]],
+    out = pl.pallas_call(
+        functools.partial(_prepare_bwd_kernel, rep=rep),
+        grid=(steps // rep, rep),
+        in_specs=[sp["key"], sp["key"], sp["v"], sp["row"], sp["row"],
+                  sp["t"], sp["v"], sp["k"], sp["k"], sp["k"], sp["p"],
+                  sp["row"]],
+        out_specs=[sp["key"], sp["key"], sp["v"], sp["row"], sp["row"]],
         out_shape=[like(wide_k, F32), like(wide_k, F32), like(wide_v, F32),
                    like(row, F32), like(row, F32)],
         compiler_params=_prepare_params(),
         interpret=interpret,
         name="gdn_prepare_bwd",
     )(*ins)
-    chunks = [(C, dk), (C, dk), (C, dv), (C,), (C,)]
-    return tuple(
-        jnp.moveaxis(_from_pairs(x, B, nc, *c).reshape(
-            (b, H, s) + c[1:]), 1, 2) for x, c in zip(grads, chunks))
+    heads = q.shape[0]
+    to_keys = [_from_pairs(x, heads, nc, C, dk) for x in out[:2]]
+    if heads != Bk:     # copied a value head: back to the key heads
+        to_keys = [x.reshape((Bk, -1) + x.shape[1:]).sum(1) for x in to_keys]
+    return (*to_keys, _from_pairs(out[2], B, nc, C, dv),
+            *(_from_pairs(x, B, nc, C) for x in out[3:]))
 
 
 # ======================= dispatch =======================
@@ -650,32 +712,50 @@ def prepare_path() -> str:
 
 
 def gated_delta_rule(q, k, v, g, beta):
-    """o [b, s, H, dv] in v's type from q, k [b, s, H, dk], v
+    """o [b, s, H, dv] in v's type from q, k [b, s, Hk, dk], v
     [b, s, H, dv] and g (log decay, <= 0), beta [b, s, H];
-    differentiable in all five. The state and every product in float32.
-    A row that is no whole number of chunks is padded to one: a padded
-    token has beta = 0 and g = 0, so the state passes through it."""
-    s = v.shape[1]
+    differentiable in all five. q and k come a value head (Hk = H) or a
+    key head, Hk dividing H: key head h serves value heads h H / Hk and
+    those that follow, and on the kernels its q and k are read from
+    where they lie, never copied a value head. The state and every
+    product in float32. A row that is no whole number of chunks is
+    padded to one: a padded token has beta = 0 and g = 0, so the state
+    passes through it."""
+    b, s = v.shape[:2]
     pad = -s % CHUNK
     if pad:
         q, k, v, g, beta = (
             jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
             for x in (q, k, v, g, beta))
-    return _rule(q, k, v, g, beta)[:, :s]
+    O = _rule(*(_heads_first(x) for x in (q, k, v, g, beta)))
+    return _tokens_first(O, b)[:, :s]
+
+
+def gated_delta_rule_heads_first(q, k, v, g, beta):
+    """`gated_delta_rule` on q, k [b, Hk, sp, dk] and v [b, H, sp, dv]
+    that lie heads first already, as `gdn_operands.py` writes them: sp
+    is s up to whole chunks, the rows beyond s zeros. g and beta
+    [b, s, H] and o [b, s, H, dv] as ever. Nothing is turned or cast on
+    the way to the kernels but the two rows a head."""
+    b, s, _H = g.shape
+    sp = v.shape[2]
+    g, beta = (_heads_first(jnp.pad(x, ((0, 0), (0, sp - s), (0, 0))))
+               for x in (g, beta))
+    O = _rule(*(x.reshape((-1, sp // CHUNK, CHUNK, x.shape[-1]))
+                for x in (q, k, v)), g, beta)
+    return _tokens_first(O, b)[:, :s]
 
 
 @jax.custom_vjp
 def _rule(q, k, v, g, beta):
+    """The rule on chunks, heads first: q, k [Bk, nc, C, dk], v
+    [B, nc, C, dv], g, beta [B, nc, C] -> O [B, nc, C, dv] in v's
+    type."""
     return _rule_fwd(q, k, v, g, beta)[0]
 
 
 def _f32(xs):
     return tuple(x.astype(F32) for x in xs)
-
-
-def _tokens_first(O, b, H):
-    """[b * H, nc, C, dv] -> [b, s, H, dv]."""
-    return jnp.moveaxis(O.reshape((b, H, -1, O.shape[-1])), 1, 2)
 
 
 def _rule_fwd(q, k, v, g, beta):
@@ -687,8 +767,7 @@ def _rule_fwd(q, k, v, g, beta):
             *made, _T = _prepare_fwd_pallas(
                 *_f32(ins), interpret=mode == "interpret")
     O, res = _state_vjp_fwd(*made, state_path())
-    b, _s, H, _dv = v.shape
-    return _tokens_first(O, b, H).astype(v.dtype), (ins, res[-1])
+    return O.astype(v.dtype), (ins, res[-1])
 
 
 def _rule_bwd(res, do):
@@ -705,7 +784,7 @@ def _rule_bwd(res, do):
     backward (the step's reported peak 16.89 GB for 15.73 at
     `qwen3-next-80b-l4-e64`, PERF.md section 6, PR 40)."""
     ins, states = res
-    dO = _heads_first(do.astype(F32), do.shape[2])
+    dO = do.astype(F32)
     mode = prepare_path()
     with jax.named_scope("prepare"):
         if mode == "xla":

@@ -39,13 +39,17 @@ class GatedDeltaNet(Layer):
         its value heads' v and z; its value heads' b and a;
       * q | k | v (2 Hk d + Hv d channels) through a causal depthwise
         convolution of `taps` taps without bias (`conv_weight`), then
-        silu;
+        silu; q and k scaled to unit length, q also by 1 / sqrt(d):
+        `ops.gdn_operands`, which reads the projection's output where
+        it lies and writes q and k (at the Hk key heads) and v in
+        float32, heads first, as the rule's kernels read them (one
+        Pallas kernel each way on a TPU, a chain of XLA's elsewhere);
       * beta = sigmoid(b), g = -exp(A_log) * softplus(a + dt_bias), a
-        value head, float32; q and k scaled to unit length, q also by
-        1 / sqrt(d);
+        value head, float32;
       * `ops.gated_delta_rule`: S_t = e^{g_t} S_{t-1} + beta_t k_t
         (v_t - e^{g_t} S_{t-1}^T k_t)^T, o_t = S_t^T q_t, S [d, d] a
-        value head;
+        value head, which reads key head h's q and k for its value
+        heads;
       * RMSNorm over a value head's d (`norm_weight`, a plain weight)
         times silu(z), then `out_proj` [Hv d, hidden].
 
@@ -78,45 +82,32 @@ class GatedDeltaNet(Layer):
     def forward(self, u):
         from ...kernels.pallas.gated_delta import (CHUNK, prepare_path,
                                                    state_path)
+        from ...ops.linear_attn_ops import gdn_operands_path
         b, s, _ = u.shape
         Hk, Hv, d = self.key_heads, self.value_heads, self.head_dim
         rep = Hv // Hk
+        qkvz = self.in_proj_qkvz(u)
         perf.trace_note(
             "gdn", f"heads {Hv} on {Hk}, state {d} x {d}, chunk {CHUNK}, "
-            f"conv {self.taps} taps, chunk preparation: {prepare_path()}, "
+            f"conv {self.taps} taps, operands: "
+            f"{gdn_operands_path(qkvz.shape, qkvz.dtype.np_dtype, self.taps,
+                                 Hk, Hv)}, q k at {Hk} heads, "
+            f"chunk preparation: {prepare_path()}, "
             f"state pass: {state_path()}")
-        qkvz = ops.reshape(self.in_proj_qkvz(u), (b, s, Hk, (2 + 2 * rep) * d))
         ba = ops.reshape(self.in_proj_ba(u), (b, s, Hk, 2 * rep))
-        q, k, v, z = ops.split(qkvz, [d, d, rep * d, rep * d], axis=-1)
         beta, a = ops.split(ba, [rep, rep], axis=-1)
         with traced_scope("conv"):
-            mixed = ops.concat([ops.reshape(x, (b, s, -1))
-                                for x in (q, k, v)], axis=-1)
-            mixed = ops.silu(ops.causal_conv1d(mixed, self.conv_weight))
-            q, k, v = ops.split(mixed, [Hk * d, Hk * d, Hv * d], axis=-1)
+            q, k, v, z = ops.gdn_operands(qkvz, self.conv_weight, Hk, Hv)
         with traced_scope("gates"):
             beta = ops.sigmoid(ops.cast(ops.reshape(beta, (b, s, Hv)),
                                         "float32"))
             a = ops.cast(ops.reshape(a, (b, s, Hv)), "float32")
             g = -ops.exp(ops.cast(self.A_log, "float32")) \
                 * ops.softplus(a + self.dt_bias)
-            q = _unit(ops.reshape(q, (b, s, Hk, d)), d ** -0.5)
-            k = _unit(ops.reshape(k, (b, s, Hk, d)), 1.0)
-            q = ops.repeat_interleave(q, rep, axis=2)
-            k = ops.repeat_interleave(k, rep, axis=2)
         with traced_scope("delta_rule"):
-            o = ops.gated_delta_rule(q, k, ops.reshape(v, (b, s, Hv, d)),
-                                     g, beta)
+            o = ops.gated_delta_rule(q, k, v, g, beta, heads_first=True)
         with traced_scope("gated_norm"):
             y = ops.rms_norm(o, self.norm_weight, self.epsilon) \
-                * ops.silu(ops.cast(ops.reshape(z, (b, s, Hv, d)),
-                                    "float32"))
+                * ops.silu(ops.cast(z, "float32"))
             y = ops.reshape(y, (b, s, Hv * d))
         return self.out_proj(y)
-
-
-def _unit(x, scale):
-    """scale * x * rsqrt(sum x^2 + 1e-6) over a head, float32."""
-    x = ops.cast(x, "float32")
-    return x * (ops.rsqrt(ops.sum(x * x, axis=-1, keepdim=True) + 1e-6)
-                * scale)

@@ -295,7 +295,7 @@ BEYOND_YAML = {
         "models.jamba.JambaMambaMixer"),
     "causal_conv1d": (
         "ops/ssm_ops.py", "-",
-        "models.jamba.JambaMambaMixer, nn.GatedDeltaNet"),
+        "models.jamba.JambaMambaMixer; ops.gdn_operands off a TPU"),
     "moe_route": (
         "ops/moe_ops.py", "-",
         "nn.SparseExpertFFN: sigmoid scores (models.laguna), softmax "
@@ -314,5 +314,10 @@ BEYOND_YAML = {
     "gated_delta_rule": (
         "ops/linear_attn_ops.py", "kernels/pallas/gated_delta.py "
         "(gdn_prepare_fwd, gdn_prepare_bwd, gdn_state_fwd, gdn_state_bwd)",
+        "nn.GatedDeltaNet (models.qwen3_next): q and k at the key heads, "
+        "heads first"),
+    "gdn_operands": (
+        "ops/linear_attn_ops.py", "kernels/pallas/gdn_operands.py "
+        "(gdn_operands_fwd, gdn_operands_bwd)",
         "nn.GatedDeltaNet (models.qwen3_next)"),
 }

@@ -169,11 +169,13 @@ PREPARED = [    # tokens a row, heads: the chunks a step holds, and beyond
 def _prepare_operands(s, H, decay, pad=0, **kw):
     """float32 operands of one row of s tokens, the last `pad` of them
     as `gated_delta_rule` pads a row to a chunk: zeros, so beta = 0 and
-    g = 0."""
+    g = 0; heads first and in chunks, as `prepare` and its kernels take
+    them."""
     ins = operands(s, decay, b=1, H=H, **kw)
     keep = (jnp.arange(s) < s - pad).astype(jnp.float32)
-    return tuple((x * keep.reshape((1, s) + (1,) * (x.ndim - 2)))
-                 .astype(jnp.float32) for x in ins)
+    return tuple(gd._heads_first(
+        (x * keep.reshape((1, s) + (1,) * (x.ndim - 2))).astype(jnp.float32))
+        for x in ins)
 
 
 @pytest.mark.parametrize("decay", [0.05, 0.999], ids=["fast", "slow"])

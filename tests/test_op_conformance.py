@@ -467,6 +467,10 @@ class TestRegistryCoverage:
         # token-by-token recurrence in float64, values and all five
         # gradients; the kernels against the scan)
         "gated_delta_rule",
+        # covered by tests/test_gdn_operands.py (against the chain of
+        # causal_conv1d, silu and the unit norms, values and both
+        # gradients; the kernels in the interpreter)
+        "gdn_operands",
     }
 
     def test_coverage_accounting(self):

@@ -357,8 +357,8 @@ def test_a_step_hands_counts_out_with_its_loss_and_notes_its_paths(
     assert last < first
     record = perf.compile_record("train_step")
     assert record["gdn"] == ("heads 4 on 2, state 8 x 8, chunk 64, conv 4 "
-                             "taps, chunk preparation: xla, state pass: "
-                             "lax.scan")
+                             "taps, operands: xla, q k at 2 heads, chunk "
+                             "preparation: xla, state pass: lax.scan")
     assert "experts 8 held of 16, top 4" in record["moe"]
     assert record["moe"].endswith("softmax scores")
     assert record["rope"].startswith("composite")

@@ -12,6 +12,7 @@ times come from `chip_smoke.py` on the chip.
 The tuning sweep is off (it would run kernels) and so is the persistent
 compilation cache (an entry written for a described device cannot be
 read back here and warns)."""
+import functools
 import re
 from importlib import import_module
 
@@ -770,17 +771,20 @@ def test_the_gated_delta_state_kernels_compile(v5e, back):
 
 
 @pytest.mark.parametrize("back", [False, True], ids=["fwd", "bwd"])
-def test_the_gated_delta_preparation_kernels_compile(v5e, back):
+@pytest.mark.parametrize("keys", [Q3_LINEAR, Q3_LINEAR // 2],
+                         ids=["keys32", "keys16"])
+def test_the_gated_delta_preparation_kernels_compile(v5e, back, keys):
     """`gdn_prepare_fwd` and `gdn_prepare_bwd` at the cell's shape: 32
     value heads, 256 chunks of 64 tokens, keys and values of 128, the
-    chunks in pairs."""
+    chunks in pairs; q and k a value head, and at the cell's 16 key
+    heads, where a value head's grid step reads its key head's block."""
     one = SingleDeviceSharding(v5e[0])
     B, nc, C, d = Q3_LINEAR, Q3_SEQ // gdr.CHUNK, gdr.CHUNK, 128
 
     def S(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)
 
-    ins = [S(1, Q3_SEQ, B, d)] * 3 + [S(1, Q3_SEQ, B)] * 2
+    ins = [S(keys, nc, C, d)] * 2 + [S(B, nc, C, d)] + [S(B, nc, C)] * 2
     if back:
         made = S(B, nc, C, d)
         text = _compiled_text(
@@ -790,6 +794,35 @@ def test_the_gated_delta_preparation_kernels_compile(v5e, back):
     else:
         text = _compiled_text(gdr._prepare_fwd_pallas, *ins)
     name = "gdn_prepare_bwd" if back else "gdn_prepare_fwd"
+    assert len(re.findall(rf"%{name}[.\d]* = .*custom-call\(", text)) == 1
+
+
+@pytest.mark.parametrize("back", [False, True], ids=["fwd", "bwd"])
+def test_the_gated_delta_nets_operand_kernels_compile(v5e, back):
+    """`gdn_operands_fwd` and `gdn_operands_bwd` at the cell's shape: the
+    projection's bfloat16 output of 16 key heads x 768 lanes over 16384
+    tokens, four taps; q and k out at 16 heads, v at 32, float32."""
+    ops_k = import_module("paddle_tpu.kernels.pallas.gdn_operands")
+    one = SingleDeviceSharding(v5e[0])
+    Hk, Hv, d = Q3_LINEAR // 2, Q3_LINEAR, 128
+
+    def S(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    qkvz = S((1, Q3_SEQ, (2 * Hk + 2 * Hv) * d), jnp.bfloat16)
+    w = S((Hk, 4, 4 * d))
+    assert ops_k.reject_reason(qkvz.shape, qkvz.dtype, 4, Hk, Hv) is None
+    if back:
+        text = _compiled_text(
+            functools.partial(ops_k.operands_bwd, key_heads=Hk,
+                              value_heads=Hv),
+            qkvz, w, S((Hk, Q3_SEQ, d)), S((Hk, Q3_SEQ, d)),
+            S((Hv, Q3_SEQ, d)))
+    else:
+        text = _compiled_text(
+            functools.partial(ops_k.operands_fwd, key_heads=Hk,
+                              value_heads=Hv), qkvz, w)
+    name = "gdn_operands_bwd" if back else "gdn_operands_fwd"
     assert len(re.findall(rf"%{name}[.\d]* = .*custom-call\(", text)) == 1
 
 
@@ -920,6 +953,8 @@ def qwen3next_step(v5e):
     ("gdn_state_bwd", 1),
     ("gdn_prepare_fwd", 3),     # forward, again, and the backward's own:
     ("gdn_prepare_bwd", 1),     # merged with the second it would hold 1.2 GB
+    ("gdn_operands_fwd", 2),    # the operands: forward, and run again
+    ("gdn_operands_bwd", 1),
     ("flash_fwd", 1),       # the full layer: once, its block keeps o, lse
     ("flash_bwd_transpose", 1),
     ("moe_gmm", 12),        # two products a layer: forward, again, to rows
@@ -931,7 +966,7 @@ def test_the_qwen3next_step_holds_its_mosaic_kernels(qwen3next_step, kernel,
     text, _notes = qwen3next_step
     found = re.findall(rf"%{kernel}[.\d]* = .*custom-call\(", text)
     assert len(found) == calls, (kernel, len(found))
-    assert text.count("tpu_custom_call") == 35
+    assert text.count("tpu_custom_call") == 38
 
 
 def test_the_qwen3next_step_says_which_paths_it_took(qwen3next_step):
@@ -949,7 +984,8 @@ def test_the_qwen3next_step_says_which_paths_it_took(qwen3next_step):
         assert f"{root}/{scope}/" in text, scope
     assert notes == {
         "gdn": "heads 32 on 16, state 128 x 128, chunk 64, conv 4 taps, "
-               "chunk preparation: pallas, state pass: pallas",
+               "operands: pallas, q k at 16 heads, chunk preparation: "
+               "pallas, state pass: pallas",
         "attention": "pallas", "flash_operands": "split",
         "flash_kept": "o and lse kept across recompute in 1 of 2 "
                       "recomputed layers",
