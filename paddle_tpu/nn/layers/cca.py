@@ -17,7 +17,9 @@ class CompressedConvAttention(Layer):
     H query heads on Hk = 2 key/value heads of d, the hidden state is
     projected to H d for the queries, Hk d for the keys and d for each
     of two value heads (one matrix, `qkv_proj`: W_q | W_k | W_v1 | W_v2),
-    and then (`ops.cca_mix`):
+    and then (`ops.cca_mix`: on a TPU one Pallas kernel each way,
+    kernels/pallas/cca_mix.py, which reads the projection's rows and
+    writes q, k and v as rows; `compile_record(...)["cca_mix"]`):
 
       * two causal convolutions of two taps along the sequence over
         q~ | k~: depthwise (`conv_dw_*`: a_0, a_1, b a channel), then

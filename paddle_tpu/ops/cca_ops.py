@@ -8,9 +8,10 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ..observability import perf as _pf
 from .registry import register_op
 
-__all__ = ["cca_mix"]
+__all__ = ["cca_mix", "cca_mix_path"]
 
 F32 = jnp.float32
 
@@ -23,6 +24,20 @@ def _before(x):
 def _unit(x):
     n2 = jnp.sum(jnp.square(x), axis=-1, keepdims=True)
     return x * jax.lax.rsqrt(jnp.maximum(n2, 1e-24))
+
+
+def cca_mix_path(qkv_shape, qkv_dtype, heads, kv_heads):
+    """-> (what mixes the latent in a program traced now, why if not the
+    kernels): `pallas` (`cca_mix_fwd`, `cca_mix_bwd`,
+    kernels/pallas/cca_mix.py) on a TPU backend wherever they take the
+    shape, else `xla` (`_chain`). The CPU tests also give `interpret`,
+    by `gated_delta.prepare_path`."""
+    from ..kernels.pallas import cca_mix as kernels, gated_delta
+    mode = gated_delta.prepare_path()
+    if mode == "xla":
+        return mode, "no TPU backend"
+    why = kernels.reject_reason(qkv_shape, qkv_dtype, heads, kv_heads)
+    return ("xla", why) if why else (mode, None)
 
 
 @register_op("cca_mix", amp_policy="keep")
@@ -47,7 +62,30 @@ def cca_mix(qkv, dw_weight, dw_bias, group_weight, group_bias, temperature,
     [2 d, d] a head, no padded copy for a convolution to slide over.
     The elementwise part, the norms and the temperature are computed in
     float32 whatever qkv's type (amp's black list); the grouped product
-    takes and returns qkv's type."""
+    takes and returns qkv's type.
+
+    On a TPU backend one Pallas kernel each way
+    (kernels/pallas/cca_mix.py): a block of rows is read as the
+    projection wrote it, everything between is float32 in VMEM (z''
+    too), and q, k and v come out as rows. Elsewhere, and for what the
+    kernels refuse, the chain of XLA ops below. Which, and why, is
+    `compile_record(...)["cca_mix"]` (`cca_mix_path`)."""
+    from ..kernels.pallas import cca_mix as kernels
+    path, why = cca_mix_path(qkv.shape, qkv.dtype, heads, kv_heads)
+    _pf.trace_note("cca_mix", f"{path}: " + (
+        why or f"cca_mix_fwd, cca_mix_bwd, rows of "
+        f"{kernels.rows_a_block(qkv.shape[1])}"))
+    params = (dw_weight, dw_bias, group_weight, group_bias, temperature)
+    if path == "xla":
+        return _chain(qkv, *params, heads, kv_heads)
+    return kernels.mix(qkv, *params, heads, kv_heads, path == "interpret")
+
+
+def _chain(qkv, dw_weight, dw_bias, group_weight, group_bias, temperature,
+           heads, kv_heads):
+    """`cca_mix` as XLA's: two multiply-adds, one grouped product, the
+    mean, the norms and the shift, each a pass or two over
+    [b, s, (H + Hk) d]."""
     b, s, width = qkv.shape
     H, Hk = heads, kv_heads
     if Hk != 2:

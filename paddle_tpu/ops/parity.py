@@ -306,8 +306,9 @@ BEYOND_YAML = {
         "ops/moe_ops.py", "kernels/pallas/grouped_matmul.py (moe_gmm, "
         "moe_gmm_dw), kernels/pallas/moe_sum_rows.py",
         "nn.SparseExpertFFN"),
-    "cca_mix": ("ops/cca_ops.py", "-",
-                "nn.CompressedConvAttention (models.zaya)"),
+    "cca_mix": (
+        "ops/cca_ops.py", "kernels/pallas/cca_mix.py (cca_mix_fwd, "
+        "cca_mix_bwd)", "nn.CompressedConvAttention (models.zaya)"),
     "rope_rotate_half": (
         "ops/rope_ops.py", "kernels/pallas/rope.py (rope_rotate)",
         "models.laguna, models.zaya, models.qwen3_next"),

@@ -640,6 +640,41 @@ def test_rope_rotate_compiles_at_zayas_shape(v5e, heads, back):
                           text)) == 1
 
 
+@pytest.mark.parametrize("back", [False, True], ids=["fwd", "bwd"])
+def test_the_latents_mixing_kernels_compile_at_zayas_shape(v5e, back):
+    """`cca_mix_fwd` and `cca_mix_bwd` at the cell's shape: `qkv_proj`'s
+    bfloat16 output of 8 + 2 + 2 heads of 128 over 32768 tokens in
+    blocks of 512 rows; q, k and v out as rows, the gradient of qkv in
+    one array and the parameters' sums beside it. The heads' lanes are
+    dynamic slices of a block, which the interpreter cannot refuse."""
+    mix = import_module("paddle_tpu.kernels.pallas.cca_mix")
+    one = SingleDeviceSharding(v5e[0])
+    H, Hk, d = ZAYA_HEADS, ZAYA_KV, 128
+    n = (H + Hk) * d
+
+    def S(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def rows(heads):
+        return S((1, ZAYA_SEQ, heads * d), jnp.bfloat16)
+
+    operands = (rows(H + 2 * Hk), S((4, n)),
+                S((H + Hk, 2 * d, d), jnp.bfloat16), S((1, Hk * d)))
+    assert mix.reject_reason(operands[0].shape, operands[0].dtype, H,
+                             Hk) is None
+    assert mix.rows_a_block(ZAYA_SEQ) == 512
+    if back:
+        text = _compiled_text(
+            functools.partial(mix.mix_bwd, heads=H, kv_heads=Hk),
+            *operands, rows(H), rows(Hk), rows(Hk))
+    else:
+        text = _compiled_text(
+            functools.partial(mix.mix_fwd, heads=H, kv_heads=Hk), *operands)
+    name = "cca_mix_bwd" if back else "cca_mix_fwd"
+    assert len(re.findall(rf"%{name}[.\d]* = .*custom-call\(", text)) == 1
+    assert text.count("tpu_custom_call") == 1
+
+
 @pytest.fixture(scope="module")
 def zaya_step(v5e):
     """Two layers at ZAYA1-8B's widths (2 of its 16 experts held, a
@@ -705,26 +740,34 @@ def zaya_step(v5e):
                             # residual's alpha_o needs y), take_rows back
     ("flash_fwd", 2),       # a layer: once, its block keeps o and lse
     ("flash_bwd_transpose", 2),
-    ("rope_rotate", 12)])   # a layer's q, its k: forward, again, back
+    ("rope_rotate", 12),    # a layer's q, its k: forward, again, back
+    ("cca_mix_fwd", 4),     # a layer: forward and again
+    ("cca_mix_bwd", 2)])
 def test_the_zaya_step_holds_its_mosaic_kernels(zaya_step, kernel, calls):
     text, _notes = zaya_step
     found = re.findall(rf"%{kernel}[.\d]* = .*custom-call\(", text)
     assert len(found) == calls, (kernel, len(found))
-    assert text.count("tpu_custom_call") == 38
+    assert text.count("tpu_custom_call") == 44      # 38 before `cca_mix`'s
 
 
 def test_the_zaya_step_says_which_paths_it_took(zaya_step):
-    """The latent and its taps; the flash kernels on three arrays (the
-    convolutions stand between the projection and them), walking to the
+    """The latent and its taps, mixed by `cca_mix`'s kernels with no
+    copy between them and the rotary's; the flash kernels on three arrays
+    (the convolutions stand between the projection and them), walking to the
     diagonal; the expert products on the kernels with their weight
     blocks in column tiles; the head in one chunk at this small size."""
     text, notes = zaya_step
     for scope in ("attn_res/res_scale", "moe_res/res_scale", "attn/cca_mix",
                   "moe/router"):
         assert f"zaya/layers/1/{scope}/" in text, scope
+    # q and k reach the rotary as rows: the einsum wrote them head-major
+    # and a copy stood before every `rope_rotate`
+    assert not [line for line in text.splitlines()
+                if "/attn/rope/" in line and " copy(" in line]
     assert notes == {
         "cca": "latent 1024 q, 256 k, 256 v of 2048, 8 heads on 2, taps 2 "
                "depthwise and 2 grouped, value shift on head 1",
+        "cca_mix": "pallas: cca_mix_fwd, cca_mix_bwd, rows of 512",
         "attention": "pallas", "flash_operands": "split",
         "flash_kept": "o and lse kept across recompute in 2 of 2 "
                       "recomputed layers",
