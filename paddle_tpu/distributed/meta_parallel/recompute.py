@@ -27,6 +27,8 @@ first pass's backward.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 
 from ...core.tensor import Tensor
@@ -106,6 +108,25 @@ def note_flash_kept(policies):
         perf.trace_note(
             "flash_kept", f"o and lse kept across recompute in {kept} of "
             f"{len(policies)} recomputed layers")
+
+
+def layer_calls(layers, remat, interval=1):
+    """A decoder stack's walk over its layers: for each of `layers` in
+    turn, what the model calls with the layer's arguments. That is the
+    layer itself, or, with `remat`, for every `interval`-th layer from
+    the first, `recompute` of it under `flash_policy` of its `attn` (a
+    layer without one: no policy). What a layer takes and hands on is
+    the model's business: `for call in layer_calls(...): x = call(x)`.
+    Once the walk is through, `note_flash_kept` says how many of the
+    recomputed layers keep their flash outputs."""
+    kept = []
+    for i, layer in enumerate(layers):
+        if remat and i % interval == 0:
+            kept.append(flash_policy(getattr(layer, "attn", None)))
+            yield functools.partial(recompute, layer, policy=kept[-1])
+        else:
+            yield layer
+    note_flash_kept(kept)
 
 
 def recompute(function, *args, use_reentrant=True, preserve_rng_state=True,

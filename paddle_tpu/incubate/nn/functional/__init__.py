@@ -11,8 +11,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .... import ops
+from ....core.tensor import Tensor
+from ....observability import perf
 from ....ops.registry import register_op
 from ....kernels import pallas as pk
+from ....kernels.pallas.flash_attention import attention_path
 
 
 @register_op("fused_rms_norm", amp_policy="black")
@@ -96,9 +100,39 @@ def fused_flash_attention(query, key, value, attn_mask=None, causal=False,
                               segment_ids=segment_ids, window=window)
 
 
+def causal_attention(q, k, v, use_flash, window=None):
+    """Causal softmax attention of q [batch, seq, H, dim] on k and v of
+    Hk heads (query head h reads key/value head h // (H // Hk)), a row
+    seeing every key up to its own or, with a `window`, its own and the
+    window - 1 before it: where a decoder family's attention layer
+    reaches a kernel, on tensors. `use_flash`: `fused_flash_attention`,
+    which takes grouped heads as they are; else k and v repeated to the
+    query heads and `ops.scaled_dot_product_attention`.
+    `compile_record(...)["attention"]` says which of `pallas`, `xla` and
+    `composite` a traced program took."""
+    if use_flash:
+        perf.trace_note("attention", attention_path(q.shape, k.shape)[0])
+        return fused_flash_attention(q, k, v, causal=True, window=window)
+    perf.trace_note("attention", "composite")
+    rep = q.shape[2] // k.shape[2]
+    if rep > 1:
+        k = ops.repeat_interleave(k, rep, axis=2)
+        v = ops.repeat_interleave(v, rep, axis=2)
+    if window is None:
+        return ops.scaled_dot_product_attention(q, k, v, is_causal=True)
+    return ops.scaled_dot_product_attention(
+        q, k, v, attn_mask=_window_mask(q.shape[1], window))
+
+
+def _window_mask(seq, window):
+    """[seq, seq] bool: row i sees keys max(i - window + 1, 0) .. i."""
+    gap = np.arange(seq)[:, None] - np.arange(seq)[None, :]
+    return Tensor._wrap(jnp.asarray((gap >= 0) & (gap < window)),
+                        stop_gradient=True)
+
+
 def _warn_if_composite(q_shape, k_shape):
     if jax.default_backend() == "tpu":
-        from ....kernels.pallas.flash_attention import attention_path
         path, why = attention_path(q_shape, k_shape)
         if path == "xla":
             import warnings

@@ -22,6 +22,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..core.mesh_plan import mesh_plan
 from ..core.tensor import Tensor
 from ..core.generator import rng_scope, next_key
 from ..nn.layer import Layer, _TRACING
@@ -422,7 +423,6 @@ class TrainStep:
         # the batch dimension is sharded over
         self._kernel_plan = contextlib.nullcontext
         if self.mesh is not None:
-            from ..kernels.pallas.flash_attention import mesh_plan
             lead = (self._data_sharding.spec[0]
                     if self._data_sharding is not None
                     and len(self._data_sharding.spec) else None)

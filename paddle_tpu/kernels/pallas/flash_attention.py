@@ -59,8 +59,6 @@ projection takes `flash_attention_qkv`, which has none.
 """
 from __future__ import annotations
 
-import contextlib
-import contextvars
 import functools
 import math
 
@@ -70,6 +68,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from ...core.mesh_plan import current_mesh_plan
 from ...observability import perf as _pf
 
 _NEG_INF = -1e30
@@ -457,8 +456,6 @@ def _autotuned_blocks(kind, shape, H, Hk, causal, has_seg, defaults,
     # batch size is deliberately NOT in the key: blocks are per-tile
     # choices and b only multiplies the grid — keying on it would stall
     # a variable-batch serving workload with a fresh search per b
-    # (the backward gets H/Hk back from custom_vjp residuals as typed
-    # scalars: plain ints keep one spelling of the key)
     key = (kind, sq, sk, int(H), int(Hk), int(D), dtype,
            int(causal), int(has_seg))
     hit = autotune.lookup(key)
@@ -1155,84 +1152,67 @@ def _kept(o, lse):
     return checkpoint_name(o, FLASH_O), checkpoint_name(lse, FLASH_LSE)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash_core(q, k, v, segment_ids, causal, sm_scale, use_pallas,
-                window=None):
-    """[b, s, h, d] in/out; k, v may carry fewer (kv) heads (GQA/MQA).
-    segment_ids: None or (q_seg [b,sq], kv_seg [b,sk]) int32. On the
-    Pallas path the kernels read q, k, v as [b, s, h*d] (three arrays,
-    column block 0 each), scale q themselves and return dq scaled."""
-    out, _ = _flash_core_fwd(q, k, v, segment_ids, causal, sm_scale,
-                             use_pallas, window)
-    return out
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
+def _flash_core(operands, segment_ids, causal, sm_scale, use_pallas,
+                window=None, heads=None):
+    """`operands`: (q, k, v), each [b, s, h, d], k and v maybe on fewer
+    (kv) heads (GQA/MQA), and [b, s, h, d] out; or (qkv,), one fused
+    projection [b, s, 3*h*d] of `heads` heads (the Pallas path only),
+    [b, s, h*d] out, whose gradient leaves as the one [b, s, 3*h*d]
+    array the projection's backward reads. segment_ids: None or (q_seg
+    [b, sq], kv_seg [b, sk]) int32. On the Pallas path the kernels read
+    q, k and v as [b, s, h*d] rows (three arrays at column block 0 each,
+    or the projection's column blocks 0, 1, 2), scale q themselves and
+    return dq scaled."""
+    return _flash_core_fwd(operands, segment_ids, causal, sm_scale,
+                           use_pallas, window, heads)[0]
 
 
-def _flash_core_fwd(q, k, v, segment_ids, causal, sm_scale, use_pallas,
-                    window=None):
-    if use_pallas:
-        _pf.trace_note("flash_operands", "split")
-        b, s, h, d = q.shape
-        hk = k.shape[2]
-        qm = q.reshape(b, s, h * d)
-        km = k.reshape(b, -1, hk * d)
-        vm = v.reshape(b, -1, hk * d)
-        o, lse = _kept(*_flash_fwd_fused(qm, km, vm, h, causal, Hk=hk,
-                                         segment_ids=segment_ids,
-                                         sm_scale=sm_scale, window=window))
-        return o.reshape(b, s, h, d), (qm, km, vm, o, lse, h, hk,
-                                       segment_ids)
-    out = _xla_attention(q, k, v, None, causal, sm_scale,
-                         segment_ids=segment_ids, window=window)
-    return out, (q, k, v, None, None, None, None, segment_ids)
+def _flash_core_fwd(operands, segment_ids, causal, sm_scale, use_pallas,
+                    window=None, heads=None):
+    if not use_pallas:
+        out = _xla_attention(*operands, None, causal, sm_scale,
+                             segment_ids=segment_ids, window=window)
+        return out, (operands, None, None, segment_ids)
+    if len(operands) == 1:
+        rows, cols, h, hk = operands, _QKV, heads, heads
+        d = rows[0].shape[2] // (3 * h)
+    else:
+        (b, _, h, d), hk = operands[0].shape, operands[1].shape[2]
+        rows, cols = tuple(x.reshape(b, x.shape[1], -1)
+                           for x in operands), (0, 0, 0)
+    # one array stands for q, k and v: the kernels take it three times
+    o, lse = _kept(*_flash_fwd_fused(
+        *rows * (3 // len(rows)), h, causal, Hk=hk, segment_ids=segment_ids,
+        sm_scale=sm_scale, cols=cols, D=d, window=window))
+    out = o if len(rows) == 1 else o.reshape(operands[0].shape)
+    return out, (rows, o, lse, segment_ids)
 
 
-def _flash_core_bwd(causal, sm_scale, use_pallas, window, res, g):
-    q, k, v, o, lse, h, hk, segment_ids = res
-    if use_pallas:
-        b, s, hd = q.shape
-        dq, dk, dv = _flash_bwd_fused(q, k, v, o, lse, g.reshape(b, s, hd),
-                                      h, causal, Hk=hk,
+def _flash_core_bwd(causal, sm_scale, use_pallas, window, heads, res, g):
+    rows, o, lse, segment_ids = res
+    if not use_pallas:
+        _, vjp = jax.vjp(
+            lambda *x: _xla_attention(*x, None, causal, sm_scale,
                                       segment_ids=segment_ids,
-                                      sm_scale=sm_scale, window=window)
-        d = hd // h
-        return (dq.reshape(b, s, h, d), dk.reshape(b, -1, hk, d),
-                dv.reshape(b, -1, hk, d), None)
-    _, vjp = jax.vjp(
-        lambda q_, k_, v_: _xla_attention(q_, k_, v_, None, causal, sm_scale,
-                                          segment_ids=segment_ids,
-                                          window=window),
-        q, k, v)
-    return vjp(g) + (None,)
+                                      window=window), *rows)
+        return vjp(g), None
+    if len(rows) == 1:
+        cols, h, hk, d = _QKV, heads, heads, g.shape[2] // heads
+    else:
+        cols, (h, d) = (0, 0, 0), g.shape[2:]
+        hk = rows[1].shape[2] // d
+    grads = _flash_bwd_fused(
+        *rows * (3 // len(rows)), o, lse, g.reshape(o.shape), h, causal,
+        Hk=hk, segment_ids=segment_ids, sm_scale=sm_scale, cols=cols, D=d,
+        window=window)
+    if len(rows) == 1:
+        return (grads,), None       # the one [b, s, 3*h*d] array
+    return tuple(x.reshape(*x.shape[:2], n, d)
+                 for x, n in zip(grads, (h, hk, hk))), None
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
-def _flash_core_qkv(qkv, segment_ids, h, causal, sm_scale):
-    """qkv [b, s, 3*h*d] in, o [b, s, h*d] out, the Pallas path only:
-    the kernels read q, k and v where the projection wrote them, and the
-    gradient leaves as the [b, s, 3*h*d] its backward reads."""
-    out, _ = _flash_core_qkv_fwd(qkv, segment_ids, h, causal, sm_scale)
-    return out
-
-
-def _flash_core_qkv_fwd(qkv, segment_ids, h, causal, sm_scale):
-    o, lse = _kept(*_flash_fwd_fused(
-        qkv, qkv, qkv, h, causal, segment_ids=segment_ids,
-        sm_scale=sm_scale, cols=_QKV, D=qkv.shape[2] // (3 * h)))
-    return o, (qkv, o, lse, segment_ids)
-
-
-def _flash_core_qkv_bwd(h, causal, sm_scale, res, g):
-    qkv, o, lse, segment_ids = res
-    dqkv = _flash_bwd_fused(qkv, qkv, qkv, o, lse, g, h, causal,
-                            segment_ids=segment_ids, sm_scale=sm_scale,
-                            cols=_QKV, D=qkv.shape[2] // (3 * h))
-    return dqkv, None
-
-
-_flash_core_qkv.defvjp(_flash_core_qkv_fwd, _flash_core_qkv_bwd)
 
 
 def _shapes_ok(q_shape, k_shape):
@@ -1272,29 +1252,6 @@ def attention_path(q_shape, k_shape, masked=False):
     if reason:
         return ("xla", reason)
     return ("pallas", "")
-
-
-# (mesh, batch_axes) while a program that GSPMD will partition over
-# `mesh` is being traced; None otherwise
-_MESH_PLAN: contextvars.ContextVar = contextvars.ContextVar(
-    "flash_mesh_plan", default=None)
-
-
-@contextlib.contextmanager
-def mesh_plan(mesh, batch_axes=()):
-    """Tell the kernels traced inside this block that the program will
-    be partitioned over `mesh`, with the batch dimension of its data
-    split over `batch_axes`. The compiler cannot partition a Mosaic
-    kernel by itself, so under a plan `flash_attention` splits its call
-    with `shard_map`: batch over `batch_axes`, heads over the mesh's
-    other axes (the Megatron layout) where the per-device head count
-    still fits the kernel, whole on every device of an axis where it
-    does not."""
-    token = _MESH_PLAN.set((mesh, tuple(batch_axes)))
-    try:
-        yield
-    finally:
-        _MESH_PLAN.reset(token)
 
 
 def _planned_specs(plan, q_shape, k_shape):
@@ -1348,23 +1305,23 @@ def flash_attention(q, k, v, attn_mask=None, causal=False,
     if attn_mask is not None:
         return _xla_attention(q, k, v, attn_mask, causal, sm_scale,
                               segment_ids=segment_ids, window=window)
-    use_pallas = _pallas_available() and _shapes_ok(q.shape, k.shape)
+    use_pallas = bool(_pallas_available() and _shapes_ok(q.shape, k.shape))
     segment_ids = _int32_pair(segment_ids)
-    plan = _MESH_PLAN.get()
+    window = window if window is None else int(window)
+    if use_pallas:
+        _pf.trace_note("flash_operands", "split")
+    plan = current_mesh_plan()
     if plan is not None and use_pallas:
         spec, seg_spec = _planned_specs(plan, q.shape, k.shape)
         return jax.shard_map(
-            lambda q, k, v, seg: _flash_core(q, k, v, seg, causal,
+            lambda q, k, v, seg: _flash_core((q, k, v), seg, causal,
                                              sm_scale, True, window),
             mesh=plan[0],
             in_specs=(spec, spec, spec,
                       None if segment_ids is None else (seg_spec,) * 2),
             out_specs=spec, check_vma=False)(q, k, v, segment_ids)
-    if window is None:
-        return _flash_core(q, k, v, segment_ids, causal, sm_scale,
-                           bool(use_pallas))
-    return _flash_core(q, k, v, segment_ids, causal, sm_scale,
-                       bool(use_pallas), int(window))
+    return _flash_core((q, k, v), segment_ids, causal, sm_scale, use_pallas,
+                       window)
 
 
 def flash_attention_qkv(qkv, num_heads, causal=False, softmax_scale=None,
@@ -1382,10 +1339,11 @@ def flash_attention_qkv(qkv, num_heads, causal=False, softmax_scale=None,
     hd = w // 3
     shape = (b, s, num_heads, hd // num_heads)
     if (attention_path(shape, shape)[0] == "pallas"
-            and _MESH_PLAN.get() is None):
+            and current_mesh_plan() is None):
         _pf.trace_note("flash_operands", "qkv in place")
-        return _flash_core_qkv(qkv, _int32_pair(segment_ids), num_heads,
-                               causal, _scale(softmax_scale, shape[3]))
+        return _flash_core((qkv,), _int32_pair(segment_ids), causal,
+                           _scale(softmax_scale, shape[3]), True, None,
+                           num_heads)
     q, k, v = (qkv[:, :, i * hd:(i + 1) * hd].reshape(shape)
                for i in range(3))
     return flash_attention(q, k, v, causal=causal,

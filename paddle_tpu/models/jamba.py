@@ -30,6 +30,7 @@ from __future__ import annotations
 from dataclasses import dataclass, fields
 
 from .. import ops
+from ..incubate.nn.functional import causal_attention
 from ..nn.initializer import Constant, Normal
 from ..nn.layer import Layer
 from ..nn.layers.common import Embedding, Linear
@@ -200,19 +201,7 @@ class JambaAttention(Layer):
         q = ops.reshape(self.q_proj(u), (b, s, self.heads, d))
         k = ops.reshape(self.k_proj(u), (b, s, self.kv_heads, d))
         v = ops.reshape(self.v_proj(u), (b, s, self.kv_heads, d))
-        if self.use_flash_attention:
-            from ..incubate.nn.functional import fused_flash_attention
-            from ..kernels.pallas.flash_attention import attention_path
-            perf.trace_note("attention",
-                            attention_path(q.shape, k.shape)[0])
-            out = fused_flash_attention(q, k, v, causal=True)
-        else:
-            perf.trace_note("attention", "composite")
-            if self.kv_heads != self.heads:
-                rep = self.heads // self.kv_heads
-                k = ops.repeat_interleave(k, rep, axis=2)
-                v = ops.repeat_interleave(v, rep, axis=2)
-            out = ops.scaled_dot_product_attention(q, k, v, is_causal=True)
+        out = causal_attention(q, k, v, self.use_flash_attention)
         return self.o_proj(ops.reshape(out, (b, s, self.heads * d)))
 
 
@@ -267,17 +256,10 @@ class JambaModel(Layer):
     def forward(self, input_ids):
         x = self.embed_tokens(input_ids)
         cfg = self.config
-        remat = cfg.recompute and self.training
-        from ..distributed.meta_parallel.recompute import (
-            flash_policy, note_flash_kept, recompute)
-        kept = []
-        for i, layer in enumerate(self.layers):
-            if remat and i % cfg.recompute_interval == 0:
-                kept.append(flash_policy(getattr(layer, "attn", None)))
-                x = recompute(layer, x, policy=kept[-1])
-            else:
-                x = layer(x)
-        note_flash_kept(kept)
+        from ..distributed.meta_parallel.recompute import layer_calls
+        for call in layer_calls(self.layers, cfg.recompute and self.training,
+                                cfg.recompute_interval):
+            x = call(x)
         return self.final_layernorm(x)
 
 

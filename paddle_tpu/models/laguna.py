@@ -38,13 +38,15 @@ from __future__ import annotations
 from dataclasses import dataclass, field, fields
 
 from .. import ops
+from ..incubate.nn.functional import causal_attention
 from ..nn.initializer import Normal
 from ..nn.layer import Layer, traced_scope
 from ..nn.layers.common import Embedding, Linear
 from ..nn.layers.container import LayerList
 from ..nn.layers.moe import (SparseExpertFFN, SwiGLU,  # noqa: F401
-                             observe_expert_load, rope_tables)
+                             observe_expert_load)
 from ..nn.layers.norm import RMSNorm
+from ..nn.layers.rope import rope_tables
 from ..observability import perf
 from . import lm_head as _lm_head
 
@@ -195,33 +197,9 @@ class LagunaAttention(Layer):
         if self.window is not None:
             perf.trace_note("attention_window",
                             f"layer {self.index}: {self.window}")
-        if self.use_flash_attention:
-            from ..incubate.nn.functional import fused_flash_attention
-            from ..kernels.pallas.flash_attention import attention_path
-            perf.trace_note("attention",
-                            attention_path(q.shape, k.shape)[0])
-            out = fused_flash_attention(q, k, v, causal=True,
-                                        window=self.window)
-        else:
-            perf.trace_note("attention", "composite")
-            rep = self.heads // self.kv_heads
-            k = ops.repeat_interleave(k, rep, axis=2)
-            v = ops.repeat_interleave(v, rep, axis=2)
-            out = ops.scaled_dot_product_attention(
-                q, k, v, attn_mask=_window_mask(s, self.window))
+        out = causal_attention(q, k, v, self.use_flash_attention,
+                               self.window)
         return self.o_proj(ops.reshape(out, (b, s, self.heads * d)))
-
-
-def _window_mask(seq, window):
-    """[seq, seq] bool: row i sees keys max(i - window + 1, 0) .. i."""
-    import jax.numpy as jnp
-    import numpy as np
-    from ..core.tensor import Tensor
-    i = np.arange(seq)
-    ok = i[:, None] >= i[None, :]
-    if window is not None:
-        ok &= i[:, None] - i[None, :] < window
-    return Tensor._wrap(jnp.asarray(ok), stop_gradient=True)
 
 
 class LagunaDecoderLayer(Layer):
@@ -279,21 +257,16 @@ class LagunaModel(Layer):
         tables = {kind: rope_tables(seq, cfg.head_dim, **params)
                   for kind, params in cfg.rope_parameters.items()
                   if isinstance(params, dict) and kind in cfg.layer_types}
-        remat = cfg.recompute and self.training
-        from ..distributed.meta_parallel.recompute import (
-            flash_policy, note_flash_kept, recompute)
-        counts, kept = [], []
-        for i, layer in enumerate(self.layers):
-            cos, sin = tables[cfg.layer_types[i]]
-            if remat and i % cfg.recompute_interval == 0:
-                # a full layer keeps its flash outputs, a window layer not
-                kept.append(flash_policy(layer.attn))
-                x, c = recompute(layer, x, cos, sin, policy=kept[-1])
-            else:
-                x, c = layer(x, cos, sin)
+        from ..distributed.meta_parallel.recompute import layer_calls
+        counts = []
+        # recomputed, a full layer keeps its flash outputs, a window
+        # layer not
+        for i, call in enumerate(layer_calls(
+                self.layers, cfg.recompute and self.training,
+                cfg.recompute_interval)):
+            x, c = call(x, *tables[cfg.layer_types[i]])
             if c is not None:
                 counts.append(c)
-        note_flash_kept(kept)
         return self.norm(x), counts
 
 

@@ -7,13 +7,14 @@ fused_rotary_position_embedding / swiglu
 """
 from __future__ import annotations
 
-import math
 from dataclasses import dataclass
 
 import jax.numpy as jnp
 
 from .. import ops
 from ..core.tensor import Tensor
+from ..incubate.nn.functional import (causal_attention,
+                                      fused_rotary_position_embedding)
 from ..nn.layer import Layer
 from ..nn.layers.common import Linear, Embedding
 from ..nn.layers.norm import RMSNorm
@@ -99,20 +100,9 @@ class LlamaAttention(Layer):
             rope_cos_sin = _rope_cos_sin(s, self.head_dim, self.rope_theta,
                                          q._data.dtype)
         cos, sin = rope_cos_sin
-        from ..incubate.nn.functional import fused_rotary_position_embedding
         q, k = fused_rotary_position_embedding(
             q, k, sin=Tensor(sin), cos=Tensor(cos))
-        if self.use_flash_attention:
-            # GQA stays native: the Pallas kernel maps q-head h to kv-head
-            # h // (H//Hk) in-kernel — no repeat_interleave materialization
-            from ..incubate.nn.functional import fused_flash_attention
-            out = fused_flash_attention(q, k, v, causal=True)
-        else:
-            if self.num_kv_heads != self.num_heads:
-                rep = self.num_heads // self.num_kv_heads
-                k = ops.repeat_interleave(k, rep, axis=2)
-                v = ops.repeat_interleave(v, rep, axis=2)
-            out = ops.scaled_dot_product_attention(q, k, v, is_causal=True)
+        out = causal_attention(q, k, v, self.use_flash_attention)
         out = ops.reshape(out, (b, s, self.hidden_size))
         return self.o_proj(out)
 

@@ -10,6 +10,7 @@ from .. import ops
 from ..amp import auto_cast
 from ..amp.state import maybe_cast_inputs
 from ..autograd import tape
+from ..core.mesh_plan import current_mesh_plan
 from ..core.tensor import DeferredTensor, Tensor
 from ..nn.layer import traced_scope, _TRACING
 from ..observability import perf
@@ -53,8 +54,7 @@ def deferred_logits(training, hidden, tied_weight, head=None):
             and (head is None or not (head._forward_pre_hooks
                                       or head._forward_post_hooks))):
         return None
-    from ..kernels.pallas.flash_attention import _MESH_PLAN
-    if _MESH_PLAN.get() is not None:    # TrainStep's, under a mesh
+    if current_mesh_plan() is not None:     # TrainStep's, under a mesh
         perf.trace_note("head_loss", "whole")
         return None
     w = tied_weight if head is None else head.weight

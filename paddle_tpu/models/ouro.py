@@ -39,12 +39,14 @@ from dataclasses import dataclass, fields
 from .. import ops
 from ..amp import auto_cast
 from ..core.tensor import DeferredTensor
+from ..incubate.nn.functional import causal_attention
 from ..nn.initializer import Constant, Normal
 from ..nn.layer import Layer, traced_scope
 from ..nn.layers.common import Embedding, Linear
 from ..nn.layers.container import LayerList
-from ..nn.layers.moe import SwiGLU, rope_tables
+from ..nn.layers.moe import SwiGLU
 from ..nn.layers.norm import RMSNorm
+from ..nn.layers.rope import rope_tables
 from ..observability import perf
 from . import lm_head as _lm_head
 
@@ -158,17 +160,7 @@ class OuroAttention(Layer):
         with traced_scope("rope"):
             q = ops.rope_rotate_half(q, cos, sin)
             k = ops.rope_rotate_half(k, cos, sin)
-        if self.use_flash_attention:
-            from ..incubate.nn.functional import fused_flash_attention
-            from ..kernels.pallas.flash_attention import attention_path
-            perf.trace_note("attention",
-                            attention_path(q.shape, k.shape)[0])
-            out = fused_flash_attention(q, k, v, causal=True)
-        else:
-            perf.trace_note("attention", "composite")
-            k = ops.repeat_interleave(k, H // Hk, axis=2)
-            v = ops.repeat_interleave(v, H // Hk, axis=2)
-            out = ops.scaled_dot_product_attention(q, k, v, is_causal=True)
+        out = causal_attention(q, k, v, self.use_flash_attention)
         return self.o_proj(ops.reshape(out, (b, s, H * d)))
 
 
@@ -215,15 +207,9 @@ class OuroModel(Layer):
         cfg = self.config
         remat = cfg.recompute and self.training
         from ..distributed.meta_parallel.recompute import (
-            flash_policy, note_flash_kept, recompute)
-        kept = []
-        for i, layer in enumerate(self.layers):
-            if remat and i % cfg.recompute_interval == 0:
-                kept.append(flash_policy(layer.attn))
-                x = recompute(layer, x, cos, sin, policy=kept[-1])
-            else:
-                x = layer(x, cos, sin)
-        note_flash_kept(kept)
+            layer_calls, recompute)
+        for call in layer_calls(self.layers, remat, cfg.recompute_interval):
+            x = call(x, cos, sin)
         if remat:
             # the norm's input alone is kept a pass, not its statistics
             # and its normalised stream as well

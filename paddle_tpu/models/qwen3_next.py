@@ -40,13 +40,14 @@ from __future__ import annotations
 from dataclasses import dataclass, fields
 
 from .. import ops
+from ..incubate.nn.functional import causal_attention
 from ..nn.initializer import Normal
 from ..nn.layer import Layer, traced_scope
 from ..nn.layers.common import Embedding, Linear
 from ..nn.layers.container import LayerList
 from ..nn.layers.gdn import GatedDeltaNet, ZeroCenteredRMSNorm
-from ..nn.layers.moe import SparseExpertFFN, rope_tables
-from ..observability import perf
+from ..nn.layers.moe import SparseExpertFFN
+from ..nn.layers.rope import rope_tables
 from . import lm_head as _lm_head
 
 
@@ -184,17 +185,7 @@ class Qwen3NextAttention(Layer):
         with traced_scope("rope"):
             q = ops.rope_rotate_half(q, cos, sin)
             k = ops.rope_rotate_half(k, cos, sin)
-        if self.use_flash_attention:
-            from ..incubate.nn.functional import fused_flash_attention
-            from ..kernels.pallas.flash_attention import attention_path
-            perf.trace_note("attention",
-                            attention_path(q.shape, k.shape)[0])
-            out = fused_flash_attention(q, k, v, causal=True)
-        else:
-            perf.trace_note("attention", "composite")
-            k = ops.repeat_interleave(k, H // Hk, axis=2)
-            v = ops.repeat_interleave(v, H // Hk, axis=2)
-            out = ops.scaled_dot_product_attention(q, k, v, is_causal=True)
+        out = causal_attention(q, k, v, self.use_flash_attention)
         with traced_scope("out_gate"):
             out = ops.reshape(out * ops.sigmoid(gate), (b, s, H * d))
         return self.o_proj(out)
@@ -255,20 +246,14 @@ class Qwen3NextModel(Layer):
         cos, sin = rope_tables(
             input_ids.shape[1], cfg.head_dim, rope_theta=cfg.rope_theta,
             partial_rotary_factor=cfg.partial_rotary_factor)
-        remat = cfg.recompute and self.training
-        from ..distributed.meta_parallel.recompute import (
-            flash_policy, note_flash_kept, recompute)
-        counts, kept = [], []
-        for i, layer in enumerate(self.layers):
-            if remat and i % cfg.recompute_interval == 0:
-                # a full layer keeps its flash outputs; a linear one has
-                # none, and runs its state pass again
-                kept.append(flash_policy(getattr(layer, "attn", None)))
-                x, c = recompute(layer, x, cos, sin, policy=kept[-1])
-            else:
-                x, c = layer(x, cos, sin)
+        from ..distributed.meta_parallel.recompute import layer_calls
+        counts = []
+        # recomputed, a full layer keeps its flash outputs; a linear one
+        # has none, and runs its state pass again
+        for call in layer_calls(self.layers, cfg.recompute and self.training,
+                                cfg.recompute_interval):
+            x, c = call(x, cos, sin)
             counts.append(c)
-        note_flash_kept(kept)
         return self.norm(x), counts
 
 

@@ -45,8 +45,9 @@ from ..nn.layer import Layer
 from ..nn.layers.cca import CompressedConvAttention, ResidualScale
 from ..nn.layers.common import Embedding
 from ..nn.layers.container import LayerList
-from ..nn.layers.moe import SparseExpertFFN, rope_tables
+from ..nn.layers.moe import SparseExpertFFN
 from ..nn.layers.norm import RMSNorm
+from ..nn.layers.rope import rope_tables
 from . import lm_head as _lm_head
 
 
@@ -196,19 +197,12 @@ class ZayaModel(Layer):
                                **cfg.rope_parameters["hybrid"])
         # the second stream: the router's state, zero before layer 0
         r = ops.zeros([b, seq, cfg.router_hidden_size], "float32")
-        remat = cfg.recompute and self.training
-        from ..distributed.meta_parallel.recompute import (
-            flash_policy, note_flash_kept, recompute)
-        told, kept = [], []
-        for i, layer in enumerate(self.layers):
-            if remat and i % cfg.recompute_interval == 0:
-                kept.append(flash_policy(layer.attn))
-                x, r, *tell = recompute(layer, x, r, cos, sin,
-                                        policy=kept[-1])
-            else:
-                x, r, *tell = layer(x, r, cos, sin)
+        from ..distributed.meta_parallel.recompute import layer_calls
+        told = []
+        for call in layer_calls(self.layers, cfg.recompute and self.training,
+                                cfg.recompute_interval):
+            x, r, *tell = call(x, r, cos, sin)
             told.append(tell)
-        note_flash_kept(kept)
         return (self.norm(x),) + tuple(zip(*told))
 
 

@@ -19,9 +19,8 @@ from .layers.common import (  # noqa: F401
     PixelShuffle, PixelUnshuffle, ChannelShuffle, Unfold,
 )
 from .layers.moe import (  # noqa: F401
-    SparseExpertFFN, SwiGLU, observe_expert_load, rope_tables,
-    yarn_inv_freq,
-)
+    SparseExpertFFN, SwiGLU, observe_expert_load)
+from .layers.rope import rope_tables, yarn_inv_freq  # noqa: F401
 from .layers.cca import CompressedConvAttention, ResidualScale  # noqa: F401
 from .layers.gdn import GatedDeltaNet, ZeroCenteredRMSNorm  # noqa: F401
 from .layers.conv import (  # noqa: F401

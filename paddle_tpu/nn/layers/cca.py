@@ -85,17 +85,8 @@ class CompressedConvAttention(Layer):
         with traced_scope("rope"):
             q = ops.rope_rotate_half(q, cos, sin)
             k = ops.rope_rotate_half(k, cos, sin)
-        if self.use_flash_attention:
-            from ...incubate.nn.functional import fused_flash_attention
-            from ...kernels.pallas.flash_attention import attention_path
-            perf.trace_note("attention",
-                            attention_path(q.shape, k.shape)[0])
-            out = fused_flash_attention(q, k, v, causal=True)
-        else:
-            perf.trace_note("attention", "composite")
-            k = ops.repeat_interleave(k, H // Hk, axis=2)
-            v = ops.repeat_interleave(v, H // Hk, axis=2)
-            out = ops.scaled_dot_product_attention(q, k, v, is_causal=True)
+        from ...incubate.nn.functional import causal_attention
+        out = causal_attention(q, k, v, self.use_flash_attention)
         with traced_scope("out_proj"):
             return self.o_proj(ops.reshape(out, (b, s, H * d)))
 

@@ -80,7 +80,7 @@ def _composite(x, cos, sin):
 @register_op("rope_rotate_half", amp_policy="keep")
 def rope_rotate_half(x, cos, sin):
     """x [b, s, h, d]; cos, sin [s, rot] float32, rot <= d
-    (`nn.layers.moe.rope_tables`). The first rot dimensions of every head
+    (`nn.layers.rope.rope_tables`). The first rot dimensions of every head
     are rotated in pairs (i, i + rot/2): x * cos + rotate_half(x) * sin
     with rotate_half(x) = [-x2, x1]; the other d - rot pass through.
     Computed in float32 and returned in x's type.

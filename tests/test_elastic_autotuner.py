@@ -195,13 +195,15 @@ class TestCostModel:
 
     def test_ranking_matches_two_measured_trials(self):
         """The VERDICT validation: the model's ordering agrees with two
-        REAL measured CPU-mesh trials. The pair differs in pure compute
-        (recompute re-runs every block forward in backward), so the
-        measured signal is structural, not noise."""
-        import time
+        REAL compiled CPU steps. The pair differs in pure compute
+        (recompute re-runs every block forward in backward), and what is
+        measured is what the compiler counts of each program
+        (`cost_analysis()["flops"]`), not how long the host took to run
+        it: on a shared box the clock ranks the load as often as the
+        programs."""
+        import jax
         import numpy as np
         import paddle_tpu as pt
-        from paddle_tpu import amp
         from paddle_tpu.jit import TrainStep
         from paddle_tpu.models import GPTForCausalLM, GPTPretrainingCriterion
         from paddle_tpu.models.gpt import GPTConfig
@@ -212,7 +214,7 @@ class TestCostModel:
         tc = dict(world_size=1, model_num_params=3.5e6, hidden_size=256,
                   seq_length=128, num_layers=4, global_batch_size=4)
 
-        def build(use_recompute):
+        def flops(use_recompute):
             pt.seed(5)
             cfg = GPTConfig(vocab_size=512, hidden_size=256, num_layers=4,
                             num_heads=4, max_position_embeddings=128,
@@ -228,42 +230,18 @@ class TestCostModel:
                 return crit(mm(ids), labels)
 
             step = TrainStep(m, opt, loss_fn)
-            rng = np.random.default_rng(0)
-            ids = rng.integers(0, 512, (4, 128)).astype(np.int32)
-            lbl = rng.integers(0, 512, (4, 128)).astype(np.int32)
-            step(ids, lbl)
-            float(step(ids, lbl).numpy())
-            return step, ids, lbl
+            ids = np.zeros((4, 128), np.int32)
+            cost = step._step_fn.jit_fn.lower(
+                step.params, step.opt_states, step.buffers,
+                jax.random.PRNGKey(0), np.float32(1e-4), [ids, ids],
+                {}).compile().cost_analysis()
+            return cost["flops"]
 
-        def timed(step, ids, lbl, n=2):
-            t0 = time.perf_counter()
-            for _ in range(n):
-                loss = step(ids, lbl)
-            float(loss.numpy())
-            return (time.perf_counter() - t0) / n
-
-        # INTERLEAVED A/B over best-of-3 trial windows (min-of-6
-        # each): both variants sample the same load conditions, so
-        # shared-worker CPU contention cancels out of the ranking
-        # (sequential trials flipped it under pytest -n 2). A window
-        # whose noise spike still flipped the ordering is retried —
-        # the running min over MORE interleaved samples only converges
-        # toward the true ordering, and remat is structurally slower
-        # (it re-runs every block forward in backward), so a window
-        # that shows it decisively slower is terminal evidence while a
-        # flipped one is only ever noise.
-        plain = build(False)
-        remat = build(True)
-        measured_plain = measured_remat = float("inf")
-        for _window in range(3):
-            for _ in range(6):
-                measured_plain = min(measured_plain, timed(*plain))
-                measured_remat = min(measured_remat, timed(*remat))
-            if measured_remat > measured_plain * 1.02:
-                break               # decisively ordered — stop early
+        measured_plain, measured_remat = flops(False), flops(True)
         est_plain = estimate_step_time(Config(use_recompute=False), tc)
         est_remat = estimate_step_time(Config(use_recompute=True), tc)
-        # the model predicts remat is slower; the measurement agrees
+        # the model predicts remat is slower; the programs agree, by
+        # about a forward's share of a step
         assert est_remat > est_plain
-        assert measured_remat > measured_plain, (
+        assert measured_remat > 1.1 * measured_plain, (
             measured_plain, measured_remat)

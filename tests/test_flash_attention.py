@@ -16,6 +16,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.core.mesh_plan import mesh_plan
+
 fa = importlib.import_module("paddle_tpu.kernels.pallas.flash_attention")
 
 
@@ -614,18 +616,18 @@ def test_qkv_in_place_is_the_split_entry_bit_for_bit(monkeypatch, h, d,
     b, s, _ = qkv.shape
     seg = (seg, seg) if with_seg else None
     sc = 1.0 / np.sqrt(d)
-    o, res = fa._flash_core_qkv_fwd(qkv, seg, h, causal, sc)
-    dqkv, _ = fa._flash_core_qkv_bwd(h, causal, sc, res, g)
+    o, res = fa._flash_core_fwd((qkv,), seg, causal, sc, True, None, h)
+    (dqkv,), _ = fa._flash_core_bwd(causal, sc, True, None, h, res, g)
     q, k, v = (x.reshape(b, s, h, d) for x in jnp.split(qkv, 3, axis=2))
-    o4, res4 = fa._flash_core_fwd(q, k, v, seg, causal, sc, True)
-    grads = fa._flash_core_bwd(causal, sc, True, None, res4,
-                               g.reshape(b, s, h, d))
+    o4, res4 = fa._flash_core_fwd((q, k, v), seg, causal, sc, True)
+    grads, _ = fa._flash_core_bwd(causal, sc, True, None, None, res4,
+                                  g.reshape(b, s, h, d))
     np.testing.assert_array_equal(np.asarray(o),
                                   np.asarray(o4.reshape(b, s, h * d)))
-    np.testing.assert_array_equal(np.asarray(res[2]), np.asarray(res4[4]))
+    np.testing.assert_array_equal(np.asarray(res[2]), np.asarray(res4[2]))
     np.testing.assert_array_equal(
         np.asarray(dqkv), np.asarray(jnp.concatenate(
-            [x.reshape(b, s, h * d) for x in grads[:3]], axis=2)))
+            [x.reshape(b, s, h * d) for x in grads], axis=2)))
     # and the public entries agree with the composite
     want = fa._xla_attention(q, k, v, None, causal, sc, segment_ids=seg)
     np.testing.assert_allclose(
@@ -726,7 +728,7 @@ def test_gpt_attention_is_the_same_through_both_entries(monkeypatch, dtype):
     one_device = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("dp",))
 
     def loss(params, x, planned):
-        plan = fa.mesh_plan(one_device) if planned else (
+        plan = mesh_plan(one_device) if planned else (
             contextlib.nullcontext())
         with _functional_params(tensors, params), tape.no_grad(), plan:
             out = layer(pt.to_tensor(x))._data
@@ -754,7 +756,7 @@ def test_fwd_bwd_tpu_compiled():
     sc = 1.0 / np.sqrt(q.shape[-1])
 
     def f_p(q, k, v):
-        return (_ := fa._flash_core(q, k, v, True, sc, True)).astype(
+        return (_ := fa._flash_core((q, k, v), None, True, sc, True)).astype(
             jnp.float32).sum()
 
     def f_x(q, k, v):
@@ -777,7 +779,7 @@ def test_bwd_tpu_bf16_multi_kblock_partials():
     sc = 1.0 / np.sqrt(q.shape[-1])
 
     def f_p(q, k, v):
-        return fa._flash_core(q, k, v, True, sc, True).astype(
+        return fa._flash_core((q, k, v), None, True, sc, True).astype(
             jnp.float32).sum()
 
     def f_x(q, k, v):
@@ -852,7 +854,7 @@ def test_mesh_plan_splits_kernel_with_shard_map(monkeypatch, batch_axes, h,
     grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2), has_aux=True))
     ref_g, ref_out = grad(q, k, v)
     sh = NamedSharding(mesh, P("dp"))
-    with fa.mesh_plan(mesh, batch_axes):
+    with mesh_plan(mesh, batch_axes):
         got_g, got_out = jax.jit(
             jax.grad(loss, argnums=(0, 1, 2), has_aux=True),
             in_shardings=(sh, sh, sh))(q, k, v)

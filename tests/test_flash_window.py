@@ -112,7 +112,7 @@ def _lowered(window_kw):
     k = jnp.zeros((1, 512, 1, D), jnp.bfloat16)
 
     def loss(q, k, v):
-        return fa._flash_core(q, k, v, None, True, 1.0, True,
+        return fa._flash_core((q, k, v), None, True, 1.0, True,
                               *window_kw).astype(jnp.float32).sum()
     # the traced program, kernels' bodies and grids included (lowering a
     # Mosaic kernel needs a TPU target: tests/test_tpu_aot_compile.py)

@@ -25,6 +25,8 @@ from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from paddle_tpu.core.mesh_plan import mesh_plan
+
 fa = import_module("paddle_tpu.kernels.pallas.flash_attention")
 norms = import_module("paddle_tpu.kernels.pallas.norms")
 rpa = import_module("paddle_tpu.kernels.pallas.ragged_paged_attention")
@@ -89,7 +91,7 @@ def test_ragged_paged_attention_compiles(v5e, tokens, with_pool, int8):
 
 
 def _flash_loss(q, k, v):
-    out = fa._flash_core(q, k, v, None, True, D ** -0.5, True)
+    out = fa._flash_core((q, k, v), None, True, D ** -0.5, True)
     return out.astype(jnp.float32).sum()
 
 
@@ -110,7 +112,7 @@ def test_flash_attention_splits_itself_over_a_mesh(v5e):
         sharding=NamedSharding(mesh, P("dp", None, "mp", None)))
 
     def attend(q, k, v):
-        with fa.mesh_plan(mesh, ("dp",)):
+        with mesh_plan(mesh, ("dp",)):
             return fa.flash_attention(q, k, v, causal=True)
 
     spec, _ = fa._planned_specs((mesh, ("dp",)), x.shape, x.shape)
@@ -389,7 +391,7 @@ def test_flash_attention_at_lagunas_heads_compiles(v5e, heads, window):
                              sharding=one)
 
     def loss(q, k, v):
-        return fa._flash_core(q, k, v, None, True, D ** -0.5, True,
+        return fa._flash_core((q, k, v), None, True, D ** -0.5, True,
                               window).astype(jnp.float32).sum()
 
     text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, k, k)
@@ -612,7 +614,7 @@ def test_flash_attention_at_zayas_latent_heads_compiles(v5e):
                              sharding=one)
 
     def loss(q, k, v):
-        return fa._flash_core(q, k, v, None, True, D ** -0.5,
+        return fa._flash_core((q, k, v), None, True, D ** -0.5,
                               True).astype(jnp.float32).sum()
 
     text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, k, k)
@@ -905,7 +907,7 @@ def test_flash_attention_at_head_size_256_compiles(v5e):
     assert fa._shape_reject_reason(q.shape, k.shape) is None
 
     def loss(q, k, v):
-        return fa._flash_core(q, k, v, None, True, Q3_D ** -0.5,
+        return fa._flash_core((q, k, v), None, True, Q3_D ** -0.5,
                               True).astype(jnp.float32).sum()
 
     text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, k, k)
