@@ -100,7 +100,8 @@ def fused_flash_attention(query, key, value, attn_mask=None, causal=False,
                               segment_ids=segment_ids, window=window)
 
 
-def causal_attention(q, k, v, use_flash, window=None):
+def causal_attention(q, k, v, use_flash, window=None, *, shared=None,
+                     scale=None):
     """Causal softmax attention of q [batch, seq, H, dim] on k and v of
     Hk heads (query head h reads key/value head h // (H // Hk)), a row
     seeing every key up to its own or, with a `window`, its own and the
@@ -109,7 +110,18 @@ def causal_attention(q, k, v, use_flash, window=None):
     which takes grouped heads as they are; else k and v repeated to the
     query heads and `ops.scaled_dot_product_attention`.
     `compile_record(...)["attention"]` says which of `pallas`, `xla` and
-    `composite` a traced program took."""
+    `composite` a traced program took.
+
+    `shared` = (q' [batch, seq, H, r], k' [batch, seq, 1, r]): the key
+    comes in two parts, head j's score q_j . k_j + q'_j . k' with k' ONE
+    head that all read (multi-head latent attention), v at k's size.
+    `scale`: the scores' factor where it is not 1 / sqrt(dim (+ r))."""
+    if shared is not None:
+        return _two_part_attention(q, k, v, use_flash, shared, scale)
+    if scale is not None:
+        raise NotImplementedError(
+            "causal_attention: a scale of its own only with a key in two "
+            "parts")
     if use_flash:
         perf.trace_note("attention", attention_path(q.shape, k.shape)[0])
         return fused_flash_attention(q, k, v, causal=True, window=window)
@@ -124,6 +136,27 @@ def causal_attention(q, k, v, use_flash, window=None):
         q, k, v, attn_mask=_window_mask(q.shape[1], window))
 
 
+def _two_part_attention(q, k, v, use_flash, shared, scale):
+    """`causal_attention` with a key in two parts."""
+    q_pe, k_pe = shared
+    if use_flash:
+        path, why = attention_path(q.shape, k.shape,
+                                   shared=(q_pe.shape, k_pe.shape),
+                                   v_shape=v.shape)
+        perf.trace_note("attention", "pallas, two-part key"
+                        if path == "pallas" else f"{path}: {why}")
+        return fused_flash_attention_two_part_key(
+            q, q_pe, k, k_pe, v, causal=True, softmax_scale=scale)
+    perf.trace_note("attention", "composite")
+    wide = q.shape[-1] + q_pe.shape[-1]
+    q = ops.concat([q, q_pe], axis=-1)
+    k = ops.concat([k, ops.expand(k_pe, list(k.shape[:3])
+                                  + [k_pe.shape[-1]])], axis=-1)
+    if scale is not None:       # the composite divides by sqrt(wide)
+        q = q * float(scale * np.sqrt(wide))
+    return ops.scaled_dot_product_attention(q, k, v, is_causal=True)
+
+
 def _window_mask(seq, window):
     """[seq, seq] bool: row i sees keys max(i - window + 1, 0) .. i."""
     gap = np.arange(seq)[:, None] - np.arange(seq)[None, :]
@@ -131,14 +164,33 @@ def _window_mask(seq, window):
                         stop_gradient=True)
 
 
-def _warn_if_composite(q_shape, k_shape):
+def _warn_if_composite(q_shape, k_shape, shared=None, v_shape=None):
     if jax.default_backend() == "tpu":
-        path, why = attention_path(q_shape, k_shape)
+        path, why = attention_path(q_shape, k_shape, shared=shared,
+                                   v_shape=v_shape)
         if path == "xla":
             import warnings
             warnings.warn(
                 f"flash_attention fell back to the XLA composite: {why}",
                 RuntimeWarning, stacklevel=4)
+
+
+@register_op("fused_flash_attention_two_part_key", amp_policy="white")
+def fused_flash_attention_two_part_key(query, query_shared, key, key_shared,
+                                       value, causal=False,
+                                       softmax_scale=None):
+    """Flash attention whose key comes in two parts: query, key, value
+    [batch, seq, heads, dim], query_shared [batch, seq, heads, r],
+    key_shared [batch, seq, 1, r], ONE head that every query head reads;
+    a score is q . k + q' . k', scaled by 1 / sqrt(dim + r) unless
+    `softmax_scale` says otherwise (`kernels/pallas/flash_attention.py:
+    flash_attention(shared=...)`). A shape the kernel refuses falls to
+    the composite with a warning that names the part at fault."""
+    _warn_if_composite(query.shape, key.shape,
+                       (query_shared.shape, key_shared.shape), value.shape)
+    return pk.flash_attention(query, key, value, causal=causal,
+                              softmax_scale=softmax_scale,
+                              shared=(query_shared, key_shared))
 
 
 @register_op("fused_flash_attention_qkv", amp_policy="white")

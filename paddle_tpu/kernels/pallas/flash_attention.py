@@ -177,6 +177,23 @@ def _pick(mine, x, other):
     return jax.lax.select(_over_rows(mine, x.shape[0]), x, other)
 
 
+def _shared_q(qf, h, HD, R):
+    """Head h's second part of a q block whose key comes in two parts,
+    qf [bq, HD + H*R]: after the H heads' own parts of D lanes come their
+    second parts of R, which all multiply the ONE shared key head. The
+    128-lane slab that holds the head's R lanes, the other heads' lanes
+    of it zeroed, the mask of its own, and the slab's first lane: against
+    the shared keys repeated along the slab's lanes (`_shared_rows`), the
+    zeros drop the neighbours' products out of the contraction, as in
+    `_head_slabs`."""
+    at = HD + h * R
+    start, lo = at - at % _LANES, at % _LANES
+    slab = _cols(qf, start, _LANES)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+    mine = jnp.logical_and(lane >= lo, lane < lo + R)
+    return _pick(mine, slab, jnp.zeros_like(slab)), mine, start
+
+
 def _nt_dot(a, b):
     """a @ b^T in float32: both contract their lanes."""
     return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
@@ -327,14 +344,19 @@ def _seg_tile_mask(row_ref, lane_ref, r0, rows, l0, lanes):
 # ======================= forward =======================
 
 def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, sub, H, Hk, D,
-                offset, has_seg, window=None):
+                offset, has_seg, window=None, shared=0):
+    """`shared`: 0, or the size of the key's second part (see
+    `_shared_q`): q_ref then holds [bq, H*D + H*shared] and a fourth
+    input the shared head's keys, and a score is the sum of the two
+    products, taken as one contraction over a head's lanes and the
+    shared slab's."""
+    q_ref, k_ref, v_ref, *refs = refs
+    kpe_ref = qseg_ref = kseg_ref = None
+    if shared:
+        kpe_ref, *refs = refs
     if has_seg:
-        (q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
-         o_ref, lse_ref, qs_ref, acc_ref, m_ref, l_ref) = refs
-    else:
-        (q_ref, k_ref, v_ref,
-         o_ref, lse_ref, qs_ref, acc_ref, m_ref, l_ref) = refs
-        qseg_ref = kseg_ref = None
+        qseg_ref, kseg_ref, *refs = refs
+    o_ref, lse_ref, qs_ref, acc_ref, m_ref, l_ref = refs
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -357,6 +379,8 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, sub, H, Hk, D,
         qf = qs_ref[:]                     # [bq, H*D], scaled
         kf = k_ref[0, pl.ds(k0, sub), :]   # [sub, Hk*D]
         vf = v_ref[0, pl.ds(k0, sub), :]
+        if shared:
+            kpe = kpe_ref[0, pl.ds(k0, sub), :]          # [sub, LANES]
         ok = (_causal_tile_mask(offset + qi * block_q, kb * block_k + k0,
                                 (block_q, sub), window=window)
               if causal else None)
@@ -370,9 +394,13 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, sub, H, Hk, D,
             q2, k2, v2 = _cols(qf, c, w), _cols(kf, ck, wk), _cols(vf, ck, wk)
             pv = scale = None
             for h, mine in heads:
-                s = _nt_dot(q2 if mine is None
-                            else _pick(mine, q2, jnp.zeros_like(q2)),
-                            k2)                          # [bq, sub] f32
+                qh, kh = (q2 if mine is None
+                          else _pick(mine, q2, jnp.zeros_like(q2))), k2
+                if shared:
+                    qh = jax.lax.concatenate(
+                        [qh, _shared_q(qf, h, H * D, shared)[0]], 1)
+                    kh = jax.lax.concatenate([k2, kpe], 1)
+                s = _nt_dot(qh, kh)                      # [bq, sub] f32
                 if ok is not None:
                     s = jax.lax.select(ok, s, neg)
                 m_prev = m_ref[h]                        # [bq, LANES]
@@ -648,7 +676,7 @@ def _fwd_call(q, k, v, segment_ids, *, cols, sm_scale, H, Hk, D, causal,
 
 def _bwd_kernel(*refs, sm_scale, causal, block_q, block_k, sub, H, Hk, D,
                 offset, has_seg, one_array, dq_whole, window=None,
-                n_qblocks=None):
+                n_qblocks=None, shared=0):
     """Single-pass backward: one s/p recompute per block pair feeds dk, dv
     AND this pair's dq contribution (vs. the classic two-kernel split that
     recomputes s/p and the dp dot twice). dq contributions can't accumulate
@@ -674,7 +702,13 @@ def _bwd_kernel(*refs, sm_scale, causal, block_q, block_k, sub, H, Hk, D,
     does nothing, and its index maps name the last one's blocks again.
     The partials then have a slot for each k block a q block can see
     (`_window_kblocks`), not for every k block; a slot no pair writes
-    keeps the zero it was handed."""
+    keeps the zero it was handed.
+
+    `shared`: as the forward's. The key's second part is a seventh input,
+    its gradient one output more (float32, this grid row's heads' sum)
+    with an accumulator of its own; dq's block has the layout of q's."""
+    refs = list(refs)
+    kpe_ref = refs.pop(6) if shared else None
     n_in = 8 if has_seg else 6
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref = refs[:6]
     qseg_ref, kseg_ref = refs[6:n_in] or (None, None)
@@ -701,6 +735,9 @@ def _bwd_kernel(*refs, sm_scale, causal, block_q, block_k, sub, H, Hk, D,
     else:
         dq_ref, dk_ref, dv_ref, *rest = refs[n_in:]
         dk_ref, dv_ref = dk_ref.at[0], dv_ref.at[0]
+    if shared:
+        dkpe_ref, *rest = rest
+        *rest, dkpe_acc = rest
     qs_ref, delta_ref, dk_acc, dv_acc, *dq_acc = rest
     if causal:
         dq_acc, = dq_acc    # [bq, HD] f32: the walk's sum of dq
@@ -736,6 +773,9 @@ def _bwd_kernel(*refs, sm_scale, causal, block_q, block_k, sub, H, Hk, D,
         dof = do_ref[0]
         kf = k_ref[0, rows, :]               # [sub, Hk*D]
         vf = v_ref[0, rows, :]
+        if shared:
+            kpe = kpe_ref[0, rows, :]        # [sub, LANES]
+            dkpe, dq_slabs = None, {}
         ok = (_causal_tile_mask(offset + qi * block_q, ki * block_k + k0,
                                 (sub, block_q), q_axis=1, window=window)
               if causal else None)
@@ -754,7 +794,13 @@ def _bwd_kernel(*refs, sm_scale, causal, block_q, block_k, sub, H, Hk, D,
                 if mine is not None:
                     q1 = _pick(mine, q2, jnp.zeros_like(q2))
                     do1 = _pick(mine, do2, jnp.zeros_like(do2))
-                s = _nt_dot(k2, q1)                      # [sub, bq]
+                qh, kh, qs2 = q1, k2, q2
+                if shared:
+                    qr, its, at = _shared_q(qf, h, H * D, shared)
+                    qh = jax.lax.concatenate([q1, qr], 1)
+                    qs2 = jax.lax.concatenate([q2, qr], 1)
+                    kh = jax.lax.concatenate([k2, kpe], 1)
+                s = _nt_dot(kh, qh)                      # [sub, bq]
                 p = jax.lax.exp(jax.lax.sub(
                     s, _over_rows(lse_ref[0, st, :], sub)))
                 if ok is not None:
@@ -765,8 +811,19 @@ def _bwd_kernel(*refs, sm_scale, causal, block_q, block_k, sub, H, Hk, D,
                 ds = jax.lax.mul(p, jax.lax.sub(
                     dp, _over_rows(delta_ref[st, :], sub))).astype(
                         q2.dtype)
-                dk_h = _dot(ds, q2)          # dk = ds^T @ q_scaled
-                dq_h = _dot(ds, k2, 0)       # this visit's dq: ds @ k
+                dk_h = _dot(ds, qs2)         # dk = ds^T @ q_scaled
+                dq_h = _dot(ds, kh, 0)       # this visit's dq: ds @ k
+                if shared:
+                    # the slab's lanes past the head's own: the shared
+                    # keys' gradient, zero off the head's lanes of the
+                    # slab as qr is; and the head's second part of dq,
+                    # in every R lanes of the slab, its own kept
+                    dk_r = _cols(dk_h, w, _LANES)
+                    dkpe = dk_r if dkpe is None else jax.lax.add(dkpe, dk_r)
+                    dq_r = _cols(dq_h, w, _LANES)
+                    dq_slabs[at] = _pick(
+                        its, dq_r, dq_slabs.get(at, jnp.zeros_like(dq_r)))
+                    dk_h, dq_h = _cols(dk_h, 0, w), _cols(dq_h, 0, w)
                 if dv is None:
                     dv, dk, dq = dv_h, dk_h, dq_h
                 else:
@@ -780,11 +837,21 @@ def _bwd_kernel(*refs, sm_scale, causal, block_q, block_k, sub, H, Hk, D,
                 dq_acc[:, sl] = jax.lax.add(dq_acc[:, sl], dq)
             else:
                 dq_ref[:, sl] = _scaled(dq, sm_scale, dq_ref.dtype)
+        if shared:
+            dkpe_acc[rows, :] = jax.lax.add(dkpe_acc[rows, :], dkpe)
+            for at, dq in dq_slabs.items():
+                sl = slice(at, at + _LANES)
+                if causal:
+                    dq_acc[:, sl] = jax.lax.add(dq_acc[:, sl], dq)
+                else:
+                    dq_ref[:, sl] = _scaled(dq, sm_scale, dq_ref.dtype)
 
     @pl.when(step == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
+        if shared:
+            dkpe_acc[:] = jnp.zeros_like(dkpe_acc)
 
     if window is not None:
         n_visit = _walk_bounds(qi, ki, block_q, block_k, sub, offset)
@@ -820,6 +887,8 @@ def _bwd_kernel(*refs, sm_scale, causal, block_q, block_k, sub, H, Hk, D,
     def _finalize():
         dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
+        if shared:
+            dkpe_ref[0] = dkpe_acc[:]
 
 
 def _flash_bwd_fused(q, k, v, o, lse, do, H, causal,
@@ -1087,6 +1156,266 @@ def _fit_blocks(block_q, block_k, HD, n_bufs_q, n_bufs_k, HDk=None,
     return max(block_q, 128), max(block_k, 128)
 
 
+# ============ a key in two parts (multi-head latent attention) ============
+#
+# A query head's score is q . k + q' . k': k [heads of D] a head's own
+# part, k' ONE head of R that every query head reads (the rotary part of a
+# latent key). The kernels above take it as the option `shared`; what
+# differs around them is here. The grid's first axis runs over (batch,
+# group of `_SHARED_GROUP` heads): a grid row holds a group's lanes of q,
+# k, v, o and the whole shared head, so the unrolled body is a group's
+# and not sixteen heads', the resident key block of the backward is wide
+# in keys at a group's width in lanes, and dq's partials are few.
+
+_SHARED_GROUP = 2       # heads a grid row works: R = 64, one 128-lane slab
+# q rows and keys a grid step holds. A group's lanes are few, so the
+# blocks are long in rows: at 16 heads and 32,768 keys the forward took
+# 65.7 / 50.9 / 44.2 / 41.3 ms a call at 256 / 512 / 1024 / 2048 q rows
+# of 1024 keys, the backward 167.0 / 104.1 / 99.7 ms at 128 / 256 / 512 q
+# rows of 8192 keys (PERF.md, PR 46)
+_FWD_SHARED_BLOCKS = (2048, 1024)
+_BWD_SHARED_BLOCK_Q = 512
+
+
+def _shared_rows(operands):
+    """(q, q', k, k', v), [b, s, H, D], [b, s, H, R], [b, sk, H, D],
+    [b, sk, 1, R], [b, sk, H, D] -> the kernels' rows: q by group, a
+    group's own parts then its second parts [b, s, H*(D + R)]; k and v
+    [b, sk, H*D]; k' repeated along one slab's lanes [b, sk, 128] (what
+    a tile of 64 lanes is padded to in memory anyway)."""
+    q, q_pe, k, k_pe, v = operands
+    (b, sq, H, D), R, sk = q.shape, q_pe.shape[-1], k.shape[1]
+    n = H // _SHARED_GROUP
+    q_rows = jnp.concatenate(
+        [q.reshape(b, sq, n, -1), q_pe.reshape(b, sq, n, -1)],
+        axis=-1).reshape(b, sq, H * (D + R))
+    kpe = jnp.tile(k_pe.reshape(b, sk, R), (1, 1, _LANES // R))
+    return (q_rows, k.reshape(b, sk, H * D), kpe, v.reshape(b, sk, H * D))
+
+
+def _shared_grads(dq_rows, dk, dkpe, dv, H, D, R):
+    """The kernels' gradients back in the operands' shapes; k''s summed
+    over the groups and over the slab's repeats, in float32."""
+    b, sq, _ = dq_rows.shape
+    sk, n = dk.shape[1], H // _SHARED_GROUP
+    dq_rows = dq_rows.reshape(b, sq, n, -1)
+    own = _SHARED_GROUP * D
+    dk_pe = dkpe.reshape(b, n, sk, _LANES // R, R).sum((1, 3))
+    return (dq_rows[..., :own].reshape(b, sq, H, D),
+            dq_rows[..., own:].reshape(b, sq, H, R),
+            dk.reshape(b, sk, H, D),
+            dk_pe.reshape(b, sk, 1, R).astype(dk.dtype),
+            dv.reshape(b, sk, H, D))
+
+
+def _whole_heads(operands):
+    """(q, k, v) at whole heads from a key in two parts (the composite's
+    operands): q and q' side by side a head, k' beside every head's k."""
+    if len(operands) != 5:
+        return operands
+    q, q_pe, k, k_pe, v = operands
+    k_pe = jnp.broadcast_to(k_pe, k.shape[:3] + k_pe.shape[3:])
+    return (jnp.concatenate([q, q_pe], -1), jnp.concatenate([k, k_pe], -1),
+            v)
+
+
+def _fit_shared_bwd(sk, block_q, D, R, itemsize):
+    """The backward's key block for a key in two parts: all the keys (dq
+    then leaves whole), halved until a group's blocks, twice buffered,
+    and their float32 accumulators fit: 8192 keys at 32768 of two heads
+    of 128, dq in four partials."""
+    G = _SHARED_GROUP
+    block_k = sk
+
+    def est(bk):
+        k_side = bk * (2 * G * D + _LANES) * itemsize        # k, v, k'
+        outs = bk * (2 * G * D * itemsize + _LANES * 4)      # dk, dv, dk'
+        accs = bk * (2 * G * D + _LANES) * 4
+        q_side = block_q * (2 * G * (D + R) + 2 * G * D) * itemsize
+        return 2 * (k_side + outs + q_side) + accs
+    while est(block_k) > _VMEM_LIMIT_BWD * 0.75 and block_k > 128:
+        block_k = _pick_block(sk, block_k // 2)
+    return block_k
+
+
+def _flash_fwd_shared(q, k, kpe, v, H, D, R, causal, sm_scale,
+                      interpret=False, blocks=None):
+    """The forward on `_shared_rows`' rows -> (o [b, s, H*D], lse)."""
+    sq, sk = q.shape[1], k.shape[1]
+    block_q, block_k = blocks or _FWD_SHARED_BLOCKS
+    block_q, block_k = _pick_block(sq, block_q), _pick_block(sk, block_k)
+    sub = _sub_block(block_k, _WALK if causal else None)
+    _note_causal("fwd", sq, sk, block_q, block_k, sub, causal,
+                 f", key in two parts ({D} a head + {R} shared)")
+    return _fwd_call_shared(q, k, v, kpe, sm_scale=sm_scale, H=H, D=D, R=R,
+                            causal=causal, block_q=block_q, block_k=block_k,
+                            sub=sub, interpret=interpret)
+
+
+def _flash_bwd_shared(q, k, kpe, v, o, lse, do, H, D, R, causal, sm_scale,
+                      interpret=False, blocks=None):
+    """The backward on `_shared_rows`' rows -> (dq in q's layout, dk, dk'
+    [b * groups, sk, 128] float32, dv)."""
+    sq, sk = q.shape[1], k.shape[1]
+    block_q, block_k = blocks or (
+        _BWD_SHARED_BLOCK_Q,
+        _fit_shared_bwd(sk, _BWD_SHARED_BLOCK_Q, D, R, q.dtype.itemsize))
+    block_q, block_k = _pick_block(sq, block_q), _pick_block(sk, block_k)
+    sub = _sub_block(block_k, _WALK if causal else None)
+    nk = sk // block_k
+    _note_causal("bwd", sq, sk, block_q, block_k, sub, causal,
+                 (f", dq partials {nk}" if nk > 1 else ", dq whole")
+                 + ", key in two parts")
+    return _bwd_call_shared(q, k, v, kpe, o, lse, do, sm_scale=sm_scale,
+                            H=H, D=D, R=R, causal=causal, block_q=block_q,
+                            block_k=block_k, sub=sub, interpret=interpret)
+
+
+_SHARED_STATICS = ("sm_scale", "H", "D", "R", "causal", "block_q",
+                   "block_k", "sub", "interpret")
+
+
+def _group_of(n):
+    """A grid row g of b * n -> (its batch row, its group of heads)."""
+    return lambda g: (_div(g, n), jax.lax.rem(g, jnp.int32(n)))
+
+
+@functools.partial(jax.jit, static_argnames=_SHARED_STATICS, inline=True)
+@_pf.trace_timed_call("flash_mla_fwd")
+def _fwd_call_shared(q, k, v, kpe, *, sm_scale, H, D, R, causal, block_q,
+                     block_k, sub, interpret):
+    b, sq = q.shape[:2]
+    sk = k.shape[1]
+    G, n = _SHARED_GROUP, H // _SHARED_GROUP
+    GD, GQ = G * D, G * (D + R)
+    offset, nk = sk - sq, sk // block_k
+    at = _group_of(n)
+
+    def kj(i, j):       # as `_fwd_call`'s: nothing fetched above the diagonal
+        if not causal:
+            return j
+        last_q = jnp.maximum(offset + (i + 1) * block_q - 1, 0)
+        return jnp.minimum(j, jnp.minimum(_div(last_q, block_k), nk - 1))
+
+    def q_at(g, i, j):
+        return (at(g)[0], i, at(g)[1])
+
+    def k_at(g, i, j):
+        return (at(g)[0], kj(i, j), at(g)[1])
+
+    return pl.pallas_call(
+        functools.partial(
+            _fwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
+            block_k=block_k, sub=sub, H=G, Hk=G, D=D, offset=offset,
+            has_seg=False, shared=R),
+        grid=(b * n, sq // block_q, nk),
+        in_specs=[
+            pl.BlockSpec((1, block_q, GQ), q_at),
+            pl.BlockSpec((1, block_k, GD), k_at),
+            pl.BlockSpec((1, block_k, GD), k_at),
+            pl.BlockSpec((1, block_k, _LANES),
+                         lambda g, i, j: (at(g)[0], kj(i, j), 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, block_q, GD), q_at),
+            pl.BlockSpec((1, G * _SUBL, block_q),
+                         lambda g, i, j: (at(g)[0], at(g)[1], i)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, sq, H * D), q.dtype),
+            jax.ShapeDtypeStruct((b, H * _SUBL, sq), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, GQ), q.dtype),      # the q block, scaled
+            pltpu.VMEM((block_q, GD), jnp.float32),
+            pltpu.VMEM((G, block_q, _LANES), jnp.float32),
+            pltpu.VMEM((G, block_q, _LANES), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name="flash_mla_fwd",
+    )(q, k, v, kpe)
+
+
+@functools.partial(jax.jit, static_argnames=_SHARED_STATICS, inline=True)
+@_pf.trace_timed_call("flash_mla_bwd_transpose")
+def _bwd_call_shared(q, k, v, kpe, o, lse, do, *, sm_scale, H, D, R, causal,
+                     block_q, block_k, sub, interpret):
+    b, sq = q.shape[:2]
+    sk = k.shape[1]
+    G, n = _SHARED_GROUP, H // _SHARED_GROUP
+    GD, GQ = G * D, G * (D + R)
+    offset = sk - sq
+    nk, nq = sk // block_k, sq // block_q
+    at = _group_of(n)
+
+    def qi(j, i):       # as `_bwd_call`'s: the first q block that does work
+        if not causal:
+            return i
+        first = _div(jnp.maximum(j * block_k - offset, 0), block_q)
+        return jnp.maximum(i, jnp.minimum(first, nq - 1))
+
+    def q_spec(width):
+        return pl.BlockSpec((1, block_q, width), lambda g, j, i: (
+            at(g)[0], qi(j, i), at(g)[1]))
+
+    def k_spec(width):
+        return pl.BlockSpec((1, block_k, width),
+                            lambda g, j, i: (at(g)[0], j, at(g)[1]))
+
+    if nk > 1:          # partials, summed over the k blocks below
+        dq_spec = pl.BlockSpec((None, None, block_q, GQ), lambda g, j, i: (
+            at(g)[0], j, i, at(g)[1]))
+        dq_shape = jax.ShapeDtypeStruct(
+            (b, nk, sq, n * GQ), q.dtype if nk <= 8 else jnp.float32)
+    else:
+        dq_spec = pl.BlockSpec((None, block_q, GQ), lambda g, j, i: (
+            at(g)[0], i, at(g)[1]))
+        dq_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
+    dq, dk, dv, dkpe = pl.pallas_call(
+        functools.partial(
+            _bwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
+            block_k=block_k, sub=sub, H=G, Hk=G, D=D, offset=offset,
+            has_seg=False, one_array=False, dq_whole=nk == 1, shared=R),
+        grid=(b * n, nk, nq),
+        in_specs=[
+            q_spec(GQ), k_spec(GD), k_spec(GD), q_spec(GD), q_spec(GD),
+            pl.BlockSpec((1, G * _SUBL, block_q), lambda g, j, i: (
+                at(g)[0], at(g)[1], qi(j, i))),
+            pl.BlockSpec((1, block_k, _LANES),
+                         lambda g, j, i: (at(g)[0], j, 0)),
+        ],
+        out_specs=[
+            dq_spec, k_spec(GD), k_spec(GD),
+            pl.BlockSpec((1, block_k, _LANES), lambda g, j, i: (g, j, 0)),
+        ],
+        out_shape=[
+            dq_shape,
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct((b * n, sk, _LANES), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, GQ), q.dtype),      # the q block, scaled
+            pltpu.VMEM((G * _SUBL, block_q), jnp.float32),   # its delta
+            pltpu.VMEM((block_k, GD), jnp.float32),
+            pltpu.VMEM((block_k, GD), jnp.float32),
+        ] + ([pltpu.VMEM((block_q, GQ), jnp.float32)] if causal else [])
+        + [pltpu.VMEM((block_k, _LANES), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BWD),
+        interpret=interpret,
+        name="flash_mla_bwd_transpose",
+    )(q, k, v, o, do, lse, kpe)
+    if nk > 1:
+        dq = functools.reduce(jax.lax.add, (
+            dq[:, j].astype(jnp.float32) for j in range(nk))).astype(q.dtype)
+    return dq, dk, dkpe, dv
+
+
 # ======================= dispatch =======================
 
 def _xla_attention(q, k, v, attn_mask, causal, sm_scale, segment_ids=None,
@@ -1156,7 +1485,10 @@ def _kept(o, lse):
 def _flash_core(operands, segment_ids, causal, sm_scale, use_pallas,
                 window=None, heads=None):
     """`operands`: (q, k, v), each [b, s, h, d], k and v maybe on fewer
-    (kv) heads (GQA/MQA), and [b, s, h, d] out; or (qkv,), one fused
+    (kv) heads (GQA/MQA), and [b, s, h, d] out; or (q, q', k, k', v), a
+    key in two parts: q, k, v [b, s, h, d], q' [b, s, h, r] and k'
+    [b, s, 1, r], ONE head that every query head reads, a score
+    q . k + q' . k' (`_shared_rows`); or (qkv,), one fused
     projection [b, s, 3*h*d] of `heads` heads (the Pallas path only),
     [b, s, h*d] out, whose gradient leaves as the one [b, s, 3*h*d]
     array the projection's backward reads. segment_ids: None or (q_seg
@@ -1171,9 +1503,15 @@ def _flash_core(operands, segment_ids, causal, sm_scale, use_pallas,
 def _flash_core_fwd(operands, segment_ids, causal, sm_scale, use_pallas,
                     window=None, heads=None):
     if not use_pallas:
-        out = _xla_attention(*operands, None, causal, sm_scale,
-                             segment_ids=segment_ids, window=window)
+        out = _xla_attention(*_whole_heads(operands), None, causal,
+                             sm_scale, segment_ids=segment_ids,
+                             window=window)
         return out, (operands, None, None, segment_ids)
+    if len(operands) == 5:      # a key in two parts
+        rows = _shared_rows(operands)
+        (_, _, h, d), r = operands[0].shape, operands[1].shape[-1]
+        o, lse = _kept(*_flash_fwd_shared(*rows, h, d, r, causal, sm_scale))
+        return o.reshape(operands[0].shape), (rows, o, lse, segment_ids)
     if len(operands) == 1:
         rows, cols, h, hk = operands, _QKV, heads, heads
         d = rows[0].shape[2] // (3 * h)
@@ -1193,10 +1531,16 @@ def _flash_core_bwd(causal, sm_scale, use_pallas, window, heads, res, g):
     rows, o, lse, segment_ids = res
     if not use_pallas:
         _, vjp = jax.vjp(
-            lambda *x: _xla_attention(*x, None, causal, sm_scale,
-                                      segment_ids=segment_ids,
+            lambda *x: _xla_attention(*_whole_heads(x), None, causal,
+                                      sm_scale, segment_ids=segment_ids,
                                       window=window), *rows)
         return vjp(g), None
+    if len(rows) == 4:          # `_shared_rows`
+        h, d = g.shape[2:]
+        r = rows[0].shape[2] // h - d
+        return _shared_grads(*_flash_bwd_shared(
+            *rows, o, lse, g.reshape(o.shape), h, d, r, causal, sm_scale),
+            h, d, r), None
     if len(rows) == 1:
         cols, h, hk, d = _QKV, heads, heads, g.shape[2] // heads
     else:
@@ -1215,14 +1559,31 @@ def _flash_core_bwd(causal, sm_scale, use_pallas, window, heads, res, g):
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
-def _shapes_ok(q_shape, k_shape):
-    return not _shape_reject_reason(q_shape, k_shape)
+def _shapes_ok(q_shape, k_shape, shared=None, v_shape=None):
+    return not _shape_reject_reason(q_shape, k_shape, shared, v_shape)
 
 
-def _shape_reject_reason(q_shape, k_shape):
-    """None if the Pallas kernel applies, else a human-readable reason."""
+def _shape_reject_reason(q_shape, k_shape, shared=None, v_shape=None):
+    """None if the Pallas kernel applies, else a human-readable reason.
+    What it admits: heads of 64, 128 or 256, q, k and v alike; or, with
+    `shared` (the shapes of q' and k', a key in two parts), a head part
+    of one of those sizes on as many key heads as query heads, an even
+    number of them, a shared part of ONE head of 64 and a value
+    (`v_shape`) of the head part's size."""
     sq, sk, h, d = q_shape[1], k_shape[1], q_shape[2], q_shape[-1]
     hk = k_shape[2]
+    if shared is not None:
+        (*_, r), (_, _, hr, rk) = shared
+        if (r, rk, hr) != (64, 64, 1):
+            return (f"shared key part: {hr} head(s) of {rk} for a query "
+                    f"part of {r}; the kernel takes one shared head of 64")
+        if v_shape is not None and v_shape[-1] != d:
+            return (f"value part {v_shape[-1]} is not the head part's "
+                    f"size {d}")
+        if hk != h or h % _SHARED_GROUP:
+            return (f"a key in two parts needs its head part on every "
+                    f"query head and an even number of them, not "
+                    f"{hk} for {h}")
     if d not in (64, 128, 256):
         return f"head_dim {d} not in (64, 128, 256)"
     if sq < 128 or sk < 128 or sq % 128 or sk % 128:
@@ -1236,19 +1597,24 @@ def _shape_reject_reason(q_shape, k_shape):
     return None
 
 
-def attention_path(q_shape, k_shape, masked=False):
+def attention_path(q_shape, k_shape, masked=False, shared=None,
+                   v_shape=None):
     """('pallas'|'xla', reason) — which implementation flash_attention will
     take for these shapes and why. Lets callers (chip_smoke.py checks it;
     nn.functional.flash_attention warns on fallback) see when the Pallas
     kernel disengages. masked=True means a dense attn_mask (XLA
     composite); segment-id masking stays on the Pallas path and needs no
-    flag."""
+    flag. `shared` = (q' shape, k' shape) and `v_shape`: a key in two
+    parts, as `_shape_reject_reason` takes them."""
     if masked:
         return ("xla", "dense attn_mask forces the XLA composite — use "
                 "segment_ids or causal for the Pallas path")
     if not _pallas_available():
         return ("xla", f"no TPU Pallas backend ({jax.default_backend()})")
-    reason = _shape_reject_reason(q_shape, k_shape)
+    if shared is not None and current_mesh_plan() is not None:
+        return ("xla", "a key in two parts under a mesh_plan takes the "
+                "composite (its kernels are not split over a mesh)")
+    reason = _shape_reject_reason(q_shape, k_shape, shared, v_shape)
     if reason:
         return ("xla", reason)
     return ("pallas", "")
@@ -1285,8 +1651,18 @@ def _int32_pair(segment_ids):
 
 
 def flash_attention(q, k, v, attn_mask=None, causal=False,
-                    softmax_scale=None, segment_ids=None, window=None):
+                    softmax_scale=None, segment_ids=None, window=None,
+                    shared=None):
     """[b, s, h, d] in and out; k/v may have fewer heads (GQA/MQA).
+
+    shared: None, or (q' [b, s, h, r], k' [b, sk, 1, r]), the second part
+    of a key in two parts: head j's score is q_j . k_j + q'_j . k', k'
+    ONE head that every query head reads (multi-head latent attention's
+    rotary part), scaled by 1 / sqrt(d + r) unless `softmax_scale` says
+    otherwise. The kernels take the two products as they are: no copy of
+    k' a head and no head padded to a lane multiple is written, and k''s
+    gradient is summed over the heads where it is made. Without a
+    window, segment ids, a mask or a `mesh_plan`.
 
     window: None, or how many keys a row sees, its own position and the
     window - 1 before it (causal only). A static argument: None traces
@@ -1299,6 +1675,14 @@ def flash_attention(q, k, v, attn_mask=None, causal=False,
     Causal masking is bottom-right aligned when sq != sk (FA2 semantics,
     ref: python/paddle/nn/functional/flash_attention.py:146 routing to the
     FlashAttention-2 library)."""
+    if shared is not None:
+        if (attn_mask is not None or segment_ids is not None
+                or window is not None):
+            raise NotImplementedError(
+                "flash_attention: a key in two parts takes no mask, "
+                "segment ids or window")
+        return _flash_attention_shared(q, k, v, shared, causal,
+                                       softmax_scale)
     sm_scale = _scale(softmax_scale, q.shape[-1])
     if window is not None and not causal:
         raise ValueError("flash_attention: a window needs causal=True")
@@ -1322,6 +1706,19 @@ def flash_attention(q, k, v, attn_mask=None, causal=False,
             out_specs=spec, check_vma=False)(q, k, v, segment_ids)
     return _flash_core((q, k, v), segment_ids, causal, sm_scale, use_pallas,
                        window)
+
+
+def _flash_attention_shared(q, k, v, shared, causal, softmax_scale):
+    """`flash_attention` with a key in two parts."""
+    q_pe, k_pe = shared
+    sm_scale = _scale(softmax_scale, q.shape[-1] + q_pe.shape[-1])
+    use_pallas = attention_path(
+        q.shape, k.shape, shared=(q_pe.shape, k_pe.shape),
+        v_shape=v.shape)[0] == "pallas"
+    if use_pallas:
+        _pf.trace_note("flash_operands", "key in two parts")
+    return _flash_core((q, q_pe, k, k_pe, v), None, causal, sm_scale,
+                       use_pallas)
 
 
 def flash_attention_qkv(qkv, num_heads, causal=False, softmax_scale=None,

@@ -1,5 +1,5 @@
 """Flagship model families (GPT / LLaMA / Jamba / Laguna / ZAYA1 /
-Qwen3-Next / Ouro / BERT).
+Qwen3-Next / Ouro / DeepSeek-V2 / BERT).
 
 The reference keeps language models out-of-tree (PaddleNLP) but its
 north-star benchmarks are GPT-3/LLaMA hybrid-parallel training
@@ -39,3 +39,17 @@ from .bert import (  # noqa: F401
     BertConfig, BertModel, BertForMaskedLM, bert_tiny, bert_base,
 )
 from .generation import generate  # noqa: F401
+
+
+_DEEPSEEK_V2 = ("DeepseekV2Config", "DeepseekV2Model", "DeepseekV2ForCausalLM",
+                "DeepseekV2DecoderLayer", "DeepseekV2PretrainingCriterion",
+                "deepseek_v2_tiny")
+
+
+def __getattr__(name):
+    """`models.deepseek_v2`'s names, imported when first asked for: a
+    program that builds another family pays nothing for this one."""
+    if name in _DEEPSEEK_V2:
+        from . import deepseek_v2
+        return getattr(deepseek_v2, name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -61,3 +61,13 @@ from .layers.rnn import (  # noqa: F401
     LSTM, GRU, SimpleRNN, LSTMCell, GRUCell,
     RNN, BiRNN, RNNCellBase, SimpleRNNCell,
 )
+
+
+def __getattr__(name):
+    """`nn.MultiHeadLatentAttention`, imported when first asked for (its
+    module reaches the fused functional ops, which this package's other
+    layers do not need at import)."""
+    if name == "MultiHeadLatentAttention":
+        from .layers.mla import MultiHeadLatentAttention
+        return MultiHeadLatentAttention
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

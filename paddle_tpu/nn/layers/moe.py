@@ -17,16 +17,19 @@ class _Router(Layer):
     carries_state = False
 
     def __init__(self, hidden, num_experts, top_k, routed_scale, std,
-                 score="sigmoid"):
+                 score="sigmoid", normalize=True, with_scores=False):
         super().__init__()
         self.top_k, self.routed_scale = top_k, routed_scale
         self.score = score
+        # what `ops.moe_route` is told beyond the older routers' call
+        self.more = {} if normalize and not with_scores else {
+            "normalize": normalize, "with_scores": with_scores}
         self.weight = self.create_parameter((hidden, num_experts),
                                             attr=Normal(std=std))
 
     def forward(self, x):
         return ops.moe_route(x, self.weight, self.top_k, self.routed_scale,
-                             self.score)
+                             self.score, **self.more)
 
 
 class _MLPRouter(Layer):
@@ -94,6 +97,14 @@ class SparseExpertFFN(Layer):
     `shared_gate=True`: the shared expert's output is multiplied by
     sigmoid(x w_g), w_g [hidden, 1] (`shared_expert_gate`), a token.
 
+    `router_normalize=False` (the linear routers): w = routed_scale * s,
+    the chosen scores as they are (`deepseek-v2-lite-e8`).
+    `aux="sequence_balance"`: forward returns a third value, the
+    layer's sequence-wise balance term (`ops.moe_sequence_balance`: over
+    a row of x [rows, T, hidden], sum_e f_e P_e, the rows' mean), for a
+    criterion to add to the loss. It runs over all `num_experts` router
+    outputs, so it is whole on a share.
+
     `held = (first, count)`: the experts this layer's weights are, of
     `num_experts`. The router keeps its `num_experts` outputs and routes
     over all of them; the layer computes its own experts' part of the
@@ -115,8 +126,14 @@ class SparseExpertFFN(Layer):
     def __init__(self, hidden, width, num_experts=256, top_k=8, held=None,
                  shared_width=512, routed_scale=2.5, std=0.02,
                  router_mlp=None, router_score="sigmoid",
-                 shared_gate=False):
+                 shared_gate=False, router_normalize=True, aux=None):
         super().__init__()
+        if aux not in (None, "sequence_balance") or (
+                router_mlp is not None and (aux or not router_normalize)):
+            raise ValueError(
+                f"SparseExpertFFN: aux {aux!r}; the balance term and "
+                "unnormalised weights are the linear routers'")
+        self.aux = aux
         first, count = held or (0, num_experts)
         if first < 0 or count < 1 or first + count > num_experts:
             raise ValueError(f"held {held} is no range of {num_experts} "
@@ -125,7 +142,8 @@ class SparseExpertFFN(Layer):
         self.first, self.count = first, count
         if router_mlp is None:
             self.router = _Router(hidden, num_experts, top_k, routed_scale,
-                                  std, router_score)
+                                  std, router_score, router_normalize,
+                                  aux is not None)
         else:
             if top_k != 1:
                 raise ValueError("the MLP router chooses one expert")
@@ -142,6 +160,10 @@ class SparseExpertFFN(Layer):
             if shared_gate else None
         self._scores_note = ", softmax scores" \
             if router_mlp is None and router_score == "softmax" else ""
+        if not router_normalize:
+            self._scores_note += ", weights as scored"
+        if aux:
+            self._scores_note += ", sequence balance term"
 
     def forward(self, x, state=None):
         from ...kernels.pallas.grouped_matmul import (ROW_TILE, gmm_path,
@@ -154,6 +176,8 @@ class SparseExpertFFN(Layer):
             weights, experts, state = self.router(
                 flat, ops.reshape(state, (-1, state.shape[-1])))
             state = ops.reshape(state, tuple(shape[:-1]) + (-1,))
+        elif self.aux:
+            weights, experts, scores = self.router(flat)
         else:
             weights, experts = self.router(flat)
         perf.trace_note("moe", f"{gmm_path()}, experts {self.count} held "
@@ -170,6 +194,9 @@ class SparseExpertFFN(Layer):
             if self.shared_expert_gate is not None:
                 shared = shared * ops.sigmoid(self.shared_expert_gate(x))
             y = y + shared
+        if self.aux:
+            rows = 1 if len(shape) < 3 else int(np.prod(shape[:-2]))
+            return y, counts, ops.moe_sequence_balance(scores, experts, rows)
         return (y, counts, state, weights, experts) if carries \
             else (y, counts)
 

@@ -39,7 +39,8 @@ from .random import (  # noqa: F401
 from .longtail import *  # noqa: F401,F403
 from .nn_ops import *  # noqa: F401,F403
 from .ssm_ops import selective_scan, causal_conv1d  # noqa: F401
-from .moe_ops import moe_route, moe_route_mlp, moe_experts  # noqa: F401
+from .moe_ops import (moe_route, moe_route_mlp, moe_sequence_balance,  # noqa: F401
+                      moe_experts)
 from .cca_ops import cca_mix  # noqa: F401
 from .linear_attn_ops import gated_delta_rule, gdn_operands  # noqa: F401
 from .rope_ops import rope_rotate_half  # noqa: F401

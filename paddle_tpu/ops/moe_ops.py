@@ -29,14 +29,16 @@ from ..kernels.pallas import moe_sum_rows as _sr
 from ..kernels.pallas.flash_attention import _pallas_available
 from .registry import register_op
 
-__all__ = ["moe_route", "moe_route_mlp", "moe_experts"]
+__all__ = ["moe_route", "moe_route_mlp", "moe_sequence_balance",
+           "moe_experts"]
 
 _CHUNK = 2048       # rows a loop iteration gathers or activates
 F32 = jnp.float32
 
 
 @register_op("moe_route", amp_policy="black")
-def moe_route(x, router_weight, top_k, routed_scale=1.0, score="sigmoid"):
+def moe_route(x, router_weight, top_k, routed_scale=1.0, score="sigmoid",
+              normalize=True, with_scores=False):
     """x [T, d], router_weight [d, E] -> (weights [T, top_k] float32,
     experts [T, top_k] int32): scores = sigmoid(x W), or with
     `score="softmax"` softmax(x W) over all E, in float32 (the product
@@ -46,14 +48,43 @@ def moe_route(x, router_weight, top_k, routed_scale=1.0, score="sigmoid"):
     by routed_scale (of a softmax that is the softmax over the chosen
     logits). On amp's black list. The sigmoid is the router of
     `laguna-xs2-l5-e64`, the softmax of `qwen3-next-80b-l4-e64`;
-    `zaya1-8b-l5-e8`'s is `moe_route_mlp`."""
+    `zaya1-8b-l5-e8`'s is `moe_route_mlp`.
+
+    `normalize=False`: the weights are the chosen scores themselves times
+    routed_scale, not divided by their sum (`norm_topk_prob: false`;
+    `deepseek-v2-lite-e8`). `with_scores=True`: the scores [T, E] are a
+    third output, for a balance loss over all E."""
     squash = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}[score]
     scores = squash(jnp.matmul(
         x.astype(F32), router_weight.astype(F32),
         precision=jax.lax.Precision.HIGHEST))
     top, experts = _top_k(scores, top_k)
-    weights = top * (routed_scale / jnp.sum(top, axis=-1, keepdims=True))
-    return weights, experts
+    if normalize:
+        weights = top * (routed_scale
+                         / jnp.sum(top, axis=-1, keepdims=True))
+    else:
+        weights = top * routed_scale
+    return (weights, experts, scores) if with_scores else (weights, experts)
+
+
+@register_op("moe_sequence_balance", amp_policy="black")
+def moe_sequence_balance(scores, experts, rows=1):
+    """The sequence-wise balance term of a router (DeepSeek-V2,
+    arXiv:2405.04434, eq. 12-14; `seq_aux`): scores [rows * T, E] the
+    router's scores over ALL E experts, experts [rows * T, K] the chosen
+    ones. Over each row of T tokens f_e = E / (K T) * (the tokens that
+    chose e) and P_e = mean_t scores[t, e]; returns the rows' mean of
+    sum_e f_e P_e, float32 (1 where the load is even). The counts are
+    constants: the gradient reaches the router through P alone."""
+    E, K = scores.shape[-1], experts.shape[-1]
+    s = scores.astype(F32).reshape(rows, -1, E)
+    chosen = experts.reshape(rows, -1, K)
+    lanes = jnp.arange(E, dtype=jnp.int32)
+    chose = sum((chosen[..., j, None] == lanes).astype(F32)
+                for j in range(K))                          # [rows, T, E]
+    f = jnp.sum(chose, axis=1) * (E / (K * s.shape[1]))
+    return jnp.mean(jnp.sum(jax.lax.stop_gradient(f) * jnp.mean(s, axis=1),
+                            axis=-1))
 
 
 @register_op("moe_route_mlp", amp_policy="black")

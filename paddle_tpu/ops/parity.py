@@ -299,7 +299,18 @@ BEYOND_YAML = {
     "moe_route": (
         "ops/moe_ops.py", "-",
         "nn.SparseExpertFFN: sigmoid scores (models.laguna), softmax "
-        "scores (models.qwen3_next)"),
+        "scores (models.qwen3_next), softmax scores as they are "
+        "(normalize=False) and handed out (with_scores=True: "
+        "models.deepseek_v2)"),
+    "moe_sequence_balance": (
+        "ops/moe_ops.py", "-",
+        "nn.SparseExpertFFN(aux=\"sequence_balance\") (models.deepseek_v2)"),
+    "fused_flash_attention_two_part_key": (
+        "incubate/nn/functional/__init__.py",
+        "kernels/pallas/flash_attention.py (flash_mla_fwd, "
+        "flash_mla_bwd_transpose)",
+        "incubate.nn.functional.causal_attention(shared=): "
+        "nn.MultiHeadLatentAttention (models.deepseek_v2)"),
     "moe_route_mlp": ("ops/moe_ops.py", "-",
                       "nn.SparseExpertFFN(router_mlp=...) (models.zaya)"),
     "moe_experts": (
@@ -311,7 +322,8 @@ BEYOND_YAML = {
         "cca_mix_bwd)", "nn.CompressedConvAttention (models.zaya)"),
     "rope_rotate_half": (
         "ops/rope_ops.py", "kernels/pallas/rope.py (rope_rotate)",
-        "models.laguna, models.zaya, models.qwen3_next"),
+        "models.laguna, models.zaya, models.qwen3_next, models.ouro; "
+        "nn.MultiHeadLatentAttention (the composite: a head of 64)"),
     "gated_delta_rule": (
         "ops/linear_attn_ops.py", "kernels/pallas/gated_delta.py "
         "(gdn_prepare_fwd, gdn_prepare_bwd, gdn_state_fwd, gdn_state_bwd)",

@@ -471,6 +471,11 @@ class TestRegistryCoverage:
         # causal_conv1d, silu and the unit norms, values and both
         # gradients; the kernels in the interpreter)
         "gdn_operands",
+        # covered by tests/test_deepseek_v2_model.py (against plain
+        # attention on keys assembled at both parts' width, values and
+        # all five gradients; the kernels in the interpreter) and
+        # benchmarks/tests/test_deepseek_v2.py (against the reference)
+        "fused_flash_attention_two_part_key",
     }
 
     def test_coverage_accounting(self):

@@ -1146,3 +1146,137 @@ def test_the_ouro_step_fits_the_chip_and_says_which_paths_it_took(ouro_step):
                         "bwd 136/256 of 256-wide tiles, dq partials 4",
         "rope": "rope_rotate: 16 heads, rot 128 of 128",
         "head_loss": "fused, chunks 8, rows 32768"}
+
+
+# ---------------------------------------------------------------------------
+# DeepSeek-V2-Lite: flash with a key in two parts (16 heads of 128 beside
+# ONE shared rotary head of 64, values of 128) over one row of 32768 keys,
+# and a step of the dense layer and one sparse layer at the cell's widths
+# ---------------------------------------------------------------------------
+DS_SEQ, DS_HEADS, DS_ROPE = 32768, 16, 64       # deepseek-v2-lite-e8
+
+
+def test_flash_attention_with_a_key_in_two_parts_compiles(v5e):
+    """The forward and the one backward kernel at the cell's shape; the
+    shared head goes in at one slab's width and never a head's copy, and
+    dq leaves in four partials."""
+    one = SingleDeviceSharding(v5e[0])
+
+    def S(heads, d):
+        return jax.ShapeDtypeStruct((1, DS_SEQ, heads, d), jnp.bfloat16,
+                                    sharding=one)
+
+    shapes = (S(DS_HEADS, D), S(DS_HEADS, DS_ROPE), S(DS_HEADS, D),
+              S(1, DS_ROPE), S(DS_HEADS, D))
+    assert fa._shape_reject_reason(
+        shapes[0].shape, shapes[2].shape,
+        (shapes[1].shape, shapes[3].shape), shapes[4].shape) is None
+
+    def loss(*xs):
+        return fa._flash_core(xs, None, True, 0.11472,
+                              True).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *shapes).compile()
+    text = compiled.as_text()
+    for kernel in ("flash_mla_fwd", "flash_mla_bwd_transpose"):
+        assert kernel in text, kernel
+    assert text.count("tpu_custom_call") == 2
+    # the shared keys at [1, 32768, 128]; no [.., 16, 64] copy of them
+    assert "bf16[1,32768,128]" in text
+    assert fa._fit_shared_bwd(DS_SEQ, 512, D, DS_ROPE, 2) == DS_SEQ // 4
+    # q's rows, dq's four partials and the rest: under 2 GiB
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30
+
+
+@pytest.fixture(scope="module")
+def deepseek_step(v5e):
+    """Two layers at DeepSeek-V2-Lite's widths (the dense layer and one
+    sparse one, 2 of its 64 experts held, a fiftieth of the vocabulary,
+    1 x 4096 tokens), the step written as
+    benchmarks/drivers/deepseek_v2_train_window.py writes it, compiled
+    for one described v5e: (text, compile record)."""
+    import paddle_tpu as pt
+    from paddle_tpu import amp
+    from paddle_tpu.jit import TrainStep
+    from paddle_tpu.models.deepseek_v2 import (
+        DeepseekV2Config, DeepseekV2ForCausalLM,
+        DeepseekV2PretrainingCriterion)
+    from paddle_tpu.observability import perf
+    from paddle_tpu.optimizer import AdamW
+    one = SingleDeviceSharding(v5e[0])
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
+
+    crit = DeepseekV2PretrainingCriterion(0.001)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("PADDLE_TPU_PALLAS_AUTOTUNE", "0")
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        was_on = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        with pt.LazyGuard():
+            model = DeepseekV2ForCausalLM(DeepseekV2Config(
+                vocab_size=2048, num_hidden_layers=2, experts_held=(0, 2),
+                use_flash_attention=True, recompute=True))
+        model.train()
+
+        def loss_fn(m, ids, labels):
+            with amp.auto_cast(enable=True, level="O1", dtype="bfloat16"):
+                logits = m(ids)
+            loss, balance = crit(logits, labels, m.balance_terms)
+            return loss, (m.expert_counts, balance)
+
+        step = TrainStep(model, AdamW(
+            learning_rate=1e-4, parameters=model.parameters(),
+            moment_dtype="bfloat16"), loss_fn, has_aux=True)
+        ids = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=one)
+        notes = {}
+        outer, perf._TRACE_NOTES.notes = perf._TRACE_NOTES.notes, notes
+        try:
+            compiled = step._step_fn.jit_fn.lower(
+                [spec(p) for p in step.params],
+                [{k: spec(v) for k, v in st.items()}
+                 for st in step.opt_states],
+                [spec(b) for b in step.buffers],
+                spec(jax.random.PRNGKey(0)), spec(jnp.float32(1e-4)),
+                [ids, ids], {}).compile()
+        finally:
+            perf._TRACE_NOTES.notes = outer
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        compilation_cache.reset_cache()
+    return compiled.as_text(), notes
+
+
+@pytest.mark.parametrize("kernel,calls", [
+    ("flash_mla_fwd", 2),   # a layer: once, its block keeps o and lse
+    ("flash_mla_bwd_transpose", 2),
+    ("moe_gmm", 6),         # the sparse layer's two products: forward,
+    ("moe_gmm_dw", 2)])     # again, to rows; and to weights
+def test_the_deepseek_step_holds_its_mosaic_kernels(deepseek_step, kernel,
+                                                    calls):
+    text, _notes = deepseek_step
+    found = re.findall(rf"%{kernel}[.\d]* = .*custom-call\(", text)
+    assert len(found) == calls, (kernel, len(found))
+    assert not re.findall(r"%flash_fwd[.\d]* = .*custom-call\(", text)
+
+
+def test_the_deepseek_step_says_which_paths_it_took(deepseek_step):
+    text, notes = deepseek_step
+    for scope in ("layers/0/attn/mla_latent", "layers/1/attn/mla_latent",
+                  "layers/1/attn/rope", "layers/1/moe/router",
+                  "layers/0/mlp/down_proj"):
+        assert f"model/{scope}/" in text, scope
+    assert notes["attention"] == "pallas, two-part key"
+    assert notes["flash_operands"] == "key in two parts"
+    assert notes["flash_kept"] == ("o and lse kept across recompute in 2 of "
+                                   "2 recomputed layers")
+    assert notes["flash_causal"] == (
+        "fwd 24/32 of 256-wide tiles, key in two parts (128 a head + 64 "
+        "shared); bwd 72/128 of 256-wide tiles, dq whole, key in two parts")
+    assert notes["moe"].startswith("pallas, experts 2 held of 64, top 6")
+    assert notes["moe"].endswith(
+        "softmax scores, weights as scored, sequence balance term")
+    assert notes["rope"].startswith("composite: ")
+    assert notes["head_loss"] == "fused, chunks 1"
