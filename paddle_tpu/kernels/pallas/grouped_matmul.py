@@ -23,12 +23,21 @@ over unaligned groups would compute the straddling tile twice.
 A weight block is a group's whole [K, N] where that is at most
 `_BLOCK_BYTES` (4 MiB: [2048, 1024] in bf16, the largest the thin-expert
 shapes have, two of which the pipeline holds beside the row tiles). A
-wider expert's is cut into column tiles of that size (`column_tile`),
-and the grid gains an outer axis over them: for one column tile the
-kernel walks all the row tiles, so a group's consecutive tiles still
-name one weight block and it is fetched once, and the rows are read
-once a column tile. `moe_gmm_dw`'s float32 accumulator is one column
-tile wide the same way.
+wider expert's is cut into the fewest column tiles that budget allows
+(`column_tile`: balanced multiples of 128, which need not divide the
+columns), and the grid gains an outer axis over them: for one column
+tile the kernel walks all the row tiles, so a group's consecutive tiles
+still name one weight block and it is fetched once, and the rows are
+read once a column tile: every column step is one more pass over the
+rows, which is why the steps are the fewest and not the tile the
+largest divisor (1408 = 11 x 128 columns had eleven tiles of 128 that
+way). `moe_gmm_dw`'s float32 accumulator is one column tile wide the
+same way. Where the tile does not divide the columns the last block is
+ragged: it hangs over the array's edge. Every product here has
+independent columns (`out[r, n]`, `dx[r, n]` and `dw[g, k, n]` each
+depend on column n of one operand alone), so whatever the edge block
+reads past the array lands only in output columns that the edge
+block's write drops.
 
 Shapes are static and sized by the caller for its worst case; the
 tiles past the used prefix are not visited: their grid steps name the
@@ -59,26 +68,17 @@ F32 = jnp.float32
 
 def column_tile(contract, cols, itemsize):
     """Columns of a weight block [contract, columns]: all `cols` where
-    that is at most `_BLOCK_BYTES`, else the largest multiple of 128
-    that divides `cols` and keeps the block within it (128 at least)."""
+    that is at most `_BLOCK_BYTES`, else a multiple of 128 (128 at
+    least): the fewest tiles whose block stays within it, balanced, so
+    that only the last tile is short. A divisor of `cols` that gives as
+    few tiles is what comes out ([2048, 4096] in bf16: four of 1024);
+    where none does ([2048, 2816]: its best divisor, 256, makes eleven)
+    the tiles are 1024, 1024 and a ragged 768."""
     if contract * cols * itemsize <= _BLOCK_BYTES or cols % 128:
         return cols
-    best = 128
-    for t in range(128, cols, 128):
-        if cols % t == 0 and contract * t * itemsize <= _BLOCK_BYTES:
-            best = t
-    return best
-
-
-def tiles_note(gate_up_shape, itemsize=2):
-    """For the `moe` note: how the two products' weight blocks are cut
-    into column tiles, or nothing where each is a group's whole."""
-    _g, hidden, two_wide = gate_up_shape
-    cut = (column_tile(hidden, two_wide, itemsize),
-           column_tile(two_wide // 2, hidden, itemsize))
-    if cut == (two_wide, hidden):
-        return ""
-    return f", weight blocks in column tiles of {cut[0]} and {cut[1]}"
+    widest = max(_BLOCK_BYTES // (contract * itemsize * 128), 1) * 128
+    steps = pl.cdiv(cols, widest)
+    return pl.cdiv(cols, steps * 128) * 128
 
 
 def group_layout(group_sizes, n_tiles, row_tile=ROW_TILE):
@@ -160,7 +160,7 @@ def _gmm_call(x, w, tile_group, used, *, transpose_w, interpret):
     R, K = x.shape
     N = w.shape[1] if transpose_w else w.shape[2]
     cols = column_tile(K, N, x.dtype.itemsize)
-    grid, semantics, ij = _grid(R // ROW_TILE, N // cols)
+    grid, semantics, ij = _grid(R // ROW_TILE, pl.cdiv(N, cols))
 
     def rows(*a):
         _j, i, _g, u = ij(*a)
@@ -203,7 +203,7 @@ def _dw_call(x, dy, tile_group, used, *, groups, interpret):
     R, K = x.shape
     N = dy.shape[1]
     cols = column_tile(K, N, x.dtype.itemsize)
-    grid, semantics, ij = _grid(R // ROW_TILE, N // cols)
+    grid, semantics, ij = _grid(R // ROW_TILE, pl.cdiv(N, cols))
     # one entry past the table's end for the kernel's look at tile i + 1
     table = jnp.concatenate([tile_group, tile_group[-1:]])
 
@@ -321,3 +321,22 @@ def gmm(x, w, group_sizes, interpret=False):
     _starts, tile_group, used = group_layout(group_sizes, R // ROW_TILE)
     return _gmm(x, w.astype(x.dtype), tile_group, used.reshape(1),
                 bool(interpret or _pallas_available()), bool(interpret))
+
+
+def tiles_note(gate_up_shape, itemsize=2):
+    """For the `moe` note: how the weight blocks of the two products and
+    of each one's gradient to the rows are cut into column tiles
+    ("tile x steps", `moe_gmm_dw`'s are its forward product's), or
+    nothing where each is a group's whole."""
+    _g, hidden, two_wide = gate_up_shape
+    wide = two_wide // 2
+    products = ((hidden, two_wide), (wide, hidden),
+                (two_wide, hidden), (hidden, wide))
+    tiles = [column_tile(k, n, itemsize) for k, n in products]
+    if tiles == [n for _k, n in products]:
+        return ""
+    gate_up, down, gate_up_rows, down_rows = (
+        f"{t} x {pl.cdiv(n, t)}" + (f" (the last {n % t})" if n % t else "")
+        for t, (_k, n) in zip(tiles, products))
+    return (f", weight blocks in column tiles: gate_up {gate_up}, down "
+            f"{down}, to the rows {gate_up_rows} and {down_rows}")

@@ -102,14 +102,18 @@ def test_rows_must_be_whole_tiles():
     (2048, 2048, (2, 2, 2)),    # ... and its down
     (2048, 1024, (1, 1, 1)),    # laguna-xs2's: a group's whole block,
     (512, 2048, (1, 1, 1)),     # the grid over the row tiles alone
-])
+    (2048, 2816, (3, 4, 3)),    # deepseek-v2-lite's gate_up, 22 x 128
+    (1408, 2048, (2, 2, 2)),    # columns, and its down, 11 x 128 rows: no
+])                              # divisor fits, the last tile is ragged
 def test_wide_weight_blocks_are_cut_into_column_tiles(K, N, tiles):
-    """bf16 at the two cells' widths, the kernels run by the
+    """bf16 at the three cells' widths, the kernels run by the
     interpreter: the product, its gradient to the rows (the weight's
     other axis cut) and `moe_gmm_dw` (its accumulator one column tile
     wide) against a loop over the groups on the same rounded operands."""
-    assert (N // gm.column_tile(K, N, 2), K // gm.column_tile(N, K, 2),
-            N // gm.column_tile(K, N, 2)) == tiles
+    steps = gm.pl.cdiv
+    assert (steps(N, gm.column_tile(K, N, 2)),
+            steps(K, gm.column_tile(N, K, 2)),
+            steps(N, gm.column_tile(K, N, 2))) == tiles
     sizes, starts, live, x, w, _used = _case([130, 0, 5], K=K, N=N,
                                              spare_tiles=1)
     x, w = x.astype(jnp.bfloat16), (w / np.sqrt(K)).astype(jnp.bfloat16)
@@ -144,6 +148,22 @@ def test_column_tile_keeps_a_block_within_four_mebibytes():
     assert gm.column_tile(2048, 1024, 2) == 1024    # whole
     assert gm.column_tile(64, 128, 4) == 128
     assert gm.column_tile(4096, 1000, 4) == 1000    # no multiple of 128
-    assert gm.tiles_note((8, 2048, 1024)) == ""
-    assert gm.tiles_note((8, 2048, 4096)) == (
-        ", weight blocks in column tiles of 1024 and 1024")
+    # the fewest steps within the budget, not the largest divisor
+    assert gm.column_tile(2048, 2816, 2) == 1024    # 1024, 1024, 768
+    assert gm.column_tile(2048, 1408, 2) == 768     # 768, 640
+    assert gm.column_tile(1408, 2048, 2) == 1024
+    assert gm.column_tile(2816, 2048, 2) == 512
+    assert gm.column_tile(2048, 1408, 4) == 512     # 512, 512, 384
+    assert gm.column_tile(512, 2048, 2) == 2048     # whole
+
+
+@pytest.mark.parametrize("shape,note", [
+    ((64, 2048, 1024), ""),         # laguna-xs2's and qwen3-next's: whole
+    ((8, 2048, 4096), ", weight blocks in column tiles: gate_up 1024 x 4, "
+     "down 1024 x 2, to the rows 512 x 4 and 1024 x 2"),    # zaya1-8b's
+    ((8, 2048, 2816), ", weight blocks in column tiles: gate_up 1024 x 3 "
+     "(the last 768), down 1024 x 2, to the rows 512 x 4 and 768 x 2 "
+     "(the last 640)"),                                 # deepseek-v2-lite's
+])
+def test_tiles_note_names_the_four_products_tiles(shape, note):
+    assert gm.tiles_note(shape) == note

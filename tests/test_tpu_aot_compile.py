@@ -577,15 +577,21 @@ def test_the_laguna_steps_way_back_gathers_no_slot(laguna_step):
 ZAYA_SEQ, ZAYA_HEADS, ZAYA_KV = 32768, 8, 2             # zaya1-8b-l5-e8
 
 
-@pytest.mark.parametrize("K,N,tiles", [(2048, 4096, 4), (2048, 2048, 2)])
-def test_grouped_matmul_at_wide_experts_compiles(v5e, K, N, tiles):
+@pytest.mark.parametrize("K,N,tiles,choices", [
+    (2048, 4096, 4, 1), (2048, 2048, 2, 1),
+    (2048, 2816, 3, 6), (1408, 2048, 2, 6)])
+def test_grouped_matmul_at_wide_experts_compiles(v5e, K, N, tiles, choices):
     """ZAYA1-8B's gate_up (16 MB a group in bf16) and down (8 MB), 8 held
     experts and the worst case's rows of one 32768-token row at one
     choice a token: `moe_gmm` forward and to the rows, `moe_gmm_dw`,
-    their weight blocks cut into column tiles of 4 MB."""
+    their weight blocks cut into column tiles of 4 MB. And
+    DeepSeek-V2-Lite's at six choices a token, 22 x 128 and 11 x 128
+    columns: no divisor fits, so the last column tile is ragged on each
+    of the three layouts (the weight's last axis, its middle axis for
+    the transposed product, the lanes of dy and of the outputs)."""
     one = SingleDeviceSharding(v5e[0])
-    rows = gmm.padded_rows(ZAYA_SEQ, 8)
-    assert N // gmm.column_tile(K, N, 2) == tiles
+    rows = gmm.padded_rows(ZAYA_SEQ * choices, 8)
+    assert gmm.pl.cdiv(N, gmm.column_tile(K, N, 2)) == tiles
 
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
@@ -776,7 +782,8 @@ def test_the_zaya_step_says_which_paths_it_took(zaya_step):
         "flash_causal": "fwd 136/256 of 256-wide tiles; "
                         "bwd 136/256 of 256-wide tiles, dq whole",
         "moe": "pallas, experts 2 held of 16, top 1, tiles of 128 rows, "
-               "weight blocks in column tiles of 1024 and 1024, "
+               "weight blocks in column tiles: gate_up 1024 x 4, down "
+               "1024 x 2, to the rows 512 x 4 and 1024 x 2, "
                "way back: held rows in windows of 16 (moe_sum_rows)",
         "rope": "rope_rotate: 8 heads, rot 64 of 128; "
                 "rope_rotate: 2 heads, rot 64 of 128",
@@ -1275,7 +1282,10 @@ def test_the_deepseek_step_says_which_paths_it_took(deepseek_step):
     assert notes["flash_causal"] == (
         "fwd 24/32 of 256-wide tiles, key in two parts (128 a head + 64 "
         "shared); bwd 72/128 of 256-wide tiles, dq whole, key in two parts")
-    assert notes["moe"].startswith("pallas, experts 2 held of 64, top 6")
+    assert notes["moe"].startswith(
+        "pallas, experts 2 held of 64, top 6, tiles of 128 rows, weight "
+        "blocks in column tiles: gate_up 1024 x 3 (the last 768), down "
+        "1024 x 2, to the rows 512 x 4 and 768 x 2 (the last 640), ")
     assert notes["moe"].endswith(
         "softmax scores, weights as scored, sequence balance term")
     assert notes["rope"].startswith("composite: ")
