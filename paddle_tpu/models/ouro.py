@@ -20,9 +20,13 @@ Language Models", Zhu et al., 2025).
   tokens of `sum_t p_t CE_t - beta H(p)`.
 
 The loop is `recompute.scan_passes`: one `jax.lax.scan` over the passes
-whose body holds each layer once (recomputed, where `recompute` is set,
-with the flash kernel's outputs kept) and the final norm; its stacked
-outputs are the T normed streams. There is no written-out form.
+whose body holds each layer once and the final norm; its stacked
+outputs are the T normed streams. There is no written-out form. Where
+`recompute` is set a layer is recomputed, and keeps beside its input
+the flash kernel's `o` and `lse` and `down_proj`'s output
+(`OuroDecoderLayer.branch_outputs`): the norm inside the branch reads
+that output in its backward, and the block would make the whole product
+again for it. `o_proj`'s output is named the same way and not kept.
 
 `OuroForCausalLM.forward` returns (logits, gate logits): the logits of
 all passes [T, batch, seq, vocab] as ONE promise in a traced training
@@ -39,6 +43,8 @@ from dataclasses import dataclass, fields
 from .. import ops
 from ..amp import auto_cast
 from ..core.tensor import DeferredTensor
+from ..distributed.meta_parallel.recompute import (
+    ATTN_OUT, MLP_OUT, branch_output, layer_calls, recompute, scan_passes)
 from ..incubate.nn.functional import causal_attention
 from ..nn.initializer import Constant, Normal
 from ..nn.layer import Layer, traced_scope
@@ -166,7 +172,15 @@ class OuroAttention(Layer):
 
 class OuroDecoderLayer(Layer):
     """The sandwich: a norm before and a norm after each sublayer, the
-    second inside the residual branch."""
+    second inside the residual branch. That norm's backward reads its
+    input, the branch's last product: the layer names both
+    (`branch_output`), and a recomputed layer keeps `down_proj`'s, which
+    buys three times what `o_proj`'s does for the same bytes (32 ms a GB
+    against 11, where the stack itself costs 15: `recompute.py`'s
+    table); with both the cell's step also reads over the fit guard
+    (`tests/test_tpu_aot_compile.py`)."""
+
+    branch_outputs = (MLP_OUT,)
 
     def __init__(self, config: OuroConfig):
         super().__init__()
@@ -180,10 +194,10 @@ class OuroDecoderLayer(Layer):
         self.post_attention_layernorm_2 = RMSNorm(h, epsilon=eps)
 
     def forward(self, x, cos, sin):
-        x = x + self.input_layernorm_2(
-            self.attn(self.input_layernorm(x), cos, sin))
-        return x + self.post_attention_layernorm_2(
-            self.mlp(self.post_attention_layernorm(x)))
+        x = x + self.input_layernorm_2(branch_output(
+            self.attn(self.input_layernorm(x), cos, sin), ATTN_OUT))
+        return x + self.post_attention_layernorm_2(branch_output(
+            self.mlp(self.post_attention_layernorm(x)), MLP_OUT))
 
 
 class OuroModel(Layer):
@@ -206,8 +220,6 @@ class OuroModel(Layer):
         """The stack once and the final norm: the loop's body."""
         cfg = self.config
         remat = cfg.recompute and self.training
-        from ..distributed.meta_parallel.recompute import (
-            layer_calls, recompute)
         for call in layer_calls(self.layers, remat, cfg.recompute_interval):
             x = call(x, cos, sin)
         if remat:
@@ -221,7 +233,6 @@ class OuroModel(Layer):
         x = self.embed_tokens(input_ids)
         cos, sin = rope_tables(input_ids.shape[1], cfg.head_dim,
                                rope_theta=cfg.rope_theta)
-        from ..distributed.meta_parallel.recompute import scan_passes
         perf.trace_note("ut_loop", f"scan, {cfg.total_ut_steps} x "
                         f"{cfg.num_hidden_layers} layers")
         with traced_scope("ut_loop"):

@@ -390,6 +390,7 @@ def _note_compile(family: str, parts: dict, outcome: str,
 
 class _TraceNotes(threading.local):
     notes = None    # a dict while a CompileTimed's first call runs here,
+    options = None  # and one for what the traced code asks of the compile,
     family = None   # that CompileTimed's family
     step = None     # and the TrainStep step that made the call, if one
     scopes = None   # a dict while that call traces its program
@@ -416,6 +417,19 @@ def trace_note(key: str, value: str) -> None:
             notes[key] = value
         elif value not in seen.split("; "):
             notes[key] = f"{seen}; {value}"
+
+
+def trace_compile_option(key: str, value) -> None:
+    """From code that runs while a program is traced: an option for the
+    compile of that program (`lowered.compile(compiler_options=...)`),
+    where the traced code knows of its own structure what the compiler
+    cannot (`scan_passes`, distributed/meta_parallel/recompute.py). The
+    `CompileTimed` whose first call is tracing on this thread compiles
+    with it and says so in `compile_record(family)["compile_options"]`;
+    outside such a call it is dropped, as a `trace_note` is."""
+    options = _TRACE_NOTES.options
+    if options is not None:
+        options[key] = value
 
 
 class _Timed:
@@ -885,7 +899,7 @@ class CompileTimed:
         out = None
         ran = False
         parts = {"lower": 0.0, "backend": 0.0, "first_run": 0.0}
-        notes, scopes = {}, {}
+        notes, scopes, options = {}, {}, {}
         th = _TRACE_NOTES
         step = _in_flight(1)[1]
 
@@ -894,17 +908,17 @@ class CompileTimed:
             # phase of the set-up record, its seconds kept for the
             # family's compile_record beside what the traced code noted
             # of itself (`trace_note`, and `trace_timed` while it traces)
-            outer = th.notes, th.family, th.step, th.scopes
+            outer = th.notes, th.family, th.step, th.scopes, th.options
             th.notes, th.family, th.step = notes, self.family, step
             if part in ("lower", "trace"):
-                th.scopes = scopes
+                th.scopes, th.options = scopes, options
             phase = setup_phase(f"{self.family}.{part}",
                                 span="compile." + part, family=self.family)
             try:
                 with phase:
                     return fn(*a)
             finally:
-                th.notes, th.family, th.step, th.scopes = outer
+                th.notes, th.family, th.step, th.scopes, th.options = outer
                 parts[part] = parts.get(part, 0.0) + phase.seconds
 
         def lower():
@@ -936,8 +950,14 @@ class CompileTimed:
         if compiled is None:
             try:
                 lowered = timed("lower", lower)
+                compile_ = lowered.compile
+                if options:     # what the traced code asked for
+                    compile_ = functools.partial(
+                        compile_, compiler_options=dict(options))
+                    notes["compile_options"] = "; ".join(
+                        f"{k}={v}" for k, v in sorted(options.items()))
                 # a cache load when jax's persistent cache hits
-                compiled = timed("backend", lowered.compile)
+                compiled = timed("backend", compile_)
             except Exception:
                 compiled = None     # fall back to plain jit dispatch
             else:

@@ -152,8 +152,9 @@ def test_a_training_stack_recomputes_by_its_interval(monkeypatch, name,
                                                      interval):
     """Every family's loop is the one walk: layer i is recomputed where
     i % interval == 0, once a forward (Ouro's passes are one scanned
-    body), and only a layer with an `attn` that sees every key keeps
-    its flash outputs."""
+    body), only a layer with an `attn` that sees every key keeps its
+    flash outputs, and only a layer that declares `branch_outputs` those
+    (then the policy is the names)."""
     pt.seed(0)
     model, layers = _stack(name, recompute=True, use_flash_attention=True,
                            recompute_interval=interval)
@@ -163,7 +164,11 @@ def test_a_training_stack_recomputes_by_its_interval(monkeypatch, name,
     for i, policy in seen:
         attn = getattr(layers[i], "attn", None)
         full = attn is not None and getattr(attn, "window", None) is None
-        assert policy == ("flash_outputs" if full else None), (i, policy)
+        branch = getattr(layers[i], "branch_outputs", ())
+        if branch:      # Ouro's sandwich: the names, the branch's among them
+            assert full and policy == (fa.FLASH_O, fa.FLASH_LSE) + branch
+        else:
+            assert policy == ("flash_outputs" if full else None), (i, policy)
 
 
 @pytest.mark.parametrize("name", FAMILIES)

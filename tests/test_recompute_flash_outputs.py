@@ -3,7 +3,13 @@ flash forward kernel's `o` and `lse` (`FLASH_O`, `FLASH_LSE`), so its
 backward holds no second forward kernel; the three models ask for it in
 a layer whose attention has no window. On the CPU, the kernels
 interpreted at the smallest sizes they take. The compiled steps' kernel
-counts at the models' widths are `tests/test_tpu_aot_compile.py`'s."""
+counts at the models' widths are `tests/test_tpu_aot_compile.py`'s.
+
+And what a layer keeps where its class declares `branch_outputs` (the
+products a norm inside a residual branch reads: Ouro's sandwich): the
+policy of names, the note's count of layers and bytes, and that every
+other family's step is what it was (Ouro's own bits and products:
+`tests/test_ouro_model.py`)."""
 import functools
 import re
 from importlib import import_module
@@ -13,22 +19,24 @@ import numpy as np
 import pytest
 
 import paddle_tpu as pt
-from paddle_tpu import nn, ops
+from paddle_tpu import models, nn, ops
 from paddle_tpu.jit import TrainStep
 from paddle_tpu.models import (GPTForCausalLM, GPTPretrainingCriterion,
                                JambaForCausalLM, LagunaForCausalLM,
-                               ZayaForCausalLM)
+                               OuroDecoderLayer, OuroForCausalLM,
+                               ZayaForCausalLM, ouro_tiny)
 from paddle_tpu.models.gpt import GPTConfig
 from paddle_tpu.models.jamba import JambaConfig
 from paddle_tpu.models.laguna import LagunaConfig
 from paddle_tpu.models.zaya import ZayaConfig
+from paddle_tpu.observability import perf
 from paddle_tpu.optimizer import AdamW
 
 fa = import_module("paddle_tpu.kernels.pallas.flash_attention")
 rc = import_module("paddle_tpu.distributed.meta_parallel.recompute")
 # the other kernels copy `fa._pallas_available` as they are imported: they
 # are imported here, before a test stands another in its place
-for _kernel in ("selective_scan", "grouped_matmul", "norms"):
+for _kernel in ("selective_scan", "grouped_matmul", "norms", "gated_delta"):
     import_module(f"paddle_tpu.kernels.pallas.{_kernel}")
 
 SEQ, VOCAB = 128, 256       # one q block of 64-wide heads: what the kernels take
@@ -248,20 +256,167 @@ def test_a_layer_keeps_where_every_key_is_in_sight(attention, policy):
     assert rc.flash_policy(attention) == policy
 
 
+def _notes(run):
+    """(`run()`, what it noted) as a traced first call would see it."""
+    notes = {}
+    outer, perf._TRACE_NOTES.notes = perf._TRACE_NOTES.notes, notes
+    try:
+        return run(), notes
+    finally:
+        perf._TRACE_NOTES.notes = outer
+
+
+SANDWICH = (fa.FLASH_O, fa.FLASH_LSE, rc.MLP_OUT)
+
+
 @pytest.mark.parametrize("policies,note", [
     (["flash_outputs"] * 5, "5 of 5"),
     (["flash_outputs", None, None, None, "flash_outputs"], "2 of 5"),
     ([None] * 3, "0 of 3"),
     ([], None),                                 # nothing recomputed
-], ids=["all", "some", "none", "no recompute"])
+    # sandwich layers: a clause behind the text, which stays as it was
+    ([SANDWICH] * 3, "3 of 3 recomputed layers, branch outputs a norm "
+                     "reads in 3 (4096 bytes a pass)"),
+    ([SANDWICH, "flash_outputs", None, (rc.ATTN_OUT, rc.MLP_OUT)],
+     "2 of 4 recomputed layers, branch outputs a norm reads in 2 "
+     "(4096 bytes a pass)"),
+], ids=["all", "some", "none", "no recompute", "sandwich", "mixed"])
 def test_the_note_counts_the_recomputed_layers_that_keep(policies, note):
-    from paddle_tpu.observability import perf
-    notes = {}
-    outer, perf._TRACE_NOTES.notes = perf._TRACE_NOTES.notes, notes
-    try:
-        rc.note_flash_kept(policies)
-    finally:
-        perf._TRACE_NOTES.notes = outer
+    _, notes = _notes(lambda: rc.note_flash_kept(policies, 4096))
+    if note is not None and "branch" not in note:
+        note += " recomputed layers"
     assert notes == ({} if note is None else {
-        "flash_kept": f"o and lse kept across recompute in {note} "
-                      "recomputed layers"})
+        "flash_kept": f"o and lse kept across recompute in {note}"})
+
+
+# -- a layer that declares branch outputs -------------------------------------
+class _Sandwich:
+    branch_outputs = (rc.MLP_OUT,)
+
+    def __init__(self, attention=None):
+        self.attn = attention
+
+
+@pytest.mark.parametrize("layer,policy", [
+    (_Sandwich(_Attention()), SANDWICH),
+    (_Sandwich(_Attention(flash=False)), (rc.MLP_OUT,)),
+    (_Sandwich(_Attention(window=512)), (rc.MLP_OUT,)),
+    (_Sandwich(), (rc.MLP_OUT,)),
+    (_Attention(attn=_Attention()), "flash_outputs"),   # declares nothing
+    (_Attention(), None),                               # ... and no `attn`
+], ids=["flash", "composite", "window", "no attention", "pre-norm",
+        "pre-norm, no attention"])
+def test_a_layers_policy_is_its_flash_policy_and_what_it_declares(layer,
+                                                                 policy):
+    assert rc.layer_policy(layer) == policy
+
+
+def test_a_policy_of_names_keeps_those_names_and_nothing_else():
+    """`recompute(..., policy=(names))`: the block's backward reads the
+    named value and makes the rest again; the bits are the block's."""
+    class Block(nn.Layer):
+        def __init__(self):
+            super().__init__()
+            self.first, self.second = nn.Linear(16, 32), nn.Linear(32, 16)
+
+        def forward(self, x):
+            return self.second(ops.tanh(
+                rc.branch_output(self.first(x), rc.MLP_OUT)))
+
+    pt.seed(0)
+    block = Block()
+    x = np.random.default_rng(0).standard_normal((4, 16)).astype(np.float32)
+    got = []
+    for policy in (None, (rc.MLP_OUT,), (rc.ATTN_OUT,)):
+        for p in block.parameters():
+            p.clear_grad()
+        inp = pt.to_tensor(x, stop_gradient=False)
+        out = rc.recompute(block, inp, policy=policy)
+        ops.mean(out ** 2).backward()
+        got.append([out.numpy(), inp.grad.numpy()]
+                   + [p.grad.numpy() for p in block.parameters()])
+    assert len(got[0]) == 6
+    for other in got[1:]:
+        for a, b in zip(got[0], other):
+            assert a.tobytes() == b.tobytes()
+
+    def dots(policy):
+        def loss(a):
+            with pt.no_grad():      # JAX's own gradient, past the tape
+                return rc.recompute(block, pt.to_tensor(a),
+                                    policy=policy)._data.sum()
+        return str(jax.make_jaxpr(jax.grad(loss))(x)).count("dot_general")
+
+    # to the input alone: both products forward, a product back each,
+    # and the first one again for tanh's slope, unless its output is kept
+    assert (dots(None), dots((rc.MLP_OUT,))) == (5, 4)
+    assert dots((rc.ATTN_OUT,)) == 5                # a name nobody gave
+
+
+@pytest.mark.parametrize("declared,layers,size", [
+    ((), 0, 0), ((rc.MLP_OUT,), 3, 1), ((rc.ATTN_OUT, rc.MLP_OUT), 3, 2)],
+    ids=["none", "down_proj's", "both"])
+def test_the_walk_counts_the_branch_outputs_a_sandwich_stack_keeps(
+        monkeypatch, declared, layers, size):
+    """One forward of `ouro_tiny` (three layers, recomputed): the note's
+    clause counts the layers that keep and the bytes of what they named
+    for their policy, float32 [2, 24, 64] an output here; a stack that
+    declares nothing says what it said."""
+    monkeypatch.setattr(OuroDecoderLayer, "branch_outputs", declared)
+    pt.seed(0)
+    model = OuroForCausalLM(ouro_tiny(recompute=True))
+    model.train()
+    ids, _ = _batch()
+    _, notes = _notes(lambda: model(pt.to_tensor(ids[:, :24].repeat(2, 0))))
+    said = "o and lse kept across recompute in 0 of 3 recomputed layers"
+    if layers:
+        said += (f", branch outputs a norm reads in {layers} "
+                 f"({size * 3 * 2 * 24 * 64 * 4} bytes a pass)")
+    assert notes["flash_kept"] == said
+
+
+FAMILIES = {"jamba": "JambaForCausalLM", "laguna": "LagunaForCausalLM",
+            "zaya": "ZayaForCausalLM", "qwen3_next": "Qwen3NextForCausalLM",
+            "deepseek_v2": "DeepseekV2ForCausalLM"}
+
+
+@pytest.mark.parametrize("name", sorted(FAMILIES))
+def test_a_family_that_declares_nothing_lowers_to_the_step_it_had(
+        monkeypatch, name):
+    """The five other families that walk `layer_calls`, at their tiny
+    configurations with every block recomputed and flash asked for: the
+    lowered `TrainStep`'s text under `layer_policy` and under
+    `flash_policy` of the layer's `attn` alone, the walk's policy before
+    a layer could declare branch outputs, is the same text."""
+    tiny = getattr(import_module(f"paddle_tpu.models.{name}"),
+                   f"{name}_tiny")
+
+    def lowered():
+        pt.seed(0)
+        model = getattr(models, FAMILIES[name])(tiny(
+            recompute=True, use_flash_attention=True))
+        model.train()
+        crit = GPTPretrainingCriterion()
+        step = TrainStep(
+            model, AdamW(learning_rate=1e-3, parameters=model.parameters()),
+            lambda m, ids, labels: crit(m(ids), labels))
+        toks = np.random.default_rng(0).integers(
+            0, 512, (1, 33)).astype(np.int32)
+        return step._step_fn.jit_fn.lower(
+            step.params, step.opt_states, step.buffers,
+            jax.random.PRNGKey(0), np.float32(1e-3),
+            [toks[:, :-1], toks[:, 1:]], {}).as_text()
+
+    asked = []
+    policy = rc.layer_policy
+
+    def spy(layer):
+        asked.append(policy(layer))
+        return asked[-1]
+
+    monkeypatch.setattr(rc, "layer_policy", spy)
+    text = lowered()
+    assert asked and set(asked) <= {None, "flash_outputs"}
+    monkeypatch.setattr(rc, "layer_policy", lambda layer: rc.flash_policy(
+        getattr(layer, "attn", None)))
+    assert len(text) > 10000 and lowered() == text

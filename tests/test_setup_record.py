@@ -305,6 +305,54 @@ def test_trace_raising_falls_back_with_trace_s_absent():
     assert "trace_s" not in perf.compile_record("t_lower_only")
 
 
+@pytest.mark.parametrize("asked,options", [
+    ({}, None),
+    ({"xla_cpu_enable_fast_min_max": True},
+     "xla_cpu_enable_fast_min_max=True"),
+    ({"no_such_option_of_any_compiler": 1},
+     "no_such_option_of_any_compiler=1"),
+], ids=["nothing asked", "an option", "an option the compiler refuses"])
+def test_the_traced_code_asks_the_compile_for_an_option(asked, options):
+    """`trace_compile_option` from the traced body reaches
+    `lowered.compile(compiler_options=...)` of the first call and the
+    family's record; a compile that raises on it falls back to plain
+    jit dispatch, as on any other compile error; outside a first call it
+    is dropped."""
+    seen = []
+
+    def body(x):
+        for key, value in asked.items():
+            perf.trace_compile_option(key, value)
+        return x * 3.0
+
+    jitted = jax.jit(body)
+
+    class Spy:                      # no `.trace`: lowered in one call
+        __call__ = staticmethod(jitted)
+
+        def lower(self, *args):
+            lowered = jitted.lower(*args)
+            compile_ = lowered.compile
+
+            def compile(**kw):
+                seen.append(kw)
+                return compile_(**kw)
+
+            lowered.compile = compile
+            return lowered
+
+    family = f"t_option_{len(asked)}_{options}"
+    perf._FAMILY_COMPILE.pop(family, None)
+    fn = perf.CompileTimed(Spy(), family)
+    assert np.allclose(np.asarray(fn(jnp.ones((2,)))), 3.0)
+    assert seen == [{"compiler_options": asked} if asked else {}]
+    rec = perf.compile_record(family)
+    assert rec.get("compile_options") == options
+    assert (fn.expected is None) == ("no_such" in str(options))
+    perf.trace_compile_option("dropped", 1)     # nobody is tracing
+    assert perf._TRACE_NOTES.options is None
+
+
 def test_a_phase_opened_inside_itself_counts_its_seconds_once():
     perf._SETUP.pop("t.nested", None)
     with perf.setup_phase("t.nested") as outer:
