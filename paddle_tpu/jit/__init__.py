@@ -335,12 +335,19 @@ class TrainStep:
     With `has_aux` the loss function returns (loss, aux): aux (arrays
     the same program makes, not differentiated: an expert layer's load
     counts) is `step.aux` after each call, to be read with the loss.
+
+    `mesh`, `shard_param(name, shape) -> PartitionSpec` and `shard_data`
+    (the batch's PartitionSpec) lay the step over several devices.
+    `expert_axis` names the mesh axis the experts lie on
+    (`models.shard_plans.expert_parallel_rules`): the expert layers then
+    run their exchange over it and the fused head loss takes the
+    vocabulary in its slices (`core.mesh_plan`).
     """
 
     @_pf.setup_phase("build.train_step")
     def __init__(self, model: Layer, optimizer, loss_fn: Callable = None,
                  has_aux=False, donate=True, mesh=None, shard_param=None,
-                 shard_data=None):
+                 shard_data=None, expert_axis=None):
         self.model = model
         self.loss_fn = loss_fn
         self.optimizer = optimizer
@@ -429,7 +436,7 @@ class TrainStep:
             batch_axes = (() if lead is None else
                           lead if isinstance(lead, tuple) else (lead,))
             self._kernel_plan = functools.partial(
-                mesh_plan, self.mesh, batch_axes)
+                mesh_plan, self.mesh, batch_axes, expert_axis)
         self._donate = donate
         # numerics plane: trainable-param names + optimizer group
         # labels for the packed stats bundle (computed once — the

@@ -1624,7 +1624,7 @@ def _planned_specs(plan, q_shape, k_shape):
     """PartitionSpecs (q/k/v/out [b, s, h, d], segment ids [b, s]) that
     split the kernel over the plan's mesh."""
     from jax.sharding import PartitionSpec as P
-    mesh, batch_axes = plan
+    mesh, batch_axes = plan[:2]
     b, sq, h, d = q_shape
     hk = k_shape[2]
     batch_axes = tuple(a for a in batch_axes if mesh.shape[a] > 1)

@@ -1,5 +1,5 @@
 """Flagship model families (GPT / LLaMA / Jamba / Laguna / ZAYA1 /
-Qwen3-Next / Ouro / DeepSeek-V2 / BERT).
+Qwen3-Next / Ouro / DeepSeek-V2 / Mellum2 / BERT).
 
 The reference keeps language models out-of-tree (PaddleNLP) but its
 north-star benchmarks are GPT-3/LLaMA hybrid-parallel training
@@ -41,14 +41,19 @@ from .bert import (  # noqa: F401
 from .generation import generate  # noqa: F401
 
 
+_MELLUM2 = ("Mellum2Config", "Mellum2ForCausalLM", "mellum2_tiny")
 _DEEPSEEK_V2 = ("DeepseekV2Config", "DeepseekV2Model", "DeepseekV2ForCausalLM",
                 "DeepseekV2DecoderLayer", "DeepseekV2PretrainingCriterion",
                 "deepseek_v2_tiny")
 
 
 def __getattr__(name):
-    """`models.deepseek_v2`'s names, imported when first asked for: a
-    program that builds another family pays nothing for this one."""
+    """`models.deepseek_v2`'s and `models.mellum2`'s names, imported when
+    first asked for: a program that builds another family pays nothing
+    for these."""
+    if name in _MELLUM2:
+        from . import mellum2
+        return getattr(mellum2, name)
     if name in _DEEPSEEK_V2:
         from . import deepseek_v2
         return getattr(deepseek_v2, name)

@@ -95,6 +95,9 @@ class LagunaConfig:
     moe_routed_scaling_factor: float = 2.5
     # what the config.json leaves to the model type's code
     initializer_range: float = 0.02
+    # the router's scores: "sigmoid" (this model type's), or "softmax"
+    # over all the experts (the `mellum` model type, models/mellum2.py)
+    router_score: str = "sigmoid"
     # the expert-parallel share: (first, count) of num_experts, None = all
     experts_held: tuple = None
     # this program's choices
@@ -222,7 +225,8 @@ class LagunaDecoderLayer(Layer):
                 held=tuple(config.experts_held),
                 shared_width=config.shared_expert_intermediate_size,
                 routed_scale=config.moe_routed_scaling_factor,
-                std=config.initializer_range)
+                std=config.initializer_range,
+                router_score=config.router_score)
         else:
             self.mlp = SwiGLU(h, config.intermediate_size,
                               config.initializer_range)

@@ -10,7 +10,7 @@ from .. import ops
 from ..amp import auto_cast
 from ..amp.state import maybe_cast_inputs
 from ..autograd import tape
-from ..core.mesh_plan import current_mesh_plan
+from ..core.mesh_plan import current_mesh_plan, expert_axis_plan
 from ..core.tensor import DeferredTensor, Tensor
 from ..nn.layer import traced_scope, _TRACING
 from ..observability import perf
@@ -43,9 +43,10 @@ def lm_logits(hidden, tied_weight, head=None):
 def deferred_logits(training, hidden, tied_weight, head=None):
     """The logits [*hidden.shape[:-1], vocab] as a promise, where the
     program is such that the criterion can do without them: traced for
-    training with jax's own autodiff (no tape), on one device (under a
-    mesh the tied embedding is sharded and the whole product is the path
-    that is tested there), and nothing hooked onto an untied head. Else
+    training with jax's own autodiff (no tape), on one device or under a
+    mesh plan whose expert axis the vocabulary lies over (under any other
+    mesh the whole product is the path that is tested), and nothing
+    hooked onto an untied head. Else
     None. `hidden` may carry a leading axis of passes: the promise is
     then one for all of them, and a criterion settles every pass's rows
     in one `head_cross_entropy`."""
@@ -54,7 +55,9 @@ def deferred_logits(training, hidden, tied_weight, head=None):
             and (head is None or not (head._forward_pre_hooks
                                       or head._forward_post_hooks))):
         return None
-    if current_mesh_plan() is not None:     # TrainStep's, under a mesh
+    # TrainStep's, under a mesh: the fused loss takes a vocabulary that
+    # lies over the plan's expert axis in slices, and no other layout
+    if current_mesh_plan() is not None and expert_axis_plan() is None:
         perf.trace_note("head_loss", "whole")
         return None
     w = tied_weight if head is None else head.weight
@@ -100,10 +103,19 @@ def head_cross_entropy(head: _Head, labels, weight=None, with_rows=False):
     note = f"fused, chunks {chunks}"
     if head.hidden.ndim > 3:    # the rows of several passes
         note += f", rows {n}"
+    plan = expert_axis_plan()
+    if plan is not None:
+        mesh, axis, slices = plan
+        if vocab % slices or n % slices:
+            raise ValueError(
+                f"head_cross_entropy: {vocab} vocabulary rows and {n} rows "
+                f"do not divide over the {slices} devices of {axis!r}")
+        note += f", vocabulary in {slices} slices of {vocab // slices}"
     perf.trace_note("head_loss", note)
     if weight is not None:
         weight = ops.reshape(weight, (n,))
     with auto_cast(enable=False), traced_scope("lm_head"):
         return ops.linear_cross_entropy(
             hidden, head.weight, ops.reshape(labels, (n,)), weight,
-            transpose_y=head.transpose_y, chunk=chunk, with_rows=with_rows)
+            transpose_y=head.transpose_y, chunk=chunk, with_rows=with_rows,
+            over=plan and plan[:2])

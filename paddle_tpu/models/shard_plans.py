@@ -53,3 +53,23 @@ def fsdp_rules(name: str, shape) -> P:
     spec = [None] * len(shape)
     spec[big] = "dp"
     return P(*spec)
+
+
+def expert_parallel_rules(axis: str = "ep"):
+    """-> the `shard_param(name, shape)` of an expert-parallel layout
+    over the mesh axis `axis` (`TrainStep(mesh=, shard_param=,
+    expert_axis=axis)`): the stacked experts' leading dimension
+    (`...gate_up_proj`, `...down_proj`, three-dimensional), the
+    embedding's vocabulary rows and the head's vocabulary columns lie on
+    the axis; attention, routers and norms are whole on every device,
+    which runs them on its own rows of the batch (data parallel: the
+    partitioner sums their gradients)."""
+    def rule(name: str, shape) -> P:
+        if len(shape) == 3 and name.endswith(("gate_up_proj", "down_proj")):
+            return P(axis)
+        if "embed_tokens" in name:
+            return P(axis, None)
+        if name.endswith("lm_head.weight"):
+            return P(None, axis)
+        return P()
+    return rule

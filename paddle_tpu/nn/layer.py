@@ -74,15 +74,27 @@ class LazyGuard:
     type (zeros), not draws: for a model whose weights are loaded next
     (`set_state_dict`, a harness's arrays). A draw that is thrown away
     costs a compile a shape on a cold TPU (30 s at 45 leaves of 1 B
-    parameters). ref: python/paddle/lazy_init.py (LazyGuard)."""
+    parameters). ref: python/paddle/lazy_init.py (LazyGuard).
+
+    `place(shape) -> a jax sharding or None`: where a placeholder of
+    that shape is made. A model that only fits laid over a mesh is built
+    so, shard by shard: its leaves whole would all sit on the default
+    device."""
     on = False
+    place = None
+
+    def __init__(self, place=None):
+        self._place = place
 
     def __enter__(self):
+        self._placed, LazyGuard.place = LazyGuard.place, self._place
         self._before, LazyGuard.on = LazyGuard.on, True
         return self
 
     def __exit__(self, *exc):
-        LazyGuard.on = self._before
+        LazyGuard.on, LazyGuard.place = self._before, self._placed
+
+
 _NO_SCOPE = contextlib.nullcontext()
 
 
@@ -257,8 +269,12 @@ class Layer:
             init = Constant(0.0) if is_bias else XavierUniform()
         with _pf.setup_phase("build.params") as phase:
             shape = tuple(int(s) for s in shape)
-            data = jnp.zeros(shape, dtypes.to_jnp(dtype)) if LazyGuard.on \
-                else init(shape, dtypes.to_jnp(dtype))
+            if LazyGuard.on:
+                data = jnp.zeros(
+                    shape, dtypes.to_jnp(dtype),
+                    device=LazyGuard.place and LazyGuard.place(shape))
+            else:
+                data = init(shape, dtypes.to_jnp(dtype))
             phase.count(params=1, bytes=int(getattr(data, "nbytes", 0)))
         p = Parameter(data, name=name)
         return p

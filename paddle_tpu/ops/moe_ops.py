@@ -24,9 +24,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..core.mesh_plan import expert_axis_plan
 from ..kernels.pallas import grouped_matmul as _gm
 from ..kernels.pallas import moe_sum_rows as _sr
 from ..kernels.pallas.flash_attention import _pallas_available
+from ..observability import perf as _pf
 from .registry import register_op
 
 __all__ = ["moe_route", "moe_route_mlp", "moe_sequence_balance",
@@ -210,6 +212,24 @@ def permutation(experts, first, count, tile=None):
     return p
 
 
+def _buffer(shape, dtype, rows_used):
+    """An uninitialised [R, ...] buffer for a loop over the used prefix to
+    fill. On one device `jax.lax.empty`, as ever. Under a mesh plan's
+    expert axis the buffer is sized for EVERY device's assignments, and
+    the TPU compiler, for which an allocation without operands is ready
+    at once, hoists it far ahead of its loop: the allocations of the
+    layers still to come, live beside the one at work, were 6 GiB of
+    the 11.2 GiB of temporaries a chip's `mellum2-12b-l4` step needed
+    (PERF.md, PR 49). There it is the result of a conditional on
+    `rows_used` (always the branch that allocates and writes nothing),
+    which cannot run before the permutation that gives `rows_used`."""
+    if expert_axis_plan() is None:
+        return jax.lax.empty(shape, dtype)
+    return jax.lax.cond(rows_used >= 0,
+                        lambda: jax.lax.empty(shape, dtype),
+                        lambda: jnp.zeros(shape, dtype))
+
+
 def _over_chunks(rows_used, body, init):
     """body(lo, carry) over the used prefix, `_CHUNK` rows at a time."""
     n = (rows_used + _CHUNK - 1) // _CHUNK
@@ -228,7 +248,7 @@ def _put(buf, chunk, lo):
 def _gather_rows(x, index, rows_used):
     """out[r] = x[index[r]] for r in the used prefix; the rest of out
     [R, d] is unspecified."""
-    out = jax.lax.empty((index.shape[0], x.shape[1]), x.dtype)
+    out = _buffer((index.shape[0], x.shape[1]), x.dtype, rows_used)
     return _over_chunks(
         rows_used, lambda lo, out: _put(out, x[_rows(index, lo)], lo), out)
 
@@ -331,7 +351,7 @@ def _combine_bwd(way, res, dy):
     # a row's weight gradient is read by its slot: zero past the prefix
     d_ys, d_w_row = _over_chunks(
         p["rows_used"], body,
-        (jax.lax.empty(ys.shape, ys.dtype),
+        (_buffer(ys.shape, ys.dtype, p["rows_used"]),
          jnp.zeros(p["live_row"].shape, F32)))
     d_w = jnp.where(p["held_slot"], d_w_row[p["row_of_slot"]], 0)
     return d_ys, d_w.reshape(weights.shape), None
@@ -355,7 +375,8 @@ def _swiglu(gate_up, rows_used):
         return _put(out, (jax.nn.silu(gu[:, :w]) * gu[:, w:]
                           ).astype(out.dtype), lo)
     return _over_chunks(rows_used, body,
-                        jax.lax.empty((gate_up.shape[0], w), gate_up.dtype))
+                        _buffer((gate_up.shape[0], w), gate_up.dtype,
+                                rows_used))
 
 
 def _swiglu_fwd(gate_up, rows_used):
@@ -375,10 +396,130 @@ def _swiglu_bwd(res, dh):
         return _put(out, jnp.concatenate([dg, d * g * sig], axis=1
                                          ).astype(out.dtype), lo)
     return (_over_chunks(rows_used, body,
-                         jax.lax.empty(gate_up.shape, gate_up.dtype)), None)
+                         _buffer(gate_up.shape, gate_up.dtype, rows_used)),
+            None)
 
 
 swiglu_rows.defvjp(_swiglu_fwd, _swiglu_bwd)
+
+
+def _held_part(xs, weights, experts, w_gate_up, w_down, first, interpret,
+               lean=False):
+    """`moe_experts` on operands already in the products' type: the part
+    of the routed sum that the experts first .. first + count give
+    (`first` may be traced: a device's rank times its count). `lean`:
+    the rows in the experts' order are not kept for the backward pass
+    but gathered again there (`_rows_product`)."""
+    dt = xs.dtype
+    count = w_gate_up.shape[0]
+    T, k = experts.shape
+    way = None
+    if interpret or way_back_reads_held_rows_only():
+        way = (_sr.token_tile(T, k, count, xs.shape[1], dt), bool(interpret))
+    with jax.named_scope("permute"):
+        p = permutation(experts, first, count, way and way[0])
+        used = p["rows_used"]
+        if not lean:
+            rows = take_rows(xs, p, k, way)
+    with jax.named_scope("experts"):
+        if lean:
+            gate_up = _rows_product(xs, w_gate_up.astype(dt), p, k, way,
+                                    interpret)
+        else:
+            gate_up = _gm.gmm(rows, w_gate_up.astype(dt), p["counts"],
+                              interpret=interpret)
+        h = swiglu_rows(gate_up, used)
+        ys = _gm.gmm(h, w_down.astype(dt), p["counts"], interpret=interpret)
+    with jax.named_scope("combine"):
+        y = combine_rows(ys, weights.astype(F32), p, way)
+    return y, p["counts"]
+
+
+def _rows_product(xs, w, p, k, way, interpret):
+    """gmm(take_rows(xs), w) that keeps its product for the backward pass
+    and not its rows: a `jax.checkpoint` that saves the product by name,
+    so the backward gathers the rows again (one more pass over them, no
+    matmul run twice: the kernel's second product is unused and goes).
+    Under the exchange the rows' buffer is sized for every device's
+    assignments at once (1.13 GiB at 4 x 8192 tokens of 2304): kept, the
+    step of `mellum2-12b-l4` needs 1.7 GiB more of a chip (12.2 GiB
+    against 10.5) and reads over the fit guard of
+    `tests/test_tpu_aot_compile.py`."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    def product(xs, w):
+        with jax.named_scope("permute"):
+            rows = take_rows(xs, p, k, way)
+        return checkpoint_name(
+            _gm.gmm(rows, w, p["counts"], interpret=interpret),
+            "moe_gate_up")
+
+    return jax.checkpoint(
+        product, policy=jax.checkpoint_policies.save_only_these_names(
+            "moe_gate_up"))(xs, w)
+
+
+def exchange_bytes(tokens: int, k: int, d: int, itemsize: int, n: int):
+    """What one device sends in one forward of the exchanged layer, in
+    bytes: (the way out, the way back). Out, its `tokens` rows of `d`
+    values with their k weights and k experts (4 bytes each) go to the
+    n - 1 other devices; back, it sends each of them their rows of its
+    partial sums."""
+    row = d * itemsize
+    return ((n - 1) * tokens * (row + 8 * k), (n - 1) * tokens * row)
+
+
+def _exchanged(plan, xs, weights, experts, w_gate_up, w_down, interpret):
+    """The whole routed sum with the experts laid over a mesh axis of n
+    devices, `num_experts / n` a device, for tokens whose rows are split
+    over the same axis: every device's rows, weights and choices are
+    gathered on all (the way out), each device runs `_held_part` for its
+    own experts on all n x T of them, and the partial sums are summed
+    over the axis and scattered to the rows' owners (the way back). A
+    token's row travels to n - 1 devices and n - 1 partial sums of it
+    travel back, whatever it chose: dropless, static shapes, and the
+    permutation and kernels of one device unchanged. Differentiated, the
+    two collectives change places. counts come out over all
+    `num_experts`, of all the axis's tokens."""
+    from jax.sharding import PartitionSpec as P
+    from ..observability import comms
+    mesh, axis, n = plan
+    num_experts = w_gate_up.shape[0]
+    if num_experts % n or xs.shape[0] % n:
+        raise ValueError(
+            f"moe_experts: {num_experts} experts and {xs.shape[0]} tokens "
+            f"do not divide over the {n} devices of axis {axis!r}")
+    held = num_experts // n
+    T, k = experts.shape[0] // n, experts.shape[1]
+    row = xs.shape[1] * xs.dtype.itemsize
+    # a rank's own message, as `comms` counts: its rows, weights and
+    # choices out; all n x T rows of its partial sums back
+    comms.count("all_gather", axis, T * (row + 8 * k), n=3)
+    comms.count("reduce_scatter", axis, n * T * row)
+    out_b, back_b = exchange_bytes(T, k, xs.shape[1], xs.dtype.itemsize, n)
+    _pf.trace_note(
+        "moe_exchange",
+        f"gather and reduce-scatter over {axis!r}: {n} devices, {held} "
+        f"experts each, {T} rows of {xs.shape[1]} {xs.dtype.name} a device, "
+        f"a forward sends {out_b} B out and {back_b} B back")
+
+    def on_device(xs, weights, experts, w_gate_up, w_down):
+        with jax.named_scope("exchange_out"):
+            xs, weights, experts = (
+                jax.lax.all_gather(a, axis, axis=0, tiled=True)
+                for a in (xs, weights, experts))
+        y, counts = _held_part(xs, weights, experts, w_gate_up, w_down,
+                               jax.lax.axis_index(axis) * held, interpret,
+                               lean=True)
+        with jax.named_scope("exchange_back"):
+            y = jax.lax.psum_scatter(y, axis, scatter_dimension=0,
+                                     tiled=True)
+        return y, counts
+
+    lead = P(axis)
+    return jax.shard_map(on_device, mesh=mesh, in_specs=(lead,) * 5,
+                         out_specs=(lead, lead), check_vma=False)(
+        xs, weights, experts, w_gate_up, w_down)
 
 
 @register_op("moe_experts", amp_policy="keep")
@@ -395,25 +536,24 @@ def moe_experts(x, weights, experts, w_gate_up, w_down, first=0,
                weights[t, j] * SwiGLU_{experts[t, j]}(x[t])
 
     Under amp the products take bf16 operands (cast here: the op keeps
-    its routing weights float32) and accumulate in float32."""
+    its routing weights float32) and accumulate in float32.
+
+    Traced under a `mesh_plan` with an `expert_axis`, the weights are all
+    the experts, laid over that axis, and the op runs their exchange
+    (`_exchanged`): y is the whole routed sum and counts is over all the
+    experts."""
     from ..amp.state import amp_state
     st = amp_state()
     dt = st.dtype.np_dtype if st.enabled else x.dtype
     xs = x.astype(dt)
-    count = w_gate_up.shape[0]
-    T, k = experts.shape
-    way = None
-    if interpret or way_back_reads_held_rows_only():
-        way = (_sr.token_tile(T, k, count, x.shape[1], dt), bool(interpret))
-    with jax.named_scope("permute"):
-        p = permutation(experts, first, count, way and way[0])
-        used = p["rows_used"]
-        rows = take_rows(xs, p, k, way)
-    with jax.named_scope("experts"):
-        gate_up = _gm.gmm(rows, w_gate_up.astype(dt), p["counts"],
-                          interpret=interpret)
-        h = swiglu_rows(gate_up, used)
-        ys = _gm.gmm(h, w_down.astype(dt), p["counts"], interpret=interpret)
-    with jax.named_scope("combine"):
-        y = combine_rows(ys, weights.astype(F32), p, way)
-    return y.astype(x.dtype), p["counts"]
+    plan = expert_axis_plan()
+    if plan is not None:
+        if first:
+            raise ValueError("moe_experts: under an expert axis the layer "
+                             "holds all its experts, from the first")
+        y, counts = _exchanged(plan, xs, weights, experts, w_gate_up,
+                               w_down, interpret)
+    else:
+        y, counts = _held_part(xs, weights, experts, w_gate_up, w_down,
+                               first, interpret)
+    return y.astype(x.dtype), counts

@@ -772,15 +772,32 @@ def _lce_dot(a, b, contract, out_dtype=None):
     return out.astype(out_dtype or a.dtype)
 
 
-def _lce_chunk(hidden, weight, safe, coef, transpose_y, with_grads):
+def _lce_chunk(hidden, weight, safe, coef, transpose_y, with_grads,
+               axis=None):
     """One chunk. `coef` is each token's weight in the loss, zero where
     its label is ignored. The logits are rounded as `matmul` rounds
-    them; the log-sum-exp and the softmax are float32."""
+    them; the log-sum-exp and the softmax are float32. With `axis`
+    (inside a `shard_map` over it) `weight` is this device's slice of
+    the vocabulary: the maximum and the sum of exponentials are reduced
+    over the axis and the label's logit comes from the device that
+    holds its row."""
     wdim = 1 if transpose_y else 0          # weight's hidden axis
     logits = _lce_dot(hidden, weight, ((1,), (wdim,)))         # [c, v]
     z = logits.astype(jnp.float32)
-    lse = jax.scipy.special.logsumexp(z, axis=-1)
-    picked = jnp.take_along_axis(logits, safe[:, None], axis=-1)[:, 0]
+    if axis is None:
+        lse = jax.scipy.special.logsumexp(z, axis=-1)
+        picked = jnp.take_along_axis(logits, safe[:, None], axis=-1)[:, 0]
+    else:
+        width = z.shape[1]
+        top = jax.lax.pmax(jnp.max(z, axis=-1), axis)
+        lse = top + jnp.log(jax.lax.psum(
+            jnp.sum(jnp.exp(z - top[:, None]), axis=-1), axis))
+        safe = safe - jax.lax.axis_index(axis) * width  # in the slice or not
+        mine = jnp.logical_and(safe >= 0, safe < width)
+        picked = jnp.take_along_axis(
+            logits, jnp.clip(safe, 0, width - 1)[:, None], axis=-1)[:, 0]
+        picked = jax.lax.psum(jnp.where(mine, picked.astype(jnp.float32),
+                                        0.0), axis)
     ce = lse - picked.astype(jnp.float32)
     if not with_grads:
         return ce, None, None
@@ -796,7 +813,7 @@ def _lce_chunk(hidden, weight, safe, coef, transpose_y, with_grads):
 
 
 def _lce_run(hidden, weight, label, token_weight, transpose_y,
-             ignore_index, chunk, with_grads):
+             ignore_index, chunk, with_grads, axis=None):
     n, h = hidden.shape
     k, c = _lce_plan(n, chunk)
     lbl = label.astype(jnp.int32)
@@ -812,7 +829,7 @@ def _lce_run(hidden, weight, label, token_weight, transpose_y,
 
     def body(dw_acc, x):
         ce, dh, dw = _lce_chunk(x[0], weight, x[1], x[2], transpose_y,
-                                with_grads)
+                                with_grads, axis)
         if with_grads:
             dw_acc = dw_acc + dw
         return dw_acc, (ce, dh)
@@ -887,10 +904,73 @@ def _lce_rows_bwd(transpose_y, ignore_index, chunk, res, g):
 _lce_rows.defvjp(_lce_rows_fwd, _lce_rows_bwd)
 
 
+def _lce_sliced_run(hidden, weight, label, token_weight, transpose_y,
+                    ignore_index, chunk, over, with_grads):
+    """`_lce_run` with the vocabulary in slices over a mesh axis (`over`:
+    (mesh, axis)) and the rows split over the same axis: every device
+    gathers all the rows, takes them through its slice of `weight` chunk
+    by chunk (`_lce_chunk` with `axis`), keeps its slice's whole `dW`,
+    and `d hidden`, the sum of every slice's part, is summed over the
+    axis and scattered to the rows' owners. No device holds more than a
+    chunk's rows of its slice's logits."""
+    from jax.sharding import PartitionSpec as P
+    mesh, axis = over
+    rows, whole = P(axis), P()
+    w_spec = P(axis, None) if transpose_y else P(None, axis)
+
+    def on_device(hidden, weight, label, token_weight):
+        hidden, label, token_weight = (
+            jax.lax.all_gather(a, axis, axis=0, tiled=True)
+            for a in (hidden, label, token_weight))
+        loss, (dh, dw, ce) = _lce_run(
+            hidden, weight, label, token_weight, transpose_y, ignore_index,
+            chunk, with_grads, axis)
+        if not with_grads:
+            return loss
+        mine = ce.shape[0] // mesh.shape[axis]
+        return loss, (
+            jax.lax.psum_scatter(dh, axis, scatter_dimension=0, tiled=True),
+            dw, jax.lax.dynamic_slice_in_dim(
+                ce, jax.lax.axis_index(axis) * mine, mine))
+
+    return jax.shard_map(
+        on_device, mesh=mesh, in_specs=(rows, w_spec, rows, rows),
+        out_specs=(whole, (rows, w_spec, rows)) if with_grads else whole,
+        check_vma=False)(hidden, weight, label, token_weight)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _lce_sliced(hidden, weight, label, token_weight, transpose_y,
+                ignore_index, chunk, over):
+    """`_lce` under a mesh that lays the vocabulary over an axis. The
+    `shard_map` is inside the `custom_vjp`, so nothing differentiates
+    through its collectives: the forward makes loss and gradients, the
+    backward scales them."""
+    return _lce_sliced_run(hidden, weight, label, token_weight, transpose_y,
+                           ignore_index, chunk, over, False)
+
+
+def _lce_sliced_fwd(hidden, weight, label, token_weight, transpose_y,
+                    ignore_index, chunk, over):
+    loss, (dh, dw, ce) = _lce_sliced_run(
+        hidden, weight, label, token_weight, transpose_y, ignore_index,
+        chunk, over, True)
+    like = (jnp.zeros((0,), hidden.dtype), jnp.zeros((0,), weight.dtype),
+            jnp.zeros((0,), token_weight.dtype))
+    return loss, (dh, dw, ce, like)
+
+
+def _lce_sliced_bwd(transpose_y, ignore_index, chunk, over, res, g):
+    return _lce_bwd(transpose_y, ignore_index, chunk, res, g)
+
+
+_lce_sliced.defvjp(_lce_sliced_fwd, _lce_sliced_bwd)
+
+
 @register_op("linear_cross_entropy", amp_policy="keep")
 def linear_cross_entropy(hidden, weight, label, token_weight=None,
                          transpose_y=True, ignore_index=-100,
-                         chunk=LCE_CHUNK, with_rows=False):
+                         chunk=LCE_CHUNK, with_rows=False, over=None):
     """sum_i token_weight[i] * cross_entropy(hidden[i] @ W, label[i]):
     the vocabulary projection and the loss in one op, so that no
     [tokens, vocab] array exists. `hidden` [n, h]; `weight` [v, h] (a
@@ -918,10 +998,22 @@ def linear_cross_entropy(hidden, weight, label, token_weight=None,
     expected loss over a learnt distribution, gets the distribution's
     gradient from here). With `with_rows` the op returns (loss, rows):
     every row's cross-entropy [n] float32 beside the sum, for reading
-    (a mean by group); nothing is differentiated through the rows."""
+    (a mean by group); nothing is differentiated through the rows.
+
+    `over=(mesh, axis)`: the program will be partitioned over `mesh`
+    with `weight`'s vocabulary and the rows of `hidden` both split over
+    `axis`; the op then takes the vocabulary in the axis's slices
+    (`_lce_sliced_run`). Without rows."""
     if token_weight is None:
         token_weight = jnp.full((hidden.shape[0],), 1.0 / hidden.shape[0],
                                 jnp.float32)
+    if over is not None:
+        if with_rows:
+            raise NotImplementedError(
+                "linear_cross_entropy: no rows beside a sliced vocabulary")
+        return _lce_sliced(hidden, weight, label, token_weight,
+                           bool(transpose_y), int(ignore_index), int(chunk),
+                           tuple(over))
     run = _lce_rows if with_rows else _lce
     return run(hidden, weight, label, token_weight, bool(transpose_y),
                int(ignore_index), int(chunk))

@@ -7,8 +7,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.mesh_plan import current_mesh_plan
 from ..kernels.pallas import rope as _rope
-from ..kernels.pallas.flash_attention import _pallas_available
+from ..kernels.pallas.flash_attention import (_pallas_available,
+                                                _planned_specs)
 from ..observability import perf as _pf
 from .registry import register_op
 
@@ -96,4 +98,13 @@ def rope_rotate_half(x, cos, sin):
         why or f"{x.shape[2]} heads, rot {rot} of {x.shape[-1]}"))
     if path == "composite":
         return _composite(x, cos, sin)
-    return _turned(x, cos, sin, False)
+    plan = current_mesh_plan()
+    if plan is None:
+        return _turned(x, cos, sin, False)
+    # the compiler cannot split the kernel: rows and heads as flash
+    # takes them (`_planned_specs`), the tables whole on every device
+    from jax.sharding import PartitionSpec as P
+    spec = _planned_specs(plan, x.shape, x.shape)[0]
+    return jax.shard_map(lambda x, cos, sin: _turned(x, cos, sin, False),
+                         mesh=plan[0], in_specs=(spec, P(), P()),
+                         out_specs=spec, check_vma=False)(x, cos, sin)

@@ -3,6 +3,7 @@ adamw,lamb}.py). Update rules are pure jax — reused by both eager step()
 and the jit train-step compiler."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from .optimizer import Optimizer
@@ -87,9 +88,14 @@ class Adam(Optimizer):
     def _init_state(self, p):
         base = self._master(p) if self._master(p) is not None else p._data
         mdt = self._moment_dtype(base)
+        # a moment is born where its parameter lives: one laid over a
+        # mesh has moments in the same shards, never whole on one device
+        laid = getattr(base, "sharding", None)
+        if not isinstance(laid, jax.sharding.NamedSharding):
+            laid = None
         return {
-            "moment1": jnp.zeros(base.shape, mdt),
-            "moment2": jnp.zeros(base.shape, mdt),
+            "moment1": jnp.zeros(base.shape, mdt, device=laid),
+            "moment2": jnp.zeros(base.shape, mdt, device=laid),
             "beta1_pow": jnp.asarray(1.0, jnp.float32),
             "beta2_pow": jnp.asarray(1.0, jnp.float32),
         }

@@ -1313,3 +1313,158 @@ def test_the_deepseek_step_says_which_paths_it_took(deepseek_step):
         "softmax scores, weights as scored, sequence balance term")
     assert notes["rope"].startswith("composite: ")
     assert notes["head_loss"] == "fused, chunks 1"
+
+
+# ---------------------------------------------------------------------------
+# Mellum2-12B-A2.5B: the whole step of `mellum2-12b-l4.train-8k-ep4` over
+# the described 2x2 host as one axis of four: the experts' exchange, the
+# head over the vocabulary's four slices, flash and the rotary split over
+# the rows, every Mosaic kernel inside a `shard_map`
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def mellum2_step(v5e):
+    """The step at the cell's own sizes (4 layers x 2304, 64 experts of
+    896, 98,304 rows, 4 x 8192 tokens; placeholders that cost nothing for
+    weights: nothing runs), written as
+    benchmarks/drivers/mellum2_train_window.py writes it, compiled for
+    the four described chips: (text, notes, memory analysis)."""
+    import json
+    import os
+    import paddle_tpu as pt
+    from paddle_tpu import amp
+    from paddle_tpu.jit import TrainStep
+    from paddle_tpu.models import GPTPretrainingCriterion
+    from paddle_tpu.models.mellum2 import Mellum2Config, Mellum2ForCausalLM
+    from paddle_tpu.models.shard_plans import expert_parallel_rules
+    from paddle_tpu.nn import layer as nn_layer
+    from paddle_tpu.observability import perf
+    from paddle_tpu.optimizer import AdamW, optimizers
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs",
+            "mellum2-12b-l4.json")) as f:
+        cfg = json.load(f)
+    mesh = Mesh(np.array(v5e), ("ep",))
+    rule = expert_parallel_rules("ep")
+    whole = NamedSharding(mesh, P())
+
+    class Free:     # `jnp` whose zeros are a view of one: 2.1 B parameters
+        @staticmethod
+        def zeros(shape, dtype=jnp.float32, device=None):
+            return np.broadcast_to(np.zeros((), dtype), tuple(shape))
+
+        def __getattr__(self, name):
+            return getattr(jnp, name)
+
+    crit = GPTPretrainingCriterion()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("PADDLE_TPU_PALLAS_AUTOTUNE", "0")
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        mp.setattr(nn_layer, "jnp", Free())
+        mp.setattr(optimizers, "jnp", Free())
+        was_on = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        with pt.LazyGuard():
+            model = Mellum2ForCausalLM(Mellum2Config.from_dict(
+                cfg, use_flash_attention=True, recompute=True))
+        model.train()
+
+        def loss_fn(m, ids, labels):
+            with amp.auto_cast(enable=True, level="O1", dtype="bfloat16"):
+                logits = m(ids)
+            return crit(logits, labels), m.expert_counts
+
+        step = TrainStep(model, AdamW(
+            learning_rate=1e-4, parameters=model.parameters(),
+            weight_decay=0.01, moment_dtype="bfloat16"), loss_fn,
+            has_aux=True)
+
+        def spec(x, sharding):
+            return jax.ShapeDtypeStruct(np.shape(x), np.result_type(x),
+                                        sharding=sharding)
+
+        def laid(name, p):
+            return NamedSharding(mesh, rule(name, tuple(p.shape)))
+
+        params = [spec(p, laid(n, p))
+                  for n, p in zip(step._pnames, step.params)]
+        states = [{k: spec(v, laid(n, p) if np.ndim(v) else whole)
+                   for k, v in st.items()}
+                  for n, p, st in zip(step._pnames, step.params,
+                                      step.opt_states)]
+        ids = jax.ShapeDtypeStruct((4, 8192), jnp.int32,
+                                   sharding=NamedSharding(mesh, P("ep", None)))
+        notes, options = {}, {}
+        th = perf._TRACE_NOTES
+        outer = th.notes, th.options
+        th.notes, th.options = notes, options
+        try:
+            with mesh_plan(mesh, ("ep",), "ep"):
+                compiled = step._step_fn.jit_fn.lower(
+                    params, states, [], spec(jax.random.PRNGKey(0), whole),
+                    spec(jnp.float32(1e-4), whole), [ids, ids], {}).compile(
+                    compiler_options=options)
+        finally:
+            th.notes, th.options = outer
+            jax.config.update("jax_enable_compilation_cache", was_on)
+            compilation_cache.reset_cache()
+    return compiled.as_text(), notes, compiled.memory_analysis()
+
+
+@pytest.mark.parametrize("kernel,calls", [
+    # a layer once, a window layer again (the full layer keeps o and lse)
+    ("flash_fwd", 7), ("flash_bwd_transpose", 4),
+    ("rope_rotate", 24),    # a layer's q and k: forward, again, back
+    # a layer's two products forward, again, and to the rows: the rows are
+    # gathered a third time for the weights' gradient (`_rows_product`)
+    # and no product is made a third time
+    ("moe_gmm", 24), ("moe_gmm_dw", 8),
+    ("moe_sum_rows", 8)])   # the way back: the sums forward, the rows' back
+def test_the_mellum2_step_holds_its_mosaic_kernels(mellum2_step, kernel,
+                                                   calls):
+    text, _notes, _memory = mellum2_step
+    found = re.findall(rf"%{kernel}[.\d]* = .*custom-call\(", text)
+    assert len(found) == calls, (kernel, len(found))
+
+
+def test_the_mellum2_step_exchanges_and_fits_the_chip(mellum2_step):
+    text, notes, memory = mellum2_step
+    # a chip's arguments: 595,153,152 parameters at 8 B
+    arguments = memory.argument_size_in_bytes
+    assert arguments == pytest.approx(8 * 595153152, rel=1e-3)
+    total = (arguments + memory.output_size_in_bytes
+             + memory.temp_size_in_bytes - memory.alias_size_in_bytes)
+    # a v5e holds 16 GiB; the cell's floor is a quarter of it
+    assert 0.25 * 2 ** 34 < total < 0.95 * 2 ** 34, total
+    # the rows go out whole (32768 x 2304 bfloat16 gathered) in every
+    # layer's forward and again, the cotangent's rows in its backward
+    gathered = re.findall(r"= bf16\[(?:1,)?32768,2304\]\S* all-gather\(.*"
+                          r"layers/(\d)/moe/shard_map/(exchange_\w+)/", text)
+    assert sorted(gathered) == sorted(
+        [(str(i), "exchange_out") for i in range(4)] * 2
+        + [(str(i), "exchange_back") for i in range(4)])
+    # no whole logits, no whole head: a chunk of a slice at most
+    assert not re.search(r"\[\d*,?32768,98304\]|\[2304,98304\]", text)
+    assert re.search(r"bf16\[4096,24576\]", text)
+    for scope in ("layers/3/moe/shard_map/exchange_out/",
+                  "layers/0/moe/shard_map/permute/",
+                  "layers/2/attn/shard_map/flash_fwd",
+                  "layers/1/attn/rope/shard_map/",
+                  "lm_head/shard_map/while/body"):
+        assert scope in text, scope
+    assert notes["moe_exchange"] == (
+        "gather and reduce-scatter over 'ep': 4 devices, 16 experts each, "
+        "8192 rows of 2304 bfloat16 a device, a forward sends 114819072 B "
+        "out and 113246208 B back")
+    assert notes["head_loss"] == \
+        "fused, chunks 8, vocabulary in 4 slices of 24576"
+    assert notes["attention"] == "pallas"
+    assert notes["attention_window"] == \
+        "layer 0: 1024; layer 1: 1024; layer 2: 1024"
+    assert notes["rope"] == ("rope_rotate: 32 heads, rot 128 of 128; "
+                             "rope_rotate: 4 heads, rot 128 of 128")
+    assert notes["moe"].startswith(
+        "pallas, experts 64 held of 64, top 8, tiles of 128 rows, weight "
+        "blocks in column tiles: gate_up 896 x 2, down 2304 x 1, ")
+    assert notes["moe"].endswith("softmax scores")
